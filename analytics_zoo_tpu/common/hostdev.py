@@ -1,12 +1,13 @@
-"""Forced multi-device CPU host topology (re-exec helpers).
+"""Host/device topology decisions made from the environment, never by
+initialising a jax backend: a process that touches jax on a chip host
+takes the chip, and a child that needs it then fails or hangs.
 
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` must be set
 BEFORE jax initializes its backends — too late for any code that runs
 after ``import jax``. Every place that needs a guaranteed N-device CPU
-host therefore re-execs itself into a subprocess carrying the flag:
-``attn_smoke`` hand-rolled the pattern first, the ``zero-smoke`` CLI
-and the ``multi_device_cpu`` test fixture need the same thing, so the
-one canonical copy lives here.
+host therefore re-execs itself into a subprocess carrying the flag
+(``attn-smoke``, ``zero-smoke``, the ``multi_device_cpu`` test fixture);
+the one canonical copy of that pattern lives here.
 
 ``ZOO_HOSTDEV_CHILD=1`` marks the child (re-exec exactly once: a child
 whose topology still comes up short must fail loudly, not fork-bomb).
@@ -15,31 +16,44 @@ whose topology still comes up short must fail loudly, not fork-bomb).
 from __future__ import annotations
 
 import os
+import re
 import subprocess
 import sys
-from typing import Dict, Optional, Sequence
+from typing import Dict, Mapping, Optional, Sequence
 
 CHILD_ENV = "ZOO_HOSTDEV_CHILD"
+
+_COUNT_FLAG = re.compile(r"--xla_force_host_platform_device_count=(\d+)")
+
+
+def env_cpu_devices(env: Optional[Mapping[str, str]] = None) -> int:
+    """How many CPU devices ``env`` guarantees a jax process: the forced
+    host device count when ``JAX_PLATFORMS`` is exactly ``cpu``, else 0
+    (any other setting may resolve to an accelerator)."""
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS") != "cpu":
+        return 0
+    m = _COUNT_FLAG.search(env.get("XLA_FLAGS", ""))
+    return int(m.group(1)) if m else 1
 
 
 def cpu_device_env(n: int, base: Optional[Dict[str, str]] = None) \
         -> Dict[str, str]:
     """Environment for a subprocess pinned to an ``n``-device CPU host
-    platform: forces the CPU backend, adds the device-count flag unless
-    one is already present, and marks the child."""
+    platform: forces the CPU backend whatever the parent's setting, sets
+    the device-count flag to at least ``n``, and marks the child."""
     env = dict(os.environ if base is None else base)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    if "host_platform_device_count" not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (
-            env.get("XLA_FLAGS", "") +
-            f" --xla_force_host_platform_device_count={n}").strip()
+    env["JAX_PLATFORMS"] = "cpu"
+    flags = env.get("XLA_FLAGS", "")
+    m = _COUNT_FLAG.search(flags)
+    if m is None:
+        flags = f"{flags} --xla_force_host_platform_device_count={n}"
+    elif int(m.group(1)) < n:
+        flags = _COUNT_FLAG.sub(
+            f"--xla_force_host_platform_device_count={n}", flags)
+    env["XLA_FLAGS"] = flags.strip()
     env[CHILD_ENV] = "1"
     return env
-
-
-def have_devices(n: int) -> bool:
-    import jax
-    return len(jax.devices()) >= n
 
 
 def reexec_module(module: str, n: int,
@@ -47,10 +61,10 @@ def reexec_module(module: str, n: int,
     """Re-exec ``python -m module argv...`` pinned to ``n`` CPU devices.
 
     Returns ``None`` when the caller should just proceed inline — the
-    process already has ``n`` devices, or IS the re-exec child (short
-    topology in the child is then the caller's own loud failure).
-    Otherwise runs the child and returns its exit code."""
-    if os.environ.get(CHILD_ENV) == "1" or have_devices(n):
+    environment already pins it to ``n`` CPU devices, or it IS the
+    re-exec child (short topology in the child is then the caller's own
+    loud failure). Otherwise runs the child and returns its exit code."""
+    if os.environ.get(CHILD_ENV) == "1" or env_cpu_devices() >= n:
         return None
     return subprocess.run(
         [sys.executable, "-m", module] +
@@ -64,3 +78,21 @@ def reexec_pytest(nodeid: str, n: int, timeout: float = 900) -> int:
     return subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-x", nodeid],
         env=cpu_device_env(n), timeout=timeout).returncode
+
+
+def require_cpu_workers(n_workers: int, env: Mapping[str, str],
+                        what: str) -> None:
+    """Refuse to start more than one jax worker process on this host
+    unless their environment pins them to the CPU backend.
+
+    Nothing assigns a worker its own device yet (no ``TPU_VISIBLE_*`` /
+    ``local_device_ids`` plumbing; ROADMAP R7), so on a chip host every
+    worker would open the same accelerator: the first takes it and the
+    second fails, hangs, or lands on the CPU unannounced."""
+    if n_workers > 1 and env.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            f"{what}: {n_workers} worker processes on one host with "
+            f"JAX_PLATFORMS={env.get('JAX_PLATFORMS')!r}. A chip belongs "
+            f"to one process at a time and workers are not pinned to "
+            f"devices yet, so the second worker could not get one. Run "
+            f"multi-worker flows with JAX_PLATFORMS=cpu.")

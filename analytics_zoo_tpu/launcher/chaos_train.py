@@ -39,10 +39,6 @@ def state_digest(trainer) -> str:
 def main() -> int:
     ckpt_dir = sys.argv[1]
     steps = int(sys.argv[2]) if len(sys.argv) > 2 else 12
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from analytics_zoo_tpu.common.nncontext import (ZooConfig,

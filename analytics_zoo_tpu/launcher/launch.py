@@ -43,6 +43,7 @@ import threading
 import time
 from typing import Dict, IO, List, NamedTuple, Optional, Sequence
 
+from ..common.hostdev import require_cpu_workers
 from ..utils import telemetry
 from .supervisor import (inject_pythonpath, pump_lines, spawn_supervised,
                          terminate_all)
@@ -169,6 +170,11 @@ def launch(script_argv: Sequence[str], num_hosts: Optional[int] = None,
         world = num_hosts if num_hosts is not None else 1
     if world < 1:
         raise LaunchError(f"need >= 1 worker, got {world}")
+    try:
+        require_cpu_workers(world, {**os.environ, **(env or {})},
+                            "zoo-launch")
+    except RuntimeError as e:
+        raise LaunchError(str(e)) from None
     stream = stream if stream is not None else sys.stdout
     python = python or sys.executable
     base_env = dict(os.environ)

@@ -22,9 +22,6 @@ def main() -> int:
     uri = sys.argv[1]
     batch_size = int(sys.argv[2]) if len(sys.argv) > 2 else 8
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from analytics_zoo_tpu.common.nncontext import ZooConfig, init_nncontext
 
     init_nncontext(ZooConfig(log_every_n_steps=1000))

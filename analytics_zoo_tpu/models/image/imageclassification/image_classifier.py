@@ -124,8 +124,8 @@ def _res_block(x, filters, stride=1, conv_shortcut=False, fmt="th"):
 def _resnet50(class_num, shape=(3, 224, 224), data_format="th"):
     """data_format "tf" builds the NHWC variant (input (224, 224, 3)):
     XLA TPU's native conv layout, so no per-conv relayouts — an on-chip
-    A/B knob for the conv-layout cost of the reference's NCHW ordering
-    (tools/tpu_perf_session.py leg ``resnet_layout``)."""
+    A/B knob for the conv-layout cost of the reference's NCHW
+    ordering."""
     fmt = "tf" if str(data_format).lower() in ("tf", "nhwc", "channels_last") \
         else "th"
     shape = tuple(shape)

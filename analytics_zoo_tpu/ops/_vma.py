@@ -17,10 +17,7 @@ import jax
 
 
 def _vma(x):
-    try:
-        return frozenset(getattr(jax.typeof(x), "vma", ()) or ())
-    except Exception:  # noqa: BLE001 — outside a trace / old jax
-        return frozenset()
+    return frozenset(jax.typeof(x).vma)
 
 
 def psum_grad_like(grad, param, cotangent):
@@ -32,6 +29,17 @@ def psum_grad_like(grad, param, cotangent):
     return jax.lax.psum(grad, extra)
 
 
+def vary_like(x, *like):
+    """Mark a freshly built ``x`` (zeros, constants) as varying over the
+    mesh axes the ``like`` operands vary over. A ``scan``/``fori_loop``
+    carry inside ``shard_map`` must enter the loop with the type it
+    leaves with, and it leaves varying as soon as the body mixes in a
+    sharded operand. No-op outside shard_map."""
+    axes = tuple(sorted(
+        frozenset().union(*[_vma(a) for a in like]) - _vma(x)))
+    return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+
 def out_struct(shape, dtype, *like):
     """``ShapeDtypeStruct`` for a ``pallas_call`` output whose ``vma``
     is the union of the operands' varying axes. Inside ``shard_map``
@@ -40,9 +48,5 @@ def out_struct(shape, dtype, *like):
     "vma on jax.ShapeDtypeStruct must not be None"; a kernel output
     varies over exactly the axes its operands do. No-op outside
     shard_map (empty vma)."""
-    vma = frozenset().union(*[_vma(x) for x in like]) if like \
-        else frozenset()
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:   # older jax without the vma argument
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*[_vma(x) for x in like])
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

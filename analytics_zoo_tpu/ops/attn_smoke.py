@@ -147,7 +147,7 @@ def _check_dp_parity(out):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
     from .attention import attention_reference, flash_attention_blhd
 
     if len(jax.devices()) < 2:

@@ -1,10 +1,9 @@
 """Fused dropout + residual-add + layer-norm (Pallas, TPU).
 
 The transformer block's ``ln(dropout(x) + resid)`` pattern lowers on XLA
-to one fusion per site that the r5 session-3 device trace measured at
-~0.7-1.1 ms each — ~4x off bandwidth-ideal — for 17.6 ms of the 132 ms
-BERT-base b32 L512 step (25 sites). This kernel does the whole pattern
-in one bandwidth-bound pass: read x, resid, and raw uniform bits; mask,
+to one fusion per site (25 sites in a BERT-base step). This kernel does
+the whole pattern in one bandwidth-bound pass: read x, resid, and raw
+uniform bits; mask,
 scale, add, single-pass f32 statistics; write y + per-row (mean, inv).
 Backward saves the normalized input z (not x and resid separately), the
 bits, and the row stats, and emits per-block dgamma/dbeta partials that
@@ -33,11 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ._vma import out_struct, psum_grad_like
+from .attention import _interpret_mode, mosaic_partition_ok
 from .layernorm import layer_norm
-
-
-def _interpret_mode() -> bool:
-    return os.environ.get("ZOO_TPU_PALLAS_INTERPRET", "0") == "1"
 
 
 def _thresh(keep: float) -> np.uint32:
@@ -45,9 +41,23 @@ def _thresh(keep: float) -> np.uint32:
     return np.uint32(min(int(keep * 2.0 ** 32), 2 ** 32 - 1))
 
 
-def _pick_rows(n_rows: int) -> int:
+# Mosaic's scoped-VMEM limit is 16 MB. The backward kernel is the larger
+# of the pair: per element it streams dy, z, dx, dres in the activation
+# dtype plus the uint32 bits, double-buffered, and keeps about two f32
+# temporaries. Measured on a v5e (jax 0.9.0, libtpu 0.0.34): bf16
+# (512, 768) blocks compile; f32 (512, 768) asks for 18.46 MB and bf16
+# (512, 4096) for 48 MB. The estimate below gives 12.6, 18.9 and 67 MB
+# for those three, so a 14 MB budget keeps the first and shrinks the
+# others.
+_VMEM_BUDGET = 14 << 20
+
+
+def _pick_rows(n_rows: int, d: int, itemsize: int) -> int:
+    """Largest row block dividing ``n_rows`` whose backward working set
+    fits the VMEM budget; 0 when none does (the caller routes to XLA)."""
+    per_row = d * (2 * (4 * itemsize + 4) + 8)
     for cand in (512, 256, 128, 64, 32, 16, 8):
-        if n_rows % cand == 0:
+        if n_rows % cand == 0 and cand * per_row <= _VMEM_BUDGET:
             return cand
     return 0
 
@@ -116,8 +126,9 @@ def _dln_forward(x2, r2, bits2, gamma, beta, keep, eps, block_rows):
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     kernel = functools.partial(
         _dln_fwd_kernel, keep=keep, thresh=_thresh(keep), eps=eps, d=d)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="zoo_dln_fwd",
         grid=(nblk,),
         in_specs=[row_spec, row_spec, row_spec, vec_spec, vec_spec],
         out_specs=[row_spec, row_spec, one_spec, one_spec],
@@ -128,7 +139,11 @@ def _dln_forward(x2, r2, bits2, gamma, beta, keep, eps, block_rows):
             out_struct((n, 1), jnp.float32, x2, r2, bits2),
         ],
         interpret=_interpret_mode(),
-    )(x2, r2, bits2, gamma.reshape(1, d), beta.reshape(1, d))
+    )
+    # the zoo_* scope names the kernel in optimized HLO (see
+    # ops/attention.py _flash_forward)
+    with jax.named_scope("zoo_dln_fwd"):
+        return call(x2, r2, bits2, gamma.reshape(1, d), beta.reshape(1, d))
 
 
 def _dln_backward(dy2, z2, bits2, gamma, mean, inv, keep, block_rows):
@@ -142,8 +157,9 @@ def _dln_backward(dy2, z2, bits2, gamma, mean, inv, keep, block_rows):
     part_spec = pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0))
     kernel = functools.partial(
         _dln_bwd_kernel, keep=keep, thresh=_thresh(keep), d=d)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name="zoo_dln_bwd",
         grid=(nblk,),
         in_specs=[row_spec, row_spec, row_spec, vec_spec, one_spec,
                   one_spec],
@@ -155,7 +171,9 @@ def _dln_backward(dy2, z2, bits2, gamma, mean, inv, keep, block_rows):
             out_struct((nblk, 1, d), jnp.float32, dy2, z2, bits2),
         ],
         interpret=_interpret_mode(),
-    )(dy2, z2, bits2, gamma.reshape(1, d), mean, inv)
+    )
+    with jax.named_scope("zoo_dln_bwd"):
+        return call(dy2, z2, bits2, gamma.reshape(1, d), mean, inv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -185,56 +203,8 @@ _dln.defvjp(_dln_fwd_rule, _dln_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
-# probe + public entry
+# public entry
 # ---------------------------------------------------------------------------
-
-_DLN_OK: dict = {}
-
-
-def _kernel_ok(n, d, dtype, keep, block_rows) -> bool:
-    if os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") == "1":
-        return False
-    if _interpret_mode():
-        return True
-    key = (n, d, jnp.dtype(dtype).name, round(keep, 6), block_rows)
-    if key not in _DLN_OK:
-        try:
-            x = jax.ShapeDtypeStruct((n, d), dtype)
-            bits = jax.ShapeDtypeStruct((n, d), jnp.uint32)
-            g = jax.ShapeDtypeStruct((d,), jnp.float32)
-            one = jax.ShapeDtypeStruct((n, 1), jnp.float32)
-            jax.jit(functools.partial(
-                _dln_forward, keep=keep, eps=1e-5,
-                block_rows=block_rows)).lower(x, x, bits, g, g).compile()
-            jax.jit(functools.partial(
-                _dln_backward, keep=keep,
-                block_rows=block_rows)).lower(
-                x, x, bits, g, one, one).compile()
-            _DLN_OK[key] = True
-        except Exception as e:  # noqa: BLE001
-            import logging
-            logging.getLogger("analytics_zoo_tpu.ops").warning(
-                "fused dropout+add+LN kernel unavailable for (N=%d, D=%d,"
-                " %s): %s; using the composed XLA path", n, d, dtype,
-                str(e).splitlines()[0] if str(e) else repr(e))
-            _DLN_OK[key] = False
-    return _DLN_OK[key]
-
-
-def dln_kernel_status() -> str:
-    """Probe-cache summary for measurement harnesses: "interpret" /
-    "unprobed" (kernel never eligible this process) / "ok" / "partial" /
-    "failed" — so a bench record can say whether the fused kernel
-    actually ran instead of leaving a silent fallback ambiguous."""
-    if _interpret_mode():
-        return "interpret"
-    if not _DLN_OK:
-        return "unprobed"
-    oks = list(_DLN_OK.values())
-    if all(oks):
-        return "ok"
-    return "partial" if any(oks) else "failed"
-
 
 def dropout_add_layer_norm(x, resid, gamma, beta, rng, p_drop,
                            training=True, eps=1e-5):
@@ -243,23 +213,24 @@ def dropout_add_layer_norm(x, resid, gamma, beta, rng, p_drop,
     x, resid: (..., D); gamma/beta: (D,). On TPU, training, with
     0 < p_drop < 1 and kernel-legal shapes, runs the Pallas kernel pair
     (dropout mask thresholded from hardware-generated uint32 bits).
-    Everywhere else falls back to the exact pre-existing composition —
+    Everywhere else runs the exact pre-existing composition —
     ``jax.random.bernoulli`` dropout + the fused ``layer_norm`` — so CPU
-    semantics and test streams are unchanged.
+    semantics and test streams are unchanged. The choice is static: a
+    shape routed to the kernel compiles it, or fails the caller's compile
+    with Mosaic's message.
     """
     if not training or rng is None or p_drop <= 0.0:
         return layer_norm(x + resid, gamma, beta, eps)
     keep = 1.0 - float(p_drop)
     d = x.shape[-1]
     n = int(np.prod(x.shape[:-1]))
-    block_rows = _pick_rows(n)
-    from .attention import mosaic_partition_ok
-
+    block_rows = _pick_rows(n, d, jnp.dtype(x.dtype).itemsize)
     on_tpu = jax.default_backend() == "tpu" or _interpret_mode()
-    eligible = (on_tpu and keep < 1.0 and d % 128 == 0 and d <= 4096 and
+    eligible = (on_tpu and keep < 1.0 and d % 128 == 0 and
                 block_rows > 0 and mosaic_partition_ok() and
+                os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") != "1" and
                 os.environ.get("ZOO_TPU_DISABLE_FUSED_DLN", "0") != "1")
-    if eligible and _kernel_ok(n, d, x.dtype, keep, block_rows):
+    if eligible:
         bits = jax.random.bits(rng, (n, d), jnp.uint32)
         y = _dln(x.reshape(n, d), resid.reshape(n, d).astype(x.dtype),
                  bits, gamma, beta, keep, eps, block_rows)

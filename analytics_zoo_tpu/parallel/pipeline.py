@@ -31,16 +31,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common.jax_compat import shard_map
 
 
 def _pvary(x, axis):
     """Mark ``x`` as device-varying over ``axis`` (no-op data-wise)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis,))  # older spelling
-    return x  # pre-vma jax: no device-varying type system to satisfy
+    return lax.pcast(x, (axis,), to="varying")
 
 
 def _record_schedule(S: int, M: int) -> None:
@@ -129,7 +124,7 @@ def pipeline_forward(stage_fn: Callable, stacked_params, x, mesh: Mesh,
         lambda leaf: P(axis, *([None] * (leaf.ndim - 1))), stacked_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_spec, data_spec),
         out_specs=data_spec)
     def run(params, xs):

@@ -29,41 +29,6 @@ from typing import Optional
 
 import jax
 
-from ..common.jax_compat import shard_map as shard_map_compat
-
-
-def _ulysses_impl(q, k, v, axis_name, head_axis, seq_axis, attn_fn,
-                  causal, sm_scale, kbias):
-    """Shared all-to-all head/seq swap: split the head axis N ways,
-    exchange so each device holds a head subset at full L, run the local
-    attention, swap back. ``head_axis``/``seq_axis`` locate those dims in
-    the operand layout; ``attn_fn(q, k, v, bias, causal, sm_scale)`` is
-    the matching full-L local attention."""
-    n = jax.lax.psum(1, axis_name)
-    h, d = q.shape[head_axis], q.shape[3]
-    if h % n != 0:
-        raise ValueError(f"ulysses needs heads % devices == 0, got "
-                         f"H={h} over {n} devices (use ring_attention)")
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-
-    def seq_to_head(x):
-        return jax.lax.all_to_all(x, axis_name, split_axis=head_axis,
-                                  concat_axis=seq_axis, tiled=True)
-
-    def head_to_seq(x):
-        return jax.lax.all_to_all(x, axis_name, split_axis=seq_axis,
-                                  concat_axis=head_axis, tiled=True)
-
-    qh, kh, vh = seq_to_head(q), seq_to_head(k), seq_to_head(v)
-
-    bias = None
-    if kbias is not None:
-        kb_full = jax.lax.all_gather(kbias, axis_name, axis=1, tiled=True)
-        bias = kb_full[:, None, None, :]          # (B, 1, 1, L)
-
-    return head_to_seq(attn_fn(qh, kh, vh, bias, causal, sm_scale))
-
 
 def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
                       sm_scale: Optional[float] = None, kbias=None):
@@ -75,62 +40,53 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     """
     from ..ops.attention import flash_attention
 
-    def attn(q, k, v, bias, causal, sm_scale):
-        return flash_attention(q, k, v, bias=bias, causal=causal,
-                               sm_scale=sm_scale)
+    n = jax.lax.psum(1, axis_name)
+    h, d = q.shape[1], q.shape[3]
+    if h % n != 0:
+        raise ValueError(f"ulysses needs heads % devices == 0, got "
+                         f"H={h} over {n} devices (use ring_attention)")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
 
-    return _ulysses_impl(q, k, v, axis_name, head_axis=1, seq_axis=2,
-                         attn_fn=attn, causal=causal, sm_scale=sm_scale,
-                         kbias=kbias)
+    def seq_to_head(x):
+        return jax.lax.all_to_all(x, axis_name, split_axis=1,
+                                  concat_axis=2, tiled=True)
 
+    def head_to_seq(x):
+        return jax.lax.all_to_all(x, axis_name, split_axis=2,
+                                  concat_axis=1, tiled=True)
 
-def ulysses_attention_blhd(q, k, v, axis_name: str, causal: bool = False,
-                           sm_scale: Optional[float] = None, kbias=None):
-    """Per-shard q,k,v: (B, L_local, H, D); returns (B, L_local, H, D).
+    qh, kh, vh = seq_to_head(q), seq_to_head(k), seq_to_head(v)
 
-    The transpose-free twin of ``ulysses_attention``: activations stay in
-    the (B, L, H, d) layout the QKV projection produces, the all-to-alls
-    swap the head/seq axes of THAT layout, and local attention runs
-    through ``flash_attention_blhd`` — so neither the collective nor the
-    kernel forces a [B,H,L,d] relayout copy (the bhld variant pays both:
-    the layer transpose feeding all_to_all materializes, then the pallas
-    custom call's pinned operand layouts materialize again).
-    """
-    from ..ops.attention import flash_attention_blhd
+    bias = None
+    if kbias is not None:
+        kb_full = jax.lax.all_gather(kbias, axis_name, axis=1, tiled=True)
+        bias = kb_full[:, None, None, :]          # (B, 1, 1, L)
 
-    def attn(q, k, v, bias, causal, sm_scale):
-        return flash_attention_blhd(q, k, v, bias=bias, causal=causal,
-                                    sm_scale=sm_scale)
-
-    return _ulysses_impl(q, k, v, axis_name, head_axis=2, seq_axis=1,
-                         attn_fn=attn, causal=causal, sm_scale=sm_scale,
-                         kbias=kbias)
+    return head_to_seq(flash_attention(qh, kh, vh, bias=bias,
+                                       causal=causal, sm_scale=sm_scale))
 
 
 def sharded_seq_attention(per_shard_fn, q, k, v, mesh, causal=False,
                           sm_scale=None, seq_axis: str = "seq",
-                          kbias=None, layout: str = "bhld"):
+                          kbias=None):
     """Shared shard_map wrapper for the sequence-parallel strategies:
-    q,k,v are global arrays with L sharded over ``seq_axis`` —
-    (B,H,L,D) for ``layout="bhld"`` (``ring_attention`` /
-    ``ulysses_attention``), (B,L,H,D) for ``layout="blhd"``
-    (``ulysses_attention_blhd``). ``kbias``: optional global (B, L)
-    additive key bias (padding mask)."""
+    q,k,v are global (B,H,L,D) arrays with L sharded over ``seq_axis``.
+    ``kbias``: optional global (B, L) additive key bias (padding
+    mask)."""
     from jax.sharding import PartitionSpec as P
 
-    spec = P(None, seq_axis, None, None) if layout == "blhd" \
-        else P(None, None, seq_axis, None)
+    spec = P(None, None, seq_axis, None)
     fn = functools.partial(per_shard_fn, axis_name=seq_axis,
                            causal=causal, sm_scale=sm_scale)
     if kbias is None:
-        return shard_map_compat(fn, mesh=mesh,
-                                in_specs=(spec, spec, spec),
-                                out_specs=spec)(q, k, v)
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec)(q, k, v)
     kb_spec = P(None, seq_axis)
     fn2 = lambda q, k, v, kb: fn(q, k, v, kbias=kb)  # noqa: E731
-    return shard_map_compat(fn2, mesh=mesh,
-                            in_specs=(spec, spec, spec, kb_spec),
-                            out_specs=spec)(q, k, v, kbias)
+    return jax.shard_map(fn2, mesh=mesh,
+                         in_specs=(spec, spec, spec, kb_spec),
+                         out_specs=spec)(q, k, v, kbias)
 
 
 def ulysses_attention_sharded(q, k, v, mesh, causal=False, sm_scale=None,
@@ -138,13 +94,3 @@ def ulysses_attention_sharded(q, k, v, mesh, causal=False, sm_scale=None,
     return sharded_seq_attention(ulysses_attention, q, k, v, mesh,
                                  causal=causal, sm_scale=sm_scale,
                                  seq_axis=seq_axis, kbias=kbias)
-
-
-def ulysses_attention_blhd_sharded(q, k, v, mesh, causal=False,
-                                   sm_scale=None, seq_axis: str = "seq",
-                                   kbias=None):
-    """(B, L, H, D) global arrays, L sharded over ``seq_axis``."""
-    return sharded_seq_attention(ulysses_attention_blhd, q, k, v, mesh,
-                                 causal=causal, sm_scale=sm_scale,
-                                 seq_axis=seq_axis, kbias=kbias,
-                                 layout="blhd")

@@ -2,9 +2,8 @@
 
 The reference saves models as a language-neutral module graph (BigDL
 protobuf via ``ZooModel.saveModel`` / ``Topology.scala:109``); round 1/2
-here pickled the python object, which breaks on any class rename
-(VERDICT r2 weak #5). This module serializes the *definition*: every
-layer's class path + captured constructor config (``KerasLayer`` records
+here pickled the python object, which breaks on any class rename. This
+module serializes the *definition*: every layer's class path + captured constructor config (``KerasLayer`` records
 bound ``__init__`` args automatically) plus the Variable-DAG connectivity,
 as JSON — rebuildable across refactors, diffable, and not a code-execution
 vector. ndarray-valued config entries (e.g. embedding weight tables) go to

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .....common.jax_compat import shard_map as _shard_map
 from .....ops.attention import flash_attention_blhd
 from .....ops.fused_dropout_ln import dropout_add_layer_norm
 from ..engine.base import KerasLayer, init_tensor
@@ -64,7 +63,7 @@ def _dp_dropout_add_ln(x, resid, gamma, beta, rng, p_drop, training):
     # single elementwise+rowwise op — gradient correctness of the wrap
     # (incl. the replicated gamma/beta psum on transpose) is pinned by
     # test_dp_wrap_grad_parity on the 8-device mesh
-    return _shard_map(body, mesh=dp, in_specs=(px, px, pv, pv, P()),
+    return jax.shard_map(body, mesh=dp, in_specs=(px, px, pv, pv, P()),
                       out_specs=px, check_vma=False)(
         x, resid, gamma, beta, rng)
 
@@ -77,9 +76,9 @@ def _dp_mesh(batch):
     dp>1 mesh the layer enters a fully-manual shard_map at its kernel
     sites itself — batch-parallel attention and dropout+add+LN are
     embarrassingly parallel, so the wrap is spec-exact (no resharding)
-    and the XLA fallback inside computes identically when the kernels
-    stay ineligible. Mixed layouts (tp/pp/sp/ep) are handled by their
-    own shard_map paths or the XLA fallback."""
+    and the XLA route inside computes identically when the shapes are
+    not kernel-eligible. Mixed layouts (tp/pp/sp/ep) are handled by
+    their own shard_map paths or the XLA route."""
     from .....common import nncontext as _nn
     ctx = _nn._global_context
     if ctx is None:
@@ -91,28 +90,9 @@ def _dp_mesh(batch):
         return None
     if batch % dp != 0:
         return None
-    try:
-        from jax._src import mesh as _jmesh
-        if tuple(getattr(_jmesh.get_abstract_mesh(), "axis_names",
-                         ()) or ()):
-            return None          # already inside a shard_map
-    except Exception as e:  # noqa: BLE001 - private API moved; don't wrap
-        global _MESH_PROBE_WARNED
-        if not _MESH_PROBE_WARNED:
-            _MESH_PROBE_WARNED = True
-            import logging
-            logging.getLogger(
-                "analytics_zoo_tpu.pipeline.api.keras").warning(
-                "jax._src.mesh probe failed (%s): cannot detect an "
-                "enclosing shard_map after this jax upgrade, so the "
-                "pure-dp kernel wrap stays DISABLED (XLA fallback, "
-                "correct but slower). Update _dp_mesh for the new jax "
-                "private-API layout.", e)
-        return None
+    if jax.sharding.get_abstract_mesh().axis_names:
+        return None          # already inside a shard_map
     return ctx.mesh
-
-
-_MESH_PROBE_WARNED = False
 
 
 class TransformerLayer(KerasLayer):
@@ -146,7 +126,7 @@ class TransformerLayer(KerasLayer):
         self.hidden_size = int(hidden_size)
         self.embedding_layer = embedding_layer
         # moe_experts > 0 swaps each block's MLP for a SparseMoE (expert
-        # parallelism reachable from the model zoo, VERDICT r2 #8)
+        # parallelism reachable from the model zoo)
         self.moe_experts = int(moe_experts)
         self.moe_top_k = int(moe_top_k)
         self._moe = None
@@ -302,10 +282,8 @@ class TransformerLayer(KerasLayer):
             # memory (parallel/ulysses.py, parallel/ring_attention.py;
             # key-padding bias rides along either way)
             from .....common.nncontext import get_nncontext
-            from .....parallel.ring_attention import \
-                ring_attention_blhd_sharded
-            from .....parallel.ulysses import \
-                ulysses_attention_blhd_sharded
+            from .....parallel.ring_attention import ring_attention_sharded
+            from .....parallel.ulysses import ulysses_attention_sharded
 
             mode = str(getattr(get_nncontext().config,
                                "sequence_parallel_mode", "auto")).lower()
@@ -320,29 +298,14 @@ class TransformerLayer(KerasLayer):
                 kb = jnp.broadcast_to(
                     mask_bias.reshape(mask_bias.shape[0], l),
                     (b, l)).astype(jnp.float32)
-            if use_ulysses:
-                # blhd twin: all-to-alls swap the head/seq axes of the
-                # projection's natural layout, so neither the collective
-                # nor the kernel forces a relayout copy
-                o = ulysses_attention_blhd_sharded(
-                    q.reshape(b, l, nh, d), k.reshape(b, l, nh, d),
-                    v.reshape(b, l, nh, d), get_nncontext().mesh,
-                    causal=not self.bidirectional, kbias=kb)
-            else:
-                # blhd twin: the ring folds chunks in the projection's
-                # native (B, L, H, d) layout, so neither entry nor exit
-                # needs the [B,H,L,d] relayout transpose pair
-                o = ring_attention_blhd_sharded(
-                    q.reshape(b, l, nh, d), k.reshape(b, l, nh, d),
-                    v.reshape(b, l, nh, d), get_nncontext().mesh,
-                    causal=not self.bidirectional, kbias=kb)
+            sp_attn = ulysses_attention_sharded if use_ulysses \
+                else ring_attention_sharded
+            qh, kh, vh = (t.reshape(b, l, nh, d).transpose(0, 2, 1, 3)
+                          for t in (q, k, v))
+            o = sp_attn(qh, kh, vh, get_nncontext().mesh,
+                        causal=not self.bidirectional,
+                        kbias=kb).transpose(0, 2, 1, 3)
         else:
-            # blhd entry: the (B, L, H, d) reshape of the fused QKV
-            # projection feeds the kernel directly — no [B,H,L,d]
-            # relayout copies in, no transpose back out (ops/attention.py
-            # blhd section; falls back to the transposed path when the
-            # kernel is ineligible, where XLA folds the transposes into
-            # its dots anyway)
             q4, k4, v4 = (t.reshape(b, l, nh, d) for t in (q, k, v))
             attn = functools.partial(flash_attention_blhd,
                                      causal=not self.bidirectional)
@@ -365,7 +328,7 @@ class TransformerLayer(KerasLayer):
                 def body(q_, k_, v_, bias_=None):
                     return attn(q_, k_, v_, bias=bias_)
 
-                o = _shard_map(
+                o = jax.shard_map(
                     body, mesh=dp, in_specs=tuple(in_specs),
                     out_specs=p4, check_vma=False)(*operands)
         o = o.reshape(b, l, h)
@@ -378,10 +341,9 @@ class TransformerLayer(KerasLayer):
 
     def _block(self, p, x, mask_bias, rng, training):
         # both residual sites run the fused dropout+add+LN op: one
-        # bandwidth pass on the TPU kernel path (ops/fused_dropout_ln.py
-        # — the composed XLA fusions measured ~4x off ideal, 17.6 ms of
-        # the BERT-base step, r5 session 3), the exact pre-existing
-        # bernoulli+layer_norm composition everywhere else
+        # bandwidth pass on the TPU kernel path (ops/fused_dropout_ln.py),
+        # the exact pre-existing bernoulli+layer_norm composition
+        # everywhere else
         r1 = r2 = r3 = None
         if rng is not None:
             r1, r2, r3 = jax.random.split(rng, 3)

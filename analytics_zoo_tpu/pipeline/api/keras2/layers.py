@@ -198,7 +198,7 @@ def Softmax(axis: int = -1, input_shape=None, name=None, **kw):
     return k1.Softmax(axis=axis, input_shape=input_shape, name=name)
 
 
-# -- r4 expansion: the wider keras-2 surface (VERDICT r3 weak #8) ----------
+# -- r4 expansion: the wider keras-2 surface ----------
 # Padding / cropping / upsampling (keras-2 names + arg spellings onto the
 # keras-1 engine classes, same one-engine/two-dialects design as above)
 

@@ -264,7 +264,7 @@ class NNEstimator(_Params):
         return total
 
     def _maybe_spill(self, feats, labels) -> Optional[FeatureSet]:
-        """Auto-spill (VERDICT r3 next #8): when the PROCESSED samples of
+        """Auto-spill: when the PROCESSED samples of
         the DataFrame would exceed ``config.nnframes_spill_bytes``
         (preprocessing can expand rows by orders of magnitude — an image
         path becomes a 224x224x3 tensor), write ~64 MB ``.npz`` shards and

@@ -38,6 +38,7 @@ import traceback
 import uuid
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from ..common.hostdev import require_cpu_workers
 from .process import ProcessGuard, ProcessMonitor
 
 logger = logging.getLogger("analytics_zoo_tpu.ray")
@@ -130,11 +131,6 @@ def _actor_main(parent_pid, cls_blob, init_blob, ready_id, task_q,
         os.environ.update(env)
     if platform:
         os.environ["JAX_PLATFORMS"] = platform
-        try:
-            import jax
-            jax.config.update("jax_platforms", platform)
-        except Exception:  # noqa: BLE001
-            pass
     import cloudpickle
 
     try:
@@ -188,12 +184,6 @@ def _worker_main(worker_id: int, parent_pid: int, task_q, result_q,
         os.environ.update(env)
     if platform:
         os.environ["JAX_PLATFORMS"] = platform
-        try:
-            import jax
-            # env var alone is ignored when a TPU plugin is registered
-            jax.config.update("jax_platforms", platform)
-        except Exception:  # noqa: BLE001 - jax optional in workers
-            pass
     import cloudpickle
 
     while True:
@@ -273,6 +263,10 @@ class RayContext:
         global _global_ray_context
         if not self.stopped:
             return self
+        worker_env = {**os.environ, **self.env}
+        if self.platform:
+            worker_env["JAX_PLATFORMS"] = self.platform
+        require_cpu_workers(self.num_workers, worker_env, "RayContext")
         ctx = mp.get_context("spawn")  # hermetic workers (no jax state leak)
         self._task_q = ctx.Queue()
         self._result_q = ctx.Queue()

@@ -235,15 +235,8 @@ def _build_serving(cfg: str, workdir: str):
 
 
 def _serve(cfg: str, warmup: bool = False, workdir: str = "."):
-    # honor JAX_PLATFORMS even when a TPU plugin is registered (the env
-    # var alone is ignored then; the config update is authoritative)
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        try:
-            import jax
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # noqa: BLE001 - serving may not need jax yet
-            pass
+    from ..common.nncontext import enable_compile_cache
+    enable_compile_cache()
     serving, _ctl = _build_serving(cfg, workdir)
     if serving.helper.telemetry or telemetry.enabled():
         telemetry.configure(enabled=True,

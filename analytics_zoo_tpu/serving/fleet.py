@@ -36,6 +36,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from ..common.hostdev import require_cpu_workers
 from ..launcher.supervisor import (SupervisedProc, inject_pythonpath,
                                    spawn_supervised, terminate_all)
 from ..utils import file_io, telemetry
@@ -302,6 +303,8 @@ class ServingFleet:
         self.healthy_reset_s = float(healthy_reset_s)
         self.stream = stream if stream is not None else sys.stdout
         self.env = dict(env or {})
+        require_cpu_workers(max(self.workers, self.max_workers),
+                            {**os.environ, **self.env}, "serving fleet")
         self.python = python or sys.executable
         self._lock = threading.Lock()
         self._procs: Dict[int, SupervisedProc] = {}

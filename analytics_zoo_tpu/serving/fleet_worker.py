@@ -150,15 +150,8 @@ def main(argv=None) -> int:
     logging.basicConfig(
         level=logging.INFO,
         format=f"%(asctime)s worker-{args.worker_id} %(message)s")
-    # honor JAX_PLATFORMS even when a TPU plugin is registered (the env
-    # var alone is ignored then; the config update is authoritative)
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        try:
-            import jax
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # noqa: BLE001 - serving may not need jax yet
-            pass
+    from ..common.nncontext import enable_compile_cache
+    enable_compile_cache()
     workdir = os.path.abspath(args.workdir)
     os.makedirs(os.path.join(workdir, HEALTH_DIR), exist_ok=True)
     serving, _ctl = _build_serving(args.config, workdir, args.worker_id)

@@ -159,8 +159,7 @@ class StreamQueueBroker:
         # lock on each data-plane op, so scale-out benches on a 1-core
         # host can model N brokers on N cores (sleeping releases the GIL,
         # so two brokers' ops overlap the way two cores would, while one
-        # broker's ops stay serialized on its lock).  0 = off; see
-        # BENCH_NOTES.md for the stubbed-cost methodology.
+        # broker's ops stay serialized on its lock).  0 = off.
         self.op_cost_ms = float(op_cost_ms)
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)          # stream

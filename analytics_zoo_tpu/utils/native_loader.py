@@ -16,6 +16,7 @@ from typing import Iterator, Optional
 _REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", ".."))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "zoo_data.cpp")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libzoo_data.so")
 
 _loaded: Optional["ZooDataLib"] = None
@@ -161,8 +162,19 @@ def build_native(quiet: bool = True) -> bool:
         return False
 
 
+def _stale() -> bool:
+    """The library is missing or older than its source. ``native/build``
+    is git-ignored, so a library that merely exists may come from another
+    commit; what runs must be built from the source in this checkout."""
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
+
+
 def load_zoo_data(auto_build: bool = True) -> ZooDataLib:
-    """Load (building if necessary) the native library.
+    """Load the native library, (re)building it first when it is missing
+    or older than ``native/zoo_data.cpp``.
 
     Raises ImportError when unavailable so call sites can fall back to
     pure python.
@@ -172,12 +184,13 @@ def load_zoo_data(auto_build: bool = True) -> ZooDataLib:
         return _loaded
     if _load_failed:
         raise ImportError("native zoo_data previously failed to load")
-    if not os.path.exists(_LIB_PATH):
+    if _stale():
         if not (auto_build and os.path.exists(
                 os.path.join(_NATIVE_DIR, "Makefile")) and build_native()):
             _load_failed = True
             raise ImportError(
-                "libzoo_data.so not built (run `make -C native`)")
+                "libzoo_data.so missing or older than zoo_data.cpp "
+                "(run `make -C native`)")
     try:
         _loaded = ZooDataLib(_LIB_PATH)
     except OSError as e:

@@ -34,9 +34,8 @@ def example_args(description, **extra):
         extra["extra_args"](p)
     args = p.parse_args()
     if args.platform == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
-        # the env var alone is ignored when a TPU plugin is registered
+        # the flag wins over whatever JAX_PLATFORMS says
         jax.config.update("jax_platforms", "cpu")
     return args
 
@@ -95,7 +94,7 @@ def taxi_like(n, seed=0):
     return series
 
 
-# -- real reference mini-datasets (VERDICT r4 missing #1 / next #4) -----
+# -- real reference mini-datasets -----
 # The reference repo's own test fixtures sit in-tree; every loader
 # degrades to None so the examples keep their synthetic fallback when the
 # reference checkout is absent.

@@ -167,7 +167,7 @@ class TestDatasets:
 
 
 class TestParityHoleLayers:
-    """r5: the last four public-layer parity holes (VERDICT r4 missing #2).
+    """r5: the last four public-layer parity holes.
 
     References: SparseDense.scala, SelectTable.scala, Expand.scala /
     InternalExpand.scala (+ InternalExpandSpec), GetShape.scala.

@@ -1,8 +1,10 @@
 """Hardware-gated Pallas flash-attention tests.
 
-Round-2 lesson (VERDICT r2 weak #2): interpret-mode coverage does NOT model
-Mosaic layout constraints — the key-bias BlockSpec bug passed every CPU test
-and then broke the whole transformer zoo on a real chip. These tests compile
+Interpret-mode coverage does NOT model Mosaic layout constraints — a
+key-bias BlockSpec bug once passed every CPU test and then broke the whole
+transformer zoo on a real chip (tests/test_chip_bringup.py now catches
+that class by cross-lowering; VMEM and layouts still need the chip).
+These tests compile
 and run the kernel on the actual TPU backend in a subprocess (the main test
 process is pinned to the CPU platform by conftest) and self-skip when no TPU
 is attached. Reference test analogue: KerasBaseSpec golden checks, except on
@@ -19,10 +21,8 @@ import os
 os.environ["ZOO_TPU_FORCE_PALLAS"] = "1"   # L=512 < KERNEL_MIN_SEQ routing
 import numpy as np, jax, jax.numpy as jnp
 from analytics_zoo_tpu.ops.attention import (flash_attention,
-                                             attention_reference,
-                                             _kernel_available)
+                                             attention_reference)
 assert jax.default_backend() == "tpu", jax.default_backend()
-assert _kernel_available(), "kernel probe failed on TPU"
 B, H, L, D = 16, 12, 512, 64
 rng = np.random.default_rng(0)
 q, k, v = (jnp.asarray(rng.standard_normal((B, H, L, D)), jnp.bfloat16)

@@ -54,7 +54,7 @@ def test_dp_shard_map_blhd_fwd_bwd_parity(monkeypatch, remat):
     policy is selected."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from analytics_zoo_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", remat)
     assert _flash_remat_policy() == (

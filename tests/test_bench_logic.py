@@ -1,16 +1,19 @@
-"""Driver-bench harness logic (bench.py) — the selection/fallback rules
-the round's numbers depend on, exercised with stubbed measurement legs
-(no model runs).
+"""Driver-bench harness logic (bench.py) — the selection rules the
+numbers depend on, exercised with stubbed measurement legs (no model
+runs), and the chip-or-fail rule.
 """
 
+import os
 import sys
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture()
 def bench(monkeypatch):
-    sys.path.insert(0, "/root/repo")
+    monkeypatch.syspath_prepend(REPO)
     import bench as b
     yield b
 
@@ -44,114 +47,37 @@ def test_bert_all_candidates_fail_falls_to_16(bench, monkeypatch):
     assert "bert_runner_up" not in r
 
 
-def test_bert_cpu_fallback_uses_b16_only(bench, monkeypatch):
-    calls = []
-
-    def fake(peak, bb, seq_len=512):
-        calls.append(bb)
-        return {"bert_batch": bb, "bert_tokens_per_sec": 1.0}
-
-    monkeypatch.setattr(bench, "_bench_bert_mfu_at", fake)
-    r = bench.bench_bert_mfu(None)
-    assert calls == [16] and r["bert_batch"] == 16
-
-
-def test_bench_dtype_by_backend(bench):
-    # conftest pins the cpu backend for tests
-    assert bench._bench_dtype() == "float32"
+def test_bench_trains_in_bf16(bench):
+    assert bench.BENCH_DTYPE == "bfloat16"
 
 
 def test_peak_flops_table(bench):
+    """Exact ``device_kind`` keys; a chip that is not in the table is an
+    error in the benchmark, not a guess."""
     assert bench._peak_flops("TPU v5 lite") == 197e12
-    assert bench._peak_flops("TPU v4") == 275e12
-    assert bench._peak_flops("weird accelerator") is None
+    for kind in ("TPU v5", "TPU v4", "tpu v5 lite", "weird accelerator"):
+        with pytest.raises(KeyError, match="no peak FLOP/s"):
+            bench._peak_flops(kind)
 
 
-# -- probe_backend resilience -------------------------------------------
-
-def _fake_run(script):
-    """A subprocess.run stand-in driven by a scripted list of outcomes:
-    'ok' -> device JSON, 'err' -> rc=1, 'hang' -> TimeoutExpired."""
-    import json as _json
-    import subprocess as _sp
-
-    calls = []
-
-    def run(cmd, capture_output=True, text=True, timeout=None):
-        outcome = script[len(calls)]
-        calls.append(outcome)
-        if outcome == "hang":
-            raise _sp.TimeoutExpired(cmd, timeout)
-
-        class R:
-            pass
-
-        r = R()
-        if outcome == "ok":
-            r.returncode = 0
-            r.stdout = _json.dumps({"platform": "tpu",
-                                    "device_kind": "TPU v5 lite", "n": 4})
-            r.stderr = ""
-        else:
-            r.returncode = 1
-            r.stdout = ""
-            r.stderr = "RuntimeError: tunnel flapped\n"
-        return r
-
-    return run, calls
+def test_no_chip_is_an_error(bench):
+    """conftest pins the cpu backend: no chip, no benchmark."""
+    with pytest.raises(SystemExit) as e:
+        bench.require_chip()
+    assert e.value.code not in (0, None)
+    assert "measures the chip" in str(e.value.code)
+    for gone in ("probe_backend", "_read_probe_cache", "PROBE_CACHE",
+                 "_bench_dtype"):
+        assert not hasattr(bench, gone), gone
 
 
-def test_probe_retries_then_succeeds(bench, monkeypatch, tmp_path):
-    run, calls = _fake_run(["err", "hang", "ok"])
-    monkeypatch.setattr(bench, "_PROBE_MEMO", None)
-    monkeypatch.setattr(bench.subprocess, "run", run)
-    cache = str(tmp_path / "probe.json")
-    info, err = bench.probe_backend(attempts=3, timeout_s=1,
-                                    retry_delay_s=0, cache_path=cache)
-    assert err is None
-    assert len(calls) == 3
-    assert info["platform"] == "tpu"
-    assert info["provenance"] == "probe"
-    # success was persisted as the known-good record
-    cached = bench._read_probe_cache(cache)
-    assert cached["device_kind"] == "TPU v5 lite"
-    assert cached["probed_at"] > 0
+def test_bench_main_without_a_chip_exits_nonzero():
+    import subprocess
 
-
-def test_probe_memoizes_known_good_handle(bench, monkeypatch, tmp_path):
-    run, calls = _fake_run(["ok", "err", "err", "err"])
-    monkeypatch.setattr(bench, "_PROBE_MEMO", None)
-    monkeypatch.setattr(bench.subprocess, "run", run)
-    cache = str(tmp_path / "probe.json")
-    first, _ = bench.probe_backend(attempts=1, retry_delay_s=0,
-                                   cache_path=cache)
-    assert first["provenance"] == "probe"
-    # re-entry (helper legs) must NOT spawn another probe subprocess
-    again, err = bench.probe_backend(attempts=3, retry_delay_s=0,
-                                     cache_path=cache)
-    assert err is None
-    assert len(calls) == 1
-    assert again["platform"] == "tpu"
-    assert again["provenance"] == "memo"
-
-
-def test_probe_total_failure_reports_tail(bench, monkeypatch, tmp_path):
-    run, calls = _fake_run(["err", "err"])
-    monkeypatch.setattr(bench, "_PROBE_MEMO", None)
-    monkeypatch.setattr(bench.subprocess, "run", run)
-    info, err = bench.probe_backend(attempts=2, retry_delay_s=0,
-                                    cache_path=str(tmp_path / "p.json"))
-    assert info is None
-    assert "tunnel flapped" in err
-    assert len(calls) == 2
-
-
-def test_probe_cache_round_trip_and_corruption(bench, tmp_path):
-    path = str(tmp_path / "cache.json")
-    assert bench._read_probe_cache(path) is None  # missing
-    bench._write_probe_cache({"platform": "tpu",
-                              "device_kind": "TPU v4"}, path)
-    assert bench._read_probe_cache(path)["platform"] == "tpu"
-    with open(path, "w") as f:
-        f.write("{not json")
-    assert bench._read_probe_cache(path) is None  # corrupt -> best effort
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300, cwd=REPO)
+    assert p.returncode != 0
+    assert "measures the chip" in p.stderr
+    assert p.stdout.strip() == ""          # no JSON line, no number

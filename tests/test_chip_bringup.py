@@ -1,0 +1,361 @@
+"""What can be known about the chip without one.
+
+- Every Pallas entry the router can select cross-lowers for
+  ``platforms=["tpu"]`` at the shapes the chip runs (jax.export needs no
+  device): a BlockSpec the Pallas TPU lowering refuses fails here, on the
+  CPU, before it costs chip time. (Mosaic itself — VMEM, layouts — only
+  answers on the chip; ``chip_smoke.py`` is that check.)
+- Nothing on the kernel path turns a compile failure into an XLA route.
+- The compile cache is placed by one rule.
+- Parents decide topology from the environment and never take the chip.
+"""
+
+import os
+import re
+import shutil
+import subprocess
+import sys
+import tempfile
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from analytics_zoo_tpu.ops import attention as A
+from analytics_zoo_tpu.ops import fused_dropout_ln as D
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Make the routers believe the backend is one TPU chip."""
+    monkeypatch.delenv("ZOO_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("ZOO_TPU_FORCE_PALLAS", raising=False)
+    monkeypatch.delenv("ZOO_TPU_DISABLE_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: True)
+    monkeypatch.setattr(D, "mosaic_partition_ok", lambda: True)
+
+
+def _tpu_mlir(fn, *args):
+    return jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *args).mlir_module()
+
+
+def _kernel_names(mlir):
+    return sorted(re.findall(r'kernel_name = "([^"]+)"', mlir))
+
+
+# ---------------------------------------------------------------------------
+# cross-lowering
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("b,h,l,d,causal", [
+    (32, 12, 512, 64, False),      # BERT-base train, batch 32
+    (8, 12, 2048, 64, False),      # BERT long
+    (4, 12, 512, 64, True),        # decode engine's batched causal prefill
+])
+def test_flash_attention_cross_lowers_for_tpu(on_tpu, b, h, l, d, causal):
+    s = jax.ShapeDtypeStruct
+    q = s((b, h, l, d), jnp.bfloat16)
+    bias = s((b, 1, 1, l), jnp.float32)
+
+    def loss(q, k, v, bias):
+        return (A.flash_attention(q, k, v, bias=bias, causal=causal)
+                .astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_mlir(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, bias)
+    assert _kernel_names(mlir) == [
+        "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
+    assert mlir.count("tpu_custom_call") == 3
+
+
+def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
+    """The layer's default entry: (B, L, H, d) in, the bhld kernels
+    underneath."""
+    s = jax.ShapeDtypeStruct
+    q = s((32, 512, 12, 64), jnp.bfloat16)
+    bias = s((32, 1, 1, 512), jnp.float32)
+    mlir = _tpu_mlir(
+        lambda q, k, v, b: A.flash_attention_blhd(q, k, v, bias=b),
+        q, q, q, bias)
+    assert _kernel_names(mlir) == ["zoo_flash_fwd"]
+
+
+@pytest.mark.parametrize("n,d,dtype", [
+    (32 * 512, 768, jnp.bfloat16),     # BERT-base b32 L512
+    (8 * 2048, 768, jnp.bfloat16),
+    (32 * 512, 768, jnp.float32),
+])
+def test_dropout_add_layer_norm_cross_lowers_for_tpu(on_tpu, n, d, dtype):
+    s = jax.ShapeDtypeStruct
+    x = s((n, d), dtype)
+    g = s((d,), jnp.float32)
+    key = jax.random.PRNGKey(0)
+
+    def loss(x, r, g, b):
+        return (D.dropout_add_layer_norm(x, r, g, b, key, 0.1, True)
+                .astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_mlir(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, g, g)
+    assert _kernel_names(mlir) == ["zoo_dln_bwd", "zoo_dln_fwd"]
+
+
+def test_dln_row_block_fits_the_vmem_budget():
+    """Static selection from what a v5e accepted and refused (see
+    fused_dropout_ln._VMEM_BUDGET): bf16 (512, 768) stays, the two
+    refused shapes shrink, a row that cannot fit routes to XLA."""
+    assert D._pick_rows(16384, 768, 2) == 512
+    assert D._pick_rows(16384, 768, 4) == 256
+    assert D._pick_rows(16384, 4096, 2) == 64
+    assert D._pick_rows(16384, 1 << 20, 4) == 0
+    assert D._pick_rows(12, 768, 2) == 0          # no 8-row divisor
+
+
+# ---------------------------------------------------------------------------
+# no fallback that hides the device
+# ---------------------------------------------------------------------------
+
+def test_attention_kernel_failure_raises_not_reroutes(on_tpu, monkeypatch):
+    def boom(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: boom")
+
+    monkeypatch.setattr(A, "_flash_forward", boom)
+    q = jnp.ones((1, 1, 2048, 64), jnp.bfloat16)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        A.flash_attention(q, q, q, bias=jnp.zeros((1, 1, 1, 2048)))
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        A.flash_attention_blhd(q.transpose(0, 2, 1, 3),
+                               q.transpose(0, 2, 1, 3),
+                               q.transpose(0, 2, 1, 3))
+
+
+def test_dln_kernel_failure_raises_not_reroutes(on_tpu, monkeypatch):
+    def boom(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: boom")
+
+    monkeypatch.setattr(D, "_dln_forward", boom)
+    x = jnp.ones((1024, 768), jnp.bfloat16)
+    g = jnp.ones((768,))
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        D.dropout_add_layer_norm(x, x, g, g, jax.random.PRNGKey(0), 0.1,
+                                 True)
+
+
+def test_no_probe_api_left():
+    """The per-shape compile probes and their caches are gone: routing is
+    the static rules and nothing else."""
+    for mod, names in ((A, ("_kernel_ok_for", "_SHAPE_OK",
+                            "kernel_layouts_ok", "_flash_attention_blhd")),
+                       (D, ("_kernel_ok", "_DLN_OK", "dln_kernel_status"))):
+        for name in names:
+            assert not hasattr(mod, name), (mod.__name__, name)
+
+
+def test_interpret_mode_on_tpu_raises(monkeypatch):
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    assert A._interpret_mode() is True             # CPU backend: fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="INTERPRET"):
+        A._interpret_mode()
+    q = jnp.ones((1, 1, 512, 64), jnp.bfloat16)
+    with pytest.raises(RuntimeError, match="INTERPRET"):
+        A.flash_attention(q, q, q)
+    with pytest.raises(RuntimeError, match="INTERPRET"):
+        D.dropout_add_layer_norm(
+            jnp.ones((8, 128)), jnp.ones((8, 128)), jnp.ones((128,)),
+            jnp.ones((128,)), jax.random.PRNGKey(0), 0.1, True)
+
+
+def test_mosaic_kernel_counts_reads_scope_tags():
+    """Two instructions as a v5e's optimized HLO prints them (bodies
+    cut), one untagged custom call, one unrelated instruction."""
+    from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
+
+    hlo = "\n".join([
+        '  %jvp_attn_hot_zoo_flash_fwd_.1 = (bf16[24,512,64]{2,1,0}, '
+        'f32[24,512,1]{2,1,0}) custom-call(%a, %b, %c, %d), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/jvp(attn_hot)/zoo_flash_fwd/pallas_call" '
+        'stack_frame_id=10}, backend_config={"custom_call_config":{}}',
+        '  %x.2 = bf16[24,512,64]{2,1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/transpose(jvp(attn_hot))/zoo_flash_bwd_dq/pallas_call"}',
+        '  %x.3 = bf16[24,512,64]{2,1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/while/body/zoo_flash_bwd_dq/pallas_call"}',
+        '  %y = f32[8]{0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/pallas_call"}',
+        '  %z = f32[8]{0} custom-call(%a), custom_call_target="Sharding", '
+        'metadata={op_name="jit(f)/zoo_dln_fwd/x"}',
+    ])
+    assert mosaic_kernel_counts(hlo) == {
+        "zoo_flash_fwd": 1, "zoo_flash_bwd_dq": 2, "untagged": 1}
+
+
+def test_peak_flops_is_keyed_by_exact_device_kind():
+    from analytics_zoo_tpu.utils.profiling import peak_flops
+
+    assert peak_flops("TPU v5 lite") == 197e12
+    # no substring matching, no catch-all, no env override
+    assert peak_flops("TPU v5") is None
+    assert peak_flops("TPU v5 lite pod") is None
+    assert peak_flops("tpu v5 lite") is None
+    assert peak_flops("") is None
+
+
+# ---------------------------------------------------------------------------
+# the compile cache rule
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def cache_config():
+    """Restore the three cache knobs: a directory left set would make the
+    rest of the suite fill the checkout."""
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_cache_default_is_one_fixed_path_in_the_checkout(
+        cache_config, monkeypatch):
+    from analytics_zoo_tpu.common import nncontext as NN
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.config.update("jax_compilation_cache_dir", None)
+    got = NN.enable_compile_cache()
+    assert got == NN.COMPILE_CACHE_DIR == \
+        os.path.join(REPO, ".jax_compile_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    # fixed: not a temp name, no pid in it, the same on every call
+    assert not got.startswith(tempfile.gettempdir() + os.sep)
+    assert str(os.getpid()) not in got
+    assert NN.enable_compile_cache() == got
+    # and git never sees it
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_compile_cache/" in f.read().split()
+
+
+def test_cache_yields_to_the_environment_variable(cache_config,
+                                                  monkeypatch):
+    from analytics_zoo_tpu.common import nncontext as NN
+
+    # jax reads the variable at import; stand in for that here
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    jax.config.update("jax_compilation_cache_dir", "/some/dir")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert NN.enable_compile_cache() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == "/some/dir"
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_cache_stays_off_on_the_cpu_backend(cache_config, monkeypatch):
+    from analytics_zoo_tpu.common import nncontext as NN
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert jax.default_backend() == "cpu"
+    assert NN.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir is None
+    assert not hasattr(NN.ZooConfig(), "compile_cache_dir")
+
+
+# ---------------------------------------------------------------------------
+# one process for each chip
+# ---------------------------------------------------------------------------
+
+def test_hostdev_decides_from_the_environment():
+    from analytics_zoo_tpu.common import hostdev
+
+    flag = "--xla_force_host_platform_device_count"
+    assert hostdev.env_cpu_devices({"JAX_PLATFORMS": "cpu"}) == 1
+    assert hostdev.env_cpu_devices(
+        {"JAX_PLATFORMS": "cpu", "XLA_FLAGS": f"--x {flag}=8"}) == 8
+    # anything but exactly "cpu" may resolve to the chip
+    assert hostdev.env_cpu_devices(
+        {"JAX_PLATFORMS": "tpu,cpu", "XLA_FLAGS": f"{flag}=8"}) == 0
+    assert hostdev.env_cpu_devices({"XLA_FLAGS": f"{flag}=8"}) == 0
+
+    # the child is pinned whatever the parent's setting
+    env = hostdev.cpu_device_env(
+        4, {"JAX_PLATFORMS": "tpu,cpu", "XLA_FLAGS": f"--x {flag}=2"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["XLA_FLAGS"] == f"--x {flag}=4"
+    assert env[hostdev.CHILD_ENV] == "1"
+    assert hostdev.cpu_device_env(2, {"XLA_FLAGS": f"{flag}=8"})[
+        "XLA_FLAGS"] == f"{flag}=8"
+
+
+def test_parents_never_initialise_a_backend():
+    """With ``JAX_PLATFORMS`` naming a platform that does not exist, any
+    backend initialisation raises. The supervisors import, and
+    ``reexec_module`` decides and starts its (CPU-pinned) child, without
+    one."""
+    code = (
+        "import analytics_zoo_tpu.serving.fleet, analytics_zoo_tpu.launcher,"
+        " analytics_zoo_tpu.ray.raycontext\n"
+        "from analytics_zoo_tpu.common import hostdev\n"
+        "rc = hostdev.reexec_module('analytics_zoo_tpu.common.hostdev', 2,"
+        " [])\n"
+        "assert rc == 0, rc\n"
+        "import jax\n"
+        "try:\n"
+        "    jax.devices()\n"
+        "except RuntimeError as e:\n"
+        "    print('NO_BACKEND_UNTIL_NOW')\n")
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform",
+               PYTHONPATH=REPO)
+    env.pop("ZOO_HOSTDEV_CHILD", None)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-800:]
+    assert "NO_BACKEND_UNTIL_NOW" in p.stdout
+
+
+def test_second_worker_on_a_chip_host_is_refused_by_name(tmp_path):
+    from analytics_zoo_tpu.common.hostdev import require_cpu_workers
+    from analytics_zoo_tpu.launcher import LaunchError, launch
+    from analytics_zoo_tpu.ray.raycontext import RayContext
+
+    require_cpu_workers(1, {"JAX_PLATFORMS": "tpu"}, "x")   # one is fine
+    require_cpu_workers(4, {"JAX_PLATFORMS": "cpu"}, "x")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        require_cpu_workers(2, {}, "x")
+    script = tmp_path / "train.py"
+    script.write_text("print('never runs')\n")
+    with pytest.raises(LaunchError, match="zoo-launch: 2 worker processes"):
+        launch([str(script)], num_hosts=2, env={"JAX_PLATFORMS": "tpu"})
+    with pytest.raises(RuntimeError, match="RayContext: 2 worker"):
+        RayContext(num_ray_nodes=2, platform="tpu").init()
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py itself
+# ---------------------------------------------------------------------------
+
+def test_chip_smoke_refuses_a_cpu_backend(tmp_path):
+    """No accelerator: non-zero, and no result line. The same alone in an
+    empty directory (nothing of the repo to import)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, os.path.join(REPO,
+                                                     "chip_smoke.py")],
+                       env=env, capture_output=True, text=True,
+                       timeout=300, cwd=REPO)
+    assert p.returncode != 0
+    assert '"ok"' not in p.stdout and "no accelerator" in p.stderr
+
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env.pop("PYTHONPATH", None)
+    p = subprocess.run([sys.executable, "chip_smoke.py"], env=env,
+                       capture_output=True, text=True, timeout=300,
+                       cwd=tmp_path)
+    assert p.returncode != 0 and '"ok"' not in p.stdout

@@ -1,5 +1,5 @@
 """CRF: forward-algorithm + Viterbi vs brute-force enumeration, and the
-BiLSTM-CRF text models end-to-end (VERDICT r2 missing #4; reference head:
+BiLSTM-CRF text models end-to-end (reference head:
 pyzoo/zoo/tfpark/text/keras/ner.py:49 NERCRF)."""
 
 import itertools

@@ -269,15 +269,15 @@ def test_zooconfig_env_overrides(monkeypatch):
     cfg = ZooConfig.from_env()
     assert cfg.async_checkpoint is True
     assert cfg.nnframes_spill_bytes == 12345
-    # fused-eval / grad-accum / compile-cache fields (Optional[str] passes
+    # fused-eval / grad-accum fields, and an Optional[str] one (passes
     # through as a plain string)
     monkeypatch.setenv("ZOO_TPU_GRAD_ACCUM_STEPS", "4")
     monkeypatch.setenv("ZOO_TPU_EVAL_STEPS_PER_DISPATCH", "8")
-    monkeypatch.setenv("ZOO_TPU_COMPILE_CACHE_DIR", "/tmp/zoo-xla-cache")
+    monkeypatch.setenv("ZOO_TPU_PROFILE_DIR", "/tmp/zoo-profile")
     cfg = ZooConfig.from_env()
     assert cfg.grad_accum_steps == 4
     assert cfg.eval_steps_per_dispatch == 8
-    assert cfg.compile_cache_dir == "/tmp/zoo-xla-cache"
+    assert cfg.profile_dir == "/tmp/zoo-profile"
 
 
 def test_auto_steps_per_dispatch_stays_per_step_on_cpu():
@@ -291,19 +291,25 @@ def test_auto_steps_per_dispatch_stays_per_step_on_cpu():
     assert trainer._steps_per_dispatch_target() == 1
 
 
-def test_mfu_scalar_emitted_for_plain_fit(tmp_path, monkeypatch):
+@pytest.mark.parametrize("known_kind", [True, False])
+def test_mfu_scalar_emitted_for_plain_fit(tmp_path, monkeypatch,
+                                          known_kind):
     """The MFU TrainSummary scalar must appear for a plain Model.fit run:
     flops_per_step is auto-derived from the step program's XLA cost
-    analysis at first dispatch (VERDICT r3 weak #5)."""
+    analysis at first dispatch. A device kind with no entry in the peak
+    table gets no MFU scalar."""
     import numpy as np
     from analytics_zoo_tpu.common.nncontext import (ZooConfig, ZooContext,
                                                     set_nncontext)
     from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
     from analytics_zoo_tpu.pipeline.api.keras.models import Sequential
 
-    # CPU has no peak-FLOPs table entry; the env override provides one so
-    # the scalar is computable in tests
-    monkeypatch.setenv("ZOO_TPU_PEAK_FLOPS", "1e12")
+    # the CPU has no peak-FLOPs table entry; give it one
+    import jax
+    from analytics_zoo_tpu.utils import profiling
+    if known_kind:
+        monkeypatch.setitem(profiling.PEAK_BF16,
+                            jax.devices()[0].device_kind, 1e12)
     set_nncontext(None)
     set_nncontext(ZooContext(ZooConfig(log_every_n_steps=2)))
     try:
@@ -321,7 +327,7 @@ def test_mfu_scalar_emitted_for_plain_fit(tmp_path, monkeypatch):
         trainer = model._ensure_trainer()
         assert trainer.flops_per_step and trainer.flops_per_step > 0
         mfu = model.get_train_summary("MFU")
-        assert mfu, "no MFU scalar in the train event file"
+        assert bool(mfu) == known_kind, mfu
     finally:
         set_nncontext(None)
 

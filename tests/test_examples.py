@@ -50,7 +50,7 @@ def test_all_examples(name):
     _run(name)
 
 
-# -- real reference fixtures (VERDICT r4 next #4) -----------------------
+# -- real reference fixtures -----------------------
 # Each wired example asserts its analysis metric ON REAL DATA inside its
 # real_* section (NCF: HR@10/NDCG@10 lift over random on genuine
 # MovieLens ratings; Wide&Deep: accuracy over the majority class on the

@@ -177,18 +177,16 @@ def test_grad_accum_must_divide_batch_size():
 
 
 # ----------------------------------------------------------------------
-# persistent compilation cache
+# persistent compilation cache (the rule itself: test_chip_bringup.py)
 # ----------------------------------------------------------------------
-def test_compile_cache_config(tmp_path):
-    import jax
+def test_context_applies_the_compile_cache_rule(monkeypatch):
+    """Creating a context is one of the entry points that call
+    ``enable_compile_cache`` before the first compile."""
+    from analytics_zoo_tpu.common import nncontext as NN
 
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        set_nncontext(None)
-        set_nncontext(ZooContext(ZooConfig(
-            compile_cache_dir=str(tmp_path / "xla-cache"))))
-        assert jax.config.jax_compilation_cache_dir == \
-            str(tmp_path / "xla-cache")
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-        set_nncontext(None)
+    calls = []
+    monkeypatch.setattr(NN, "enable_compile_cache",
+                        lambda: calls.append(1))
+    set_nncontext(None)
+    set_nncontext(ZooContext(ZooConfig()))
+    assert calls == [1]

@@ -357,9 +357,13 @@ class TestEngineIntegration:
         set_nncontext(None)
         set_nncontext(ZooContext(ZooConfig(log_every_n_steps=2, **cfg_kw)))
         try:
+            # explicit names: get_weights() orders by layer name, and
+            # auto-names (dense_99, dense_100) sort differently from run
+            # to run depending on how many layers earlier tests built
             m = Sequential()
-            m.add(Dense(8, activation="relu", input_shape=(4,)))
-            m.add(Dense(1))
+            m.add(Dense(8, activation="relu", input_shape=(4,),
+                        name="hidden"))
+            m.add(Dense(1, name="out"))
             m.compile(optimizer="sgd", loss="mse")
             m.set_tensorboard(str(tmp_path), tb_name)
             rng = np.random.default_rng(0)

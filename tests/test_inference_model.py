@@ -159,7 +159,7 @@ def test_inference_model_load_zoo_wrapper_dir(tmp_path):
 
 class TestCalibratedInt8:
     """Activation-calibrated int8 compute (ops/quant.py) — the compute
-    half of the OpenVINO-int8 replacement (VERDICT r4 missing #3).
+    half of the OpenVINO-int8 replacement.
     Reference accuracy claim for the scheme replaced: <0.1% drop
     (wp-bigdl.md:192)."""
 

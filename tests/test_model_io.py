@@ -1,5 +1,5 @@
 """Definition-based persistence (model_io) + saveToTf export
-(VERDICT r2 weak #5 / missing #7; parity: Topology.scala:109,557-568)."""
+(parity: Topology.scala:109,557-568)."""
 
 import json
 import os

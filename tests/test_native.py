@@ -119,3 +119,34 @@ class TestMemoryTiers:
         batches = list(fs.batches(10, shuffle=True))
         assert len(batches) == 8
         assert batches[0].inputs[0].shape == (10, 3)
+
+
+def test_library_older_than_its_source_is_rebuilt(lib, monkeypatch):
+    """``native/build`` is git-ignored: a library that merely exists may
+    be another commit's. Missing, or older than zoo_data.cpp, means
+    rebuild before load; a failed rebuild is an ImportError, not a load
+    of the stale file."""
+    import os
+
+    from analytics_zoo_tpu.utils import native_loader as NL
+
+    assert not NL._stale()                 # the fixture just built/loaded
+    src_mtime = os.path.getmtime(NL._SRC_PATH)
+    lib_times = (os.path.getatime(NL._LIB_PATH),
+                 os.path.getmtime(NL._LIB_PATH))
+    try:
+        os.utime(NL._LIB_PATH, (src_mtime - 10, src_mtime - 10))
+        assert NL._stale()
+        monkeypatch.setattr(NL, "_loaded", None)
+        monkeypatch.setattr(NL, "_load_failed", False)
+        monkeypatch.setattr(NL, "build_native", lambda quiet=True: False)
+        with pytest.raises(ImportError, match="older than"):
+            NL.load_zoo_data()
+        monkeypatch.undo()
+        monkeypatch.setattr(NL, "_loaded", None)
+        monkeypatch.setattr(NL, "_load_failed", False)
+        assert NL.load_zoo_data().crc32c(b"abc") == py_crc32c(b"abc")
+        assert not NL._stale()             # make rebuilt it
+    finally:
+        if NL._stale():
+            os.utime(NL._LIB_PATH, lib_times)

@@ -81,7 +81,7 @@ def test_flash_attention_kernel_interpret_parity(monkeypatch):
                     reason="needs real TPU (kernel compiled by Mosaic)")
 def test_flash_attention_kernel_tpu_parity(monkeypatch):
     """Hardware proof: the compiled kernel matches reference fwd+bwd at
-    bf16-realistic shapes (VERDICT r1 item 2)."""
+    bf16-realistic shapes."""
     monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")   # below KERNEL_MIN_SEQ
     rng = np.random.default_rng(5)
     b, h, l, d = 2, 8, 512, 64
@@ -314,64 +314,6 @@ def test_flash_bwd_kernel_matches_xla_escape_hatch(monkeypatch):
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_flash_bwd_blhd_escape_hatch(monkeypatch):
-    """ZOO_TPU_FLASH_BWD=xla must take effect on the default blhd layout
-    too (it used to silently no-op there) and agree with the blhd kernel
-    backward, including the bias cotangent path through the layout
-    transposes."""
-    from analytics_zoo_tpu.ops.attention import flash_attention_blhd
-
-    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
-    q, k, v = _qkv(b=1, h=2, l=128, d=64, seed=9)
-    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))   # -> blhd
-    bias = jnp.zeros((1, 1, 1, 128)).at[:, :, :, 100:].set(-10000.0)
-
-    def loss(q, k, v):
-        return (flash_attention_blhd(q, k, v, bias=bias) ** 2).mean()
-
-    g_kernel = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("ZOO_TPU_FLASH_BWD", "xla")
-    g_xla = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_kernel, g_xla):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-3, atol=2e-3)
-
-
-def test_per_shape_probe_silent_fallback(monkeypatch):
-    """A shape whose kernel compile fails must silently route to the XLA
-    reference path (per-shape probe, r4); ZOO_TPU_FORCE_PALLAS=1 must skip
-    the probe and let the failure surface loudly."""
-    from analytics_zoo_tpu.ops import attention as A
-
-    monkeypatch.setattr(A, "_SHAPE_OK", {})
-    monkeypatch.setattr(A, "_interpret_mode", lambda: False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu",
-                        raising=False)
-    # this test exercises the probe; pin the (separately tested)
-    # multi-device partition guard open — the 8-device CPU runtime
-    # would otherwise block eligibility before the probe runs
-    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: True)
-
-    def boom(*a, **kw):
-        raise RuntimeError("Mosaic lowering failed for this shape")
-
-    monkeypatch.setattr(A, "_flash_forward", boom)
-
-    q, k, v = _qkv(b=1, h=1, l=2048, d=64, seed=10)
-    bias = jnp.zeros((1, 1, 1, 2048))
-    out = A.flash_attention(q, k, v, bias=bias)   # probe fails -> XLA path
-    ref = attention_reference(q, k, v, bias=bias)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    assert A._SHAPE_OK and not any(A._SHAPE_OK.values())
-
-    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
-    monkeypatch.setattr(A, "_SHAPE_OK", {})
-    with pytest.raises(RuntimeError, match="Mosaic"):
-        A.flash_attention(q, k, v, bias=bias)
-
-
 @pytest.mark.parametrize(
     "b,h,l,d,causal,dtype",
     [
@@ -387,7 +329,7 @@ def test_per_shape_probe_silent_fallback(monkeypatch):
         (4, 4, 128, 128, True, "float32"),
     ])
 def test_flash_kernel_parity_grid(monkeypatch, b, h, l, d, causal, dtype):
-    """r5 (VERDICT r4 next #8): pre-harden the kernels for first Mosaic
+    """r5: pre-harden the kernels for first Mosaic
     contact — fwd+bwd parity across head dims, non-power-of-two L, large
     B*H, causal x dtype. Interpret mode can't model Mosaic layouts (r2
     lesson), but it does catch indexing/masking bugs in exactly the
@@ -442,34 +384,25 @@ def test_flash_kernel_parity_grid(monkeypatch, b, h, l, d, causal, dtype):
 @pytest.mark.parametrize(
     "b,h,l,d,causal,dtype",
     [
-        (2, 2, 256, 64, False, "bfloat16"),
         (2, 2, 256, 128, True, "bfloat16"),
-        (2, 2, 384, 64, True, "float32"),
-        (1, 2, 384, 128, False, "bfloat16"),
         (6, 8, 128, 64, False, "float32"),
-        (4, 4, 128, 128, True, "float32"),
     ])
-def test_flash_kernel_blhd_parity_grid(monkeypatch, b, h, l, d, causal,
-                                       dtype):
-    """The transpose-free (B, L, H, d) entry over the same pre-hardening
-    grid as the bhld test above: fwd + all input cotangents vs the
-    reference math on transposed operands, asserting the blhd kernel
-    (not a fallback) ran. The head-squeezed BlockSpecs put the head
-    index in the DMA, which interpret mode does model at the indexing
-    level — Mosaic-level layout legality is covered by the per-shape
-    probe + the session's attn_parity leg on first chip contact."""
+def test_flash_blhd_entry_parity(monkeypatch, b, h, l, d, causal, dtype):
+    """The (B, L, H, d) entry — transposes around the bhld kernel: fwd +
+    all input cotangents vs the reference math on transposed operands,
+    asserting the kernel (not the XLA route) ran."""
     from analytics_zoo_tpu.ops import attention as A
 
     monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
     calls = []
-    real = A._flash_attention_blhd
+    real = A._flash_attention_bhld
 
     def spy(*args, **kw):
         calls.append(1)
         return real(*args, **kw)
 
-    monkeypatch.setattr(A, "_flash_attention_blhd", spy)
+    monkeypatch.setattr(A, "_flash_attention_bhld", spy)
 
     qt, kt, vt = _qkv(b=b, h=h, l=l, d=d, seed=l + d + 1)
     dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
@@ -495,7 +428,7 @@ def test_flash_kernel_blhd_parity_grid(monkeypatch, b, h, l, d, causal,
             causal=causal).astype(jnp.float32) ** 2).mean()
 
     out = A.flash_attention_blhd(q, k, v, bias=bias, causal=causal)
-    assert calls, "grid point must exercise the blhd kernel, not XLA"
+    assert calls, "grid point must exercise the kernel, not XLA"
     ref = attention_reference(qt.astype(dt), kt.astype(dt), vt.astype(dt),
                               bias=bias, causal=causal)
     tol = 2e-2 if dtype == "bfloat16" else 2e-3
@@ -532,7 +465,7 @@ def test_fused_dropout_ln_parity(monkeypatch, n, d, dtype):
     bits = jnp.asarray(rng.integers(0, 2 ** 32, (n, d),
                                     dtype=np.uint64).astype(np.uint32))
     keep, eps = 0.9, 1e-5
-    br = F._pick_rows(n)
+    br = F._pick_rows(n, d, jnp.dtype(dt).itemsize)
     assert br > 0 and n % br == 0
 
     def ref(x, r, g, b):
@@ -666,8 +599,7 @@ def test_dp_wrap_grad_parity(monkeypatch):
 
 def test_mosaic_partition_guard(monkeypatch):
     """Mosaic custom calls raise under a multi-device jit unless ALL
-    mesh axes are manual (jax._src.tpu_custom_call) — the probe can't
-    catch it (it compiles unsharded avals), so routing must. On this
+    mesh axes are manual, so routing must keep them out. On this
     8-device CPU runtime: blocked outside shard_map, allowed inside a
     fully-manual shard_map, bypassed in interpret mode."""
     from analytics_zoo_tpu.common import nncontext as NN
@@ -713,56 +645,10 @@ def test_mosaic_partition_guard(monkeypatch):
 
     monkeypatch.setattr(NN, "_global_context", None)
     monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
-    assert A.mosaic_partition_ok()         # loud-failure contract kept
+    assert A.mosaic_partition_ok()         # the user insists
     monkeypatch.delenv("ZOO_TPU_FORCE_PALLAS", raising=False)
     monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
     assert A.mosaic_partition_ok()
-
-
-def test_kernel_layouts_ok_scoping(monkeypatch):
-    """The probe-cache accessor bench.py records per leg: scoped to a
-    signature (a blhd pass at another batch must not mask this batch's
-    fallback), and 'forced' when FORCE_PALLAS/interpret skip probing."""
-    from analytics_zoo_tpu.ops import attention as A
-
-    monkeypatch.setattr(A, "_SHAPE_OK", {
-        (64, 12, 512, 512, 64, False, "bfloat16", 512, 512, "blhd"): True,
-        (32, 12, 512, 512, 64, False, "bfloat16", 512, 512, "blhd"): False,
-        (32, 12, 512, 512, 64, False, "bfloat16", 512, 512, "bhld"): True,
-    })
-    monkeypatch.delenv("ZOO_TPU_FORCE_PALLAS", raising=False)
-    monkeypatch.delenv("ZOO_TPU_PALLAS_INTERPRET", raising=False)
-    assert A.kernel_layouts_ok(b=32, h=12, lq=512, lk=512,
-                               d=64) == ["bhld"]
-    assert A.kernel_layouts_ok(b=64, h=12, lq=512, lk=512,
-                               d=64) == ["blhd"]
-    assert A.kernel_layouts_ok() == ["bhld", "blhd"]
-    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
-    assert A.kernel_layouts_ok() == ["forced"]
-
-
-def test_flash_blhd_layout_env_forces_fallback(monkeypatch):
-    """ZOO_TPU_ATTN_LAYOUT=bhld must route blhd inputs through the
-    transposed flash_attention path (escape hatch + A/B arm), bit-equal
-    to calling it directly."""
-    from analytics_zoo_tpu.ops import attention as A
-
-    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setenv("ZOO_TPU_ATTN_LAYOUT", "bhld")
-    calls = []
-    monkeypatch.setattr(
-        A, "_flash_attention_blhd",
-        lambda *a, **kw: calls.append(1) or (_ for _ in ()).throw(
-            AssertionError("blhd kernel must not run")))
-    qt, kt, vt = _qkv(b=2, h=2, l=256, d=64, seed=9)
-    bias = jnp.zeros((2, 1, 1, 256), jnp.float32)
-    out = A.flash_attention_blhd(
-        qt.transpose(0, 2, 1, 3), kt.transpose(0, 2, 1, 3),
-        vt.transpose(0, 2, 1, 3), bias=bias)
-    ref = A.flash_attention(qt, kt, vt, bias=bias)
-    assert not calls
-    np.testing.assert_array_equal(
-        np.asarray(out.transpose(0, 2, 1, 3)), np.asarray(ref))
 
 
 def test_flash_kernel_ineligible_shapes_route_to_xla(monkeypatch):
@@ -850,49 +736,6 @@ class TestUlysses:
                             np.asarray(a), np.asarray(b_),
                             rtol=2e-4, atol=2e-4)
 
-    def test_blhd_parity_fwd_bwd(self):
-        """The transpose-free (B, L, H, d) twin the layer's ulysses
-        branch now uses: fwd + input/kbias cotangents vs the reference
-        math over the causal x kbias grid."""
-        from analytics_zoo_tpu.parallel.ulysses import \
-            ulysses_attention_blhd_sharded
-
-        mesh = self._mesh()
-        rng = np.random.default_rng(3)
-        b, h, l, d = 2, 8, 64, 16
-        qt, kt, vt = (jnp.asarray(rng.standard_normal((b, h, l, d)),
-                                  jnp.float32) for _ in range(3))
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (qt, kt, vt))
-        kbias = jnp.zeros((b, l)).at[:, 50:].set(-10000.0)
-        for causal in (False, True):
-            for kb in (None, kbias):
-                out = ulysses_attention_blhd_sharded(
-                    q, k, v, mesh, causal=causal, kbias=kb)
-                bias4 = None if kb is None else kb[:, None, None, :]
-                ref = attention_reference(qt, kt, vt, bias=bias4,
-                                          causal=causal)
-                np.testing.assert_allclose(
-                    np.asarray(out.transpose(0, 2, 1, 3)),
-                    np.asarray(ref), rtol=2e-5, atol=2e-5)
-
-                def loss(q, k, v, _c=causal, _kb=kb):
-                    return (ulysses_attention_blhd_sharded(
-                        q, k, v, mesh, causal=_c, kbias=_kb) ** 2).mean()
-
-                def loss_ref(q, k, v, _c=causal, _kb=kb):
-                    b4 = None if _kb is None else _kb[:, None, None, :]
-                    return (attention_reference(
-                        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                        v.transpose(0, 2, 1, 3), bias=b4,
-                        causal=_c) ** 2).mean()
-
-                g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-                gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-                for a, b_ in zip(g, gr):
-                    np.testing.assert_allclose(
-                        np.asarray(a), np.asarray(b_),
-                        rtol=2e-4, atol=2e-4)
-
     def test_head_count_guard(self):
         from analytics_zoo_tpu.parallel import ulysses_attention_sharded
 
@@ -901,16 +744,6 @@ class TestUlysses:
         q = jnp.asarray(rng.standard_normal((1, 4, 64, 8)), jnp.float32)
         with pytest.raises(ValueError, match="heads % devices"):
             ulysses_attention_sharded(q, q, q, mesh)   # 4 heads, 8 devs
-
-    def test_blhd_head_count_guard(self):
-        from analytics_zoo_tpu.parallel.ulysses import \
-            ulysses_attention_blhd_sharded
-
-        mesh = self._mesh()
-        rng = np.random.default_rng(1)
-        q = jnp.asarray(rng.standard_normal((1, 64, 4, 8)), jnp.float32)
-        with pytest.raises(ValueError, match="heads % devices"):
-            ulysses_attention_blhd_sharded(q, q, q, mesh)
 
     def test_layer_strategy_routing(self, monkeypatch):
         """sequence_parallel_mode: auto picks ulysses when heads divide
@@ -927,9 +760,8 @@ class TestUlysses:
             import TransformerLayer
 
         calls = {"ring": 0, "ulysses": 0}
-        # the layer's ulysses branch goes through the blhd twin (r5)
         real_r = R.ring_attention_sharded
-        real_u = U.ulysses_attention_blhd_sharded
+        real_u = U.ulysses_attention_sharded
 
         def spy_r(*a, **kw):
             calls["ring"] += 1
@@ -940,7 +772,7 @@ class TestUlysses:
             return real_u(*a, **kw)
 
         monkeypatch.setattr(R, "ring_attention_sharded", spy_r)
-        monkeypatch.setattr(U, "ulysses_attention_blhd_sharded", spy_u)
+        monkeypatch.setattr(U, "ulysses_attention_sharded", spy_u)
 
         rng = np.random.default_rng(2)
         tokens = rng.integers(0, 50, (2, 8)).astype(np.int32)

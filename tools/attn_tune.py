@@ -6,7 +6,7 @@ A/B at BERT head geometry across sequence lengths:
   - jax's library TPU flash kernel (no bias) as an achievability bound
 
 Appends JSON lines to ATTN_TUNE.jsonl. Run serialized — nothing else on
-the chip (BENCH_NOTES trap #7).
+the chip.
 
 Usage: python tools/attn_tune.py
 """
