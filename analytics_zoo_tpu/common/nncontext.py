@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import threading
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -315,7 +316,66 @@ def enable_compile_cache() -> Optional[str]:
     directory = jax.config.jax_compilation_cache_dir
     if directory:
         logger.info("persistent compilation cache -> %s", directory)
+    _register_compile_listeners()
     return directory
+
+
+# jax's own compile events, as this process hears them
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_listeners_on = False
+
+
+def _register_compile_listeners():
+    """Once per process: jax's compile events become program spans
+    (``compile/trace``, ``compile/lower``, ``compile/backend``) and two
+    always-on counters, ``zoo_compile_backend_total{cache_hit=...}``.
+
+    jax reports a load from the persistent cache under the same
+    ``backend_compile_duration`` event as a compile, after a
+    ``cache_hits`` event and the retrieval time on the same thread:
+    those two, heard first, mark the span that follows as a load."""
+    global _compile_listeners_on
+    if _compile_listeners_on:
+        return
+    _compile_listeners_on = True
+    import jax
+
+    from ..utils import telemetry
+
+    pending = threading.local()     # what this thread's compile heard
+    for hit in ("false", "true"):   # both in every snapshot, from 0
+        telemetry.counter("zoo_compile_backend_total", cache_hit=hit)
+
+    def on_event(event, **kw):
+        if event == _CACHE_HIT_EVENT:
+            pending.hit = True
+
+    def on_duration(event, duration, **kw):
+        if event == _CACHE_RETRIEVAL_EVENT:
+            pending.retrieval_ms = duration * 1e3
+            return
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        args = {"fun": kw["fun_name"]} if "fun_name" in kw else {}
+        if name == "compile/backend":
+            hit = getattr(pending, "hit", False)
+            args["cache_hit"] = hit
+            if hit:
+                args["retrieval_ms"] = getattr(pending, "retrieval_ms", 0.0)
+            pending.hit = False
+            telemetry.counter("zoo_compile_backend_total",
+                              cache_hit=str(hit).lower()).inc()
+        telemetry.complete_span(name, duration, **args)
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def _maybe_enable_telemetry(cfg: ZooConfig):

@@ -3,7 +3,7 @@
 Same skeleton as :mod:`launcher.chaos_train` but tuned so every span
 family the telemetry spine promises actually fires in a 3-step run:
 
-- ``log_every_n_steps=1`` — ``train/device_sync`` + ``train/metric_fetch``
+- ``log_every_n_steps=1`` — ``train/device_sync`` + ``train/window_log``
   run every step instead of only at the log boundary;
 - ``checkpoint_trigger=SeveralIteration(1)`` — a ``ckpt/write`` span per
   step;

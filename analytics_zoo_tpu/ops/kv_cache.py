@@ -233,20 +233,23 @@ def cached_attention_step(q, k_new, v_new, k_cache, v_cache, lengths,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    k_cache = _write_row(k_cache, k_new, lengths)
-    v_cache = _write_row(v_cache, v_new, lengths)
+    with jax.named_scope("zoo_kv_update"):
+        k_cache = _write_row(k_cache, k_new, lengths)
+        v_cache = _write_row(v_cache, v_new, lengths)
     new_lengths = lengths + 1
 
     # (B, H, S) scores: single query row vs the whole slab — the only
     # attention contraction in the step jaxpr, and it is O(S), not O(S^2).
     f32 = jnp.float32
-    s = _score_slab(q[:, 0].astype(f32), k_cache) * sm_scale
-    valid = jnp.arange(k_cache.shape[1])[None, :] < new_lengths[:, None]
-    s = jnp.where(valid[:, None, :], s, -1e30)
-    # rows with lengths == 0 (empty slots) softmax over the single -1e30
-    # plateau — finite, and the scheduler discards their output anyway
-    p = jax.nn.softmax(s, axis=-1)
-    o = _mix_slab(p, v_cache)
+    with jax.named_scope("zoo_decode_attn"):
+        s = _score_slab(q[:, 0].astype(f32), k_cache) * sm_scale
+        valid = jnp.arange(k_cache.shape[1])[None, :] < \
+            new_lengths[:, None]
+        s = jnp.where(valid[:, None, :], s, -1e30)
+        # rows with lengths == 0 (empty slots) softmax over the single
+        # -1e30 plateau — finite, and the scheduler discards their output
+        p = jax.nn.softmax(s, axis=-1)
+        o = _mix_slab(p, v_cache)
     return (o[:, None].astype(q.dtype), k_cache, v_cache, new_lengths)
 
 
@@ -294,29 +297,33 @@ def cached_attention_chunk(q, k_new, v_new, k_cache, v_cache, lengths,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     c = q.shape[1]
-    k_cache = _write_row(k_cache, k_new, lengths)
-    v_cache = _write_row(v_cache, v_new, lengths)
+    with jax.named_scope("zoo_kv_update"):
+        k_cache = _write_row(k_cache, k_new, lengths)
+        v_cache = _write_row(v_cache, v_new, lengths)
     new_lengths = lengths + (c if n_valid is None else n_valid)
 
     f32 = jnp.float32
-    if isinstance(k_cache, Int8KVSlab):
-        s = jnp.einsum("bchd,bshd->bhcs", q.astype(f32),
-                       k_cache.q.astype(f32))
-        s = s * k_cache.scale[..., 0].transpose(0, 2, 1)[:, :, None, :]
-    else:
-        s = jnp.einsum("bchd,bshd->bhcs", q.astype(f32),
-                       k_cache.astype(f32))
-    s = s * sm_scale
-    pos = lengths[:, None] + jnp.arange(c)[None, :]            # (B, C)
-    valid = (jnp.arange(k_cache.shape[1])[None, None, :]
-             <= pos[:, :, None])                               # (B, C, S)
-    s = jnp.where(valid[:, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    if isinstance(v_cache, Int8KVSlab):
-        p = p * v_cache.scale[..., 0].transpose(0, 2, 1)[:, :, None, :]
-        o = jnp.einsum("bhcs,bshd->bchd", p, v_cache.q.astype(f32))
-    else:
-        o = jnp.einsum("bhcs,bshd->bchd", p, v_cache.astype(f32))
+    with jax.named_scope("zoo_decode_attn"):
+        if isinstance(k_cache, Int8KVSlab):
+            s = jnp.einsum("bchd,bshd->bhcs", q.astype(f32),
+                           k_cache.q.astype(f32))
+            s = s * k_cache.scale[..., 0].transpose(
+                0, 2, 1)[:, :, None, :]
+        else:
+            s = jnp.einsum("bchd,bshd->bhcs", q.astype(f32),
+                           k_cache.astype(f32))
+        s = s * sm_scale
+        pos = lengths[:, None] + jnp.arange(c)[None, :]        # (B, C)
+        valid = (jnp.arange(k_cache.shape[1])[None, None, :]
+                 <= pos[:, :, None])                           # (B, C, S)
+        s = jnp.where(valid[:, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        if isinstance(v_cache, Int8KVSlab):
+            p = p * v_cache.scale[..., 0].transpose(
+                0, 2, 1)[:, :, None, :]
+            o = jnp.einsum("bhcs,bshd->bchd", p, v_cache.q.astype(f32))
+        else:
+            o = jnp.einsum("bhcs,bshd->bchd", p, v_cache.astype(f32))
     return (o.astype(q.dtype), k_cache, v_cache, new_lengths)
 
 

@@ -29,6 +29,7 @@ from .....common.nncontext import get_nncontext
 from .....feature.feature_set import ArrayFeatureSet, FeatureSet
 from .....pipeline.engine import GradientClipping, SPMDTrainer
 from .....utils import serialization, tensorboard
+from .....utils.telemetry import span
 from ..metrics import get_metric
 from ..objectives import get_loss
 from ..optimizers import get_optimizer
@@ -152,7 +153,8 @@ class KerasNet(KerasLayer):
                                rng=rng, collect_state=True)
 
         def init_fn(rng):
-            return graph.init(rng)
+            with span("model/build"):
+                return graph.init(rng)
 
         optimizer = self.optimizer or get_optimizer("sgd")
         loss = self.loss if self.loss is not None else get_loss("mse")
@@ -334,12 +336,13 @@ class KerasNet(KerasLayer):
         leaves = jax.tree_util.tree_leaves(params)
         assert len(leaves) == len(weights), \
             f"expected {len(leaves)} arrays, got {len(weights)}"
-        new_leaves = [jnp.asarray(w, l.dtype) if hasattr(l, "dtype")
-                      else w for w, l in zip(weights, leaves)]
-        new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
-        self._built_params = (new_params, state)
-        if self.trainer is not None:
-            self.trainer.set_params(new_params, state)
+        with span("model/set_weights", leaves=len(leaves)):
+            new_leaves = [jnp.asarray(w, l.dtype) if hasattr(l, "dtype")
+                          else w for w, l in zip(weights, leaves)]
+            new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
+            self._built_params = (new_params, state)
+            if self.trainer is not None:
+                self.trainer.set_params(new_params, state)
 
     def get_params(self):
         return self._params_tuple()[0]

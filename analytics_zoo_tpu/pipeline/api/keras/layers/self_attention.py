@@ -466,7 +466,8 @@ class TransformerLayer(KerasLayer):
         if self.embedding_layer is not None:
             raise ValueError("lm_logits needs the built-in token "
                              "embedding (weight tying)")
-        return jnp.matmul(x, params["tok_emb"].T.astype(x.dtype))
+        with jax.named_scope("zoo_lm_head"):
+            return jnp.matmul(x, params["tok_emb"].T.astype(x.dtype))
 
     def prefill(self, params, tokens, lengths, state):
         """Fill the cache from padded prompts; return last-token logits.

@@ -244,7 +244,7 @@ class SPMDTrainer:
         # breakdown from memory_analysis() (utils/memory.py) and the
         # programs already accounted (one AOT compile each)
         self.hbm_breakdown: Optional[Dict[str, int]] = None
-        self._mem_accounted: set = set()
+        self._mem_accounted = False
         # training health monitor (pipeline/health.py), built per
         # train() when ZooConfig.health_monitor is on
         self._health = None
@@ -310,10 +310,12 @@ class SPMDTrainer:
     def ensure_initialized(self):
         if self.params is not None:
             return
-        rng = jax.random.PRNGKey(self.seed)
-        params, state = self.init_fn(rng)
-        self._place_state(params, state)
-        self.opt_state = self._place_opt_state(self.tx.init(self.params))
+        with span("train/init_state"):
+            rng = jax.random.PRNGKey(self.seed)
+            params, state = self.init_fn(rng)
+            self._place_state(params, state)
+            self.opt_state = self._place_opt_state(
+                self.tx.init(self.params))
 
     # Explicit placement: every input of the compiled step carries the
     # mesh NamedSharding. One leaf left on a jit-default/single-device
@@ -782,10 +784,9 @@ class SPMDTrainer:
         def step_fn(params, opt_state, net_state, batch, step):
             return self._step_body(params, opt_state, net_state, batch, step)
 
-        if self.ctx.config.donate_buffers:
-            self._train_step = jax.jit(step_fn, donate_argnums=(0, 1, 2))
-        else:
-            self._train_step = jax.jit(step_fn)
+        donate = (0, 1, 2) if self.ctx.config.donate_buffers else ()
+        with span("train/build_program", k=1):
+            self._train_step = jax.jit(step_fn, donate_argnums=donate)
         return self._train_step
 
     def build_multi_step(self, k: int):
@@ -828,11 +829,9 @@ class SPMDTrainer:
         # always rebinds self.params/... to the returned arrays. Honors
         # donate_buffers=False for callers that must keep param aliases
         # alive across steps.
-        if self.ctx.config.donate_buffers:
-            self._multi_steps[k] = jax.jit(multi_fn,
-                                           donate_argnums=(0, 1, 2))
-        else:
-            self._multi_steps[k] = jax.jit(multi_fn)
+        donate = (0, 1, 2) if self.ctx.config.donate_buffers else ()
+        with span("train/build_program", k=k):
+            self._multi_steps[k] = jax.jit(multi_fn, donate_argnums=donate)
         return self._multi_steps[k]
 
     def _eval_stats(self, params, net_state, batch):
@@ -1149,11 +1148,9 @@ class SPMDTrainer:
         if self.flops_per_step is not None or self.train_summary is None:
             return
         try:
-            abs_args = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-                if hasattr(x, "shape") and hasattr(x, "dtype") else x,
-                args, is_leaf=lambda x: x is None)
-            cost = fn.lower(*abs_args).cost_analysis() or {}
+            with span("train/record_flops", k=k):
+                cost = fn.lower(
+                    *self._abstractify(args)).cost_analysis() or {}
             flops = cost.get("flops")
             # 0 disables re-tries (and the MFU scalar) if analysis yields
             # nothing useful
@@ -1169,36 +1166,32 @@ class SPMDTrainer:
             if hasattr(x, "shape") and hasattr(x, "dtype") else x,
             args, is_leaf=lambda x: x is None)
 
-    def _maybe_account_memory(self, program: str, fn, args):
+    def _maybe_account_memory(self, fn, args):
         """Device-memory accountant hook (utils/memory.py): AOT-compile
-        the program once with abstract args, record its
+        the train program once with abstract args, record its
         ``memory_analysis()`` breakdown (params / optimizer state /
         activations+temp / transfers) into ``zoo_hbm_program_*`` gauges,
         and keep the HLO tail for OOM forensics. Unlike
         :meth:`_maybe_record_flops` this is a real second XLA compile of
-        the program — gated by ``ZooConfig.memory_accounting``."""
-        if program in self._mem_accounted or \
+        the program, so it runs only for its consumer, a ``TrainSummary``
+        (as :meth:`_maybe_record_flops` does), and never because tracing
+        is on: a traced run compiles what an untraced run compiles."""
+        if self._mem_accounted or self.train_summary is None or \
                 not getattr(self.ctx.config, "memory_accounting", True):
             return
-        # only pay the AOT compile when the result has a consumer: a
-        # TrainSummary for the train breakdown, or the telemetry spine
-        # for the zoo_hbm_program_* gauges (mirrors _maybe_record_flops)
-        if not telemetry.enabled() and \
-                not (program == "train" and self.train_summary is not None):
-            return
-        self._mem_accounted.add(program)
+        self._mem_accounted = True
         try:
-            compiled = fn.lower(*self._abstractify(args)).compile()
+            with span("train/account_memory"):
+                compiled = fn.lower(*self._abstractify(args)).compile()
             hlo = None
             try:
                 hlo = compiled.as_text()
             except Exception:  # noqa: BLE001 - HLO text is best-effort
                 pass
             bd = memory.account_program(
-                program, compiled, params=self.params,
-                opt_state=self.opt_state if program == "train" else None,
-                hlo_text=hlo)
-            if program == "train" and bd is not None:
+                "train", compiled, params=self.params,
+                opt_state=self.opt_state, hlo_text=hlo)
+            if bd is not None:
                 self.hbm_breakdown = bd
                 logger.info(
                     "train step HBM breakdown: total %.1f MiB (params "
@@ -1208,8 +1201,7 @@ class SPMDTrainer:
                     bd["activations_temp_bytes"] / 2**20,
                     bd["transfers_bytes"] / 2**20)
         except Exception:  # noqa: BLE001 - observability must not kill run
-            logger.debug("memory accounting failed for %s", program,
-                         exc_info=True)
+            logger.debug("memory accounting failed", exc_info=True)
 
     def _ckpt_allowed(self) -> bool:
         """Checkpoint writes are refused once the health monitor latched
@@ -1292,7 +1284,8 @@ class SPMDTrainer:
                 # batches for this dispatch are already device-resident:
                 # the staging iterator ran device_put while the previous
                 # dispatch was computing
-                chunk = staging.next_chunk(k)
+                with span("train/next_chunk", k=k):
+                    chunk = staging.next_chunk(k)
                 if chunk is None:
                     break
                 # chaos harness: armed step:nan@N / grad:nan@N faults
@@ -1309,9 +1302,8 @@ class SPMDTrainer:
                         multi, (self.params, self.opt_state,
                                 self.net_state, chunk.stacked, self.step), k)
                     self._maybe_account_memory(
-                        "train", multi, (self.params, self.opt_state,
-                                         self.net_state, chunk.stacked,
-                                         self.step))
+                        multi, (self.params, self.opt_state,
+                                self.net_state, chunk.stacked, self.step))
                     with span("train/dispatch", step=self.step, k=k):
                         (self.params, self.opt_state, self.net_state,
                          logs) = multi(self.params, self.opt_state,
@@ -1335,9 +1327,9 @@ class SPMDTrainer:
                                           self.net_state, batch,
                                           self.step), 1)
                             self._maybe_account_memory(
-                                "train", step_fn,
-                                (self.params, self.opt_state,
-                                 self.net_state, batch, self.step))
+                                step_fn, (self.params, self.opt_state,
+                                          self.net_state, batch,
+                                          self.step))
                         with span("train/dispatch", step=self.step + done):
                             (self.params, self.opt_state, self.net_state,
                              logs) = step_fn(self.params, self.opt_state,
@@ -1372,88 +1364,91 @@ class SPMDTrainer:
                 # the device barrier for everything dispatched before it
                 with span("train/device_sync", step=self.step):
                     loss_v = float(np.asarray(last_loss))
-                record.loss = loss_v
-                lr = float(self.lr_schedule(self.step))
-                now = time.perf_counter()
-                wall = max(now - window_t0, 1e-9)
-                with span("train/metric_fetch", step=self.step):
+                with span("train/window_log", step=self.step):
+                    record.loss = loss_v
+                    lr = float(self.lr_schedule(self.step))
+                    now = time.perf_counter()
+                    wall = max(now - window_t0, 1e-9)
                     infeed = monitor.window(window_steps, wall)
-                telemetry.gauge("zoo_train_loss").set(loss_v)
-                telemetry.gauge("zoo_train_learning_rate").set(lr)
-                gnorm_v = float(np.asarray(logs["grad_norm"])) \
-                    if "grad_norm" in logs else None
-                if self._health is not None:
-                    # EWMA z-score spike detection on the window scalars
-                    # (also a host-side non-finite backstop)
-                    self._health.observe_window(
-                        self.step, loss=loss_v, grad_norm=gnorm_v,
-                        step_time_ms=infeed["step_time_ms"])
-                if getattr(cfg, "memory_accounting", True):
-                    # live HBM watermarks (None on the CPU stub); latches
-                    # an OOM-forensics dump past hbm_watermark_fraction
-                    memory.poll_device_memory(
-                        self.ctx.devices,
-                        watermark_fraction=getattr(
-                            cfg, "hbm_watermark_fraction", 0.0),
-                        out_dir=getattr(cfg, "trace_dir", None))
-                if self.train_summary is not None:
-                    self.train_summary.add_scalar("Loss", loss_v, self.step)
-                    self.train_summary.add_scalar("LearningRate", lr,
-                                                  self.step)
-                    if gnorm_v is not None:   # opt-in; single-step path
-                        self.train_summary.add_scalar(
-                            "GradNorm", gnorm_v, self.step)
+                    telemetry.gauge("zoo_train_loss").set(loss_v)
+                    telemetry.gauge("zoo_train_learning_rate").set(lr)
+                    gnorm_v = float(np.asarray(logs["grad_norm"])) \
+                        if "grad_norm" in logs else None
                     if self._health is not None:
-                        self.train_summary.add_scalar(
-                            "HealthState", float(self._health.state),
-                            self.step)
-                    if self.hbm_breakdown is not None:
-                        bd = self.hbm_breakdown
-                        mib = 1.0 / 2**20
-                        self.train_summary.add_scalar(
-                            "HBMTotalMB", bd["total_bytes"] * mib,
-                            self.step)
-                        self.train_summary.add_scalar(
-                            "HBMParamsMB", bd["params_bytes"] * mib,
-                            self.step)
-                        self.train_summary.add_scalar(
-                            "HBMOptStateMB", bd["opt_state_bytes"] * mib,
-                            self.step)
-                        self.train_summary.add_scalar(
-                            "HBMActivationsMB",
-                            bd["activations_temp_bytes"] * mib, self.step)
-                        self.train_summary.add_scalar(
-                            "HBMTransfersMB", bd["transfers_bytes"] * mib,
-                            self.step)
-                    self.train_summary.add_scalar(
-                        "Throughput", window_steps * batch_size / wall,
-                        self.step)
-                    self.train_summary.add_scalar(
-                        "StepTimeMs", infeed["step_time_ms"], self.step)
-                    self.train_summary.add_scalar(
-                        "InfeedWaitMs", infeed["input_wait_ms_per_step"],
-                        self.step)
-                    self.train_summary.add_scalar(
-                        "InputBoundFraction",
-                        infeed["input_bound_fraction"], self.step)
-                    if "infeed_workers" in infeed:
-                        self.train_summary.add_scalar(
-                            "InfeedWorkers", infeed["infeed_workers"],
-                            self.step)
-                        self.train_summary.add_scalar(
-                            "InfeedWorkerUtilization",
-                            infeed["infeed_worker_utilization"], self.step)
-                    if self.flops_per_step:
-                        peak = peak_flops(
-                            getattr(self.ctx.devices[0], "device_kind", ""))
-                        if peak:
+                        # EWMA z-score spike detection on the window scalars
+                        # (also a host-side non-finite backstop)
+                        self._health.observe_window(
+                            self.step, loss=loss_v, grad_norm=gnorm_v,
+                            step_time_ms=infeed["step_time_ms"])
+                    if getattr(cfg, "memory_accounting", True):
+                        # live HBM watermarks (None on the CPU stub);
+                        # latches an OOM-forensics dump past
+                        # hbm_watermark_fraction
+                        with span("train/memory_poll"):
+                            memory.poll_device_memory(
+                                self.ctx.devices,
+                                watermark_fraction=getattr(
+                                    cfg, "hbm_watermark_fraction", 0.0),
+                                out_dir=getattr(cfg, "trace_dir", None))
+                    if self.train_summary is not None:
+                        self.train_summary.add_scalar("Loss", loss_v,
+                                                      self.step)
+                        self.train_summary.add_scalar("LearningRate", lr,
+                                                      self.step)
+                        if gnorm_v is not None:   # opt-in; single-step path
                             self.train_summary.add_scalar(
-                                "MFU", self.flops_per_step * window_steps
-                                / wall / peak, self.step)
-                window_t0 = now
-                window_steps = 0
-                logger.info("epoch %d step %d loss %.5f", record.epoch,
-                            self.step, loss_v)
+                                "GradNorm", gnorm_v, self.step)
+                        if self._health is not None:
+                            self.train_summary.add_scalar(
+                                "HealthState", float(self._health.state),
+                                self.step)
+                        if self.hbm_breakdown is not None:
+                            bd = self.hbm_breakdown
+                            mib = 1.0 / 2**20
+                            self.train_summary.add_scalar(
+                                "HBMTotalMB", bd["total_bytes"] * mib,
+                                self.step)
+                            self.train_summary.add_scalar(
+                                "HBMParamsMB", bd["params_bytes"] * mib,
+                                self.step)
+                            self.train_summary.add_scalar(
+                                "HBMOptStateMB", bd["opt_state_bytes"] * mib,
+                                self.step)
+                            self.train_summary.add_scalar(
+                                "HBMActivationsMB",
+                                bd["activations_temp_bytes"] * mib, self.step)
+                            self.train_summary.add_scalar(
+                                "HBMTransfersMB", bd["transfers_bytes"] * mib,
+                                self.step)
+                        self.train_summary.add_scalar(
+                            "Throughput", window_steps * batch_size / wall,
+                            self.step)
+                        self.train_summary.add_scalar(
+                            "StepTimeMs", infeed["step_time_ms"], self.step)
+                        self.train_summary.add_scalar(
+                            "InfeedWaitMs", infeed["input_wait_ms_per_step"],
+                            self.step)
+                        self.train_summary.add_scalar(
+                            "InputBoundFraction",
+                            infeed["input_bound_fraction"], self.step)
+                        if "infeed_workers" in infeed:
+                            self.train_summary.add_scalar(
+                                "InfeedWorkers", infeed["infeed_workers"],
+                                self.step)
+                            self.train_summary.add_scalar(
+                                "InfeedWorkerUtilization",
+                                infeed["infeed_worker_utilization"], self.step)
+                        if self.flops_per_step:
+                            peak = peak_flops(getattr(
+                                self.ctx.devices[0], "device_kind", ""))
+                            if peak:
+                                self.train_summary.add_scalar(
+                                    "MFU", self.flops_per_step * window_steps
+                                    / wall / peak, self.step)
+                    window_t0 = now
+                    window_steps = 0
+                    logger.info("epoch %d step %d loss %.5f", record.epoch,
+                                self.step, loss_v)
             if checkpoint_trigger is not None and checkpoint_trigger(record) \
                     and self._ckpt_allowed():
                 self.save_checkpoint(self.checkpoint_dir)
@@ -1544,9 +1539,6 @@ class SPMDTrainer:
                     break
                 if chunk.stacked is not None:
                     multi_eval = self.build_multi_eval(chunk.k)
-                    self._maybe_account_memory(
-                        "eval", multi_eval,
-                        (self.params, self.net_state, chunk.stacked))
                     with span("eval/dispatch", k=chunk.k):
                         stats = multi_eval(
                             self.params, self.net_state, chunk.stacked)
@@ -1555,9 +1547,6 @@ class SPMDTrainer:
                     stats = None
                     with span("eval/dispatch", k=len(chunk.singles)):
                         for batch in chunk.singles:
-                            self._maybe_account_memory(
-                                "eval", eval_fn,
-                                (self.params, self.net_state, batch))
                             s = eval_fn(self.params, self.net_state, batch)
                             stats = s if stats is None else jax.tree.map(
                                 jnp.add, stats, s)
@@ -1615,9 +1604,6 @@ class SPMDTrainer:
                 counts = chunk.real_counts
                 if chunk.stacked is not None:
                     multi_predict = self.build_multi_predict(chunk.k)
-                    self._maybe_account_memory(
-                        "predict", multi_predict,
-                        (self.params, self.net_state, chunk.stacked[0]))
                     with span("predict/dispatch", k=chunk.k):
                         preds = multi_predict(
                             self.params, self.net_state, chunk.stacked[0])
@@ -1626,9 +1612,6 @@ class SPMDTrainer:
                 else:
                     with span("predict/dispatch", k=len(chunk.singles)):
                         for batch, c in zip(chunk.singles, counts):
-                            self._maybe_account_memory(
-                                "predict", predict_fn,
-                                (self.params, self.net_state, batch[0]))
                             preds = predict_fn(self.params, self.net_state,
                                                batch[0])
                             results.append((False, preds, [c]))

@@ -325,13 +325,15 @@ class StubDecodeEngine:
     def step(self, state, feeds: Dict[int, int],
              temps: Dict[int, float]):
         """Advance every fed slot one token; flat gang-wide cost."""
-        if self.ms_per_step > 0:
-            time.sleep(self.ms_per_step / 1e3)
+        with span("generate/dispatch"):
+            if self.ms_per_step > 0:
+                time.sleep(self.ms_per_step / 1e3)
         out = {}
-        for slot in feeds:
-            entry = state[slot]
-            entry[1] += 1
-            out[slot] = self._stream(entry, entry[1])
+        with span("generate/pick", slots=len(feeds)):
+            for slot in feeds:
+                entry = state[slot]
+                entry[1] += 1
+                out[slot] = self._stream(entry, entry[1])
         return state, out
 
     def step_chunk(self, state, feeds: Dict[int, List[int]],
@@ -340,19 +342,21 @@ class StubDecodeEngine:
         back (row i predicts the token after prefix+feeds[:i+1]), one
         flat gang-wide cost. ``draft_skew`` never applies here — the
         verifier is the ground-truth stream."""
-        if self.ms_per_step > 0:
-            time.sleep(self.ms_per_step / 1e3)
+        with span("generate/dispatch"):
+            if self.ms_per_step > 0:
+                time.sleep(self.ms_per_step / 1e3)
         out = {}
-        for slot, toks in feeds.items():
-            entry = state[slot]
-            base, emitted, stop_at = entry
-            preds = []
-            for i in range(len(toks)):
-                pos = emitted + 1 + i
-                preds.append(self.stop_id if stop_at == pos
-                             else base + pos)
-            entry[1] = emitted + len(toks)
-            out[slot] = preds
+        with span("generate/pick", slots=len(feeds)):
+            for slot, toks in feeds.items():
+                entry = state[slot]
+                base, emitted, stop_at = entry
+                preds = []
+                for i in range(len(toks)):
+                    pos = emitted + 1 + i
+                    preds.append(self.stop_id if stop_at == pos
+                                 else base + pos)
+                entry[1] = emitted + len(toks)
+                out[slot] = preds
         return state, out
 
     def rollback(self, state, drops: Dict[int, int]):
@@ -562,10 +566,13 @@ class TransformerDecodeEngine:
         tokens = np.zeros((state.batch,), np.int32)
         for slot, tok in feeds.items():
             tokens[slot] = tok
-        logits, state = self._step_fn(self.params, state,
-                                      jnp.asarray(tokens))
-        out = {slot: self._pick(logits[slot], temps.get(slot, 0.0))
-               for slot in feeds}
+        with span("generate/dispatch"):
+            logits, state = self._step_fn(self.params, state,
+                                          jnp.asarray(tokens))
+        # one device round trip per slot: the span shows what that costs
+        with span("generate/pick", slots=len(feeds)):
+            out = {slot: self._pick(logits[slot], temps.get(slot, 0.0))
+                   for slot in feeds}
         return state, out
 
     def step_chunk(self, state, feeds: Dict[int, List[int]],
@@ -580,16 +587,18 @@ class TransformerDecodeEngine:
         tokens = np.zeros((state.batch, width), np.int32)
         for slot, toks in feeds.items():
             tokens[slot] = toks
-        logits, state = self._chunk_fn(self.params, state,
-                                       jnp.asarray(tokens), None)
+        with span("generate/dispatch"):
+            logits, state = self._chunk_fn(self.params, state,
+                                           jnp.asarray(tokens), None)
         out = {}
-        for slot in feeds:
-            rows = logits[slot]
-            greedy = np.asarray(jnp.argmax(rows, axis=-1)).tolist()
-            temp = temps.get(slot, 0.0)
-            if temp and temp > 0.0:
-                greedy[0] = self._pick(rows[0], temp)
-            out[slot] = [int(t) for t in greedy]
+        with span("generate/pick", slots=len(feeds)):
+            for slot in feeds:
+                rows = logits[slot]
+                greedy = np.asarray(jnp.argmax(rows, axis=-1)).tolist()
+                temp = temps.get(slot, 0.0)
+                if temp and temp > 0.0:
+                    greedy[0] = self._pick(rows[0], temp)
+                out[slot] = [int(t) for t in greedy]
         return state, out
 
     def rollback(self, state, drops: Dict[int, int]):
@@ -648,6 +657,8 @@ class SpeculativeDecodeEngine:
                 getattr(draft, "prefill_chunk", None) is None:
             self.prefill_chunk = None    # degrade: scheduler won't chunk
         self.prefix_cache = None         # lookups need both caches; skip
+        self._m_acceptance = telemetry.gauge(
+            "zoo_generate_draft_acceptance_rate")
 
     # -- lifecycle (paired states) ----------------------------------------
     def alloc(self, nslots: int, capacity: int):
@@ -710,8 +721,7 @@ class SpeculativeDecodeEngine:
             self._proposed += k
         t_state = self.target.rollback(t_state, drops)
         d_state = self.draft.rollback(d_state, drops)
-        telemetry.gauge("zoo_generate_draft_acceptance_rate").set(
-            self.acceptance_rate)
+        self._m_acceptance.set(self.acceptance_rate)
         return (t_state, d_state), out
 
     @property
@@ -803,6 +813,24 @@ class ContinuousBatchScheduler:
         self.counts = {"submitted": 0, "committed": 0, "tokens": 0,
                        "joins": 0, "evictions": 0, "shed": 0,
                        "duplicate_commits": 0}
+        # bound once: the loop looks no metric up by name per step or
+        # per token (these record with telemetry off too)
+        self._m_steps = telemetry.counter("zoo_generate_steps_total")
+        self._m_slot_steps = telemetry.counter(
+            "zoo_generate_slot_steps_total")
+        self._m_tokens = telemetry.counter("zoo_generate_tokens_total")
+        self._m_joins = telemetry.counter("zoo_generate_join_total")
+        self._m_batched_joins = telemetry.counter(
+            "zoo_generate_batched_join_total")
+        self._m_step_ms = telemetry.summary("zoo_generate_step_ms")
+        self._m_ttft_ms = telemetry.summary("zoo_generate_ttft_ms")
+        self._m_queue_wait_ms = telemetry.summary(
+            "zoo_generate_queue_wait_ms")
+        self._m_prefill_chunk_ms = telemetry.summary(
+            "zoo_generate_prefill_chunk_ms")
+        self._m_active_slots = telemetry.gauge("zoo_generate_active_slots")
+        self._m_cache_occupancy = telemetry.gauge(
+            "zoo_generate_cache_occupancy")
 
     # -- public surface -------------------------------------------------
     def submit(self, req: GenRequest):
@@ -951,7 +979,9 @@ class ContinuousBatchScheduler:
             self._slots[slot] = _Slot(req=req, t_join=time.perf_counter())
         with self._lock:
             self.counts["joins"] += 1
-        telemetry.counter("zoo_generate_join_total").inc()
+        self._m_joins.inc()
+        self._m_queue_wait_ms.record(
+            (self._slots[slot].t_join - req.t_in) * 1e3)
         telemetry.event("generate_join", uri=req.uri, slot=slot,
                         cached=cached, trace_id=req.trace_id)
         self._note_token(slot, int(first))
@@ -973,8 +1003,7 @@ class ContinuousBatchScheduler:
                     telemetry.flow("serving/request", req.trace_id, "f")
             self._state, firsts = self.engine.join_batch(self._state,
                                                          joins)
-        telemetry.counter("zoo_generate_batched_join_total").inc(
-            len(joins))
+        self._m_batched_joins.inc(len(joins))
         for slot, req in joins:
             self._seat(slot, req, firsts[slot])
 
@@ -1020,7 +1049,7 @@ class ContinuousBatchScheduler:
         dt = time.perf_counter() - t0
         if self.admission is not None:
             self.admission.observe_prefill_chunk(dt)
-        telemetry.summary("zoo_generate_prefill_chunk_ms").record(dt * 1e3)
+        self._m_prefill_chunk_ms.record(dt * 1e3)
         if is_last:
             s.prefill_next = None
             self._seat(slot, s.req, int(first))
@@ -1041,10 +1070,8 @@ class ContinuousBatchScheduler:
         t_now = time.perf_counter()
         if s.t_first_token is None:
             s.t_first_token = t_now
-            telemetry.summary("zoo_generate_ttft_ms").record(
-                (t_now - s.req.t_in) * 1e3)
-        if telemetry.enabled():
-            s.t_tokens.append(t_now)
+            self._m_ttft_ms.record((t_now - s.req.t_in) * 1e3)
+        s.t_tokens.append(t_now)
         s.tokens.append(tok)
         s.last = tok
         with self._lock:
@@ -1076,7 +1103,6 @@ class ContinuousBatchScheduler:
         t_done = time.perf_counter()
         decode_s = max(t_done - s.t_join, 1e-9)
         tokens_per_s = len(s.tokens) / decode_s
-        telemetry.summary("zoo_generate_tokens_per_s").record(tokens_per_s)
         timing = {
             "ttft_ms": round((s.t_first_token - s.req.t_in) * 1e3, 3),
             "decode_ms": round(decode_s * 1e3, 3),
@@ -1085,12 +1111,10 @@ class ContinuousBatchScheduler:
         }
         if s.req.trace_id:
             timing["trace_id"] = s.req.trace_id
-        if s.t_tokens:
-            # per-token boundaries relative to join — the waterfall's
-            # token ruler (`zoo-serving trace <id>`); recorded only
-            # while telemetry is enabled to keep the hot path flat
-            timing["token_ms"] = [round((t - s.t_join) * 1e3, 3)
-                                  for t in s.t_tokens]
+        # per-token boundaries relative to join — the waterfall's
+        # token ruler (`zoo-serving trace <id>`), in every result
+        timing["token_ms"] = [round((t - s.t_join) * 1e3, 3)
+                              for t in s.t_tokens]
         if s.req.enqueue_ts_ms is not None:
             # lets the client complete the rtt/transport decomposition
             timing["enqueue_ts_ms"] = s.req.enqueue_ts_ms
@@ -1162,6 +1186,8 @@ class ContinuousBatchScheduler:
         if not feeds:
             return
         temps = {i: self._slots[i].req.temperature for i in feeds}
+        self._m_steps.inc()
+        self._m_slot_steps.inc(len(feeds))
         t0 = time.perf_counter()
         self._state, out = self.engine.step(self._state, feeds, temps)
         dt = time.perf_counter() - t0
@@ -1179,17 +1205,17 @@ class ContinuousBatchScheduler:
                 emitted += 1
         if self.admission is not None:
             self.admission.observe_tokens(emitted, dt)
-        telemetry.counter("zoo_generate_tokens_total").inc(emitted)
-        telemetry.summary("zoo_generate_step_ms").record(dt * 1e3)
+        self._m_tokens.inc(emitted)
+        self._m_step_ms.record(dt * 1e3)
         self._publish_occupancy()
 
     def _publish_occupancy(self):
         active = [s for s in self._slots if s is not None]
-        telemetry.gauge("zoo_generate_active_slots").set(len(active))
+        self._m_active_slots.set(len(active))
         if self._capacity > 0:
             used = sum(int(s.req.prompt.size) + len(s.tokens)
                        for s in active)
-            telemetry.gauge("zoo_generate_cache_occupancy").set(
+            self._m_cache_occupancy.set(
                 used / (self.max_slots * self._capacity))
 
     # -- main loop -------------------------------------------------------
@@ -1198,24 +1224,31 @@ class ContinuousBatchScheduler:
         queue and gang empty first; ``drain=False`` cancels in-flight
         sequences (committed with ``code="cancelled"``)."""
         while True:
-            self._evict_finished()
-            self._refill()
-            self._prefill_step()
-            active = sum(s is not None for s in self._slots)
-            if self._stop_evt.is_set():
-                if not self._drain:
+            if not any(s is not None for s in self._slots) and \
+                    self._queue.empty():
+                # idle: block briefly for the next request, outside the
+                # stage spans (an idle server records nothing)
+                if self._stop_evt.is_set():
                     break
-                if active == 0 and self._queue.empty():
-                    break
-            if active == 0:
-                # idle: block briefly for the next request
                 try:
                     req = self._queue.get(timeout=self.idle_poll_s)
                 except queue.Empty:
                     continue
                 self._queue.put(req)   # re-enter through _refill
                 continue
-            self._step()
+            with span("generate/evict"):
+                self._evict_finished()
+            with span("generate/refill"):
+                self._refill()
+            with span("generate/prefill_step"):
+                self._prefill_step()
+            if self._stop_evt.is_set() and not self._drain:
+                break
+            active = sum(s is not None for s in self._slots)
+            if active == 0:
+                continue
+            with span("generate/step", slots=active):
+                self._step()
         if not self._drain:
             for i, s in enumerate(self._slots):
                 if s is not None:

@@ -12,9 +12,13 @@ one shared layer underneath all of them (docs/observability.md):
   (`InfeedMonitor` and `InferenceSummary` store their numbers here and
   nowhere else).
 - **Span tracer** — ``with span("train/step", step=n):`` records
-  structured begin/end events.  When telemetry is disabled ``span()``
-  returns a shared no-op context manager: the cost is one global check
-  plus an attribute-free ``with`` (guarded by the overhead test).
+  structured begin/end events; ``complete_span(name, seconds)`` records
+  one that a library reported after the fact.  Every event goes into an
+  in-memory buffer of at most ``ZOO_TPU_TRACE_CAP`` events (what the cap
+  turns away is counted in ``zoo_telemetry_events_dropped_total``).
+  When telemetry is disabled ``span()`` returns a shared no-op context
+  manager: the cost is one global check plus an attribute-free ``with``
+  (guarded by the overhead test).
 - **Flight recorder** — every event also lands in a bounded ring
   buffer; :func:`dump_flight` writes the last-N spans plus a metrics
   snapshot to ``debug/flight-<pid>-<ts>.json``.  Fault paths (SIGTERM
@@ -50,7 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Summary",
     "get_registry", "counter", "gauge", "histogram", "summary",
-    "span", "event", "flow", "new_trace_id",
+    "span", "complete_span", "event", "flow", "new_trace_id",
     "enabled", "set_enabled", "configure",
     "enable_forwarding", "drain_events", "ingest_events",
     "write_trace", "dump_flight", "flight_events",
@@ -399,7 +403,8 @@ _TRACE_CAP = int(os.environ.get("ZOO_TPU_TRACE_CAP", "500000"))
 
 _rec_lock = threading.Lock()
 _ring: deque = deque(maxlen=_RING_SIZE)      # flight recorder (last N)
-_trace: List[tuple] = []                     # full trace (when dir set)
+_trace: List[tuple] = []                     # every event, up to the cap
+_dropped: Optional[Counter] = None           # bound at the first drop
 _outbox: deque = deque(maxlen=8192)          # worker->parent forwarding
 _forwarding = False
 _tid_names: Dict[int, str] = {}
@@ -407,24 +412,36 @@ _foreign: List[dict] = []                    # ingested worker timelines
 _atexit_armed = False
 
 # Event wire format (tuple keeps the hot path + pickling cheap):
-#   (ph, name, ts_us, tid, args_or_None)
+#   (ph, name, ts_ns, tid, args_or_None)
+# ts_ns is ``time.time_ns()``: the unix clock, so that spans line up with
+# a device trace whose window mark was stamped from the same clock; the
+# Chrome-trace export divides down to microseconds.
 # ph: "B" span begin, "E" span end, "i" instant event,
 #     "s"/"t"/"f" flow start/step/finish (args carries the flow "id" —
 #     cross-process arrows in the merged trace, docs/observability.md).
 
 
-def _now_us() -> int:
-    return int(time.time() * 1e6)
+_now_ns = time.time_ns
+_IMPORTED_NS = _now_ns()
 
 
-def _record(ev: tuple):
-    tid = ev[3]
+def _record(*evs: tuple):
+    """Events of one thread, in order. The ring keeps the last N for a
+    fault dump; the trace buffer keeps every event up to
+    ``ZOO_TPU_TRACE_CAP`` and counts what the cap turns away."""
+    global _dropped
+    tid = evs[0][3]
     with _rec_lock:
-        _ring.append(ev)
-        if _TRACE_DIR is not None and len(_trace) < _TRACE_CAP:
-            _trace.append(ev)
+        _ring.extend(evs)
+        room = max(_TRACE_CAP - len(_trace), 0)
+        _trace.extend(evs[:room])
+        if room < len(evs):
+            if _dropped is None:
+                _dropped = _REGISTRY.counter(
+                    "zoo_telemetry_events_dropped_total")
+            _dropped.inc(len(evs) - room)
         if _forwarding:
-            _outbox.append(ev)
+            _outbox.extend(evs)
         if tid not in _tid_names:
             _tid_names[tid] = threading.current_thread().name
 
@@ -453,30 +470,43 @@ class _Span:
         self.args = args
 
     def __enter__(self):
-        _record(("B", self.name, _now_us(), threading.get_ident(),
+        _record(("B", self.name, _now_ns(), threading.get_ident(),
                  self.args))
         return self
 
     def __exit__(self, etype, exc, tb):
         args = {"error": repr(exc)} if exc is not None else None
-        _record(("E", self.name, _now_us(), threading.get_ident(), args))
+        _record(("E", self.name, _now_ns(), threading.get_ident(), args))
         return False
 
 
 def span(name: str, **args):
     """``with span("train/step", step=n):`` — record a begin/end pair
-    into the flight-recorder ring (and trace buffer when a trace dir is
-    configured). Returns a shared no-op when telemetry is disabled."""
+    into the flight-recorder ring and the trace buffer. Returns a shared
+    no-op when telemetry is disabled."""
     if not _ENABLED:
         return _NOOP
     return _Span(name, args or None)
+
+
+def complete_span(name: str, duration_s: float, **args):
+    """Record a span that ends now and lasted ``duration_s``: what a
+    library reports after the fact (jax's compile events) lands on the
+    same timeline as the spans opened around live code."""
+    if not _ENABLED:
+        return
+    end = _now_ns()
+    tid = threading.get_ident()
+    _record(("B", name, end - max(int(duration_s * 1e9), 0), tid,
+             args or None),
+            ("E", name, end, tid, None))
 
 
 def event(name: str, **args):
     """Record an instant event (sheds, restarts, lifecycle marks)."""
     if not _ENABLED:
         return
-    _record(("i", name, _now_us(), threading.get_ident(), args or None))
+    _record(("i", name, _now_ns(), threading.get_ident(), args or None))
 
 
 def new_trace_id() -> str:
@@ -499,7 +529,7 @@ def flow(name: str, flow_id: str, phase: str = "s", **args):
         raise ValueError(f"flow phase must be s/t/f, got {phase!r}")
     a = dict(args)
     a["id"] = str(flow_id)
-    _record((phase, name, _now_us(), threading.get_ident(), a))
+    _record((phase, name, _now_ns(), threading.get_ident(), a))
 
 
 def enabled() -> bool:
@@ -545,8 +575,8 @@ def ingest_events(events: Sequence[tuple], *, pid, process_name: str = "",
 
 def _ev_json(ev: tuple, pid) -> dict:
     ph, name, ts, tid, args = ev
-    out = {"name": name, "ph": "i" if ph == "i" else ph,
-           "ts": ts, "pid": pid, "tid": tid,
+    out = {"name": name, "ph": ph,
+           "ts": ts // 1000, "pid": pid, "tid": tid,
            "cat": name.split("/", 1)[0]}
     if ph == "i":
         out["s"] = "t"
@@ -570,7 +600,7 @@ def _meta_ev(name: str, pid, tid, value: str) -> dict:
 def trace_events_json() -> List[dict]:
     """All collected events (own + ingested) as Chrome-trace dicts."""
     with _rec_lock:
-        own = list(_trace) if _TRACE_DIR is not None else list(_ring)
+        own = list(_trace)
         foreign = list(_foreign)
         tid_names = dict(_tid_names)
     out: List[dict] = []
@@ -609,6 +639,22 @@ def _atomic_write_json(path: str, payload: dict):
     os.replace(tmp, path)
 
 
+def _process_start_us() -> int:
+    """When this process started, on the unix clock: from /proc to a
+    clock tick, so that a trace accounts for the imports and the backend
+    start that ran before this module could record a span; where /proc
+    cannot say, when this module was imported."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            up_s = float(f.read().split()[0])
+        age_s = up_s - ticks / os.sysconf("SC_CLK_TCK")
+        return int((time.time() - age_s) * 1e6)
+    except (OSError, ValueError, IndexError):
+        return _IMPORTED_NS // 1000
+
+
 def write_trace(path: str = None) -> Optional[str]:
     """Write the Chrome-trace JSON. Default path:
     ``<trace_dir>/trace-<pid>.json``. Returns the path (None when there
@@ -619,7 +665,8 @@ def write_trace(path: str = None) -> Optional[str]:
         path = os.path.join(_TRACE_DIR, f"trace-{_PID}.json")
     payload = {"traceEvents": trace_events_json(),
                "displayTimeUnit": "ms",
-               "otherData": {"service": _SERVICE, "pid": _PID}}
+               "otherData": {"service": _SERVICE, "pid": _PID,
+                             "process_start_us": _process_start_us()}}
     _atomic_write_json(path, payload)
     return path
 
@@ -724,8 +771,8 @@ def _at_exit():
 def configure(enabled: bool = None, trace_dir: str = None,
               service: str = None, export_metrics: bool = True):
     """Process entry points (init_nncontext, zoo-serving, zoo-launch
-    workers) call this once. ``trace_dir`` arms full-trace collection,
-    the periodic metrics exporter, and an atexit trace flush; child
+    workers) call this once. ``trace_dir`` arms the periodic metrics
+    exporter and an atexit flush of the trace buffer to a file; child
     processes inherit the settings via ``ZOO_TPU_TELEMETRY`` /
     ``ZOO_TPU_TRACE_DIR`` / ``ZOO_TPU_TELEMETRY_SERVICE``."""
     global _ENABLED, _TRACE_DIR, _SERVICE, _atexit_armed
@@ -752,7 +799,7 @@ def configure(enabled: bool = None, trace_dir: str = None,
 def reset_for_tests():
     """Full reset: registry, ring, trace buffer, forwarding, enable
     flag (re-read from the environment). Test isolation only."""
-    global _ENABLED, _TRACE_DIR, _SERVICE, _forwarding
+    global _ENABLED, _TRACE_DIR, _SERVICE, _forwarding, _dropped
     stop_metrics_exporter(flush=False)
     with _rec_lock:
         _ring.clear()
@@ -761,6 +808,7 @@ def reset_for_tests():
         _foreign.clear()
         _tid_names.clear()
     _REGISTRY.clear()
+    _dropped = None
     _forwarding = False
     _ENABLED = _env_bool("ZOO_TPU_TELEMETRY")
     _TRACE_DIR = os.environ.get("ZOO_TPU_TRACE_DIR") or None

@@ -15,10 +15,17 @@ timeline (docs/observability.md#tracing):
   ui.perfetto.dev draws the cross-process arrows);
 - ``zoo-trace ls --dir D`` — the trace ids seen, with event/pid counts;
 - ``zoo-trace show <trace_id> --dir D`` — the causal tree for one
-  request: per-pid spans in time order, flow hops, connectivity.
+  request: per-pid spans in time order, flow hops, connectivity;
+- ``zoo-trace phases <trace.json> [--before SPAN [--nth N]]`` — where one
+  thread's time went, by span name: count, total and self time (a span's
+  duration less what its children cover) and the remainder of the
+  interval that no span covers. The interval runs from the process's
+  start (the trace file carries it) to the N-th ``SPAN``'s start, or to
+  the thread's last event: ``--before train/dispatch --nth 2`` is a
+  training run's set-up, imports and backend start in the remainder.
 
-The library surface (:func:`merge_trace_dir`, :func:`trace_summary`)
-is what the fast-tier cross-process test asserts on.
+The library surface (:func:`merge_trace_dir`, :func:`trace_summary`,
+:func:`span_phases`) is what the fast-tier tests assert on.
 """
 
 from __future__ import annotations
@@ -30,7 +37,7 @@ import sys
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["load_trace_file", "merge_trace_dir", "index_by_trace",
-           "trace_summary", "main"]
+           "trace_summary", "named_spans", "span_phases", "main"]
 
 
 def load_trace_file(path: str) -> List[dict]:
@@ -184,6 +191,117 @@ def _fmt_summary(s: dict, stream=None) -> None:
               f"pid={ev.get('pid'):<8} {ev.get('name')}", file=stream)
 
 
+def named_spans(events: List[dict]) -> List[dict]:
+    """B/E pairs matched per (pid, tid, name) — a span recorded after the
+    fact (telemetry.complete_span) lands in the file behind events that
+    happened inside it, so a plain per-thread stack would mispair — and
+    ``X`` events, as {name, pid, tid, ts, end, args} in microseconds."""
+    open_: Dict[Tuple, List[dict]] = {}
+    out: List[dict] = []
+    for ev in events:
+        ph = ev.get("ph")
+        key = (ev.get("pid"), ev.get("tid"), ev.get("name"))
+        if ph == "B":
+            open_.setdefault(key, []).append(ev)
+        elif ph == "E" and open_.get(key):
+            b = open_[key].pop()
+            out.append({"name": key[2], "pid": key[0], "tid": key[1],
+                        "ts": b.get("ts", 0), "end": ev.get("ts", 0),
+                        "args": b.get("args") or {}})
+        elif ph == "X":
+            out.append({"name": key[2], "pid": key[0], "tid": key[1],
+                        "ts": ev.get("ts", 0),
+                        "end": ev.get("ts", 0) + ev.get("dur", 0),
+                        "args": ev.get("args") or {}})
+    return out
+
+
+def span_phases(events: List[dict], before: Optional[str] = None,
+                nth: int = 1, start_us: Optional[int] = None) -> dict:
+    """Where one thread's time went between ``start_us`` (default: the
+    trace's first event) and the ``nth`` span named ``before`` (default:
+    the thread's last event). The thread is the one that holds that
+    span, or else the one whose spans cover the most time.
+
+    Rows are keyed by span name (``compile/backend`` also by its
+    ``cache_hit``): ``count``, ``total_s`` and ``self_s``, a span's
+    duration less what the spans nested in it cover. ``unattributed_s``
+    is the part of the interval under no span at all, so the self times
+    and it sum to ``interval_s``."""
+    spans = named_spans(events)
+    if not spans:
+        raise ValueError("the trace holds no span")
+    thread_of = lambda s: (s["pid"], s["tid"])
+    if before is not None:
+        marks = sorted((s for s in spans if s["name"] == before),
+                       key=lambda s: s["ts"])
+        if len(marks) < nth:
+            raise ValueError(f"the trace holds {len(marks)} span(s) named "
+                             f"{before!r}, not {nth}")
+        thread, t1 = thread_of(marks[nth - 1]), marks[nth - 1]["ts"]
+    else:
+        busy: Dict[Tuple, int] = {}
+        for s in spans:
+            busy[thread_of(s)] = busy.get(thread_of(s), 0) + \
+                s["end"] - s["ts"]
+        thread = max(busy, key=busy.get)
+        t1 = max(s["end"] for s in spans if thread_of(s) == thread)
+    t0 = start_us if start_us is not None else \
+        min(ev["ts"] for ev in events if "ts" in ev)
+    mine = [dict(s, ts=max(s["ts"], t0), end=min(s["end"], t1))
+            for s in spans if thread_of(s) == thread]
+    mine = sorted((s for s in mine if s["end"] > s["ts"]),
+                  key=lambda s: (s["ts"], -s["end"]))
+    rows: Dict[str, Dict[str, float]] = {}
+    stack: List[dict] = []
+    covered = 0
+    for s in mine:
+        while stack and stack[-1]["end"] <= s["ts"]:
+            stack.pop()
+        dur = s["end"] - s["ts"]
+        if stack:
+            # a child that outlasts its parent by a clock's jitter
+            # counts up to the parent's end
+            stack[-1]["row"]["self_s"] -= \
+                min(dur, stack[-1]["end"] - s["ts"]) / 1e6
+        else:
+            covered += dur
+        label = s["name"]
+        if "cache_hit" in s["args"]:
+            label += "{cache_hit=%s}" % str(s["args"]["cache_hit"]).lower()
+        row = rows.setdefault(label, {"count": 0, "total_s": 0.0,
+                                      "self_s": 0.0})
+        row["count"] += 1
+        row["total_s"] += dur / 1e6
+        row["self_s"] += dur / 1e6
+        stack.append({"end": s["end"], "row": row})
+    names = {(ev.get("pid"), ev.get("tid")): ev["args"]["name"]
+             for ev in events if ev.get("ph") == "M" and
+             ev.get("name") == "thread_name"}
+    return {"thread": names.get(thread, str(thread[1])), "pid": thread[0],
+            "interval_s": (t1 - t0) / 1e6, "rows": rows,
+            "unattributed_s": (t1 - t0 - covered) / 1e6,
+            "spans_on_other_threads": sum(thread_of(s) != thread
+                                          for s in spans)}
+
+
+def _fmt_phases(ph: dict, stream=None) -> None:
+    stream = stream or sys.stdout
+    print(f"thread {ph['thread']} of pid {ph['pid']}: "
+          f"{ph['interval_s']:.3f} s", file=stream)
+    print(f"  {'span':<40s} {'count':>7s} {'total_s':>10s} {'self_s':>10s}",
+          file=stream)
+    for name, r in sorted(ph["rows"].items(),
+                          key=lambda kv: -kv[1]["self_s"]):
+        print(f"  {name:<40s} {r['count']:>7d} {r['total_s']:>10.3f} "
+              f"{r['self_s']:>10.3f}", file=stream)
+    print(f"  {'(under no span)':<40s} {'':>7s} {'':>10s} "
+          f"{ph['unattributed_s']:>10.3f}", file=stream)
+    if ph["spans_on_other_threads"]:
+        print(f"  {ph['spans_on_other_threads']} span(s) on other threads "
+              f"left out", file=stream)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="zoo-trace",
@@ -199,8 +317,30 @@ def main(argv=None) -> int:
     p_show = sub.add_parser("show", help="print one request's span tree")
     p_show.add_argument("trace_id")
     p_show.add_argument("--dir", required=True)
+    p_ph = sub.add_parser(
+        "phases", help="one thread's time by span name: total, self and "
+                       "the remainder under no span")
+    p_ph.add_argument("trace", help="a trace-<pid>.json file")
+    p_ph.add_argument("--before", default=None, metavar="SPAN",
+                      help="end the interval where this span starts")
+    p_ph.add_argument("--nth", type=int, default=1,
+                      help="which span of that name (default: the first)")
     args = ap.parse_args(argv)
 
+    if args.command == "phases":
+        with open(args.trace) as f:
+            payload = json.load(f)
+        if not isinstance(payload, dict):       # the bare-array form
+            payload = {"traceEvents": payload}
+        try:
+            ph = span_phases(
+                payload.get("traceEvents") or [], args.before, args.nth,
+                (payload.get("otherData") or {}).get("process_start_us"))
+        except ValueError as e:
+            print(e, file=sys.stderr)
+            return 1
+        _fmt_phases(ph)
+        return 0
     merged = merge_trace_dir(args.dir)
     if args.command == "merge":
         out = args.out or os.path.join(args.dir, "merged.json")

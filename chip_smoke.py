@@ -9,8 +9,11 @@ non-zero and nothing is printed as a result:
   then ``evaluate`` and ``predict``. The compiled step's HLO must hold the
   Mosaic kernels the router selected.
 * serve: a causal 12-block ``TransformerLayer`` behind ``ClusterServing``
-  on the real ``TransformerDecodeEngine``; four generate requests, and
-  the first cached decode step checked against the layer's full forward.
+  on the real ``TransformerDecodeEngine``; four generate requests, the
+  decode loop reported from the program's own counters and spans (steps,
+  slots a step, the step's p50, ``generate/pick``'s share of
+  ``generate/step``, compiles against cache loads), and the first cached
+  decode step checked against the layer's full forward.
 
 Data and weights come from a seed. Times printed here are observations
 of one smoke run, NOT benchmark results. The last line of stdout is the
@@ -209,7 +212,46 @@ def train_phase(n_dev):
     return {"loss_curve": curve, "kernels": kernels}
 
 
-def serve_phase():
+def decode_report(before):
+    """The decode loop as the program itself counted and timed it: steps
+    and occupancy from the always-on counters, compiles against cache
+    loads since ``before``, and, where telemetry is on, the mean
+    ``generate/step`` with the share of it under ``generate/pick``."""
+    from analytics_zoo_tpu.utils import telemetry, trace_merge
+
+    steps = telemetry.counter("zoo_generate_steps_total").value
+    slot_steps = telemetry.counter("zoo_generate_slot_steps_total").value
+    step_ms = telemetry.summary("zoo_generate_step_ms")
+    out = {"steps": int(steps),
+           "slots_per_step": slot_steps / max(steps, 1),
+           "step_p50_ms": step_ms.percentile(50),
+           "queue_wait_p50_ms": telemetry.summary(
+               "zoo_generate_queue_wait_ms").percentile(50),
+           **{k: v - before[k] for k, v in compile_counts().items()}}
+    spans = trace_merge.named_spans(telemetry.trace_events_json())
+    total = {n: sum(s["end"] - s["ts"] for s in spans if s["name"] == n)
+             for n in ("generate/step", "generate/dispatch",
+                       "generate/pick")}
+    if total["generate/step"]:
+        n = sum(s["name"] == "generate/step" for s in spans)
+        out.update(
+            step_span_mean_ms=total["generate/step"] / n / 1e3,
+            dispatch_share=total["generate/dispatch"] /
+            total["generate/step"],
+            pick_share=total["generate/pick"] / total["generate/step"])
+    return out
+
+
+def compile_counts():
+    from analytics_zoo_tpu.utils import telemetry
+
+    return {name: int(telemetry.counter("zoo_compile_backend_total",
+                                        cache_hit=hit).value)
+            for name, hit in (("compiles", "false"),
+                              ("cache_loads", "true"))}
+
+
+def serve_phase(traced=True):
     import jax
     import jax.numpy as jnp
 
@@ -221,6 +263,11 @@ def serve_phase():
         ClusterServing, ClusterServingHelper)
     from analytics_zoo_tpu.serving.queue_backend import InProcessStreamQueue
 
+    from analytics_zoo_tpu.utils import telemetry
+
+    if traced:
+        telemetry.set_enabled(True)   # the decode loop's own spans
+    compiled_before = compile_counts()
     layer = TransformerLayer(n_block=BLOCKS, n_head=HEADS,
                              hidden_size=HIDDEN, seq_len=GEN_SEQ)
     params = layer.build(jax.random.PRNGKey(SEED), (None, GEN_SEQ))
@@ -250,6 +297,10 @@ def serve_phase():
     gen = serving.pipeline_stats()["generation"]
     log(f"serve: {len(got)} results in {wall:.1f}s (compiles included) "
         f"[smoke observation, not a benchmark]; scheduler {gen}")
+    decode = decode_report(compiled_before)
+    log("serve: the decode loop by its own counters and spans: " +
+        " ".join(f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
+                 for k, v in decode.items()))
 
     assert sorted(got) == sorted(prompts), sorted(got)
     for uri, res in got.items():
@@ -290,7 +341,7 @@ def serve_phase():
         f"max |dlogit| = {err:.4f} (logit std {b.std():.3f}, "
         f"tolerance {LOGIT_ATOL})")
     assert err < LOGIT_ATOL, err
-    return {"generation": gen, "logit_err": err}
+    return {"generation": gen, "logit_err": err, "decode": decode}
 
 
 def main():

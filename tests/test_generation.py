@@ -297,3 +297,91 @@ def test_generate_smoke_end_to_end():
     gen = stats["generation"]
     assert gen["committed"] == gen["submitted"] == 2
     assert gen["duplicate_commits"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the decode loop under program spans and always-on counters
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def fresh_telemetry():
+    from analytics_zoo_tpu.utils import telemetry
+
+    saved = os.environ.pop("ZOO_TPU_TELEMETRY", None)
+    telemetry.reset_for_tests()
+    yield telemetry
+    if saved is not None:
+        os.environ["ZOO_TPU_TELEMETRY"] = saved
+    telemetry.reset_for_tests()
+
+
+def _one_step_run(n_slots):
+    """n requests of two tokens each, queued before the loop starts: one
+    refill seats them all (first token), one step finishes them all."""
+    results, commit = _collect()
+    s = _sched(commit, max_slots=n_slots)
+    for i in range(n_slots):
+        s.submit(GenRequest(f"r{i}", np.array([10 * (i + 1)]),
+                            max_new_tokens=2))
+    s.start()
+    s.stop(drain=True, timeout=30)
+    assert len(results) == n_slots
+    return results
+
+
+@pytest.mark.parametrize("n_slots", [1, 3, 8])
+def test_scheduler_step_spans_once_per_step(fresh_telemetry, n_slots):
+    """One scheduler step is one generate/step holding one
+    generate/dispatch and one generate/pick, whatever the slot count,
+    and the two counters give the occupancy with no polling thread."""
+    from analytics_zoo_tpu.utils.trace_merge import named_spans
+
+    telemetry = fresh_telemetry
+    telemetry.set_enabled(True)
+    _one_step_run(n_slots)
+    spans = named_spans(telemetry.trace_events_json())
+    by = lambda n: [s for s in spans if s["name"] == n]
+    (step,), (disp,), (pick,) = (by("generate/step"),
+                                 by("generate/dispatch"),
+                                 by("generate/pick"))
+    assert step["ts"] <= disp["ts"] <= disp["end"] <= pick["ts"] \
+        <= pick["end"] <= step["end"]
+    assert pick["args"] == {"slots": n_slots}
+    # every loop turn that did work names its stages; the refill's one
+    # fused prefill nests in generate/refill
+    assert len(by("generate/evict")) == len(by("generate/refill")) == \
+        len(by("generate/prefill_step")) >= 2
+    refill = by("generate/refill")[0]
+    for join in by("generate/prefill") + by("generate/prefill_batch"):
+        assert refill["ts"] <= join["ts"] <= join["end"] <= refill["end"]
+    steps = telemetry.counter("zoo_generate_steps_total").value
+    slot_steps = telemetry.counter("zoo_generate_slot_steps_total").value
+    assert steps == 1 and slot_steps / steps == n_slots
+    assert telemetry.summary("zoo_generate_queue_wait_ms").count == n_slots
+
+
+def test_token_ms_and_counters_with_telemetry_off(fresh_telemetry):
+    """Tracing off changes nothing a caller sees: every committed result
+    carries token_ms, the counters still count, no span is recorded."""
+    telemetry = fresh_telemetry
+    assert not telemetry.enabled()
+    results = _one_step_run(3)
+    for payload in results.values():
+        ms = payload["timing"]["token_ms"]
+        assert len(ms) == payload["timing"]["n_tokens"] == 2
+        assert 0 <= ms[0] <= ms[1]
+    assert telemetry.counter("zoo_generate_slot_steps_total").value == 3
+    assert telemetry.counter("zoo_generate_tokens_total").value == 3
+    assert all(e["ph"] == "M" for e in telemetry.trace_events_json())
+
+
+def test_idle_scheduler_records_no_span(fresh_telemetry):
+    """An idle server's loop turns stay out of the trace buffer."""
+    telemetry = fresh_telemetry
+    telemetry.set_enabled(True)
+    results, commit = _collect()
+    s = _sched(commit, max_slots=2, idle_poll_s=0.005).start()
+    time.sleep(0.1)
+    s.stop(drain=True, timeout=30)
+    assert [e for e in telemetry.trace_events_json()
+            if e["ph"] != "M"] == []
