@@ -6,7 +6,7 @@ takes the chip, and a child that needs it then fails or hangs.
 BEFORE jax initializes its backends — too late for any code that runs
 after ``import jax``. Every place that needs a guaranteed N-device CPU
 host therefore re-execs itself into a subprocess carrying the flag
-(``attn-smoke``, ``zero-smoke``, the ``multi_device_cpu`` test fixture);
+(``ops/attn_smoke``, ``zero-smoke``, the ``multi_device_cpu`` test fixture);
 the one canonical copy of that pattern lives here.
 
 ``ZOO_HOSTDEV_CHILD=1`` marks the child (re-exec exactly once: a child

@@ -23,14 +23,14 @@ from __future__ import annotations
 
 import logging
 import multiprocessing as mp
+import multiprocessing.connection as mp_connection
 import os
 import pickle
-import queue as queue_mod
 import threading
 import weakref
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import time
 
@@ -175,47 +175,61 @@ class _RingSegment:
 
 
 class _SlotLease:
-    """One leased ring slot: returned to the worker's free queue (and
-    unleased from the segment) when the last zero-copy view wrapped from
-    it is garbage collected."""
+    """One leased ring slot: returned to its worker's current free queue
+    (and unleased from the segment) when the last zero-copy view wrapped
+    from it is garbage collected."""
 
-    __slots__ = ("free_q", "segment", "slot", "count", "lock")
+    __slots__ = ("worker", "slot", "count", "lock")
 
-    def __init__(self, free_q, segment: "_RingSegment", slot: int,
-                 count: int):
-        self.free_q = free_q
-        self.segment = segment
+    def __init__(self, worker: "_Worker", slot: int, count: int):
+        self.worker = worker
         self.slot = slot
         self.count = count
         self.lock = threading.Lock()
-        segment.lease()
+        worker.leased.add(slot)
+        worker.segment.lease()
 
     def release_one(self):
         with self.lock:
             self.count -= 1
             if self.count > 0:
                 return
-        try:
-            self.free_q.put_nowait(self.slot)
-        except Exception:  # noqa: BLE001 - pool torn down; segment gone
-            pass
-        self.segment.unlease()
+        self.worker.free_slot(self.slot)
+        self.worker.segment.unlease()
 
 
 class _Worker:
-    """Parent-side record of one spawned transform worker. Queues and the
-    ring segment outlive the process: a respawned replacement reattaches
-    to the same ones, so unclaimed tasks and free slots carry over."""
+    """Parent-side record of one transform worker and its ring segment.
 
-    __slots__ = ("wid", "proc", "task_q", "free_q", "segment", "assigned")
+    The segment outlives the process; the channels do not. A process
+    killed inside ``Queue.get`` dies holding that queue's reader lock,
+    and one killed while its feeder thread writes dies holding the
+    writer lock, so a queue a dead process has touched can block every
+    later user for good. Each incarnation therefore gets a task queue
+    and a free-slot queue of its own (the parent is their only writer)
+    and its own result pipe, written synchronously by the worker with no
+    lock: when the worker dies the parent reads what it had finished and
+    then EOF, which is how a death is seen."""
 
-    def __init__(self, wid, task_q, free_q, segment):
+    __slots__ = ("wid", "proc", "task_q", "free_q", "results", "segment",
+                 "assigned", "leased", "lock")
+
+    def __init__(self, wid, segment):
         self.wid = wid
         self.proc = None
-        self.task_q = task_q
-        self.free_q = free_q
+        self.task_q = self.free_q = self.results = None
         self.segment = segment
         self.assigned: set = set()
+        self.leased: set = set()     # slots the consumer's views still hold
+        self.lock = threading.RLock()  # views are finalized on any thread
+
+    def free_slot(self, slot: int):
+        with self.lock:
+            self.leased.discard(slot)
+            try:
+                self.free_q.put_nowait(slot)
+            except Exception:  # noqa: BLE001 - pool torn down; segment gone
+                pass
 
 
 class _RemoteError:
@@ -225,6 +239,19 @@ class _RemoteError:
 
     def __init__(self, exc: BaseException):
         self.exc = exc
+
+
+def _abandon_queues(queues):
+    """Close queues nobody will read again without waiting for their
+    feeder threads: what is still in them was meant for a worker that is
+    gone, and a feeder blocked on its full pipe would hold up the exit."""
+    for q in queues:
+        if q is not None:
+            try:
+                q.close()
+                q.cancel_join_thread()
+            except Exception:  # noqa: BLE001
+                pass
 
 
 def _reap_pool(procs, segments):
@@ -266,10 +293,11 @@ class ProcessTransformPool:
 
     Respawn-on-death rides the launcher supervision seam
     (:class:`~analytics_zoo_tpu.launcher.supervisor.Respawner`): a
-    worker killed mid-batch is restarted on the same queues + ring, its
-    unacknowledged batches are resubmitted, and late duplicates are
-    dropped by sequence number — the stream stays complete,
-    duplicate-free and ordered. Ring segments are unlinked in
+    worker killed at any point is restarted on the same ring with fresh
+    channels (see :class:`_Worker`), every slot the consumer does not
+    hold is free again, and its unacknowledged batches are resubmitted —
+    the stream stays complete, duplicate-free and ordered. A worker
+    whose parent dies exits by itself. Ring segments are unlinked in
     ``close()``'s finally (plus a GC finalizer backstop): no /dev/shm
     leak survives the pool.
     """
@@ -299,7 +327,6 @@ class ProcessTransformPool:
             "ZOO_TPU_INFEED_SLOTS", DEFAULT_SLOTS_PER_WORKER))
         self._respawner = respawner or Respawner(max_per_child=3)
         self._ctx = mp.get_context("spawn")  # fork after jax is unsafe
-        self._result_q = self._ctx.Queue()
         self._tasks: Dict[int, Any] = {}    # seq -> raw batch (requeue)
         self._ready: Dict[int, Any] = {}    # seq -> batch | _RemoteError
         self._seq_submit = 0
@@ -316,11 +343,7 @@ class ProcessTransformPool:
         for wid in range(self.num_workers):
             shm = shared_memory.SharedMemory(
                 create=True, size=self._slot_bytes * self._slots)
-            w = _Worker(wid, self._ctx.Queue(), self._ctx.Queue(),
-                        _RingSegment(shm))
-            for s in range(self._slots):
-                w.free_q.put(s)
-            self._workers[wid] = w
+            self._workers[wid] = _Worker(wid, _RingSegment(shm))
         self._finalizer = weakref.finalize(
             self, _reap_pool, self._all_procs,
             [w.segment for w in self._workers.values()])
@@ -339,12 +362,21 @@ class ProcessTransformPool:
                 "respawns": self.respawns}
 
     def _start_proc(self, w: _Worker):
+        task_q, free_q = self._ctx.Queue(), self._ctx.Queue()
+        results, send = self._ctx.Pipe(duplex=False)
+        with w.lock:
+            for slot in sorted(set(range(self._slots)) - w.leased):
+                free_q.put(slot)
+            dead = (w.task_q, w.free_q)
+            w.task_q, w.free_q, w.results = task_q, free_q, results
+        _abandon_queues(dead)
         p = self._ctx.Process(
             target=worker_main,
             args=(w.wid, w.segment.shm.name, self._slot_bytes,
-                  self._payload, w.task_q, self._result_q, w.free_q),
+                  self._payload, task_q, send, free_q),
             daemon=True, name=f"zoo-infeed-{w.wid}")
         p.start()
+        send.close()  # the worker holds the only write end: its death is EOF
         w.proc = p
         self._all_procs.append(p)
 
@@ -374,12 +406,9 @@ class ProcessTransformPool:
         path. Each view carries a finalizer on the shared lease; the
         slot returns to the worker only after every view is gone."""
         if not metas:
-            try:
-                w.free_q.put_nowait(slot)
-            except Exception:  # noqa: BLE001
-                pass
+            w.free_slot(slot)
             return rebuild_batch(template, [])
-        lease = _SlotLease(w.free_q, w.segment, slot, len(metas))
+        lease = _SlotLease(w, slot, len(metas))
         base = slot * self._slot_bytes
         arrays = []
         for off, shape, dt in metas:
@@ -403,15 +432,6 @@ class ProcessTransformPool:
             self._fatal = pickle.loads(msg[3])
             return
         w = self._workers[wid]
-        if seq not in self._tasks:
-            # late duplicate after a respawn resubmission: drop it, but
-            # hand its slot straight back so the ring doesn't shrink
-            if kind == "shm":
-                try:
-                    w.free_q.put_nowait(msg[3])
-                except Exception:  # noqa: BLE001
-                    pass
-            return
         del self._tasks[seq]
         w.assigned.discard(seq)
         if kind == "shm":
@@ -426,22 +446,22 @@ class ProcessTransformPool:
         else:  # "err"
             self._ready[seq] = _RemoteError(pickle.loads(msg[3]))
 
-    def _check_workers(self):
-        """Respawn dead workers on their existing queues + ring and
-        resubmit their unacknowledged batches. Raises RuntimeError (via
-        the Respawner budget) when deaths look structural."""
-        for wid, w in list(self._workers.items()):
-            if self._closed or w.proc is None or w.proc.is_alive():
-                continue
-            self._respawner.note_death(
-                f"infeed-{wid}", f"exit code {w.proc.exitcode}")
-            logger.warning(
-                "infeed worker %d died (exit %s); respawning and "
-                "resubmitting %d batch(es)", wid, w.proc.exitcode,
-                len(w.assigned))
-            self._start_proc(w)
-            for seq in sorted(w.assigned):
-                w.task_q.put((seq, self._tasks[seq]))
+    def _respawn(self, w: _Worker):
+        """``w``'s result pipe read EOF: everything it finished has been
+        handled. Restart it on its ring with fresh channels and resubmit
+        its unacknowledged batches. Raises RuntimeError (via the
+        Respawner budget) when deaths look structural."""
+        w.results.close()
+        w.proc.join(timeout=5.0)  # EOF comes moments before the exit code
+        self._respawner.note_death(
+            f"infeed-{w.wid}", f"exit code {w.proc.exitcode}")
+        logger.warning(
+            "infeed worker %d died (exit %s); respawning and "
+            "resubmitting %d batch(es)", w.wid, w.proc.exitcode,
+            len(w.assigned))
+        self._start_proc(w)
+        for seq in sorted(w.assigned):
+            w.task_q.put((seq, self._tasks[seq]))
 
     def __iter__(self):
         return self
@@ -458,16 +478,18 @@ class ProcessTransformPool:
                 err, self._fatal = self._fatal, None
                 self.close()
                 raise err
-            try:
-                msg = self._result_q.get(timeout=0.2)
-            except queue_mod.Empty:
+            by_pipe = {w.results: w for w in self._workers.values()}
+            for conn in mp_connection.wait(list(by_pipe)):
                 try:
-                    self._check_workers()
-                except BaseException:
-                    self.close()
-                    raise
-                continue
-            self._handle(msg)
+                    msg = conn.recv()
+                except (EOFError, OSError):
+                    try:
+                        self._respawn(by_pipe[conn])
+                    except BaseException:
+                        self.close()
+                        raise
+                else:
+                    self._handle(msg)
         out = self._ready.pop(self._seq_emit)
         if isinstance(out, _RemoteError):
             self.close()
@@ -501,17 +523,9 @@ class ProcessTransformPool:
             # ring (idempotent with the GC backstop)
             self._finalizer()
             for w in self._workers.values():
-                for q in (w.task_q, w.free_q):
-                    try:
-                        q.close()
-                        q.cancel_join_thread()
-                    except Exception:  # noqa: BLE001
-                        pass
-            try:
-                self._result_q.close()
-                self._result_q.cancel_join_thread()
-            except Exception:  # noqa: BLE001
-                pass
+                if w.results is not None:
+                    w.results.close()
+                _abandon_queues((w.task_q, w.free_q))
 
 
 class StagedChunk:

@@ -10,7 +10,7 @@ spawned (fork after jax initialises is unsafe), so every import here is
 paid once per worker at startup — numpy and the feature package, never
 jax.
 
-Wire protocol (one message per task, on the shared result queue):
+Wire protocol (one message per task, on this worker's result pipe):
 
 ``("shm", wid, seq, slot, metas, template, elapsed)``
     The batch's arrays live in worker ``wid``'s ring at ``slot``;
@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import os
 import pickle
+import queue as _q
 import time
 import traceback
 from typing import Any, List, Optional, Tuple
@@ -148,8 +149,6 @@ def _encode_error(e: BaseException) -> bytes:
 
 
 def _acquire_slot(free_q, timeout: float = 0.05) -> Optional[int]:
-    import queue as _q
-
     try:
         return free_q.get_nowait()
     except _q.Empty:
@@ -160,17 +159,34 @@ def _acquire_slot(free_q, timeout: float = 0.05) -> Optional[int]:
         return None
 
 
+def _next_task(task_q, parent):
+    """The next task, or ``None`` (the sentinel) once the parent is
+    gone: an orphan must not sit on its ring segment for ever."""
+    while True:
+        try:
+            return task_q.get(timeout=1.0)
+        except _q.Empty:
+            if parent is not None and not parent.is_alive():
+                return None
+
+
 def worker_main(wid: int, shm_name: Optional[str], slot_bytes: int,
-                fn_payload: bytes, task_q, result_q, free_q) -> None:
+                fn_payload: bytes, task_q, results, free_q) -> None:
     """Entry point of one spawned transform worker.
 
     Pulls ``(seq, raw_batch)`` tasks until the ``None`` sentinel, runs
     the unpickled Preprocessing chain, and ships results per the module
-    protocol. The ``infeed-worker`` fault site fires here — after the
-    transform, before the result ships — so an injected kill genuinely
-    loses a batch mid-flight and the parent must recover it.
+    protocol: ``results`` is the write end of a pipe only this process
+    writes, synchronously, so a kill at any point leaves whole messages
+    and then EOF. The ``infeed-worker`` fault site fires here — after
+    the transform, before the result ships — so an injected kill
+    genuinely loses a batch mid-flight and the parent must recover it.
     """
+    import multiprocessing as mp
+
     from ..utils import faults, telemetry
+
+    parent = mp.parent_process()
 
     tracing = telemetry.enabled()
     if tracing:
@@ -181,18 +197,18 @@ def worker_main(wid: int, shm_name: Optional[str], slot_bytes: int,
     def _ship_spans() -> None:
         evs = telemetry.drain_events()
         if evs:
-            result_q.put(("spans", wid, os.getpid(), evs))
+            results.send(("spans", wid, os.getpid(), evs))
 
     try:
         fn = pickle.loads(fn_payload)
     except BaseException as e:  # noqa: BLE001 - surface, don't respawn
-        result_q.put(("fatal", wid, -1, _encode_error(e)))
+        results.send(("fatal", wid, -1, _encode_error(e)))
         return
     shm = _attach_ring(shm_name) if shm_name else None
     items = 0
     try:
         while True:
-            task = task_q.get()
+            task = _next_task(task_q, parent)
             if task is None:
                 break
             seq, batch = task
@@ -203,7 +219,7 @@ def worker_main(wid: int, shm_name: Optional[str], slot_bytes: int,
                 items += 1
                 faults.check("infeed-worker", items)
             except BaseException as e:  # noqa: BLE001 - ship to parent
-                result_q.put(("err", wid, seq, _encode_error(e)))
+                results.send(("err", wid, seq, _encode_error(e)))
                 if tracing:
                     _ship_spans()
                 continue
@@ -217,16 +233,19 @@ def worker_main(wid: int, shm_name: Optional[str], slot_bytes: int,
                         with telemetry.span("infeed/slot_write", seq=seq):
                             metas = write_slot(shm.buf, slot * slot_bytes,
                                                arrays)
-                        result_q.put(("shm", wid, seq, slot, metas,
+                        results.send(("shm", wid, seq, slot, metas,
                                       template, elapsed))
                         shipped = True
             if not shipped:
-                result_q.put(("pkl", wid, seq, pickle.dumps(out, -1),
+                results.send(("pkl", wid, seq, pickle.dumps(out, -1),
                               elapsed))
             if tracing:
                 _ship_spans()
     finally:
         if tracing:
-            _ship_spans()
+            try:
+                _ship_spans()
+            except OSError:  # the parent has stopped reading
+                pass
         if shm is not None:
             shm.close()

@@ -7,7 +7,8 @@ production code paths — no test doubles, real SIGKILLs:
    prints its final param+optimizer digest.
 2. **Gang-restart leg** — the same job under ``zoo-launch --hosts 1
    --on-failure restart`` with ``ZOO_TPU_FAULT=step:kill@K`` (K random
-   mid-run) and a ``ZOO_TPU_FAULT_STATE`` dir so the kill fires exactly
+   mid-run; the tests fix K=3: the resume starts mid-epoch from
+   ``ckpt-2`` and crosses the epoch boundary at step 4) and a ``ZOO_TPU_FAULT_STATE`` dir so the kill fires exactly
    once: the worker is SIGKILLed mid-training, the launcher relaunches
    the gang, the relaunched worker auto-resumes from ``latest``, and
    the final digest is **bit-exact** vs. the reference.
@@ -60,88 +61,105 @@ def _digest(log: str):
     return (int(m.group(1)), m.group(2)) if m else (None, None)
 
 
-def run_smoke(steps: int = 12, kill_step: int = 0, stream=None) -> int:
-    out = stream if stream is not None else sys.stdout
-    work = tempfile.mkdtemp(prefix="zoo_chaos_smoke_")
-    kill_step = kill_step or random.randint(3, steps - 2)
-
+def _failer(out):
     def fail(msg, log=""):
         if log:
             out.write(log)
         out.write(f"CHAOS_SMOKE_FAIL: {msg}\n")
         return 1
+    return fail
 
+
+def reference_leg(work: str, steps: int = 6, out=sys.stdout):
+    """Leg 1: the uninterrupted run; ``(step, digest)`` or ``None``."""
+    rc, log = _run_train(os.path.join(work, "ref"), steps,
+                         extra_env={"ZOO_TPU_AUTO_RESUME": "0"})
+    ref = _digest(log)
+    if rc != 0 or ref[1] is None:
+        _failer(out)(f"reference run failed rc={rc}", log)
+        return None
+    out.write(f"CHAOS_REF_OK step={ref[0]} digest={ref[1]}\n")
+    return ref
+
+
+def restart_leg(work: str, ref, steps: int = 6, kill_step: int = 3,
+                out=sys.stdout) -> int:
+    """Leg 2: SIGKILL mid-run under gang restart resumes bit-exact."""
+    fail = _failer(out)
+    state = os.path.join(work, "fault-state")
+    os.makedirs(state)
+    rc, log = _run_train(
+        os.path.join(work, "restart"), steps,
+        extra_env={ENV_SPEC: f"step:kill@{kill_step}", ENV_STATE: state},
+        on_failure="restart", max_restarts=2, restart_backoff_s=0.1)
+    if rc != 0:
+        return fail(f"restart leg exited rc={rc}", log)
+    if "restarting gang" not in log:
+        return fail("worker survived the injected kill "
+                    f"(step:kill@{kill_step} never fired?)", log)
+    if _digest(log) != ref:
+        return fail(
+            f"resume after kill@{kill_step} is not bit-exact: "
+            f"{_digest(log)} vs reference {ref}", log)
+    out.write(f"CHAOS_RESTART_OK kill_step={kill_step} bitexact=1\n")
+    return 0
+
+
+def partial_leg(work: str, ref, steps: int = 6, out=sys.stdout) -> int:
+    """Leg 3: a crash mid-checkpoint-write is never visible, and the
+    resume past it is bit-exact."""
+    fail = _failer(out)
+    ckpt_c = os.path.join(work, "partial")
+    rc, log = _run_train(ckpt_c, steps,
+                         extra_env={ENV_SPEC: "ckpt-write:kill@2",
+                                    "ZOO_TPU_AUTO_RESUME": "0"})
+    if rc == 0:
+        return fail("ckpt-write:kill@2 never fired", log)
+    partial = os.path.join(ckpt_c, "ckpt-2")
+    if not os.path.isdir(partial):
+        return fail("no partial ckpt-2 dir left behind", log)
+    if os.path.exists(os.path.join(partial, "manifest.json")):
+        return fail("crashed-mid-write checkpoint has a manifest "
+                    "(partial write became visible)", log)
+    with open(os.path.join(ckpt_c, "latest"), "rb") as f:
+        latest = f.read().decode()
+    if latest != "ckpt-1":
+        return fail(f"latest moved to {latest!r} despite the crash "
+                    "(expected ckpt-1)", log)
+    rc, log = _run_train(ckpt_c, steps,
+                         extra_env={"ZOO_TPU_AUTO_RESUME": "1"})
+    if rc != 0 or _digest(log) != ref:
+        return fail(
+            f"resume past partial checkpoint not bit-exact: rc={rc} "
+            f"{_digest(log)} vs reference {ref}", log)
+    out.write("CHAOS_PARTIAL_OK skipped=ckpt-2 bitexact=1\n")
+    return 0
+
+
+def run_smoke(steps: int = 6, kill_step: int = 0, stream=None) -> int:
+    out = stream if stream is not None else sys.stdout
+    work = tempfile.mkdtemp(prefix="zoo_chaos_smoke_")
+    kill_step = kill_step or random.randint(2, steps - 2)
     try:
-        # -- leg 1: uninterrupted reference ----------------------------
-        rc, log = _run_train(os.path.join(work, "ref"), steps,
-                             extra_env={"ZOO_TPU_AUTO_RESUME": "0"})
-        ref_step, ref_digest = _digest(log)
-        if rc != 0 or ref_digest is None:
-            return fail(f"reference run failed rc={rc}", log)
-        out.write(f"CHAOS_REF_OK step={ref_step} digest={ref_digest}\n")
-
-        # -- leg 2: SIGKILL mid-run under gang restart -----------------
-        ckpt_b = os.path.join(work, "restart")
-        state = os.path.join(work, "fault-state")
-        os.makedirs(state)
-        rc, log = _run_train(
-            ckpt_b, steps,
-            extra_env={ENV_SPEC: f"step:kill@{kill_step}",
-                       ENV_STATE: state},
-            on_failure="restart", max_restarts=2, restart_backoff_s=0.1)
-        if rc != 0:
-            return fail(f"restart leg exited rc={rc}", log)
-        if "restarting gang" not in log:
-            return fail("worker survived the injected kill "
-                        f"(step:kill@{kill_step} never fired?)", log)
-        got_step, got_digest = _digest(log)
-        if got_step != ref_step or got_digest != ref_digest:
-            return fail(
-                f"resume after kill@{kill_step} is not bit-exact: "
-                f"step={got_step} digest={got_digest} vs reference "
-                f"step={ref_step} digest={ref_digest}", log)
-        out.write(f"CHAOS_RESTART_OK kill_step={kill_step} bitexact=1\n")
-
-        # -- leg 3: crash mid-checkpoint-write, then resume ------------
-        ckpt_c = os.path.join(work, "partial")
-        rc, log = _run_train(ckpt_c, steps,
-                             extra_env={ENV_SPEC: "ckpt-write:kill@2",
-                                        "ZOO_TPU_AUTO_RESUME": "0"})
+        ref = reference_leg(work, steps, out)
+        if ref is None:
+            return 1
+        rc = (restart_leg(work, ref, steps, kill_step, out) or
+              partial_leg(work, ref, steps, out))
         if rc == 0:
-            return fail("ckpt-write:kill@2 never fired", log)
-        partial = os.path.join(ckpt_c, "ckpt-2")
-        if not os.path.isdir(partial):
-            return fail("no partial ckpt-2 dir left behind", log)
-        if os.path.exists(os.path.join(partial, "manifest.json")):
-            return fail("crashed-mid-write checkpoint has a manifest "
-                        "(partial write became visible)", log)
-        with open(os.path.join(ckpt_c, "latest"), "rb") as f:
-            latest = f.read().decode()
-        if latest != "ckpt-1":
-            return fail(f"latest moved to {latest!r} despite the crash "
-                        "(expected ckpt-1)", log)
-        rc, log = _run_train(ckpt_c, steps,
-                             extra_env={"ZOO_TPU_AUTO_RESUME": "1"})
-        got_step, got_digest = _digest(log)
-        if rc != 0 or got_step != ref_step or got_digest != ref_digest:
-            return fail(
-                f"resume past partial checkpoint not bit-exact: rc={rc} "
-                f"step={got_step} digest={got_digest} vs reference "
-                f"step={ref_step} digest={ref_digest}", log)
-        out.write("CHAOS_PARTIAL_OK skipped=ckpt-2 bitexact=1\n")
-
-        out.write(f"CHAOS_SMOKE_OK steps={steps} kill_step={kill_step}\n")
-        return 0
+            out.write(f"CHAOS_SMOKE_OK steps={steps} "
+                      f"kill_step={kill_step}\n")
+        return rc
     finally:
         shutil.rmtree(work, ignore_errors=True)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="chaos-smoke")
-    ap.add_argument("--steps", type=int, default=12)
+    ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--kill-step", type=int, default=0,
                     help="step at which to SIGKILL the restart leg "
-                         "(default: random in [3, steps-2])")
+                         "(default: random in [2, steps-2])")
     args = ap.parse_args(argv)
     return run_smoke(steps=args.steps, kill_step=args.kill_step)
 

@@ -1,8 +1,9 @@
 """Deterministic tiny trainer behind ``scripts/chaos-smoke``.
 
-Trains a 2-layer MLP on a fixed synthetic dataset (64 rows, batch 8 —
-so 8 steps/epoch; the default 12 total steps cross an epoch boundary,
-exercising the mid-epoch dataset cursor) with a checkpoint every step,
+Trains a 2-layer MLP on a fixed synthetic dataset (32 rows, batch 8 —
+so 4 steps/epoch; the default 6 total steps cross an epoch boundary,
+exercising the mid-epoch dataset cursor) with a checkpoint every step
+(a third of a second each, which is what a run costs beyond its start),
 then prints a machine-checkable marker::
 
     FINAL step=<N> digest=<sha256 over all param + optimizer leaves>
@@ -38,7 +39,7 @@ def state_digest(trainer) -> str:
 
 def main() -> int:
     ckpt_dir = sys.argv[1]
-    steps = int(sys.argv[2]) if len(sys.argv) > 2 else 12
+    steps = int(sys.argv[2]) if len(sys.argv) > 2 else 6
     import numpy as np
 
     from analytics_zoo_tpu.common.nncontext import (ZooConfig,
@@ -54,7 +55,7 @@ def main() -> int:
     init_nncontext(ZooConfig(log_every_n_steps=1000))
 
     rng = np.random.default_rng(0)
-    x = rng.standard_normal((64, 4)).astype(np.float32)
+    x = rng.standard_normal((32, 4)).astype(np.float32)
     y = (x.sum(axis=1, keepdims=True) > 0).astype(np.float32)
     fs = ArrayFeatureSet(x, y)
 

@@ -93,107 +93,122 @@ def _intervals(events, name):
     return pairs
 
 
-def run_smoke(steps: int = 3, stream=None) -> int:
-    out = stream if stream is not None else sys.stdout
-    work = tempfile.mkdtemp(prefix="zoo_trace_smoke_")
-
+def _failer(out):
     def fail(msg, log=""):
         if log:
             out.write(log)
         out.write(f"TRACE_SMOKE_FAIL: {msg}\n")
         return 1
+    return fail
 
+
+def trace_leg(work: str, steps: int = 3, out=sys.stdout) -> int:
+    """Leg 1: the traced run under ``work``; 0 when the trace holds."""
+    fail = _failer(out)
+    td = os.path.join(work, "traces")
+    rc, log = _run_train(os.path.join(work, "ckpt"), td, steps)
+    if rc != 0:
+        return fail(f"traced run failed rc={rc}", log)
     try:
-        # -- leg 1: traced 3-step run ----------------------------------
-        td = os.path.join(work, "traces")
-        rc, log = _run_train(os.path.join(work, "ckpt"), td, steps)
-        if rc != 0:
-            return fail(f"traced run failed rc={rc}", log)
-        try:
-            traces = _load_traces(td)
-        except AssertionError as e:
-            return fail(f"trace schema violation: {e}", log)
-        if not traces:
-            return fail(f"no trace-*.json written under {td}", log)
-        # the worker's trace is the one that trained
-        trainer = [(p, t) for p, t in traces
-                   if any(e.get("name") == "train/step"
-                          for e in t["traceEvents"])]
-        if not trainer:
-            return fail("no trace file contains train/step spans", log)
-        path, trace = trainer[0]
-        evs = trace["traceEvents"]
-        names = {e["name"] for e in evs if e["ph"] != "M"}
-        for want in ("train/step", "train/dispatch", "train/device_sync",
-                     "ckpt/write", "infeed/wait", "infeed/transform"):
-            if want not in names:
-                return fail(f"{path}: span {want!r} missing "
-                            f"(have {sorted(names)})", log)
-        # nesting: some infeed/wait interval inside a train/step interval
-        # on the same pid/tid (the consumer thread)
-        steps_iv = _intervals(evs, "train/step")
-        waits_iv = _intervals(evs, "infeed/wait")
-        nested = any(sp == wp and st == wt and s0 <= w0 and w1 <= s1
-                     for (sp, st, s0, s1) in steps_iv
-                     for (wp, wt, w0, w1) in waits_iv)
-        if not nested:
-            return fail(f"{path}: no infeed/wait span nests inside a "
-                        f"train/step span on the same pid/tid", log)
-        # per-process worker timelines: infeed/transform events must come
-        # from pids other than the trainer's, under zoo-infeed-* rows
-        own_pid = trace.get("otherData", {}).get("pid")
-        foreign = [e for e in evs if e["name"] == "infeed/transform"
-                   and e["pid"] != own_pid]
-        if not foreign:
-            return fail(f"{path}: no infeed/transform events from worker "
-                        f"processes (process backend timelines missing)",
-                        log)
-        rows = {e["args"]["name"] for e in evs if e["ph"] == "M"
-                and e["name"] == "process_name"}
-        if not any(r.startswith("zoo-infeed-") for r in rows):
-            return fail(f"{path}: no zoo-infeed-* process_name metadata "
-                        f"(rows: {sorted(rows)})", log)
-        if not glob.glob(os.path.join(td, "metrics-*.json")):
-            return fail(f"no metrics-*.json exported under {td}", log)
-        out.write(f"TRACE_LEG_OK spans={len(names)} "
-                  f"workers={len({e['pid'] for e in foreign})}\n")
+        traces = _load_traces(td)
+    except AssertionError as e:
+        return fail(f"trace schema violation: {e}", log)
+    if not traces:
+        return fail(f"no trace-*.json written under {td}", log)
+    # the worker's trace is the one that trained
+    trainer = [(p, t) for p, t in traces
+               if any(e.get("name") == "train/step"
+                      for e in t["traceEvents"])]
+    if not trainer:
+        return fail("no trace file contains train/step spans", log)
+    path, trace = trainer[0]
+    evs = trace["traceEvents"]
+    names = {e["name"] for e in evs if e["ph"] != "M"}
+    for want in ("train/step", "train/dispatch", "train/device_sync",
+                 "ckpt/write", "infeed/wait", "infeed/transform"):
+        if want not in names:
+            return fail(f"{path}: span {want!r} missing "
+                        f"(have {sorted(names)})", log)
+    # nesting: some infeed/wait interval inside a train/step interval
+    # on the same pid/tid (the consumer thread)
+    steps_iv = _intervals(evs, "train/step")
+    waits_iv = _intervals(evs, "infeed/wait")
+    nested = any(sp == wp and st == wt and s0 <= w0 and w1 <= s1
+                 for (sp, st, s0, s1) in steps_iv
+                 for (wp, wt, w0, w1) in waits_iv)
+    if not nested:
+        return fail(f"{path}: no infeed/wait span nests inside a "
+                    f"train/step span on the same pid/tid", log)
+    # per-process worker timelines: infeed/transform events must come
+    # from pids other than the trainer's, under zoo-infeed-* rows
+    own_pid = trace.get("otherData", {}).get("pid")
+    foreign = [e for e in evs if e["name"] == "infeed/transform"
+               and e["pid"] != own_pid]
+    if not foreign:
+        return fail(f"{path}: no infeed/transform events from worker "
+                    f"processes (process backend timelines missing)",
+                    log)
+    rows = {e["args"]["name"] for e in evs if e["ph"] == "M"
+            and e["name"] == "process_name"}
+    if not any(r.startswith("zoo-infeed-") for r in rows):
+        return fail(f"{path}: no zoo-infeed-* process_name metadata "
+                    f"(rows: {sorted(rows)})", log)
+    if not glob.glob(os.path.join(td, "metrics-*.json")):
+        return fail(f"no metrics-*.json exported under {td}", log)
+    out.write(f"TRACE_LEG_OK spans={len(names)} "
+              f"workers={len({e['pid'] for e in foreign})}\n")
+    return 0
 
-        # -- leg 2: kill@2 leaves a flight dump ------------------------
-        td2 = os.path.join(work, "traces-fault")
-        state = os.path.join(work, "fault-state")
-        os.makedirs(state)
-        rc, log = _run_train(
-            os.path.join(work, "ckpt-fault"), td2, steps,
-            extra_env={ENV_SPEC: "step:kill@2", ENV_STATE: state})
+
+def flight_leg(work: str, steps: int = 3, out=sys.stdout) -> int:
+    """Leg 2: kill@2 under ``work`` leaves a flight dump; 0 when it does."""
+    fail = _failer(out)
+    td2 = os.path.join(work, "traces-fault")
+    state = os.path.join(work, "fault-state")
+    os.makedirs(state)
+    rc, log = _run_train(
+        os.path.join(work, "ckpt-fault"), td2, steps,
+        extra_env={ENV_SPEC: "step:kill@2", ENV_STATE: state})
+    if rc == 0:
+        return fail("step:kill@2 never fired (rc=0)", log)
+    dumps = sorted(glob.glob(os.path.join(td2, "debug",
+                                          "flight-*.json")))
+    if not dumps:
+        return fail(f"no debug/flight-*.json under {td2}", log)
+    with open(dumps[-1]) as f:
+        flight = json.load(f)
+    spans = flight.get("spans") or []
+    # the fault event is recorded immediately before the dump — it
+    # must sit at the tail of the ring (a couple of infeed-thread
+    # events may race in behind it)
+    tail = spans[-5:]
+    hit = [e for e in tail if e.get("name") == "fault/step"]
+    if not hit or hit[-1].get("args", {}).get("step") != 2:
+        return fail(
+            f"{dumps[-1]}: ring tail does not record fault/step@2 "
+            f"(tail: {[e.get('name') for e in tail]})", log)
+    if not isinstance(flight.get("metrics"), dict):
+        return fail(f"{dumps[-1]}: no metrics snapshot in flight "
+                    f"dump", log)
+    if "ZOO_TPU_FAULT" not in (flight.get("reason") or ""):
+        return fail(f"{dumps[-1]}: reason does not name the fault "
+                    f"({flight.get('reason')!r})", log)
+    out.write(f"FLIGHT_LEG_OK dump={os.path.basename(dumps[-1])} "
+              f"ring={len(spans)}\n")
+    return 0
+
+
+def run_smoke(steps: int = 3, stream=None) -> int:
+    out = stream if stream is not None else sys.stdout
+    # the killed trainer's infeed workers outlive it by a moment and
+    # write their traces as they exit, so the directory is removed
+    # ignoring what may still land in it
+    work = tempfile.mkdtemp(prefix="zoo_trace_smoke_")
+    try:
+        rc = trace_leg(work, steps, out) or flight_leg(work, steps, out)
         if rc == 0:
-            return fail("step:kill@2 never fired (rc=0)", log)
-        dumps = sorted(glob.glob(os.path.join(td2, "debug",
-                                              "flight-*.json")))
-        if not dumps:
-            return fail(f"no debug/flight-*.json under {td2}", log)
-        with open(dumps[-1]) as f:
-            flight = json.load(f)
-        spans = flight.get("spans") or []
-        # the fault event is recorded immediately before the dump — it
-        # must sit at the tail of the ring (a couple of infeed-thread
-        # events may race in behind it)
-        tail = spans[-5:]
-        hit = [e for e in tail if e.get("name") == "fault/step"]
-        if not hit or hit[-1].get("args", {}).get("step") != 2:
-            return fail(
-                f"{dumps[-1]}: ring tail does not record fault/step@2 "
-                f"(tail: {[e.get('name') for e in tail]})", log)
-        if not isinstance(flight.get("metrics"), dict):
-            return fail(f"{dumps[-1]}: no metrics snapshot in flight "
-                        f"dump", log)
-        if "ZOO_TPU_FAULT" not in (flight.get("reason") or ""):
-            return fail(f"{dumps[-1]}: reason does not name the fault "
-                        f"({flight.get('reason')!r})", log)
-        out.write(f"FLIGHT_LEG_OK dump={os.path.basename(dumps[-1])} "
-                  f"ring={len(spans)}\n")
-
-        out.write(f"TRACE_SMOKE_OK steps={steps}\n")
-        return 0
+            out.write(f"TRACE_SMOKE_OK steps={steps}\n")
+        return rc
     finally:
         shutil.rmtree(work, ignore_errors=True)
 

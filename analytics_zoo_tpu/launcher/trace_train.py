@@ -23,6 +23,21 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
+def cpu_bound_transform(batch):
+    """Deterministic, picklable, GIL-*holding* transform: a pure-Python
+    loop standing in for PIL-style decode work. Module-level, and taken
+    below under the package's name, not ``__main__``'s, so spawned infeed
+    workers can import it by reference."""
+    from analytics_zoo_tpu.feature.feature_set import MiniBatch
+
+    acc = 0
+    for i in range(200):
+        acc += i * i
+    scale = 2.0 if acc else 0.0  # the loop is real but the output fixed
+    return MiniBatch(tuple(x * scale for x in batch.inputs),
+                     batch.targets, batch.weights)
+
+
 def main() -> int:
     ckpt_dir = sys.argv[1]
     steps = int(sys.argv[2]) if len(sys.argv) > 2 else 3
@@ -33,9 +48,9 @@ def main() -> int:
     from analytics_zoo_tpu.common.zoo_trigger import (MaxIteration,
                                                       SeveralIteration)
     from analytics_zoo_tpu.feature.common import LambdaPreprocessing
-    # module-level + importable by reference: spawned infeed workers
-    # unpickle the chain by qualified name
-    from analytics_zoo_tpu.feature.data_smoke import cpu_bound_transform
+    # importable by reference: spawned infeed workers unpickle the chain
+    # by qualified name
+    from analytics_zoo_tpu.launcher.trace_train import cpu_bound_transform
     from analytics_zoo_tpu.feature.feature_set import ArrayFeatureSet
     from analytics_zoo_tpu.pipeline.api.keras.layers import Dense
     from analytics_zoo_tpu.pipeline.api.keras.models import Sequential

@@ -1,9 +1,9 @@
-"""Attention-route end-to-end smoke (``scripts/attn-smoke``; CI fast tier).
+"""Attention-route end-to-end smoke (``bench.py``'s attention leg).
 
-Proves the O(L) attention contract (docs/performance.md) on any host,
-mirroring the fleet/launch smoke pattern — one subprocess-friendly
-entrypoint that the bench, the fast test tier, and ``scripts/attn-smoke``
-all share:
+Proves the O(L) attention contract (docs/performance.md) on any host in
+a process of its own. Tier-1 makes the same checks in
+``tests/test_attn_blhd_route.py``, which also borrows this module's
+jaxpr probe; the module goes when ``bench.py`` does (ROADMAP D1):
 
 - **oracle parity**: the scan-blockwise fallback matches
   ``attention_reference`` forward and backward (causal and key-bias

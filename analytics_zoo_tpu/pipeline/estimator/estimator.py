@@ -17,6 +17,7 @@ flat weight vector.
 
 from __future__ import annotations
 
+import re
 from typing import Dict, Optional, Union
 
 import optax
@@ -71,6 +72,13 @@ class MultiOptimizer(ZooOptimizer):
                     for k in params}
 
         return optax.multi_transform(transforms, label_fn)
+
+
+def _by_count(names):
+    """Auto-generated layer names (``dense_9``, ``dense_10``) in the order
+    their counters ran, digits compared as numbers."""
+    return sorted(names, key=lambda n: [int(t) if t.isdigit() else t
+                                        for t in re.split(r"(\d+)", n)])
 
 
 class Estimator(AbstractEstimator):
@@ -237,11 +245,15 @@ class Estimator(AbstractEstimator):
                 f"{len(expected)} group(s) {shapes(expected)}; only in "
                 f"checkpoint: {sorted(set(got) - set(expected))}, only in "
                 f"model: {sorted(set(expected) - set(got))}")
-        remapped = {new: got[old]
-                    for new, old in zip(expected, got)}
+        # both sides by (layer type, count), the order the names were
+        # handed out in: jax keeps dict keys sorted as strings, which puts
+        # dense_10 before dense_9
+        remapped = {new: got[old] for new, old in
+                    zip(_by_count(expected), _by_count(got))}
         state = trainer.net_state or {}
         new_state = {new: state[old] for new, old in
-                     zip(expected_state, state)} if state else state
+                     zip(_by_count(expected_state), _by_count(state))
+                     } if state else state
         trainer.set_params(remapped, new_state)
 
     def _sync_model(self):

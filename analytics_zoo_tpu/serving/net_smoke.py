@@ -101,15 +101,19 @@ def run_smoke(records: int = 160, stub_ms: float = 30.0,
 
         # -- phase 2: SIGKILL a socket-connected worker mid-stream; the
         # broker must requeue its unacked claims ----------------------
-        victim = 1
-        h0 = read_health(workdir, victim)
-        if not h0:
-            return fail("no health file for victim worker")
         deadline = time.time() + 30.0
         while broker.stats()["delivered"] < records // 4:
             if time.time() > deadline:
                 return fail("burst never started draining")
             time.sleep(0.02)
+        # the victim is chosen now, among the workers the backlog keeps
+        # busy: before the burst the idle scale-down may already have
+        # retired any worker but the first
+        h0 = next((h for h in (read_health(workdir, w) for w in
+                               sorted(fleet._active, reverse=True))
+                   if h), None)
+        if not h0:
+            return fail("no health file for any active worker")
         os.kill(int(h0["pid"]), signal.SIGKILL)
 
         got = out_q.wait_all(uris, timeout=120.0)

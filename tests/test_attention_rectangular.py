@@ -48,15 +48,18 @@ def test_blockwise_rectangular_parity(lq, lk, causal):
     k = _rand(1, (b, h, lk, d))
     v = _rand(2, (b, h, lk, d))
 
-    o = attention_blockwise(q, k, v, causal=causal)
-    ref = attention_reference(q, k, v, causal=causal)
+    # every call under jit: one compile each, not a dispatch per op
+    o = jax.jit(lambda q, k, v: attention_blockwise(
+        q, k, v, causal=causal))(q, k, v)
+    ref = jax.jit(lambda q, k, v: attention_reference(
+        q, k, v, causal=causal))(q, k, v)
     assert o.shape == (b, h, lq, d)
     assert float(jnp.abs(o - ref).max()) < 1e-5
 
-    g = jax.grad(lambda q, k, v: (attention_blockwise(
-        q, k, v, causal=causal) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: (attention_reference(
-        q, k, v, causal=causal) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    g = jax.jit(jax.grad(lambda q, k, v: (attention_blockwise(
+        q, k, v, causal=causal) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lambda q, k, v: (attention_reference(
+        q, k, v, causal=causal) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g, gr):
         assert float(jnp.abs(a - b_).max()) < 1e-4
 
@@ -85,8 +88,10 @@ def test_flash_entry_rectangular_causal(lq, lk):
     q = _rand(0, (b, h, lq, d))
     k = _rand(1, (b, h, lk, d))
     v = _rand(2, (b, h, lk, d))
-    o = flash_attention(q, k, v, causal=True)
-    ref = attention_reference(q, k, v, causal=True)
+    o = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True))(q, k, v)
+    ref = jax.jit(lambda q, k, v: attention_reference(
+        q, k, v, causal=True))(q, k, v)
     assert float(jnp.abs(o - ref).max()) < 1e-5
 
 
@@ -100,8 +105,10 @@ def test_flash_blhd_entry_rectangular_causal(lq, lk):
     def tr(t):
         return t.transpose(0, 2, 1, 3)
 
-    o = flash_attention_blhd(ql, kl, vl, causal=True)
-    ref = tr(attention_reference(tr(ql), tr(kl), tr(vl), causal=True))
+    o = jax.jit(lambda q, k, v: flash_attention_blhd(
+        q, k, v, causal=True))(ql, kl, vl)
+    ref = jax.jit(lambda q, k, v: tr(attention_reference(
+        tr(q), tr(k), tr(v), causal=True)))(ql, kl, vl)
     assert o.shape == (b, lq, h, d)
     assert float(jnp.abs(o - ref).max()) < 1e-5
 
@@ -130,16 +137,19 @@ def test_pallas_kernel_rectangular_causal_interpret(monkeypatch, lq, lk):
     qf = q.reshape(b * h, lq, d)
     kf = k.reshape(b * h, lk, d)
     vf = v.reshape(b * h, lk, d)
-    o, lse = _flash_forward(qf, kf, vf, kb, h, True, sm, 128, 128)
-    ref = attention_reference(q, k, v, causal=True)
+    o, lse = jax.jit(lambda q, k, v, kb: _flash_forward(
+        q, k, v, kb, h, True, sm, 128, 128))(qf, kf, vf, kb)
+    ref = jax.jit(lambda q, k, v: attention_reference(
+        q, k, v, causal=True))(q, k, v)
     assert float(jnp.abs(o.reshape(b, h, lq, d) - ref).max()) < 1e-5
 
-    gq, gk, gv = jax.grad(
+    gq, gk, gv = jax.jit(jax.grad(
         lambda q, k, v: (attention_reference(q, k, v, causal=True)
-                         ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+                         ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     do = (2 * o).astype(o.dtype)
-    dq, dk, dv, _ = _flash_backward(qf, kf, vf, kb, o, lse, do, h, True,
-                                    sm, 128, 128)
+    dq, dk, dv, _ = jax.jit(lambda q, k, v, kb, o, lse, do: _flash_backward(
+        q, k, v, kb, o, lse, do, h, True, sm, 128, 128))(
+            qf, kf, vf, kb, o, lse, do)
     assert float(jnp.abs(dq.reshape(b, h, lq, d) - gq).max()) < 1e-4
     assert float(jnp.abs(dk.reshape(b, h, lk, d) - gk).max()) < 1e-4
     assert float(jnp.abs(dv.reshape(b, h, lk, d) - gv).max()) < 1e-4

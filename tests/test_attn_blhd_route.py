@@ -11,15 +11,12 @@ Covers the r6 attention work (docs/performance.md):
   fallback);
 - the HLO step-time accountant: opcode buckets on synthetic HLO text,
   the ``account_step`` integration, and the hot-path contract (zero
-  copy/transpose ops carrying the ``attn_hot`` scope);
-- the ``attn-smoke`` entrypoint end to end as a subprocess (the
-  ``scripts/attn-smoke`` CI hook).
+  copy/transpose ops carrying the ``attn_hot`` scope).
+
+``ops/attn_smoke.py`` re-runs these checks in a process of its own as
+``bench.py``'s attention leg; this file is where tier-1 makes them.
 """
 
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -34,8 +31,6 @@ from analytics_zoo_tpu.ops.attention import (_flash_remat_policy,
                                              flash_attention_blhd)
 from analytics_zoo_tpu.ops.attn_smoke import jaxpr_materializes_lxl
 from analytics_zoo_tpu.utils.profiling import account_step, hlo_accountant
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _rand(key, shape):
@@ -241,23 +236,3 @@ def test_flash_remat_policy_from_config(monkeypatch):
         assert _flash_remat_policy() == "full"
     finally:
         set_nncontext(None)
-
-
-# ---------------------------------------------------------------------------
-# attn-smoke end to end (subprocess; the ISSUE acceptance path)
-# ---------------------------------------------------------------------------
-
-def test_attn_smoke_end_to_end():
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("ZOO_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "analytics_zoo_tpu.ops.attn_smoke",
-         "--json"],
-        capture_output=True, text=True, timeout=480, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert all(payload["checks"].values()), payload
-    assert payload["dp_parity_max_err"] < 1e-4
-    assert payload["jaxpr_no_lxl"] is True

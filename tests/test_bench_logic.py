@@ -4,7 +4,6 @@ runs), and the chip-or-fail rule.
 """
 
 import os
-import sys
 
 import pytest
 
@@ -71,13 +70,8 @@ def test_no_chip_is_an_error(bench):
         assert not hasattr(bench, gone), gone
 
 
-def test_bench_main_without_a_chip_exits_nonzero():
-    import subprocess
-
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
-        text=True, timeout=300, cwd=REPO)
+def test_bench_main_without_a_chip_exits_nonzero(run_python):
+    p = run_python(os.path.join(REPO, "bench.py"))
     assert p.returncode != 0
     assert "measures the chip" in p.stderr
     assert p.stdout.strip() == ""          # no JSON line, no number

@@ -1,9 +1,7 @@
 """Caffe importer: golden-output tests vs torch (independent reference
-implementation of conv/pool/BN/LRN semantics) + the reference repo's real
-``.caffemodel`` fixtures (parity:
+implementation of conv/pool/BN/LRN semantics) + the reference repo's
+fixture pair, rebuilt in the test (parity:
 zoo/.../models/caffe/CaffeLoader.scala:718)."""
-
-import os
 
 import numpy as np
 import pytest
@@ -12,7 +10,69 @@ from analytics_zoo_tpu.pipeline.api.caffe import CaffeLoader, load_caffe
 from analytics_zoo_tpu.pipeline.api.caffe import proto as cproto
 from analytics_zoo_tpu.pipeline.api.caffe.text_format import parse_prototxt
 
-REF_RES = "/root/reference/pyzoo/test/zoo/resources"
+# The upstream repo's own fixture pair (pyzoo/test/zoo/resources/
+# test.prototxt + test.caffemodel), rebuilt here: the prototxt in its
+# old-style layout (``input_dim`` lines, fillers nested in the layer
+# params) and the binary model from the repo's protobuf writer. No
+# machine of this round holds the upstream checkout.
+REFERENCE_PROTOTXT = """\
+name: "convolution"
+input: "data"
+input_dim: 1
+input_dim: 3
+input_dim: 5
+input_dim: 5
+layer {
+  name: "conv"
+  type: "Convolution"
+  bottom: "data"
+  top: "conv"
+  convolution_param {
+    num_output: 4
+    kernel_size: 2
+    weight_filler {
+      type: "xavier"
+    }
+    bias_filler {
+      type: "gaussian"
+      std: 2
+    }
+  }
+}
+layer {
+  name: "conv2"
+  type: "Convolution"
+  bottom: "conv"
+  top: "conv2"
+  convolution_param {
+    num_output: 3
+    kernel_size: 2
+    weight_filler {
+      type: "xavier"
+    }
+    bias_filler {
+      type: "gaussian"
+      std: 2
+    }
+  }
+}
+layer {
+  name: "ip"
+  type: "InnerProduct"
+  bottom: "conv2"
+  top: "ip"
+  inner_product_param {
+    num_output: 2
+    weight_filler {
+      type: "xavier"
+    }
+    bias_filler {
+      type: "gaussian"
+      std: 2
+    }
+  }
+}
+"""
 
 
 def _blob(arr):
@@ -27,8 +87,7 @@ def _write_model(path, layers, name="net"):
 
 
 def test_prototxt_parser_reference_fixture():
-    with open(os.path.join(REF_RES, "test.prototxt")) as f:
-        net = parse_prototxt(f.read())
+    net = parse_prototxt(REFERENCE_PROTOTXT)
     assert net["name"] == "convolution"
     assert net["input"] == ["data"]
     assert net["input_dim"] == [1, 3, 5, 5]
@@ -38,10 +97,22 @@ def test_prototxt_parser_reference_fixture():
     assert conv["num_output"] == 4 and conv["kernel_size"] == [2]
 
 
-def test_load_reference_caffemodel_end_to_end():
-    """The reference's real binary fixture loads and runs."""
-    model = load_caffe(os.path.join(REF_RES, "test.prototxt"),
-                       os.path.join(REF_RES, "test.caffemodel"))
+def test_load_reference_caffemodel_end_to_end(tmp_path, rng):
+    """The reference-style fixture pair loads from disk and runs."""
+    ptx = tmp_path / "test.prototxt"
+    ptx.write_text(REFERENCE_PROTOTXT)
+    _write_model(tmp_path / "test.caffemodel", [
+        {"name": "conv", "type": "Convolution", "blobs": [
+            _blob(rng.standard_normal((4, 3, 2, 2))),
+            _blob(rng.standard_normal((4,)))]},
+        {"name": "conv2", "type": "Convolution", "blobs": [
+            _blob(rng.standard_normal((3, 4, 2, 2))),
+            _blob(rng.standard_normal((3,)))]},
+        {"name": "ip", "type": "InnerProduct", "blobs": [
+            _blob(rng.standard_normal((2, 27))),
+            _blob(rng.standard_normal((2,)))]},
+    ], name="convolution")
+    model = load_caffe(str(ptx), str(tmp_path / "test.caffemodel"))
     x = np.random.default_rng(0).standard_normal((2, 3, 5, 5)) \
         .astype(np.float32)
     out = model.predict(x, batch_size=2)

@@ -13,8 +13,6 @@
 import os
 import re
 import shutil
-import subprocess
-import sys
 import tempfile
 
 import jax
@@ -295,7 +293,7 @@ def test_hostdev_decides_from_the_environment():
         "XLA_FLAGS"] == f"{flag}=8"
 
 
-def test_parents_never_initialise_a_backend():
+def test_parents_never_initialise_a_backend(run_python):
     """With ``JAX_PLATFORMS`` naming a platform that does not exist, any
     backend initialisation raises. The supervisors import, and
     ``reexec_module`` decides and starts its (CPU-pinned) child, without
@@ -312,11 +310,7 @@ def test_parents_never_initialise_a_backend():
         "    jax.devices()\n"
         "except RuntimeError as e:\n"
         "    print('NO_BACKEND_UNTIL_NOW')\n")
-    env = dict(os.environ, JAX_PLATFORMS="no_such_platform",
-               PYTHONPATH=REPO)
-    env.pop("ZOO_HOSTDEV_CHILD", None)
-    p = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=120)
+    p = run_python("-c", code, env={"JAX_PLATFORMS": "no_such_platform"})
     assert p.returncode == 0, p.stderr[-800:]
     assert "NO_BACKEND_UNTIL_NOW" in p.stdout
 
@@ -342,20 +336,13 @@ def test_second_worker_on_a_chip_host_is_refused_by_name(tmp_path):
 # chip_smoke.py itself
 # ---------------------------------------------------------------------------
 
-def test_chip_smoke_refuses_a_cpu_backend(tmp_path):
+def test_chip_smoke_refuses_a_cpu_backend(tmp_path, run_python):
     """No accelerator: non-zero, and no result line. The same alone in an
     empty directory (nothing of the repo to import)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.run([sys.executable, os.path.join(REPO,
-                                                     "chip_smoke.py")],
-                       env=env, capture_output=True, text=True,
-                       timeout=300, cwd=REPO)
+    p = run_python(os.path.join(REPO, "chip_smoke.py"))
     assert p.returncode != 0
     assert '"ok"' not in p.stdout and "no accelerator" in p.stderr
 
     shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
-    env.pop("PYTHONPATH", None)
-    p = subprocess.run([sys.executable, "chip_smoke.py"], env=env,
-                       capture_output=True, text=True, timeout=300,
-                       cwd=tmp_path)
+    p = run_python("chip_smoke.py", cwd=tmp_path, env={"PYTHONPATH": ""})
     assert p.returncode != 0 and '"ok"' not in p.stdout

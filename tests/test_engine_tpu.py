@@ -15,7 +15,7 @@ import sys
 
 import pytest
 
-from test_attention_tpu import _clean_env, _tpu_available
+from test_attention_tpu import _clean_env, tpu  # noqa: F401 (fixture)
 
 _SMOKE = r"""
 import numpy as np, jax
@@ -57,8 +57,9 @@ print("TPU_ENGINE_OK", res["accuracy"])
 """
 
 
-@pytest.mark.skipif(not _tpu_available(), reason="no TPU attached")
-def test_fit_evaluate_predict_on_tpu():
+@pytest.mark.time_limit(960, reason="trains on a chip through the fused "
+                        "dispatch; skipped where there is none")
+def test_fit_evaluate_predict_on_tpu(tpu):
     out = subprocess.run([sys.executable, "-c", _SMOKE],
                          capture_output=True, text=True, timeout=900,
                          env=_clean_env())

@@ -54,7 +54,16 @@ def test_estimator_clipping_state_machine():
     assert est.trainer.step >= 6
 
 
-def test_estimator_checkpoint_and_resume(tmp_path):
+@pytest.mark.parametrize("next_count", [None, 9, 99])
+def test_estimator_checkpoint_and_resume(tmp_path, monkeypatch,
+                                         next_count):
+    """Resume into a fresh model instance, whose auto-generated layer
+    names differ; also with the names' counter about to grow a digit on
+    the saving side (``dense_9``, ``dense_10``: as strings they sort the
+    other way round)."""
+    if next_count is not None:
+        from analytics_zoo_tpu.pipeline.api.keras.engine import base
+        monkeypatch.setitem(base._name_counters, "dense", next_count - 1)
     x, y = _regression_data()
     model = _mlp()
     est = Estimator(model, optim_methods=SGD(lr=0.05),

@@ -6,8 +6,6 @@ import io
 import logging
 import os
 import shutil
-import subprocess
-import sys
 import types
 
 import numpy as np
@@ -304,19 +302,42 @@ def test_param_group_mismatch_reports_names_and_shapes():
     assert "only in checkpoint" in msg and "only in model" in msg
 
 
-# -- the chaos smoke, exactly as CI runs it ----------------------------
+# -- the chaos smoke's legs: real SIGKILLs under the real launcher ------
+# (launcher/chaos_smoke.py; every job is a process of the launcher's own,
+# so the legs are called in this one)
 
-def test_chaos_smoke_end_to_end():
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("ZOO_TPU_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "analytics_zoo_tpu.launcher.chaos_smoke",
-         "--kill-step", "5"],
-        cwd=REPO, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, timeout=240)
-    assert proc.returncode == 0, proc.stdout
-    assert "CHAOS_SMOKE_OK" in proc.stdout
-    assert "CHAOS_RESTART_OK kill_step=5 bitexact=1" in proc.stdout
-    assert "CHAOS_PARTIAL_OK skipped=ckpt-2 bitexact=1" in proc.stdout
+@pytest.fixture(scope="module")
+def chaos_reference(tmp_path_factory):
+    from analytics_zoo_tpu.launcher import chaos_smoke
+
+    out = io.StringIO()
+    ref = chaos_smoke.reference_leg(
+        str(tmp_path_factory.mktemp("chaos-ref")), out=out)
+    assert ref is not None, out.getvalue()
+    return ref
+
+
+def test_chaos_uninterrupted_reference_run(chaos_reference):
+    step, digest = chaos_reference
+    assert step == 6 and len(digest) == 64
+
+
+def test_chaos_sigkill_under_gang_restart_resumes_bit_exact(
+        tmp_path, chaos_reference):
+    from analytics_zoo_tpu.launcher import chaos_smoke
+
+    out = io.StringIO()
+    rc = chaos_smoke.restart_leg(str(tmp_path), chaos_reference,
+                                 kill_step=3, out=out)
+    assert rc == 0, out.getvalue()
+    assert "CHAOS_RESTART_OK kill_step=3 bitexact=1" in out.getvalue()
+
+
+def test_chaos_sigkill_mid_checkpoint_write_is_skipped_bit_exact(
+        tmp_path, chaos_reference):
+    from analytics_zoo_tpu.launcher import chaos_smoke
+
+    out = io.StringIO()
+    rc = chaos_smoke.partial_leg(str(tmp_path), chaos_reference, out=out)
+    assert rc == 0, out.getvalue()
+    assert "CHAOS_PARTIAL_OK skipped=ckpt-2 bitexact=1" in out.getvalue()

@@ -2,14 +2,11 @@
 cost scoring, affinity, stale-report fallback, substream placement +
 SIGKILL-style redelivery, and the autoscaler's decode-step weighting."""
 
-import os
-import subprocess
-import sys
+import io
 import time
 
 import numpy as np
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from analytics_zoo_tpu.serving.admission import BacklogAutoscaler
 from analytics_zoo_tpu.serving.generation import (ContinuousBatchScheduler,
@@ -307,22 +304,19 @@ def test_autoscaler_gen_steps_reset_idle_clock():
 
 
 # ---------------------------------------------------------------------------
-# fleet end-to-end smoke (subprocess; the ISSUE acceptance path)
+# fleet end-to-end smoke (real worker processes, real SIGKILL)
 # ---------------------------------------------------------------------------
 
 def test_route_smoke_end_to_end():
     """2-worker fleet with routed generate placement: repeat prompt
     affinity-routed to the heartbeat-reported prefix holder, SIGKILL
     mid-burst, and exactly-once settle via substream sweep +
-    original-rid re-drive."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("ZOO_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "analytics_zoo_tpu.serving.route_smoke",
-         "--records", "20"],
-        capture_output=True, text=True, timeout=480, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "ROUTE_SMOKE_OK records=22" in proc.stdout
-    assert "restarts=1" in proc.stdout
+    original-rid re-drive. The fleet's workers are processes of its
+    own; the smoke's driver runs in this one."""
+    from analytics_zoo_tpu.serving import route_smoke
+
+    out = io.StringIO()
+    assert route_smoke.run_smoke(records=20, stream=out) == 0, \
+        out.getvalue()
+    assert "ROUTE_SMOKE_OK records=22" in out.getvalue()
+    assert "restarts=1" in out.getvalue()

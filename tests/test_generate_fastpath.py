@@ -37,6 +37,12 @@ def _rand(key, shape):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32)
 
 
+# the ops and the layer's verbs run under jit in these tests (one compile
+# per shape instead of a dispatch per op): what they compute is the same
+_step = jax.jit(cached_attention_step)
+_chunk = jax.jit(cached_attention_chunk)
+
+
 # ---------------------------------------------------------------------------
 # ops: the rectangular chunk step
 # ---------------------------------------------------------------------------
@@ -54,7 +60,7 @@ def test_chunk_step_matches_token_steps(dtype):
     # pre-populate the prefix rows
     pre_k, pre_v = _rand(0, (B, 3, H, D)), _rand(1, (B, 3, H, D))
     for t in range(3):
-        _, k_cache, v_cache, lengths0 = cached_attention_step(
+        _, k_cache, v_cache, lengths0 = _step(
             _rand(9, (B, 1, H, D)), pre_k[:, t:t + 1], pre_v[:, t:t + 1],
             k_cache, v_cache, jnp.array([t, 0], jnp.int32))
     lengths = jnp.array([3, 3], jnp.int32)
@@ -62,13 +68,13 @@ def test_chunk_step_matches_token_steps(dtype):
     kn = _rand(3, (B, C, H, D))
     vn = _rand(4, (B, C, H, D))
 
-    o_c, kc_c, vc_c, len_c = cached_attention_chunk(
+    o_c, kc_c, vc_c, len_c = _chunk(
         q, kn, vn, k_cache, v_cache, lengths)
 
     kc_s, vc_s, len_s = k_cache, v_cache, lengths
     outs = []
     for t in range(C):
-        o, kc_s, vc_s, len_s = cached_attention_step(
+        o, kc_s, vc_s, len_s = _step(
             q[:, t:t + 1], kn[:, t:t + 1], vn[:, t:t + 1],
             kc_s, vc_s, len_s)
         outs.append(o)
@@ -86,7 +92,7 @@ def test_chunk_ragged_n_valid_then_step():
     q = _rand(0, (B, C, H, D))
     kn, vn = _rand(1, (B, C, H, D)), _rand(2, (B, C, H, D))
 
-    o_r, kc_r, vc_r, len_r = cached_attention_chunk(
+    o_r, kc_r, vc_r, len_r = _chunk(
         q, kn, vn, k_cache, v_cache, lengths,
         n_valid=jnp.array([NV], jnp.int32))
     assert int(len_r[0]) == NV
@@ -94,15 +100,15 @@ def test_chunk_ragged_n_valid_then_step():
     # exact: the same two valid tokens step-by-step
     kc, vc, ln = k_cache, v_cache, lengths
     for t in range(NV):
-        o, kc, vc, ln = cached_attention_step(
+        o, kc, vc, ln = _step(
             q[:, t:t + 1], kn[:, t:t + 1], vn[:, t:t + 1], kc, vc, ln)
         assert float(jnp.abs(o_r[:, t:t + 1] - o).max()) < 1e-5
 
     # a follow-up step overwrites the garbage rows and matches
     qs, ks, vs = _rand(3, (B, 1, H, D)), _rand(4, (B, 1, H, D)), \
         _rand(5, (B, 1, H, D))
-    o_a = cached_attention_step(qs, ks, vs, kc_r, vc_r, len_r)[0]
-    o_b = cached_attention_step(qs, ks, vs, kc, vc, ln)[0]
+    o_a = _step(qs, ks, vs, kc_r, vc_r, len_r)[0]
+    o_b = _step(qs, ks, vs, kc, vc, ln)[0]
     assert float(jnp.abs(o_a - o_b).max()) < 1e-5
 
 
@@ -142,30 +148,39 @@ def layer_and_params():
                              seq_len=64, intermediate_size=16,
                              hidden_p_drop=0.0, attn_p_drop=0.0,
                              bidirectional=False)
-    params = layer.build(jax.random.PRNGKey(0), (None, 64))
+    params = jax.jit(lambda key: layer.build(key, (None, 64)))(
+        jax.random.PRNGKey(0))
     return layer, params
 
 
-def test_chunked_prefill_logits_match_unchunked(layer_and_params):
+@pytest.fixture(scope="module")
+def verbs(layer_and_params):
+    """The layer's cached-decode verbs, jitted once for the module."""
+    layer, _ = layer_and_params
+    return (jax.jit(layer.prefill), jax.jit(layer.decode_step),
+            jax.jit(layer.decode_chunk))
+
+
+def test_chunked_prefill_logits_match_unchunked(layer_and_params, verbs):
     """decode_chunk-driven prefill reproduces layer.prefill's last-token
     logits — chunking is invisible to the model."""
     layer, params = layer_and_params
+    prefill, _, decode_chunk = verbs
     rng = np.random.default_rng(3)
     Lp, C = 13, 4
     toks = jnp.asarray(rng.integers(1, 30, (1, Lp)))
 
     st_ref = layer.init_decode_state(1, 32)
-    lg_ref, st_ref = layer.prefill(params, toks,
-                                   jnp.full((1,), Lp, jnp.int32), st_ref)
+    lg_ref, st_ref = prefill(params, toks,
+                             jnp.full((1,), Lp, jnp.int32), st_ref)
 
     st = layer.init_decode_state(1, 32)
     for start in range(0, Lp, C):
         end = min(start + C, Lp)
         buf = jnp.zeros((1, C), jnp.int32).at[0, :end - start].set(
             toks[0, start:end])
-        lg, st = layer.decode_chunk(params, st, buf,
-                                    n_valid=jnp.array([end - start],
-                                                      jnp.int32))
+        lg, st = decode_chunk(params, st, buf,
+                              n_valid=jnp.array([end - start], jnp.int32))
     assert int(st.lengths[0]) == Lp
     assert float(jnp.abs(lg[0, (Lp - 1) % C] - lg_ref[0]).max()) < 1e-4
 
@@ -253,28 +268,28 @@ def test_transformer_prefix_cache_hit_is_exact_and_skips_prefill(
     assert cache.hits == 1 and cache.misses == 1
 
 
-def test_transformer_rollback_is_length_surgery(layer_and_params):
+def test_transformer_rollback_is_length_surgery(layer_and_params, verbs):
     """Rolling back n rows then re-stepping equals never having written
     them — the speculative reject path."""
     layer, params = layer_and_params
+    prefill, decode_step, decode_chunk = verbs
     rng = np.random.default_rng(5)
     toks = jnp.asarray(rng.integers(1, 30, (1, 6)))
     eng = TransformerDecodeEngine(layer, params)
 
     st = layer.init_decode_state(1, 32)
-    _, st = layer.prefill(params, toks[:, :3],
-                          jnp.full((1,), 3, jnp.int32), st)
+    _, st = prefill(params, toks[:, :3], jnp.full((1,), 3, jnp.int32), st)
     # write 3 speculative rows, reject the last 2
-    lg_spec, st = layer.decode_chunk(params, st, toks[:, 3:6])
+    lg_spec, st = decode_chunk(params, st, toks[:, 3:6])
     st = eng.rollback(st, {0: 2})
     assert int(st.lengths[0]) == 4
-    lg_a, st = layer.decode_step(params, st, toks[:, 4])
+    lg_a, st = decode_step(params, st, toks[:, 4])
 
     st_ref = layer.init_decode_state(1, 32)
-    _, st_ref = layer.prefill(params, toks[:, :3],
-                              jnp.full((1,), 3, jnp.int32), st_ref)
-    _, st_ref = layer.decode_step(params, st_ref, toks[:, 3])
-    lg_b, st_ref = layer.decode_step(params, st_ref, toks[:, 4])
+    _, st_ref = prefill(params, toks[:, :3], jnp.full((1,), 3, jnp.int32),
+                        st_ref)
+    _, st_ref = decode_step(params, st_ref, toks[:, 3])
+    lg_b, st_ref = decode_step(params, st_ref, toks[:, 4])
     assert float(jnp.abs(lg_a - lg_b).max()) < 1e-5
 
 

@@ -28,12 +28,15 @@ def test_cached_step_matches_full_causal_attention():
     kc = jnp.zeros((B, S, H, D))
     vc = jnp.zeros((B, S, H, D))
     lengths = jnp.zeros((B,), jnp.int32)
+    # under jit: the step compiles once, the reference once per prefix
+    # length, instead of a dispatch per op at twelve shapes
+    step = jax.jit(KV.cached_attention_step)
+    full = jax.jit(lambda q, k, v: _tr(attention_reference(
+        _tr(q), _tr(k), _tr(v), causal=True)))
     for t in range(L):
-        o, kc, vc, lengths = KV.cached_attention_step(
+        o, kc, vc, lengths = step(
             q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1], kc, vc, lengths)
-        ref = _tr(attention_reference(
-            _tr(q[:, :t + 1]), _tr(k[:, :t + 1]), _tr(v[:, :t + 1]),
-            causal=True))[:, -1:]
+        ref = full(q[:, :t + 1], k[:, :t + 1], v[:, :t + 1])[:, -1:]
         assert float(jnp.abs(o - ref).max()) < 1e-5
     assert lengths.tolist() == [L, L]
 

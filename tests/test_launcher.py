@@ -4,8 +4,6 @@ policies, hosts-file surface, and the end-to-end launch smoke (2-host
 
 import io
 import os
-import subprocess
-import sys
 import textwrap
 
 import pytest
@@ -138,19 +136,18 @@ def test_cli_rejects_bad_env_pair(capsys):
     assert main(["--env", "NOEQUALS", "script.py"]) == 2
 
 
-def test_launch_smoke_end_to_end():
+def test_launch_smoke_end_to_end(no_zoo_tpu_env):
     """The ISSUE acceptance smoke, wired into the fast tier: zoo-launch
     --hosts 2 over a generated 8-shard parquet dataset trains
     ``NNEstimator.fit(dataset_uri)`` with disjoint per-host shard sets,
-    full coverage, params that moved, and **no hand-set ZOO_TPU_* env**."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("ZOO_TPU_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "analytics_zoo_tpu.launcher.launch_smoke",
-         "--hosts", "2", "--shards", "8", "--rows", "64", "--batch", "8"],
-        capture_output=True, text=True, timeout=240, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "LAUNCH_SMOKE_OK hosts=2 shards=8" in proc.stdout
-    assert "job complete: 2 worker(s) exited 0" in proc.stdout
+    full coverage, params that moved, and **no hand-set ZOO_TPU_* env**.
+    The two workers are processes of the launcher's own; the smoke's
+    driver runs in this one."""
+    from analytics_zoo_tpu.launcher import launch_smoke
+
+    out = io.StringIO()
+    rc = launch_smoke.run_smoke(hosts=2, shards=8, rows=64, batch=8,
+                                stream=out)
+    assert rc == 0, out.getvalue()
+    assert "LAUNCH_SMOKE_OK hosts=2 shards=8" in out.getvalue()
+    assert "job complete: 2 worker(s) exited 0" in out.getvalue()

@@ -412,6 +412,10 @@ def test_pipeline_stats_per_model_and_version():
     finally:
         serving.stop()
     assert len(got) == 12
+    # each record was answered by its own model, never its neighbour's
+    for i, uri in enumerate(uris):
+        assert float(np.asarray(got[uri]).ravel()[0]) == \
+            (1.0 if i % 3 else 2.0)
     stats = serving.pipeline_stats()
     models = stats["models"]
     assert models["a"]["versions"][1]["requests"] == 8

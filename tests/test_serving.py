@@ -96,35 +96,30 @@ def test_watermark_trim():
     assert q.stream_len() == 5
 
 
-def test_serving_lifecycle_cli(tmp_path):
+def test_serving_lifecycle_cli(tmp_path, run_python):
     """The ops-tier lifecycle (init -> start -> status -> serve traffic ->
-    stop) through the real CLI the scripts/ wrappers exec, on the file
-    transport across a process boundary."""
-    import os
-    import subprocess
-    import sys
-
+    stop) through the CLI's ``main``, on the file transport across a
+    process boundary: ``start`` forks its daemon from an interpreter of
+    its own (a fork of this one, jax's threads and all, would not be
+    safe); every other verb returns its exit code in this process."""
     from analytics_zoo_tpu.serving import (FileStreamQueue, InputQueue,
                                            OutputQueue)
-    from analytics_zoo_tpu.serving.cli import CONFIG
+    from analytics_zoo_tpu.serving.cli import CONFIG, main
 
     workdir = tmp_path / "serving"
     model_dir = tmp_path / "model"
     stream_dir = tmp_path / "stream"
     _tiny_image_model().save_model(str(model_dir))
 
-    # the daemon is a process of its own: pin it to the CPU explicitly
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    def cli(verb):
+        return main([verb, "--dir", str(workdir)])
 
-    def cli(*args):
-        return subprocess.run(
-            [sys.executable, "-m", "analytics_zoo_tpu.serving.cli", *args,
-             "--dir", str(workdir)],
-            capture_output=True, text=True, timeout=120, env=env,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    def start():
+        return run_python("-m", "analytics_zoo_tpu.serving.cli", "start",
+                          "--dir", str(workdir))
 
-    assert cli("init").returncode == 0
-    assert cli("init").returncode == 1          # refuses to overwrite
+    assert cli("init") == 0
+    assert cli("init") == 1          # refuses to overwrite
     cfg = workdir / CONFIG
     assert cfg.exists()
     cfg.write_text(
@@ -132,12 +127,12 @@ def test_serving_lifecycle_cli(tmp_path):
         f"data:\n  src: file:{stream_dir}\n  image_shape: 3, 16, 16\n"
         f"params:\n  batch_size: 4\n  top_n: 2\n")
 
-    assert cli("status").returncode == 3        # not running yet
-    out = cli("start")
+    assert cli("status") == 3        # not running yet
+    out = start()
     assert out.returncode == 0, out.stderr + out.stdout
     try:
-        assert cli("status").returncode == 0
-        assert cli("start").returncode == 1     # double-start refused
+        assert cli("status") == 0
+        assert start().returncode == 1         # double-start refused
 
         backend = FileStreamQueue(str(stream_dir))
         rng = np.random.default_rng(0)
@@ -153,8 +148,8 @@ def test_serving_lifecycle_cli(tmp_path):
             time.sleep(0.2)
         assert len(got) == 5, f"only {len(got)} results"
     finally:
-        assert cli("stop").returncode == 0
-    assert cli("status").returncode == 3
+        assert cli("stop") == 0
+    assert cli("status") == 3
     assert not (workdir / "cluster-serving.pid").exists()
 
 

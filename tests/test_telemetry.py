@@ -7,10 +7,9 @@ wrap, the Chrome-trace export must be schema-valid, and the end-to-end
 trace smoke must pass exactly as CI runs it.
 """
 
+import io
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -18,7 +17,6 @@ import pytest
 
 from analytics_zoo_tpu.utils import telemetry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _ENV_KEYS = ("ZOO_TPU_TELEMETRY", "ZOO_TPU_TRACE_DIR",
              "ZOO_TPU_TELEMETRY_SERVICE")
@@ -242,21 +240,26 @@ def test_foreign_worker_events_get_their_own_pid_row(tmp_path):
                for e in evs)
 
 
-# -- the trace smoke, exactly as CI runs it ----------------------------
+# -- the trace smoke's legs: real launcher, real infeed workers, real kill
+# (launcher/trace_smoke.py; every job is a process of the launcher's own,
+# so the legs are called in this one, their work directory under tmp_path)
 
-def test_trace_smoke_end_to_end():
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("ZOO_TPU_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run(
-        [sys.executable, "-m", "analytics_zoo_tpu.launcher.trace_smoke"],
-        cwd=REPO, env=env, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, timeout=240)
-    assert proc.returncode == 0, proc.stdout
-    assert "TRACE_SMOKE_OK" in proc.stdout
-    assert "TRACE_LEG_OK" in proc.stdout
-    assert "FLIGHT_LEG_OK" in proc.stdout
+def test_trace_smoke_traced_run(tmp_path, no_zoo_tpu_env):
+    from analytics_zoo_tpu.launcher import trace_smoke
+
+    out = io.StringIO()
+    assert trace_smoke.trace_leg(str(tmp_path), out=out) == 0, \
+        out.getvalue()
+    assert "TRACE_LEG_OK" in out.getvalue()
+
+
+def test_trace_smoke_kill_leaves_a_flight_dump(tmp_path, no_zoo_tpu_env):
+    from analytics_zoo_tpu.launcher import trace_smoke
+
+    out = io.StringIO()
+    assert trace_smoke.flight_leg(str(tmp_path), out=out) == 0, \
+        out.getvalue()
+    assert "FLIGHT_LEG_OK" in out.getvalue()
 
 
 # -- spans reported after the fact, the bounded buffer, compile events --

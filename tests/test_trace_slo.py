@@ -11,8 +11,6 @@ fleet yields a single connected span tree after `zoo-trace` merge.
 
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
@@ -286,14 +284,13 @@ def test_trace_summary_flow_connectivity():
 LINT = os.path.join(REPO, "scripts", "lint-telemetry")
 
 
-def test_lint_telemetry_passes_on_repo():
-    proc = subprocess.run([sys.executable, LINT], capture_output=True,
-                          text=True, cwd=REPO, timeout=120)
+def test_lint_telemetry_passes_on_repo(run_python):
+    proc = run_python(LINT)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "lint-telemetry: ok" in proc.stdout
 
 
-def test_lint_telemetry_rejects_unbounded_labels(tmp_path):
+def test_lint_telemetry_rejects_unbounded_labels(tmp_path, run_python):
     bad = tmp_path / "mod.py"
     bad.write_text(
         "from analytics_zoo_tpu.utils import telemetry\n"
@@ -302,9 +299,7 @@ def test_lint_telemetry_rejects_unbounded_labels(tmp_path):
         "    telemetry.gauge('zoo_y', k='{}'.format(i)).set(1)\n"
         "    telemetry.histogram('zoo_%s' % i).observe(1)\n"
         "    telemetry.summary('zoo_ok', code=uri).record(1)\n")
-    proc = subprocess.run([sys.executable, LINT, str(tmp_path)],
-                          capture_output=True, text=True, cwd=REPO,
-                          timeout=120)
+    proc = run_python(LINT, str(tmp_path))
     assert proc.returncode == 1
     # the three interpolations flagged; the plain-variable label is not
     assert "3 violation(s)" in proc.stderr
@@ -420,7 +415,7 @@ print("DRIVER_OK " + json.dumps(
 """
 
 
-def test_fleet_trace_merges_into_connected_tree(tmp_path):
+def test_fleet_trace_merges_into_connected_tree(tmp_path, run_python):
     """The ISSUE acceptance path: predict + generate through a 2-worker
     fleet over the file queue backend produce, after `zoo-trace` merge,
     a single timeline spanning >=3 processes where each request's span
@@ -435,12 +430,7 @@ def test_fleet_trace_merges_into_connected_tree(tmp_path):
         stream_dir=os.path.join(workdir, "stream"), trace_dir=trace_dir))
     driver = tmp_path / "driver.py"
     driver.write_text(_DRIVER)
-    env = {k: v for k, v in os.environ.items() if not k.startswith("ZOO_")}
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, str(driver), workdir],
-                          capture_output=True, text=True, timeout=480,
-                          env=env, cwd=REPO)
+    proc = run_python(str(driver), workdir)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("DRIVER_OK ")]

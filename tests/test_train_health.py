@@ -10,7 +10,6 @@ CLI view, and the bench-history regression reporter
 import glob
 import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -316,27 +315,23 @@ def _history(tmp_path, rows):
     return str(path)
 
 
-def test_bench_compare_flags_regressed_leg(tmp_path):
+def test_bench_compare_flags_regressed_leg(tmp_path, run_python):
     hist = _history(tmp_path, [
         {"ts": 1, "iso_ts": "a", "gates_failed": [],
          "metrics": {"ncf_steps_per_sec": 100.0, "serving_p99_ms": 20.0}},
         {"ts": 2, "iso_ts": "b", "gates_failed": [],
          "metrics": {"ncf_steps_per_sec": 50.0, "serving_p99_ms": 19.0}},
     ])
-    proc = subprocess.run([sys.executable, BENCH_COMPARE,
-                           "--history", hist], capture_output=True,
-                          text=True, timeout=60)
+    proc = run_python(BENCH_COMPARE, "--history", hist)
     assert proc.returncode == 0, proc.stderr
     assert "REGRESSED" in proc.stdout
     assert "ncf_steps_per_sec" in proc.stdout
     # --strict turns the flag into a nonzero exit for CI
-    proc = subprocess.run([sys.executable, BENCH_COMPARE,
-                           "--history", hist, "--strict"],
-                          capture_output=True, text=True, timeout=60)
+    proc = run_python(BENCH_COMPARE, "--history", hist, "--strict")
     assert proc.returncode == 1
 
 
-def test_bench_compare_clean_and_baseline(tmp_path):
+def test_bench_compare_clean_and_baseline(tmp_path, run_python):
     hist = _history(tmp_path, [
         {"ts": 2, "iso_ts": "b", "gates_failed": [],
          "metrics": {"ncf_steps_per_sec": 99.0, "serving_p99_ms": 20.5}},
@@ -346,10 +341,8 @@ def test_bench_compare_clean_and_baseline(tmp_path):
     snap.write_text(json.dumps({"ncf_steps_per_sec": 100.0,
                                 "serving_p99_ms": 20.0,
                                 "bench_gates_failed": []}))
-    proc = subprocess.run([sys.executable, BENCH_COMPARE,
-                           "--history", hist, "--baseline", str(snap),
-                           "--strict"],
-                          capture_output=True, text=True, timeout=60)
+    proc = run_python(BENCH_COMPARE, "--history", hist, "--baseline",
+                      str(snap), "--strict")
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "no regressions" in proc.stdout
 

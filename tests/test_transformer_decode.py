@@ -16,13 +16,18 @@ def layer_and_params():
                              seq_len=16, intermediate_size=16,
                              hidden_p_drop=0.0, attn_p_drop=0.0,
                              bidirectional=False)
-    params = layer.build(jax.random.PRNGKey(0), (None, 16))
+    params = jax.jit(lambda key: layer.build(key, (None, 16)))(
+        jax.random.PRNGKey(0))
     return layer, params
 
 
 def _full_logits(layer, params, toks):
-    seq, _ = layer.call(params, toks, training=False)
-    return layer.lm_logits(params, seq[:, -1])
+    # under jit (one compile per prefix length) instead of a dispatch
+    # per op: the reference is the same full forward
+    def full(params, toks):
+        seq, _ = layer.call(params, toks, training=False)
+        return layer.lm_logits(params, seq[:, -1])
+    return jax.jit(full)(params, toks)
 
 
 def test_prefill_and_decode_match_full_forward(layer_and_params):
@@ -35,12 +40,13 @@ def test_prefill_and_decode_match_full_forward(layer_and_params):
     tokens = jnp.asarray(rng.integers(1, 30, (B, Lp + NEW)))
 
     st = layer.init_decode_state(B, 16)
-    lg, st = layer.prefill(params, tokens[:, :Lp],
-                           jnp.full((B,), Lp, jnp.int32), st)
+    lg, st = jax.jit(layer.prefill)(params, tokens[:, :Lp],
+                                    jnp.full((B,), Lp, jnp.int32), st)
     assert float(jnp.abs(
         lg - _full_logits(layer, params, tokens[:, :Lp])).max()) < 1e-4
+    decode_step = jax.jit(layer.decode_step)
     for t in range(NEW):
-        lg, st = layer.decode_step(params, st, tokens[:, Lp + t])
+        lg, st = decode_step(params, st, tokens[:, Lp + t])
         ref = _full_logits(layer, params, tokens[:, :Lp + t + 1])
         assert float(jnp.abs(lg - ref).max()) < 1e-4
     assert st.lengths.tolist() == [Lp + NEW, Lp + NEW]
@@ -56,7 +62,7 @@ def test_prefill_ragged_prompts(layer_and_params):
     padded = tokens.at[0, 3:].set(0)
 
     st = layer.init_decode_state(2, 16)
-    lg, st = layer.prefill(params, padded, lens, st)
+    lg, st = jax.jit(layer.prefill)(params, padded, lens, st)
     for b, n in enumerate(lens.tolist()):
         ref = _full_logits(layer, params, tokens[b:b + 1, :n])
         assert float(jnp.abs(lg[b] - ref[0]).max()) < 1e-4
