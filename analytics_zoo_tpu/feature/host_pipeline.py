@@ -116,7 +116,15 @@ class ParallelTransformIterator:
         self._pool.shutdown(wait=False)
         base_close = getattr(self._base, "close", None)
         if base_close is not None:
-            base_close()
+            try:
+                base_close()
+            except ValueError:
+                # "generator already executing": the thread that feeds
+                # this stage is inside the base generator right now (a
+                # close from another thread, as shutdown_all_pipelines
+                # makes it). This stage is closed; the generator sees no
+                # further pull and goes with it.
+                pass
 
 
 DEFAULT_SLOT_BYTES = 8 << 20    # ZOO_TPU_INFEED_SLOT_BYTES

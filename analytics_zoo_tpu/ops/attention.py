@@ -456,11 +456,13 @@ def _bias_specs_3d(num_heads, block_k):
                         lambda b, i, j, h=num_heads: (b // h, 0, j))
 
 
-def _resolve_blocks(lq, lk, block_q, block_k):
+def _resolve_blocks(lq, lk, block_q, block_k, d=None):
     """Pick MXU-friendly block sizes: the largest of 512/256/128 dividing
     the sequence length (bigger tiles amortize Mosaic per-iteration
     overhead and fill the MXU). ``ZOO_TPU_ATTN_BLOCK_Q/K`` override for
-    tuning sweeps."""
+    tuning sweeps. Heads wider than 128 (``d``) take key blocks of at most
+    512: the backward kernels hold two (block_k, d) float32 accumulators
+    beside the score tiles."""
     def pick(env, asked, n, cands):
         # env/explicit choices must still divide the sequence length: the
         # non-causal kernel has no partial-block bounds mask, so a
@@ -484,20 +486,31 @@ def _resolve_blocks(lq, lk, block_q, block_k):
     # block_q 512, block_k 1024 once L allows it (ATTN_TUNE.jsonl, a
     # 2026-07 sweep): the (block_q, block_k) f32 score tile plus the
     # double-buffered q/k/v blocks stay inside the 16 MB scoped VMEM
+    wide = d is not None and d > 128
     return (pick("ZOO_TPU_ATTN_BLOCK_Q", block_q, lq, (512, 256, 128)),
-            pick("ZOO_TPU_ATTN_BLOCK_K", block_k, lk, (1024, 512, 256,
-                                                       128)))
+            pick("ZOO_TPU_ATTN_BLOCK_K", block_k, lk,
+                 (512, 256, 128) if wide else (1024, 512, 256, 128)))
+
+
+def _kv_spec(block_k, d, group):
+    """Key/value block of grid step (b, i, j) for a grid over query heads:
+    ``group`` consecutive query heads read one key/value head (b // group
+    is batch * kv_heads + kv_head, because heads = group * kv_heads)."""
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec((1, block_k, d),
+                        lambda b, i, j, g=group: (b // g, j, 0))
 
 
 def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                   block_q=None, block_k=None):
-    """Returns (o, lse) with o: (BH, Lq, d), lse: (BH, Lq, 1) f32."""
+                   block_q=None, block_k=None, group=1):
+    """Returns (o, lse) with o: (BH, Lq, d), lse: (BH, Lq, 1) f32. k and v
+    hold BH / ``group`` heads."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
     lk = k.shape[1]
-    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k)
+    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     num_q = pl.cdiv(lq, block_q)
     num_k = pl.cdiv(lk, block_k)
 
@@ -518,8 +531,8 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
         grid=(bh, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            _kv_spec(block_k, d, group),
+            _kv_spec(block_k, d, group),
             _bias_specs_3d(num_heads, block_k),
         ],
         out_specs=[
@@ -604,13 +617,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, db_ref, dk_scr, dv_scr,
                           db_scr, *, sm_scale, causal, block_q, block_k,
-                          num_q_blocks, q_offset=0):
+                          num_q_blocks, q_offset=0, group=1):
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    # the innermost axis walks the query blocks of every query head that
+    # reads this key/value head: ``group`` heads, one after the other
+    step = pl.program_id(2)
+    qi = step if group == 1 else step % num_q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -649,7 +665,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
     else:
         _compute()
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(step == group * num_q_blocks - 1)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -657,14 +673,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
 
 
 def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, group=1):
     """Blockwise dq/dk/dv/dbias. Returns grads matching (q, k, v, kbias)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
     lk = k.shape[1]
-    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k)
+    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     num_q = pl.cdiv(lq, block_q)
     num_k = pl.cdiv(lk, block_k)
 
@@ -677,7 +693,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
     qkv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    qkv_spec_k = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    qkv_spec_k = _kv_spec(block_k, d, group)
     row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
     dq_call = pl.pallas_call(
@@ -703,19 +719,27 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
 
     # dk/dv/dbias: grid transposed — k blocks parallel, q blocks innermost
     # (accumulation axis).
+    # (with grouped heads the grid runs over the key/value heads, and its
+    # innermost axis over ``group`` query heads' blocks in turn)
     kv_spec_k = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    kv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
+    if group == 1:
+        kv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+        row_spec = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
+    else:
+        q_at = lambda b, j, i, g=group, n=num_q: (b * g + i // n, i % n, 0)
+        kv_spec_q = pl.BlockSpec((1, block_q, d), q_at)
+        row_spec = pl.BlockSpec((1, block_q, 1), q_at)
+    kv_heads = num_heads // group
     dkv_call = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, num_q_blocks=num_q,
-            q_offset=lk - lq),
+            q_offset=lk - lq, group=group),
         name="zoo_flash_bwd_dkv",
-        grid=(bh, num_k, num_q),
+        grid=(bh // group, num_k, group * num_q),
         in_specs=[kv_spec_q, kv_spec_k, kv_spec_k,
                   pl.BlockSpec((1, 1, block_k),
-                               lambda b, j, i, h=num_heads: (b // h, 0, j)),
+                               lambda b, j, i, h=kv_heads: (b // h, 0, j)),
                   kv_spec_q, row_spec, row_spec],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -723,9 +747,9 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)),
         ],
         out_shape=[
-            out_struct((bh, lk, d), k.dtype, q, k, v, do),
-            out_struct((bh, lk, d), v.dtype, q, k, v, do),
-            out_struct((bh, 1, lk), jnp.float32, q, k, v, do),
+            out_struct((bh // group, lk, d), k.dtype, q, k, v, do),
+            out_struct((bh // group, lk, d), v.dtype, q, k, v, do),
+            out_struct((bh // group, 1, lk), jnp.float32, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -742,26 +766,26 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         # bias grad: the (B, Lk) key bias broadcasts over heads and query
         # rows, so its cotangent sums ds over both — rows inside the
         # kernel, heads here.
-        dkb = db.reshape(-1, num_heads, lk).sum(axis=1).astype(kbias.dtype)
+        dkb = db.reshape(-1, kv_heads, lk).sum(axis=1).astype(kbias.dtype)
     return dq, dk, dv, dkb
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_attention_bhld(q, k, v, kbias, num_heads, causal, sm_scale,
-                          block_q=None, block_k=None):
+                          block_q=None, block_k=None, group=1):
     return _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                          block_q, block_k)[0]
+                          block_q, block_k, group)[0]
 
 
 def _flash_fwd_rule(q, k, v, kbias, num_heads, causal, sm_scale,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, group=1):
     o, lse = _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                            block_q, block_k)
+                            block_q, block_k, group)
     return o, (q, k, v, kbias, o, lse)
 
 
-def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, res,
-                    do):
+def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, group,
+                    res, do):
     """Backward via the dedicated Pallas kernels (O(L) memory, two-pass
     lse recompute) under the default remat policy; the ``full`` /
     ``full-residual`` policy (or the legacy ``ZOO_TPU_FLASH_BWD=xla``
@@ -772,8 +796,8 @@ def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, res,
     if _flash_remat_policy() == "full":
         def ref(q, k, v, kb):
             qf = q[:, None]
-            kf = k[:, None]
-            vf = v[:, None]
+            kf = jnp.repeat(k, group, axis=0)[:, None]
+            vf = jnp.repeat(v, group, axis=0)[:, None]
             # kb: (B, Lk) -> per-(batch*head) rows -> (BH, 1, 1, Lk)
             kbf = jnp.repeat(kb, num_heads, axis=0)[:, None, None, :]
             return attention_reference(qf, kf, vf, bias=kbf, causal=causal,
@@ -781,7 +805,7 @@ def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, res,
 
         return jax.vjp(ref, q, k, v, kbias)[1](do)
     return _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal,
-                           sm_scale, block_q, block_k)
+                           sm_scale, block_q, block_k, group)
 
 
 _flash_attention_bhld.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -860,24 +884,62 @@ def mosaic_partition_ok() -> bool:
     return ok
 
 
-def _route_eligible(on_tpu, kb, lq, lk, d, causal) -> bool:
-    """Static routing by shape and context — the whole decision. A shape
+# From this query length on, a shape the kernels cannot take is an error
+# on the chip and no longer a quiet change of route: the blockwise scan is
+# many times slower there, and ``ZOO_TPU_ATTN_FALLBACK=reference`` holds
+# (Lq, Lk) probabilities for every head.
+KERNEL_REQUIRED_SEQ = 8192
+
+
+def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
+                    kv_heads=1) -> bool:
+    """Static routing by shape and context: the whole decision. A shape
     these rules send to the kernel compiles it inside the caller's jit;
     if Mosaic refuses, the compiler's error surfaces there (no probe, no
-    reroute). d=64 (the common head dim) is allowed: Mosaic pads the lane
-    dim. causal requires lq <= lk: the kernels mask bottom-right aligned
+    reroute).
+
+    What the kernels take: a head size that is a multiple of 64 (64 and
+    256 run in the benchmark's cells; Mosaic pads the lane dim at 64, and
+    above 128 the key blocks are capped at 512 rows); query and key
+    lengths that are multiples of 128, no shorter than 128; no bias or a
+    key-padding bias; ``heads`` query heads over ``kv_heads`` key/value
+    heads where the first is a whole multiple of the second (1, as in
+    BERT, or grouped: 8 query heads a key/value head in the gated
+    attention block), consecutive query heads sharing one key/value
+    head. causal requires lq <= lk: the kernels mask bottom-right aligned
     (offset = lk - lq, matching the reference), but lq > lk would leave
-    the leading query rows fully masked — their softmax degenerates to
-    the l_safe epsilon — so those shapes stay on the blockwise path,
-    which zeroes masked rows explicitly. ``ZOO_TPU_FORCE_PALLAS=1`` lifts
-    the KERNEL_MIN_SEQ and partitioning gates; ``ZOO_TPU_DISABLE_PALLAS=1``
-    routes everything to XLA."""
+    the leading query rows fully masked (their softmax degenerates to the
+    l_safe epsilon), so those shapes stay on the blockwise path, which
+    zeroes masked rows explicitly. ``ZOO_TPU_FORCE_PALLAS=1`` lifts the
+    KERNEL_MIN_SEQ and partitioning gates; ``ZOO_TPU_DISABLE_PALLAS=1``
+    routes everything to XLA.
+
+    On a TPU backend a shape refused at ``lq >= KERNEL_REQUIRED_SEQ`` (and
+    not by ``ZOO_TPU_DISABLE_PALLAS``) raises, naming each rule it
+    broke."""
     if os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") == "1":
         return False
-    eligible = (on_tpu and kb is not None and lq >= 128 and lk >= 128 and
-                lq % 128 == 0 and lk % 128 == 0 and
-                d % 64 == 0 and (not causal or lq <= lk) and
-                mosaic_partition_ok())
+    broken = [why for ok, why in (
+        (on_tpu, "no TPU backend (or interpret mode)"),
+        (kb is not None, "the bias is neither absent nor a key-padding "
+                         "bias of (B|1, 1, 1, Lk)"),
+        (lq >= 128 and lk >= 128 and lq % 128 == 0 and lk % 128 == 0,
+         f"lengths {lq} x {lk} are not multiples of 128 from 128 up"),
+        (d % 64 == 0, f"head size {d} is not a multiple of 64"),
+        (kv_heads >= 1 and heads % max(kv_heads, 1) == 0,
+         f"{heads} query heads are not a whole multiple of {kv_heads} "
+         f"key/value heads"),
+        (not causal or lq <= lk, f"causal with lq {lq} > lk {lk}"),
+    ) if not ok]
+    if not broken and not mosaic_partition_ok():
+        broken.append("a multi-device jit outside a fully-manual shard_map "
+                      "(Mosaic calls cannot be partitioned)")
+    if broken and lq >= KERNEL_REQUIRED_SEQ and \
+            jax.default_backend() == "tpu":
+        raise ValueError(
+            f"attention at query length {lq} has no kernel route: "
+            + "; ".join(broken))
+    eligible = not broken
     if os.environ.get("ZOO_TPU_FORCE_PALLAS", "0") != "1" and \
             lq < KERNEL_MIN_SEQ:
         eligible = False
@@ -903,7 +965,8 @@ def flash_attention_blhd(q, k, v, bias=None, causal=False, sm_scale=None,
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
                     block_q=None, block_k=None, q_offset=None):
-    """q,k,v: (B, H, L, D) -> (B, H, L, D).
+    """q: (B, H, L, D); k, v: (B, Hkv, L, D) with H a whole multiple of
+    Hkv (consecutive query heads share a key/value head) -> (B, H, L, D).
 
     Sequences of L >= KERNEL_MIN_SEQ route to the Pallas kernel on TPU
     (or interpreter mode when ``ZOO_TPU_PALLAS_INTERPRET=1`` on CPU)
@@ -923,16 +986,22 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     on_tpu = jax.default_backend() == "tpu" or _interpret_mode()
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    lk, hkv = k.shape[2], k.shape[1]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key/value heads")
+    group = h // hkv
     kb = _as_key_bias(bias, b, lk) if on_tpu else None
     # a non-default q_offset is the chunked-prefill rectangle; the Pallas
     # wrappers hardcode the bottom-right alignment, so those shapes take
     # the blockwise route (which threads the offset explicitly)
     default_off = q_offset is None or int(q_offset) == lk - lq
     use_kernel = default_off and _route_eligible(on_tpu, kb, lq, lk, d,
-                                                 causal)
-    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k)
+                                                 causal, h, hkv)
+    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     if not use_kernel:
+        if group > 1:
+            # the XLA routes know one key/value head a query head
+            k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
         if os.environ.get("ZOO_TPU_ATTN_FALLBACK", "blockwise") \
                 != "reference":
             # deliberately NOT forwarding the kernel block sizes: they may
@@ -961,8 +1030,8 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         return jax.checkpoint(lambda q, k, v, b: ref(q, k, v, bias=b))(
             q, k, v, bias)
     qf = q.reshape(b * h, lq, d)
-    kf = k.reshape(b * h, lk, d)
-    vf = v.reshape(b * h, lk, d)
+    kf = k.reshape(b * hkv, lk, d)
+    vf = v.reshape(b * hkv, lk, d)
     o = _flash_attention_bhld(qf, kf, vf, kb, h, causal, sm_scale,
-                              block_q, block_k)
+                              block_q, block_k, group)
     return o.reshape(b, h, lq, d)

@@ -1,0 +1,402 @@
+"""Decoder blocks of the kind today's open hybrid models use: a token
+mixer chosen per block from a layer pattern (Gated DeltaNet linear
+attention, or gated softmax attention with grouped query heads and
+partial rotary positions), zero-centred RMSNorm, and a dropless expert
+layer that is told which experts it holds.
+
+Rebuild-scope new work (the reference framework has none of these). The
+layers are the usual stateless descriptions, so each can stand alone in a
+``Model``; :class:`HybridDecoder` stacks them as ``h = x + Mixer(N(x))``,
+``y = h + Experts(N(h))`` behind a token embedding and recomputes per
+block, and :class:`LMHeadLoss` closes a language model without ever
+holding the (tokens x vocabulary) logits.
+
+The expert layer follows the usual expert-parallel cut: the router keeps
+its published width and its experts per token, the chip computes its own
+experts' part of the sum, and what the absent experts would have added is
+left out. On one chip it runs without its exchange; nothing stands in for
+the absent chips.
+
+HLO scopes (docs/observability.md#names): ``zoo_gdn_conv``,
+``zoo_gdn_scan``, ``zoo_gated_attn``, ``zoo_moe_route``,
+``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_lm_loss``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from .....ops.attention import flash_attention
+from .....ops.delta_rule import (DEFAULT_CHUNK, causal_depthwise_conv,
+                                 chunk_gated_delta_rule)
+from .....ops.grouped_experts import (DEFAULT_TILE, grouped_experts,
+                                      route_tables)
+from ..engine.base import KerasLayer
+
+LINEAR, FULL = "linear_attention", "full_attention"
+# what a layer with routing reports each step; the trainer sums the
+# ``_total`` names over a dispatch's steps and publishes them as counters
+# (a gauge keeps its last value): pipeline/engine.py ``_collect_step_stats``
+MOE_STATS = ("zoo_moe_assignments_total", "zoo_moe_assignments_held_total",
+             "zoo_moe_dropped_total", "zoo_moe_held_load_max_over_mean")
+
+
+def _normal(rng, shape, std=0.02):
+    return std * jax.random.normal(rng, shape, jnp.float32)
+
+
+def rms_norm(x, w, eps):
+    """Zero-centred RMSNorm ``x / rms(x) * (1 + w)``, computed in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def partial_rotary(x, rot: int, theta: float):
+    """Rotate-half rotary positions on the first ``rot`` of each head's
+    dimensions of (B, L, heads, d); position t is row t."""
+    length = x.shape[1]
+    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    xr = x[..., :rot].astype(jnp.float32)
+    half = jnp.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]], -1)
+    return jnp.concatenate([(xr * cos + half * sin).astype(x.dtype),
+                            x[..., rot:]], -1)
+
+
+class GatedAttention(KerasLayer):
+    """Causal softmax attention with ``n_head`` query heads over
+    ``n_kv_head`` key/value heads, a zero-centred RMSNorm on each query and
+    key head, rotary positions on ``rotary_dim`` of each head, and a
+    sigmoid gate on the output taken from the query projection.
+    (B, L, H) -> (B, L, H); no bias anywhere."""
+
+    def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
+                 rotary_dim: int, rope_theta: float = 1e7, eps: float = 1e-6,
+                 input_shape=None, name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        if n_head % n_kv_head:
+            raise ValueError(f"{n_head} query heads over {n_kv_head}")
+        self.n_head, self.n_kv_head, self.head_dim = n_head, n_kv_head, \
+            head_dim
+        self.rotary_dim, self.rope_theta, self.eps = rotary_dim, rope_theta, \
+            eps
+
+    def build(self, rng, input_shape):
+        h = int(input_shape[-1])
+        qd, kvd = self.n_head * self.head_dim, self.n_kv_head * self.head_dim
+        r = jax.random.split(rng, 4)
+        return {"w_q": _normal(r[0], (h, 2 * qd)),
+                "w_k": _normal(r[1], (h, kvd)),
+                "w_v": _normal(r[2], (h, kvd)), "w_o": _normal(r[3], (qd, h)),
+                "q_norm": jnp.zeros((self.head_dim,)),
+                "k_norm": jnp.zeros((self.head_dim,))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        b, l, _ = x.shape
+        n, nkv, d = self.n_head, self.n_kv_head, self.head_dim
+        with jax.named_scope("zoo_gated_attn"):
+            qg = (x @ params["w_q"]).reshape(b, l, n, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:].reshape(b, l, n * d)
+            k = (x @ params["w_k"]).reshape(b, l, nkv, d)
+            v = (x @ params["w_v"]).reshape(b, l, nkv, d)
+            q = partial_rotary(rms_norm(q, params["q_norm"], self.eps),
+                               self.rotary_dim, self.rope_theta)
+            k = partial_rotary(rms_norm(k, params["k_norm"], self.eps),
+                               self.rotary_dim, self.rope_theta)
+            tr = lambda t: t.transpose(0, 2, 1, 3)
+            o = tr(flash_attention(tr(q), tr(k), tr(v), causal=True,
+                                   sm_scale=1.0 / math.sqrt(d)))
+            o = o.reshape(b, l, n * d) * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(x.dtype)
+            return o @ params["w_o"]
+
+
+class GatedDeltaNet(KerasLayer):
+    """Gated DeltaNet token mixer: fused projections to query, key, value
+    and output gate (columns ``[q | k | v | z]``) and to the write strength
+    and decay (``[b | a]``); a short causal depthwise convolution and SiLU
+    on ``[q | k | v]``; l2-normalised query and key heads, repeated to the
+    value heads; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
+    dt_bias)`` in float32; the delta rule in chunks
+    (``ops/delta_rule.py``); a per-head RMSNorm gated by ``SiLU(z)``; the
+    output projection. (B, L, H) -> (B, L, H)."""
+
+    def __init__(self, n_key_head: int, n_value_head: int, key_dim: int,
+                 value_dim: int, conv_width: int = 4, eps: float = 1e-6,
+                 chunk_size: int = DEFAULT_CHUNK, input_shape=None,
+                 name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        if n_value_head % n_key_head:
+            raise ValueError(f"{n_value_head} value heads over {n_key_head}")
+        self.nk, self.nv, self.dk, self.dv = n_key_head, n_value_head, \
+            key_dim, value_dim
+        self.conv_width, self.eps, self.chunk_size = conv_width, eps, \
+            chunk_size
+
+    def build(self, rng, input_shape):
+        h = int(input_shape[-1])
+        kd, vd = self.nk * self.dk, self.nv * self.dv
+        r = jax.random.split(rng, 5)
+        return {"w_qkvz": _normal(r[0], (h, 2 * kd + 2 * vd)),
+                "w_ba": _normal(r[1], (h, 2 * self.nv)),
+                "conv_w": _normal(r[2], (2 * kd + vd, self.conv_width)),
+                "A_log": jnp.log(jax.random.uniform(
+                    r[3], (self.nv,), jnp.float32, 1e-3, 16.0)),
+                "dt_bias": jnp.ones((self.nv,)),
+                "norm_w": jnp.ones((self.dv,)),
+                "w_out": _normal(r[4], (vd, h))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        b, l, _ = x.shape
+        nk, nv, dk, dv = self.nk, self.nv, self.dk, self.dv
+        kd, vd = nk * dk, nv * dv
+        f32 = jnp.float32
+        qkvz = x @ params["w_qkvz"]
+        mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
+        ba = (x @ params["w_ba"]).astype(f32)
+        beta = jax.nn.sigmoid(ba[..., :nv])
+        g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., nv:] + params["dt_bias"].astype(f32))
+        mixed = jax.nn.silu(causal_depthwise_conv(mixed, params["conv_w"]))
+        q = mixed[..., :kd].reshape(b, l, nk, dk).astype(f32)
+        k = mixed[..., kd:2 * kd].reshape(b, l, nk, dk).astype(f32)
+        v = mixed[..., 2 * kd:].reshape(b, l, nv, dv)
+        l2 = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        rep = lambda t: jnp.repeat(t.astype(x.dtype), nv // nk, axis=2)
+        o = chunk_gated_delta_rule(rep(l2(q) / math.sqrt(dk)), rep(l2(k)), v,
+                                   g, beta, self.chunk_size).astype(f32)
+        o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + self.eps)
+        y = (o * jax.nn.silu(z.reshape(b, l, nv, dv).astype(f32))).astype(
+            x.dtype)
+        return y.reshape(b, l, vd) @ params["w_out"]
+
+
+class HeldExpertsMoE(KerasLayer):
+    """Softmax-routed, dropless mixture of gated-MLP experts of which this
+    layer holds ``n_held`` (experts ``first_expert .. first_expert + n_held
+    - 1`` of the router's ``n_routed``), plus one shared expert behind a
+    sigmoid gate (``shared_size`` 0: none).
+
+    The router, the ``top_k`` and the normalisation are over all
+    ``n_routed`` outputs; the layer computes the part of the routed sum
+    its own experts give, for every assignment that lands on them: there
+    is no capacity and nothing is dropped (``ops/grouped_experts.py``).
+    With ``n_held == n_routed`` it is the whole layer. Stateful only in
+    that it reports its routing each step (``MOE_STATS``).
+    (..., H) -> (..., H)."""
+
+    has_state = True
+
+    def __init__(self, n_routed: int, n_held: int, intermediate_size: int,
+                 top_k: int, shared_size: int = 0, first_expert: int = 0,
+                 norm_topk: bool = True, tile: int = DEFAULT_TILE,
+                 input_shape=None, name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        if not 0 <= first_expert <= first_expert + n_held <= n_routed:
+            raise ValueError(f"experts {first_expert}..{first_expert + n_held}"
+                             f" of {n_routed}")
+        if not 1 <= top_k <= n_routed:
+            raise ValueError(f"top_k {top_k} of {n_routed} experts")
+        self.n_routed, self.n_held, self.first_expert = n_routed, n_held, \
+            first_expert
+        self.intermediate_size, self.shared_size = intermediate_size, \
+            shared_size
+        self.top_k, self.norm_topk, self.tile = top_k, norm_topk, tile
+
+    def build(self, rng, input_shape):
+        h = int(input_shape[-1])
+        e, f, fs = self.n_held, self.intermediate_size, self.shared_size
+        r = jax.random.split(rng, 8)
+        params = {"router": _normal(r[0], (h, self.n_routed)),
+                  "w_gate": _normal(r[1], (e, h, f)),
+                  "w_up": _normal(r[2], (e, h, f)),
+                  "w_down": _normal(r[3], (e, f, h))}
+        if fs:
+            params.update(s_gate=_normal(r[4], (h, fs)),
+                          s_up=_normal(r[5], (h, fs)),
+                          s_down=_normal(r[6], (fs, h)),
+                          s_gate_w=_normal(r[7], (h,)))
+        self._annotate(router=("embed", None),
+                       w_gate=("expert", "embed", "mlp"),
+                       w_up=("expert", "embed", "mlp"),
+                       w_down=("expert", "mlp", "embed"))
+        return params
+
+    def init_state(self, input_shape):
+        return {"step_stats": {k: jnp.zeros((), jnp.float32)
+                               for k in MOE_STATS}}
+
+    def call(self, params, inputs, training: bool = False, state=None,
+             **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        flat = x.reshape(-1, x.shape[-1])
+        f32 = jnp.float32
+        with jax.named_scope("zoo_moe_route"):
+            logits = jnp.dot(flat, params["router"],
+                             preferred_element_type=f32)
+            top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, -1),
+                                         self.top_k)
+            if self.norm_topk:
+                top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+            tables = route_tables(top_i, self.first_expert, self.n_held,
+                                  self.tile)
+            held = jnp.sum(tables.counts).astype(f32)
+            counts = tables.counts.astype(f32)
+            stats = dict(zip(MOE_STATS, (
+                jnp.asarray(float(top_i.size), f32), held,
+                held - jnp.sum(tables.tile_rows).astype(f32),
+                jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9))))
+        out = grouped_experts(flat, params["w_gate"], params["w_up"],
+                              params["w_down"], top_w, tables, self.tile)
+        if self.shared_size:
+            with jax.named_scope("zoo_moe_shared"):
+                a = jnp.dot(flat, params["s_gate"],
+                            preferred_element_type=f32)
+                u = jnp.dot(flat, params["s_up"], preferred_element_type=f32)
+                y = (jax.nn.silu(a) * u).astype(x.dtype) @ params["s_down"]
+                gate = jax.nn.sigmoid(jnp.dot(
+                    flat, params["s_gate_w"], preferred_element_type=f32))
+                out = out + (y * gate[:, None].astype(x.dtype))
+        return out.reshape(x.shape), {"step_stats": stats}
+
+
+class HybridDecoder(KerasLayer):
+    """Token ids (B, L) -> hidden states (B, L, H): an embedding, then
+    ``layer_types`` blocks (each ``"linear_attention"`` or
+    ``"full_attention"``) of ``h = x + Mixer(N(x))``, ``y = h +
+    Experts(N(h))``, then a final norm. ``mixers`` and ``moe`` hold the
+    keyword arguments of :class:`GatedDeltaNet`, :class:`GatedAttention`
+    (by layer type) and :class:`HeldExpertsMoE`. Each block is recomputed
+    in the backward pass, so a step keeps one block's activations and every
+    block's input; ``remat_rows`` sequences of the batch go through a block
+    at a time (None: all at once), which bounds those activations by the
+    rows and not by the batch."""
+
+    has_state = True
+
+    def __init__(self, vocab: int, hidden_size: int,
+                 layer_types: Sequence[str], mixers: dict, moe: dict,
+                 eps: float = 1e-6, remat_rows: Optional[int] = None,
+                 input_shape=None,
+                 name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        unknown = set(layer_types) - {LINEAR, FULL}
+        if unknown:
+            raise ValueError(f"unknown layer types {sorted(unknown)}")
+        self.vocab, self.hidden_size, self.eps = vocab, hidden_size, eps
+        self.remat_rows = remat_rows
+        self.layer_types = tuple(layer_types)
+        self.blocks = [
+            ((GatedAttention if kind == FULL else GatedDeltaNet)(
+                eps=eps, name=f"{self.name}_mixer{i}", **mixers[kind]),
+             HeldExpertsMoE(name=f"{self.name}_moe{i}", **moe))
+            for i, kind in enumerate(self.layer_types)]
+
+    def compute_output_shape(self, input_shape):
+        return tuple(input_shape) + (self.hidden_size,)
+
+    def build(self, rng, input_shape):
+        h = self.hidden_size
+        shape = (None, None, h)
+        keys = jax.random.split(rng, 2 * len(self.blocks) + 1)
+        params = {"embed": _normal(keys[-1], (self.vocab, h)),
+                  "final_norm": jnp.zeros((h,))}
+        for i, (mixer, moe) in enumerate(self.blocks):
+            params[f"block{i}"] = {
+                "norm1": jnp.zeros((h,)),
+                "mixer": mixer.build(keys[2 * i], shape),
+                "norm2": jnp.zeros((h,)),
+                "moe": moe.build(keys[2 * i + 1], shape)}
+        return params
+
+    def init_state(self, input_shape):
+        return {f"block{i}": moe.init_state(None)
+                for i, (_, moe) in enumerate(self.blocks)}
+
+    def _block(self, i, p, x):
+        mixer, moe = self.blocks[i]
+        h = x + mixer.call(p["mixer"], rms_norm(x, p["norm1"], self.eps))
+        y, state = moe.call(p["moe"], rms_norm(h, p["norm2"], self.eps))
+        return h + y, state
+
+    def call(self, params, inputs, training: bool = False, state=None,
+             **kwargs):
+        tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        x = params["embed"][tokens.astype(jnp.int32)]
+        b = x.shape[0]
+        rows = self.remat_rows or b
+        if b % rows:
+            raise ValueError(f"remat_rows {rows} does not divide the batch "
+                             f"{b}")
+        new_state = {}
+        for i in range(len(self.blocks)):
+            p = params[f"block{i}"]
+            fn = lambda x, i=i, p=p: self._block(i, p, x)
+            if rows == b:
+                x, state = jax.checkpoint(fn)(x)
+            else:
+                # in turn, so that the compiler cannot overlap two
+                # recomputations
+                x, state = jax.lax.map(
+                    jax.checkpoint(fn), x.reshape((b // rows, rows) +
+                                                  x.shape[1:]))
+                x = x.reshape((b,) + x.shape[2:])
+                state = {"step_stats": {
+                    k: v.sum() if k.endswith("_total") else v.max()
+                    for k, v in state["step_stats"].items()}}
+            new_state[f"block{i}"] = state
+        return rms_norm(x, params["final_norm"], self.eps), new_state
+
+
+class LMHeadLoss(KerasLayer):
+    """[hidden (B, L, H), targets (B, L)] -> (B,): each sequence's mean
+    cross-entropy of its targets under ``softmax(hidden @ head)``, the
+    logits in float32. Computed ``block_tokens`` positions at a time and
+    recomputed in the backward pass, so no (tokens x vocabulary) array
+    outlives a block. Train it with the ``identity`` objective: the mean
+    over the batch is then the mean next-token loss. HLO scope
+    ``zoo_lm_loss``."""
+
+    def __init__(self, vocab: int, block_tokens: int = 2048,
+                 input_shape=None, name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        self.vocab, self.block_tokens = vocab, block_tokens
+
+    def compute_output_shape(self, input_shape):
+        return (input_shape[0][0],)
+
+    def build(self, rng, input_shape):
+        return {"head": _normal(rng, (int(input_shape[0][-1]), self.vocab))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        hidden, targets = inputs
+        b, l, h = hidden.shape
+        blk = math.gcd(l, self.block_tokens)
+        head = params["head"]
+
+        @jax.checkpoint
+        def one(carry, xs):
+            hid, tgt = xs                            # (B, blk, H), (B, blk)
+            logits = jnp.dot(hid, head, preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
+            return carry + jnp.sum(
+                jax.nn.logsumexp(logits, -1) - picked, -1), None
+
+        with jax.named_scope("zoo_lm_loss"):
+            hid = hidden.reshape(b, l // blk, blk, h).swapaxes(0, 1)
+            tgt = targets.astype(jnp.int32).reshape(b, l // blk,
+                                                    blk).swapaxes(0, 1)
+            total, _ = jax.lax.scan(one, jnp.zeros((b,), jnp.float32),
+                                    (hid, tgt))
+            return total / l

@@ -104,6 +104,46 @@ def _cast_tree(tree, dtype):
     return jax.tree.map(cast, tree)
 
 
+def _collect_step_stats(net_state) -> Dict[str, Any]:
+    """What the layers reported of this step: every ``"step_stats"`` dict
+    in the state tree, merged by name (names ending ``_total`` add up
+    across layers, any other keeps the largest)."""
+    out: Dict[str, Any] = {}
+
+    def walk(node):
+        if not isinstance(node, dict):
+            return
+        for key, value in node.items():
+            if key == "step_stats" and isinstance(value, dict):
+                for name, v in value.items():
+                    if name not in out:
+                        out[name] = v
+                    elif name.endswith("_total"):
+                        out[name] = out[name] + v
+                    else:
+                        out[name] = jnp.maximum(out[name], v)
+            else:
+                walk(value)
+
+    walk(net_state)
+    return out
+
+
+def _publish_step_stats(pending: list) -> None:
+    """Fetch the dispatches' step statistics (one transfer, at the
+    window's sync) and publish them: ``*_total`` names as counters, any
+    other as a gauge of the last value."""
+    if not pending:
+        return
+    for stats in jax.device_get(pending):
+        for name, v in stats.items():
+            if name.endswith("_total"):
+                telemetry.counter(name).inc(float(v))
+            else:
+                telemetry.gauge(name).set(float(v))
+    pending.clear()
+
+
 def _iteration_granularity(trigger: Optional[ZooTrigger],
                            record: TrainRecord) -> int:
     """Upper bound on how many steps may be fused into one dispatch before
@@ -314,8 +354,10 @@ class SPMDTrainer:
             rng = jax.random.PRNGKey(self.seed)
             params, state = self.init_fn(rng)
             self._place_state(params, state)
-            self.opt_state = self._place_opt_state(
-                self.tx.init(self.params))
+            # the initializer's own copy goes before the optimizer state
+            # comes: a model sized to fill the chip has no room for both
+            del params, state
+            self.opt_state = self._fresh_opt_state()
 
     # Explicit placement: every input of the compiled step carries the
     # mesh NamedSharding. One leaf left on a jit-default/single-device
@@ -443,10 +485,18 @@ class SPMDTrainer:
                 self.ctx.mesh)
             self._zero_opt_paths = frozenset(paths)
             return opt_state
-        sh_for = self._opt_sharding_resolver()
         flat, treedef = jax.tree_util.tree_flatten_with_path(opt_state)
+        return self._place_opt_leaves(flat, treedef, mode)
+
+    def _place_opt_leaves(self, flat, treedef, mode):
+        """Place ``flat`` ((path, leaf) pairs) leaf by leaf, emptying the
+        list as it goes: a caller that holds the leaves nowhere else never
+        has two whole optimizer states on the device."""
+        sh_for = self._opt_sharding_resolver()
         placed, shs = [], []
-        for path, leaf in flat:
+        for i in range(len(flat)):
+            path, leaf = flat[i]
+            flat[i] = None
             sh = sh_for(tuple(path))
             if mode == "gspmd" and hasattr(leaf, "shape") and \
                     getattr(leaf, "ndim", 0) >= 1:
@@ -461,6 +511,18 @@ class SPMDTrainer:
             self._zero_gspmd_shardings = jax.tree_util.tree_unflatten(
                 treedef, shs)
         return jax.tree_util.tree_unflatten(treedef, placed)
+
+    def _fresh_opt_state(self):
+        """``tx.init`` of the placed parameters, placed. The initializer's
+        tree is taken apart at once so that each of its leaves is freed
+        when its placed copy exists (Adam's moments of a model sized to
+        fill the chip do not fit twice)."""
+        mode = self._zero_mode_resolved()
+        if mode == "flat":
+            return self._place_opt_state(self.tx.init(self.params))
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            self.tx.init(self.params))
+        return self._place_opt_leaves(flat, treedef, mode)
 
     def _canonical_opt_state(self, opt_state=None):
         """Optimizer state in the canonical (param-shaped, zero=0)
@@ -481,7 +543,7 @@ class SPMDTrainer:
             return
         self._place_state(params, state, validate=False)
         if self.opt_state is None:
-            self.opt_state = self._place_opt_state(self.tx.init(self.params))
+            self.opt_state = self._fresh_opt_state()
 
     # ------------------------------------------------------------------
     # compiled steps
@@ -757,6 +819,11 @@ class SPMDTrainer:
         # it — never as an extra reduce — and the k-step scan body still
         # drops (DCEs) it.
         logs = {"loss": loss}
+        stats = _collect_step_stats(new_state)
+        if stats:
+            # what layers report of the step (an expert layer's routing):
+            # a few scalars beside the loss, fetched with it
+            logs["stats"] = stats
         if gnorm is not None and \
                 bool(getattr(self.ctx.config, "log_grad_norm", False)):
             logs["grad_norm"] = gnorm
@@ -810,12 +877,16 @@ class SPMDTrainer:
                     params, opt_state, net_state, batch, step)
                 bad = logs.get("health_bad", jnp.zeros((), jnp.bool_))
                 return (params, opt_state, net_state, step + 1), \
-                    (logs["loss"], bad)
+                    (logs["loss"], bad, logs.get("stats", {}))
 
-            (params, opt_state, net_state, _), (losses, bads) = \
+            (params, opt_state, net_state, _), (losses, bads, stats) = \
                 jax.lax.scan(body, (params, opt_state, net_state, step0),
                              batches)
             out = {"loss": losses[-1]}
+            if stats:
+                out["stats"] = {
+                    name: v.sum() if name.endswith("_total") else v[-1]
+                    for name, v in stats.items()}
             if self._health_sentinel_on():
                 # index of the FIRST bad step within this dispatch (-1 =
                 # clean): k sentinels reduce to one tiny scalar, so the
@@ -1251,6 +1322,7 @@ class SPMDTrainer:
         self._steps_ctr = telemetry.counter("zoo_train_steps_total")
         window_t0 = time.perf_counter()
         window_steps = 0
+        pending_stats: list = []     # per dispatch, still on the device
         self._last_log_step = min(self._last_log_step, self.step)
         profiler = ProfilerHook(cfg.profile_dir, cfg.profile_start_step,
                                 cfg.profile_num_steps) \
@@ -1310,6 +1382,8 @@ class SPMDTrainer:
                                        self.net_state, chunk.stacked,
                                        self.step)
                     done = k
+                    if "stats" in logs:
+                        pending_stats.append(logs["stats"])
                     if self._health is not None and \
                             "health_first_bad" in logs:
                         fb = int(np.asarray(logs["health_first_bad"]))
@@ -1336,6 +1410,8 @@ class SPMDTrainer:
                                              self.net_state, batch,
                                              self.step + done)
                         done += 1
+                        if "stats" in logs:
+                            pending_stats.append(logs["stats"])
                         if self._health is not None and bad_step is None \
                                 and "health_bad" in logs and \
                                 bool(np.asarray(logs["health_bad"])):
@@ -1365,6 +1441,7 @@ class SPMDTrainer:
                 with span("train/device_sync", step=self.step):
                     loss_v = float(np.asarray(last_loss))
                 with span("train/window_log", step=self.step):
+                    _publish_step_stats(pending_stats)
                     record.loss = loss_v
                     lr = float(self.lr_schedule(self.step))
                     now = time.perf_counter()
@@ -1461,6 +1538,7 @@ class SPMDTrainer:
         # epoch end
         if last_loss is not None:
             record.loss = float(last_loss)
+        _publish_step_stats(pending_stats)   # the dispatches since the sync
         self.epoch += 1
         self.epoch_batches = 0
         record.epoch = self.epoch

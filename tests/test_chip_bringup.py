@@ -69,6 +69,25 @@ def test_flash_attention_cross_lowers_for_tpu(on_tpu, b, h, l, d, causal):
     assert mlir.count("tpu_custom_call") == 3
 
 
+def test_grouped_wide_heads_cross_lower_at_the_hybrid_cells_shape(on_tpu):
+    """The gated-attention block of `qwen3next_pretrain_l8192`: one
+    sequence of 8,192, 16 query heads of 256 over 2 key/value heads,
+    causal, no bias; forward and both backward kernels, k and v unrepeated
+    (the kernels index the shared head themselves)."""
+    s = jax.ShapeDtypeStruct
+    q = s((1, 16, 8192, 256), jnp.bfloat16)
+    kv = s((1, 2, 8192, 256), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return (A.flash_attention(q, k, v, causal=True)
+                .astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_mlir(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert _kernel_names(mlir) == [
+        "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
+    assert "16x8192x256" in mlir and "2x8192x256" in mlir
+
+
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
     """The layer's default entry: (B, L, H, d) in, the bhld kernels
     underneath."""
