@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from analytics_zoo_tpu.ops import attention as A
+from analytics_zoo_tpu.ops import delta_rule as G
 from analytics_zoo_tpu.ops import fused_dropout_ln as D
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -43,6 +44,23 @@ def _tpu_mlir(fn, *args):
 
 def _kernel_names(mlir):
     return sorted(re.findall(r'kernel_name = "([^"]+)"', mlir))
+
+
+def _tpu_lowered(fn, *args):
+    """``fn`` lowered for the TPU with the locations the lowering gives:
+    a call's location is what the compiler keeps as its ``op_name``."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def _call_sites(lowered):
+    """The Mosaic calls of ``_tpu_lowered`` text, each as the line the
+    chip's optimized HLO would hold for it."""
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', lowered, re.M))
+    return [f'custom-call(), custom_call_target="tpu_custom_call", '
+            f'metadata={{op_name="{locs[m]}"}}' for m in re.findall(
+                r'custom_call @tpu_custom_call.*loc\((#loc\d+)\)$', lowered,
+                re.M)]
 
 
 # ---------------------------------------------------------------------------
@@ -86,6 +104,36 @@ def test_grouped_wide_heads_cross_lower_at_the_hybrid_cells_shape(on_tpu):
     assert _kernel_names(mlir) == [
         "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
     assert "16x8192x256" in mlir and "2x8192x256" in mlir
+
+
+def test_delta_rule_kernels_cross_lower_at_the_hybrid_cells_shape(on_tpu):
+    """A DeltaNet block of `qwen3next_pretrain_l8192`: one sequence of
+    8,192, 32 heads of 128 by 128, 64 chunks. Both kernels lower, their
+    blocks on the arrays where the chunk-local part leaves them, and each
+    call's name carries ``zoo_gdn_scan`` (what the benchmark's scope
+    metrics match) around its own tag."""
+    from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
+
+    s = jax.ShapeDtypeStruct
+    x = s((1, 8192, 32, 128), jnp.bfloat16)
+    gate = s((1, 8192, 32), jnp.float32)
+
+    def loss(*a):
+        return (G.chunk_gated_delta_rule(*a).astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_lowered(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        x, x, x, gate, gate)
+    assert _kernel_names(mlir) == ["zoo_gdn_scan_bwd", "zoo_gdn_scan_fwd"]
+    assert "32x1x64x128x128xbf16" in mlir and "stablehlo.while" in mlir
+    sites = _call_sites(mlir)
+    assert mosaic_kernel_counts("\n".join(sites)) == {
+        "zoo_gdn_scan_fwd": 1, "zoo_gdn_scan_bwd": 1}
+    assert all("zoo_gdn_scan" in re.findall(r"zoo_[a-z0-9_]+", site)
+               for site in sites)
+    # the scan is the other route, and only the chunk-local map loops
+    forward = _tpu_mlir(lambda *a: G.chunk_gated_delta_rule(*a),
+                        x, x, x, gate, gate)
+    assert forward.count("stablehlo.while") == 1
 
 
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):

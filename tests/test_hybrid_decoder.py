@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from analytics_zoo_tpu.ops import attention as A
+from analytics_zoo_tpu.ops import delta_rule
 from analytics_zoo_tpu.ops.delta_rule import chunk_gated_delta_rule
 from analytics_zoo_tpu.pipeline.api.keras.layers import hybrid_decoder as hd
 
@@ -137,18 +138,88 @@ def test_heads_go_through_the_chunk_local_part_in_blocks():
         jnp.eye(32), a.shape), atol=2e-5)
 
 
-def test_a_bfloat16_state_or_a_missing_decay_is_caught():
-    args = gdn_inputs()
+@pytest.mark.parametrize("route", ["scan", "kernels"])
+def test_a_bfloat16_state_or_a_missing_decay_is_caught(monkeypatch, route):
+    if route == "kernels":
+        monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+        args, chunk = gdn_inputs(l=200, dk=128, dv=128), 128
+        assert delta_rule._kernel_route(200, chunk, 128, 128)
+    else:
+        args, chunk = gdn_inputs(), 16
     theirs = ref.delta_rule_recurrence(*args, inner=8)
     low = chunk_gated_delta_rule(*(t.astype(jnp.bfloat16) for t in args[:3]),
-                                 *args[3:], 16).astype(jnp.float32)
+                                 *args[3:], chunk).astype(jnp.float32)
     assert rel(low, theirs) > 100 * FWD
     q, k, v, g, beta = args
-    assert rel(chunk_gated_delta_rule(q, k, v, 0 * g, beta, 16),
+    assert rel(chunk_gated_delta_rule(q, k, v, 0 * g, beta, chunk),
                theirs) > 1000 * FWD
     # the query's 1/sqrt(key_dim) dropped (it is 1/4 here): off by 3
-    assert rel(chunk_gated_delta_rule(4.0 * q, k, v, g, beta, 16),
+    assert rel(chunk_gated_delta_rule(4.0 * q, k, v, g, beta, chunk),
                theirs) > 2.9
+
+
+@pytest.mark.parametrize("heads,length", [(8, 300), (3, 512)])
+def test_delta_rule_kernels_are_the_recurrence(monkeypatch, heads, length):
+    """The loop over chunks as the two Pallas kernels, in interpret mode,
+    at the head sizes they take: a full block of heads with a padded tail,
+    and a head count the block does not divide with whole chunks. Forward
+    and all five gradients against one position at a time, and equal to
+    the scan route (the same ``_step``; the backward by hand against
+    JAX's) to float32 round-off."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    args = gdn_inputs(l=length, n=heads, dk=128, dv=128)
+    co = x_of(args[2].shape, 9)
+    calls = []
+    real = delta_rule._scan_call
+    monkeypatch.setattr(delta_rule, "_scan_call", lambda *a: calls.append(
+        (a[1], a[4][0])) or real(*a))
+    ours, g = out_and_grads(lambda *a: chunk_gated_delta_rule(*a), co, *args)
+    # (the output's own call, then the gradient's two)
+    assert calls == [("zoo_gdn_scan_fwd", min(heads, 4))] * 2 + [
+        ("zoo_gdn_scan_bwd", min(heads, 4))]
+    theirs, gr = out_and_grads(
+        lambda *a: ref.delta_rule_recurrence(*a, inner=8), co, *args)
+    assert float(jnp.abs(ours - theirs).max()) < FWD * float(
+        jnp.abs(theirs).max())
+    assert max(rel(a, b) for a, b in zip(g, gr)) < GRAD
+    monkeypatch.setenv("ZOO_TPU_DISABLE_PALLAS", "1")
+    scan, gs = out_and_grads(lambda *a: chunk_gated_delta_rule(*a), co, *args)
+    assert len(calls) == 3
+    assert max(rel(a, b) for a, b in zip((ours,) + g, (scan,) + gs)) < 1e-6
+
+
+def test_delta_rule_route_is_static_and_fails_loudly_on_the_chip(
+        monkeypatch):
+    """Chunk 128 and head sizes that are multiples of 128 on a TPU backend
+    (or in interpret mode) take the kernels, anything else the scan; at
+    8,192 on a TPU backend a refused shape raises, naming the rule."""
+    route = delta_rule._kernel_route
+    for name in ("ZOO_TPU_PALLAS_INTERPRET", "ZOO_TPU_DISABLE_PALLAS",
+                 "ZOO_TPU_FORCE_PALLAS"):
+        monkeypatch.delenv(name, raising=False)
+    assert not route(8192, 128, 128, 128)              # the CPU
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    assert route(8192, 128, 128, 128) and route(100, 128, 256, 128)
+    assert not route(8192, 64, 128, 128)
+    assert not route(8192, 128, 64, 128) and not route(8192, 128, 128, 16)
+    monkeypatch.setenv("ZOO_TPU_DISABLE_PALLAS", "1")
+    assert not route(8192, 128, 128, 128)
+    monkeypatch.delenv("ZOO_TPU_DISABLE_PALLAS")
+    monkeypatch.delenv("ZOO_TPU_PALLAS_INTERPRET")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: True)
+    assert route(8192, 128, 128, 128)
+    with pytest.raises(ValueError, match="chunk 64 is not 128"):
+        route(8192, 64, 128, 128)
+    with pytest.raises(ValueError, match="head sizes 64 and 128 are not"):
+        route(8192, 128, 64, 128)
+    assert not route(8191, 64, 128, 128)
+    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: False)
+    with pytest.raises(ValueError, match="multi-device jit"):
+        route(8192, 128, 128, 128)
+    assert not route(4096, 128, 128, 128)
+    monkeypatch.setenv("ZOO_TPU_DISABLE_PALLAS", "1")
+    assert not route(8192, 64, 128, 128)
 
 
 def test_gated_delta_net_layer_matches_the_reference():
