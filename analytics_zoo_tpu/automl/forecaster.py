@@ -1,7 +1,7 @@
 """Time-series forecasters searched by the AutoML engine.
 
-Capability target per BASELINE.md ("AutoML time-series forecaster
-(LSTM/TCN, Ray-on-TPU)"); the reference implementation lives on the
+Capability target: the reference's AutoML time-series forecaster
+(LSTM/TCN, Ray-on-TPU); the reference implementation lives on the
 off-tree ``automl`` branch, so these are spec-from-docs builds on the
 in-repo Keras API: an LSTM forecaster and a causal dilated-conv (TCN)
 forecaster, both ``(B, lookback, F) -> (B, horizon)``.

@@ -4,10 +4,10 @@ takes the chip, and a child that needs it then fails or hangs.
 
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` must be set
 BEFORE jax initializes its backends — too late for any code that runs
-after ``import jax``. Every place that needs a guaranteed N-device CPU
-host therefore re-execs itself into a subprocess carrying the flag
-(``ops/attn_smoke``, ``zero-smoke``, the ``multi_device_cpu`` test fixture);
-the one canonical copy of that pattern lives here.
+after ``import jax``. A place that needs a guaranteed N-device CPU host
+therefore re-execs itself into a subprocess carrying the flag (the
+``multi_device_cpu`` test fixture); the one copy of that pattern lives
+here.
 
 ``ZOO_HOSTDEV_CHILD=1`` marks the child (re-exec exactly once: a child
 whose topology still comes up short must fail loudly, not fork-bomb).
@@ -19,22 +19,11 @@ import os
 import re
 import subprocess
 import sys
-from typing import Dict, Mapping, Optional, Sequence
+from typing import Dict, Mapping, Optional
 
 CHILD_ENV = "ZOO_HOSTDEV_CHILD"
 
 _COUNT_FLAG = re.compile(r"--xla_force_host_platform_device_count=(\d+)")
-
-
-def env_cpu_devices(env: Optional[Mapping[str, str]] = None) -> int:
-    """How many CPU devices ``env`` guarantees a jax process: the forced
-    host device count when ``JAX_PLATFORMS`` is exactly ``cpu``, else 0
-    (any other setting may resolve to an accelerator)."""
-    env = os.environ if env is None else env
-    if env.get("JAX_PLATFORMS") != "cpu":
-        return 0
-    m = _COUNT_FLAG.search(env.get("XLA_FLAGS", ""))
-    return int(m.group(1)) if m else 1
 
 
 def cpu_device_env(n: int, base: Optional[Dict[str, str]] = None) \
@@ -54,22 +43,6 @@ def cpu_device_env(n: int, base: Optional[Dict[str, str]] = None) \
     env["XLA_FLAGS"] = flags.strip()
     env[CHILD_ENV] = "1"
     return env
-
-
-def reexec_module(module: str, n: int,
-                  argv: Optional[Sequence[str]] = None) -> Optional[int]:
-    """Re-exec ``python -m module argv...`` pinned to ``n`` CPU devices.
-
-    Returns ``None`` when the caller should just proceed inline — the
-    environment already pins it to ``n`` CPU devices, or it IS the
-    re-exec child (short topology in the child is then the caller's own
-    loud failure). Otherwise runs the child and returns its exit code."""
-    if os.environ.get(CHILD_ENV) == "1" or env_cpu_devices() >= n:
-        return None
-    return subprocess.run(
-        [sys.executable, "-m", module] +
-        (list(argv) if argv is not None else sys.argv[1:]),
-        env=cpu_device_env(n)).returncode
 
 
 def reexec_pytest(nodeid: str, n: int, timeout: float = 900) -> int:

@@ -79,14 +79,6 @@ class ZooConfig:
     # cpu_bound=True on a multi-core host
     # (feature.host_pipeline.resolve_infeed_backend).
     infeed_backend: str = "auto"
-    # flash-attention backward remat policy (ops/attention.py
-    # _flash_remat_policy): "" = default ("save-lse-recompute-probs" —
-    # keep only q/k/v/lse/o and recompute probabilities blockwise in the
-    # backward kernel, O(L) residual memory), "full-residual" = run the
-    # reference backward via XLA over saved activations (O(L^2) probs
-    # residual — more HBM, no recompute flops). Env hatch:
-    # ZOO_TPU_FLASH_REMAT.
-    flash_remat: str = ""
     # dispatch chunks kept already device_put onto the mesh data sharding
     # ahead of the compiled step, overlapping H2D with device compute
     device_ahead: int = 2

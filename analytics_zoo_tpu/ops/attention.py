@@ -1,101 +1,57 @@
-"""Attention ops: flash attention (Pallas/TPU) + reference jax fallback.
+"""Attention: a Pallas flash kernel, a blockwise XLA carrier, an oracle.
 
 The reference materializes full O(L^2) attention per replica inside
 ``TransformerLayer.block``/``Attention`` (keras/layers/TransformerLayer.scala,
 utils/zoo Attention) — sequence length bounded by one worker's RAM
-(SURVEY.md §5.7). Here the hot path is a Pallas flash-attention kernel:
-blockwise online-softmax so the L×L score matrix never hits HBM, wide
-MXU tiles (up to 512×1024, see ``_resolve_blocks``), bf16 MXU dots with
-f32 accumulation. ``ring`` sequence parallelism layers on top of this in
-``parallel/ring_attention.py``.
+(SURVEY.md §5.7). Here a call takes one of two routes, both O(L) in memory
+forward and backward, and which one is decided from its shape
+(:func:`_route_eligible`, through ``_route.kernel_route``):
 
-The kernel takes an optional *key bias* — an additive (B, Lk) bias broadcast
-over heads and query positions, which is exactly the shape of the BERT/
-padding-mask bias ``(1-mask)*-10000`` (self_attention.py) — so the model-zoo
-transformer path runs through the kernel, not the fallback.  Shapes the
-kernel declines (full (B,H,Lq,Lk) biases, odd dims, short/non-TPU runs)
-take :func:`attention_blockwise`, a ``lax.scan`` online-softmax fallback
-that is O(L) memory in both directions; :func:`attention_reference`
-remains as the test oracle.
+- the Pallas kernels: blockwise online softmax so the L×L score matrix
+  never reaches HBM, wide MXU tiles (up to 512×1024, :func:`_resolve_blocks`,
+  a function of the shape), bf16 MXU dots with f32 accumulation. The
+  forward kernel saves the per-row log-sum-exp; two backward kernels (dq;
+  dk, dv and the bias) rebuild each score block from it. The kernels take an
+  optional *key bias*, an additive (B, Lk) bias broadcast over heads and
+  query positions — the shape of the BERT padding-mask bias
+  ``(1-mask)*-10000`` (self_attention.py) — and grouped query heads over
+  fewer key/value heads.
+- :func:`attention_blockwise`: the same scheme as a ``lax.scan`` in plain
+  XLA, for every shape the kernels decline (full (B,H,Lq,Lk) biases, odd
+  dims, short or non-TPU runs, an explicit ``q_offset``).
+
+:func:`attention_reference` is the tests' oracle and nothing routes to it.
+``ring`` sequence parallelism layers on top of this in
+``parallel/ring_attention.py``.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import _route
 from ._vma import out_struct, vary_like
 
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
-def _interpret_mode() -> bool:
-    """``ZOO_TPU_PALLAS_INTERPRET=1`` runs the kernel bodies in the Pallas
-    interpreter: CPU coverage for tests. On a TPU backend it would swap
-    every Mosaic kernel for emulation without a word, so there it
-    raises."""
-    if os.environ.get("ZOO_TPU_PALLAS_INTERPRET", "0") != "1":
-        return False
-    if jax.default_backend() == "tpu":
-        raise RuntimeError(
-            "ZOO_TPU_PALLAS_INTERPRET=1 on a TPU backend: interpret mode "
-            "is for CPU tests and would replace the Mosaic kernels with "
-            "emulation; unset it")
-    return True
-
-
-_REMAT_POLICIES = {
-    "": "lse", "lse": "lse", "save-lse-recompute-probs": "lse",
-    "kernel": "lse",
-    "full": "full", "full-residual": "full", "xla": "full",
-}
-
-
-def _flash_remat_policy() -> str:
-    """Backward remat policy for the flash custom_vjp rules.
-
-    ``lse`` (alias ``save-lse-recompute-probs``, the default): backward
-    runs the dedicated blockwise kernels, rebuilding score blocks from
-    (q, k, bias) and normalizing with the saved per-row lse — O(L)
-    memory both directions.  ``full`` (alias ``full-residual``):
-    backward differentiates through the reference math instead,
-    materializing the full O(L^2) probs residual — can win at short L
-    where the two recompute passes dominate, and doubles as the escape
-    hatch when a backward kernel miscompiles.  Resolution order:
-    ``ZOO_TPU_FLASH_REMAT`` env, then ``ZooConfig.flash_remat`` when a
-    context is live (the engine plumbs it through ``from_env``), then
-    the legacy ``ZOO_TPU_FLASH_BWD=xla`` hatch (the r3 spelling of
-    ``full``)."""
-    raw = os.environ.get("ZOO_TPU_FLASH_REMAT")
-    if raw is None:
-        from ..common import nncontext as _nn
-        ctx = _nn._global_context
-        cfg = getattr(ctx, "config", None) if ctx is not None else None
-        raw = getattr(cfg, "flash_remat", "") or None
-    if raw is None:
-        raw = os.environ.get("ZOO_TPU_FLASH_BWD", "kernel")
-    key = str(raw).strip().lower()
-    if key not in _REMAT_POLICIES:
-        raise ValueError(
-            "unknown flash remat policy %r (expected 'lse'/"
-            "'save-lse-recompute-probs' or 'full'/'full-residual')"
-            % (raw,))
-    return _REMAT_POLICIES[key]
-
-
 # ---------------------------------------------------------------------------
-# Reference implementation (also the CPU / short-sequence path)
+# Reference implementation: the tests' oracle
 # ---------------------------------------------------------------------------
 
 def attention_reference(q, k, v, bias=None, causal=False, sm_scale=None,
                         q_offset=None):
-    """q,k,v: (B, H, L, D). bias broadcastable to (B, H, Lq, Lk).
+    """The oracle the tests compare every route against, and nothing
+    else: plain softmax(QK^T)V holding the full (B, H, Lq, Lk)
+    probabilities. No route of :func:`flash_attention` ends here.
+
+    q,k,v: (B, H, L, D). bias broadcastable to (B, H, Lq, Lk).
 
     ``q_offset`` places causal query row 0 at absolute key position
     ``q_offset`` (row i attends keys <= q_offset + i). None keeps the
@@ -122,27 +78,17 @@ def attention_reference(q, k, v, bias=None, causal=False, sm_scale=None,
 # This is the FlashAttention scheme expressed in plain XLA — it takes over
 # every shape the Pallas kernel declines (odd head dims, tiny or non-128
 # sequence lengths, full (B,H,Lq,Lk) biases, non-TPU backends), so the
-# (B, H, L, L) probs tensor the old ``attention_reference`` fallback
-# materialized never exists on any route. The reference stays above as the
-# test oracle only.
+# (B, H, L, L) probs tensor never exists on any route.
 # ---------------------------------------------------------------------------
 
-def _fallback_block(n, env):
+def _fallback_block(n):
     """Block length for the scan fallback: prefers 256 (then 128), the
     largest candidate strictly smaller than ``n`` that divides it —
     strict, so any L >= 256 splits into at least two blocks and no
-    (L, L) score tile is ever built. 256 won the block sweep on both
-    ends: tiles stay cache-resident on host CPU and fill a TPU
-    (8, 128)-lane register tile, while 512+ blocks regress wall time
-    ~15-40% at L = 2048. Lengths with no such divisor (tiny or odd L,
-    where L^2 is noise) run as a single block. Env override for tuning
-    sweeps must divide L (the scan has no partial-block masking)."""
-    try:
-        v = int(os.environ.get(env, "0"))
-    except ValueError:
-        v = 0
-    if v > 0 and n % min(v, n) == 0:
-        return min(v, n)
+    (L, L) score tile is ever built. 256 fills a TPU (8, 128)-lane
+    register tile and stays cache-resident on a host CPU. Lengths with no
+    such divisor (tiny or odd L, where L^2 is noise) run as a single
+    block."""
     for cand in (256, 128):
         if cand < n and n % cand == 0:
             return cand
@@ -339,9 +285,8 @@ def _blockwise_fwd_rule(q, k, v, bias, causal, sm_scale, block_q, block_k,
 def _blockwise_bwd_rule(causal, sm_scale, block_q, block_k, q_offset, res,
                         do):
     q, k, v, bias, o, m, l = res
-    with jax.named_scope("attn_hot"):
-        return _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal,
-                                   sm_scale, block_q, block_k, q_offset)
+    return _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal,
+                               sm_scale, block_q, block_k, q_offset)
 
 
 _attention_blockwise.defvjp(_blockwise_fwd_rule, _blockwise_bwd_rule)
@@ -355,24 +300,21 @@ def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
     two-pass lse-recompute backward (custom_vjp), matching
     ``attention_reference`` numerically while never materializing a
     (B, H, Lq, Lk) tensor in either direction for L >= 256. This is the
-    default fallback whenever the Pallas kernel is ineligible; block
-    sizes follow :func:`_fallback_block` (env
-    ``ZOO_TPU_ATTN_FALLBACK_BLOCK_Q/K`` for sweeps)."""
+    route of every call the Pallas kernels do not take; block sizes
+    follow :func:`_fallback_block` unless given."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     lq, lk = q.shape[2], k.shape[2]
     if bias is not None and bias.ndim != 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + tuple(bias.shape))
-    bq = _fallback_block(lq, "ZOO_TPU_ATTN_FALLBACK_BLOCK_Q")
-    bk = _fallback_block(lk, "ZOO_TPU_ATTN_FALLBACK_BLOCK_K")
+    bq, bk = _fallback_block(lq), _fallback_block(lk)
     if block_q and block_q < lq and lq % block_q == 0:
         bq = block_q
     if block_k and block_k < lk and lk % block_k == 0:
         bk = block_k
     off = None if q_offset is None else int(q_offset)
-    with jax.named_scope("attn_hot"):
-        return _attention_blockwise(q, k, v, bias, causal, sm_scale, bq,
-                                    bk, off)
+    return _attention_blockwise(q, k, v, bias, causal, sm_scale, bq, bk,
+                                off)
 
 
 # ---------------------------------------------------------------------------
@@ -457,38 +399,26 @@ def _bias_specs_3d(num_heads, block_k):
 
 
 def _resolve_blocks(lq, lk, block_q, block_k, d=None):
-    """Pick MXU-friendly block sizes: the largest of 512/256/128 dividing
-    the sequence length (bigger tiles amortize Mosaic per-iteration
-    overhead and fill the MXU). ``ZOO_TPU_ATTN_BLOCK_Q/K`` override for
-    tuning sweeps. Heads wider than 128 (``d``) take key blocks of at most
-    512: the backward kernels hold two (block_k, d) float32 accumulators
-    beside the score tiles."""
-    def pick(env, asked, n, cands):
-        # env/explicit choices must still divide the sequence length: the
-        # non-causal kernel has no partial-block bounds mask, so a
-        # non-dividing block would let Pallas-padded garbage k-columns
-        # into the softmax. Non-dividing (or malformed/non-positive)
-        # overrides fall through to auto.
-        try:
-            v = int(os.environ.get(env, "0"))
-        except ValueError:
-            v = 0
-        v = max(v, 0)
-        # fallback order: env -> explicit arg -> auto
-        if v and n % min(v, n) == 0:
-            return min(v, n)
+    """MXU-friendly block sizes from the shape: the largest of 512/256/128
+    dividing the query length, of 1024/512/256/128 dividing the key length
+    (bigger tiles amortize Mosaic's per-iteration overhead and fill the
+    MXU; the (block_q, block_k) f32 score tile plus the double-buffered
+    q/k/v blocks stay inside the 16 MB scoped VMEM). Heads wider than 128
+    (``d``) take key blocks of at most 512: the backward kernels hold two
+    (block_k, d) float32 accumulators beside the score tiles. An explicit
+    ``block_q``/``block_k`` is taken when it divides the length: the
+    non-causal kernel has no partial-block bounds mask, so a non-dividing
+    block would let Pallas-padded garbage k-columns into the softmax."""
+    def pick(asked, n, cands):
         if asked is not None and asked > 0 and n % min(asked, n) == 0:
             return min(asked, n)
         for cand in cands:
             if n % cand == 0:
                 return cand
         return min(128, n)
-    # block_q 512, block_k 1024 once L allows it (ATTN_TUNE.jsonl, a
-    # 2026-07 sweep): the (block_q, block_k) f32 score tile plus the
-    # double-buffered q/k/v blocks stay inside the 16 MB scoped VMEM
     wide = d is not None and d > 128
-    return (pick("ZOO_TPU_ATTN_BLOCK_Q", block_q, lq, (512, 256, 128)),
-            pick("ZOO_TPU_ATTN_BLOCK_K", block_k, lk,
+    return (pick(block_q, lq, (512, 256, 128)),
+            pick(block_k, lk,
                  (512, 256, 128) if wide else (1024, 512, 256, 128)))
 
 
@@ -521,9 +451,8 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
 
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
-    # named scopes: optimized HLO keeps no kernel name, only op_name
-    # metadata. ``attn_hot`` is the hlo_accountant's hot-path scope; the
-    # ``zoo_*`` tag says which kernel a tpu_custom_call is
+    # named scope: optimized HLO keeps no kernel name, only op_name
+    # metadata; the ``zoo_*`` tag says which kernel a tpu_custom_call is
     # (utils.profiling.mosaic_kernel_counts).
     call = pl.pallas_call(
         kernel,
@@ -553,9 +482,9 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
-    with jax.named_scope("attn_hot"), jax.named_scope("zoo_flash_fwd"):
+    with jax.named_scope("zoo_flash_fwd"):
         return call(q, k, v, kbias3)
 
 
@@ -687,9 +616,8 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian diagonal term.
     # One fused elementwise+reduce in XLA; (BH, Lq, 1) so backward kernel
     # blocks read it as (block_q, 1) rows.
-    with jax.named_scope("attn_hot"):
-        delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-            axis=-1, keepdims=True)
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        axis=-1, keepdims=True)
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
     qkv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
@@ -711,10 +639,9 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
-    with jax.named_scope("attn_hot"), \
-            jax.named_scope("zoo_flash_bwd_dq"):
+    with jax.named_scope("zoo_flash_bwd_dq"):
         dq = dq_call(q, k, v, kbias3, do, lse, delta)
 
     # dk/dv/dbias: grid transposed — k blocks parallel, q blocks innermost
@@ -758,15 +685,14 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
-    with jax.named_scope("attn_hot"):
-        with jax.named_scope("zoo_flash_bwd_dkv"):
-            dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)
-        # bias grad: the (B, Lk) key bias broadcasts over heads and query
-        # rows, so its cotangent sums ds over both — rows inside the
-        # kernel, heads here.
-        dkb = db.reshape(-1, kv_heads, lk).sum(axis=1).astype(kbias.dtype)
+    with jax.named_scope("zoo_flash_bwd_dkv"):
+        dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)
+    # bias grad: the (B, Lk) key bias broadcasts over heads and query
+    # rows, so its cotangent sums ds over both — rows inside the kernel,
+    # heads here.
+    dkb = db.reshape(-1, kv_heads, lk).sum(axis=1).astype(kbias.dtype)
     return dq, dk, dv, dkb
 
 
@@ -786,24 +712,9 @@ def _flash_fwd_rule(q, k, v, kbias, num_heads, causal, sm_scale,
 
 def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, group,
                     res, do):
-    """Backward via the dedicated Pallas kernels (O(L) memory, two-pass
-    lse recompute) under the default remat policy; the ``full`` /
-    ``full-residual`` policy (or the legacy ``ZOO_TPU_FLASH_BWD=xla``
-    spelling) differentiates through the reference math instead,
-    materializing the O(L^2) probs residual — see
-    :func:`_flash_remat_policy`."""
+    """The one backward: the two Pallas kernels, rebuilding score blocks
+    from (q, k, bias) and the saved lse (O(L) memory)."""
     q, k, v, kbias, o, lse = res
-    if _flash_remat_policy() == "full":
-        def ref(q, k, v, kb):
-            qf = q[:, None]
-            kf = jnp.repeat(k, group, axis=0)[:, None]
-            vf = jnp.repeat(v, group, axis=0)[:, None]
-            # kb: (B, Lk) -> per-(batch*head) rows -> (BH, 1, 1, Lk)
-            kbf = jnp.repeat(kb, num_heads, axis=0)[:, None, None, :]
-            return attention_reference(qf, kf, vf, bias=kbf, causal=causal,
-                                       sm_scale=sm_scale)[:, 0]
-
-        return jax.vjp(ref, q, k, v, kbias)[1](do)
     return _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal,
                            sm_scale, block_q, block_k, group)
 
@@ -824,103 +735,37 @@ def _as_key_bias(bias, b, lk) -> Optional[jnp.ndarray]:
     return None
 
 
-# Below this query length the XLA path takes the shape. 512 comes from a
-# 2026-07 v5e sweep (ATTN_TUNE.jsonl) that predates the current jax and
-# the kernels' last changes, and its two L=512 readings disagreed with
-# each other; the threshold has not been re-measured on today's chip
-# (ROADMAP S3). Env-overridable so a measurement can be applied without a
-# code change.
-try:
-    KERNEL_MIN_SEQ = int(os.environ.get("ZOO_TPU_KERNEL_MIN_SEQ", "512"))
-except ValueError:
-    import warnings
-    warnings.warn("ZOO_TPU_KERNEL_MIN_SEQ=%r is not an integer; using 512"
-                  % os.environ.get("ZOO_TPU_KERNEL_MIN_SEQ"))
-    KERNEL_MIN_SEQ = 512
-
-
-_PARTITION_WARNED = [False]
-
-
-def mosaic_partition_ok() -> bool:
-    """Mosaic custom calls cannot be auto-partitioned: under a
-    multi-device jit they only compile when ALL mesh axes are manual —
-    i.e. inside a plain (fully-manual) ``shard_map`` — and jax raises
-    ``NotImplementedError`` otherwise. Routing therefore sends
-    multi-device global-jit contexts to the XLA paths (which partition
-    automatically). The dp/sp/pp paths wrap their kernel sites in
-    fully-manual shard_maps, so they keep the kernels.
-
-    Inside the engine's own multi-device jit the abstract mesh reads
-    EMPTY — same as a plain single-device jit — so outside a shard_map
-    the only usable signals are process-level: the framework context's
-    mesh size when one is active, else ``jax.device_count()``. A
-    single-chip user on a multi-device host without a ZooContext is
-    therefore blocked conservatively (warned once);
-    ``ZOO_TPU_FORCE_PALLAS=1`` overrides, and a partitioning failure then
-    surfaces as jax's own error."""
-    if _interpret_mode() or \
-            os.environ.get("ZOO_TPU_FORCE_PALLAS", "0") == "1":
-        return True
-    am = jax.sharding.get_abstract_mesh()
-    if am.axis_names and set(am.manual_axes) == set(am.axis_names):
-        return True
-    from ..common import nncontext as _nn
-    ctx = _nn._global_context
-    if ctx is not None:
-        ok = int(np.prod(list(ctx.mesh.shape.values()) or [1])) == 1
-    else:
-        ok = jax.device_count() == 1
-    if not ok and not _PARTITION_WARNED[0]:
-        _PARTITION_WARNED[0] = True
-        import logging
-        logging.getLogger("analytics_zoo_tpu.ops").warning(
-            "Pallas kernels disabled outside shard_map on a multi-device"
-            " mesh (Mosaic custom calls cannot be auto-partitioned; the"
-            " XLA paths take over). Single-chip use on a multi-device"
-            " host can override with ZOO_TPU_FORCE_PALLAS=1; multi-chip"
-            " kernel use goes through the data/sequence-parallel and"
-            " pipeline shard_map paths.")
-    return ok
-
-
-# From this query length on, a shape the kernels cannot take is an error
-# on the chip and no longer a quiet change of route: the blockwise scan is
-# many times slower there, and ``ZOO_TPU_ATTN_FALLBACK=reference`` holds
-# (Lq, Lk) probabilities for every head.
-KERNEL_REQUIRED_SEQ = 8192
+# Below this query length the blockwise route takes the call (unless
+# ``ZOO_TPU_FORCE_PALLAS=1``). No cell of the benchmark sits below it, so
+# the kernels have not been measured there.
+KERNEL_MIN_SEQ = 512
 
 
 def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
                     kv_heads=1) -> bool:
-    """Static routing by shape and context: the whole decision. A shape
-    these rules send to the kernel compiles it inside the caller's jit;
-    if Mosaic refuses, the compiler's error surfaces there (no probe, no
-    reroute).
+    """Whether a call runs the Pallas kernels: this op's shape rules,
+    handed to ``_route.kernel_route``, which adds what every op shares
+    (``ZOO_TPU_DISABLE_PALLAS``, the partition check, the loud failure on
+    the chip from ``KERNEL_REQUIRED_SEQ`` on, ``KERNEL_MIN_SEQ`` and
+    ``ZOO_TPU_FORCE_PALLAS``). A call these rules send to the kernel
+    compiles it inside the caller's jit; if Mosaic refuses, the compiler's
+    error surfaces there (no probe, no reroute).
 
     What the kernels take: a head size that is a multiple of 64 (64 and
     256 run in the benchmark's cells; Mosaic pads the lane dim at 64, and
     above 128 the key blocks are capped at 512 rows); query and key
     lengths that are multiples of 128, no shorter than 128; no bias or a
-    key-padding bias; ``heads`` query heads over ``kv_heads`` key/value
-    heads where the first is a whole multiple of the second (1, as in
-    BERT, or grouped: 8 query heads a key/value head in the gated
-    attention block), consecutive query heads sharing one key/value
-    head. causal requires lq <= lk: the kernels mask bottom-right aligned
+    key-padding bias (``kb``); ``heads`` query heads over ``kv_heads``
+    key/value heads where the first is a whole multiple of the second (1,
+    as in BERT, or grouped: 8 query heads a key/value head in the gated
+    attention block), consecutive query heads sharing one key/value head.
+    causal requires lq <= lk: the kernels mask bottom-right aligned
     (offset = lk - lq, matching the reference), but lq > lk would leave
     the leading query rows fully masked (their softmax degenerates to the
     l_safe epsilon), so those shapes stay on the blockwise path, which
-    zeroes masked rows explicitly. ``ZOO_TPU_FORCE_PALLAS=1`` lifts the
-    KERNEL_MIN_SEQ and partitioning gates; ``ZOO_TPU_DISABLE_PALLAS=1``
-    routes everything to XLA.
-
-    On a TPU backend a shape refused at ``lq >= KERNEL_REQUIRED_SEQ`` (and
-    not by ``ZOO_TPU_DISABLE_PALLAS``) raises, naming each rule it
-    broke."""
-    if os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") == "1":
-        return False
-    broken = [why for ok, why in (
-        (on_tpu, "no TPU backend (or interpret mode)"),
+    zeroes masked rows explicitly."""
+    return _route.kernel_route("attention", (
+        (on_tpu, _route.NO_KERNEL_BACKEND),
         (kb is not None, "the bias is neither absent nor a key-padding "
                          "bias of (B|1, 1, 1, Lk)"),
         (lq >= 128 and lk >= 128 and lq % 128 == 0 and lk % 128 == 0,
@@ -930,27 +775,14 @@ def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
          f"{heads} query heads are not a whole multiple of {kv_heads} "
          f"key/value heads"),
         (not causal or lq <= lk, f"causal with lq {lq} > lk {lk}"),
-    ) if not ok]
-    if not broken and not mosaic_partition_ok():
-        broken.append("a multi-device jit outside a fully-manual shard_map "
-                      "(Mosaic calls cannot be partitioned)")
-    if broken and lq >= KERNEL_REQUIRED_SEQ and \
-            jax.default_backend() == "tpu":
-        raise ValueError(
-            f"attention at query length {lq} has no kernel route: "
-            + "; ".join(broken))
-    eligible = not broken
-    if os.environ.get("ZOO_TPU_FORCE_PALLAS", "0") != "1" and \
-            lq < KERNEL_MIN_SEQ:
-        eligible = False
-    return eligible
+    ), length=lq, min_length=KERNEL_MIN_SEQ)
 
 
 def flash_attention_blhd(q, k, v, bias=None, causal=False, sm_scale=None,
                          block_q=None, block_k=None, q_offset=None):
     """q,k,v: (B, L, H, D) -> (B, L, H, D) — the layout a fused QKV
     projection's reshape produces. Transposes to (B, H, L, D), runs
-    :func:`flash_attention`, transposes back. On the XLA routes the
+    :func:`flash_attention`, transposes back. On the blockwise route the
     transposes fold into the attention dots; on the kernel route the
     custom calls pin their operand layouts, so XLA materializes relayout
     copies around them."""
@@ -968,23 +800,18 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     """q: (B, H, L, D); k, v: (B, Hkv, L, D) with H a whole multiple of
     Hkv (consecutive query heads share a key/value head) -> (B, H, L, D).
 
-    Sequences of L >= KERNEL_MIN_SEQ route to the Pallas kernel on TPU
+    Sequences of L >= KERNEL_MIN_SEQ route to the Pallas kernels on TPU
     (or interpreter mode when ``ZOO_TPU_PALLAS_INTERPRET=1`` on CPU)
-    whenever the bias is absent or a key-padding bias — O(L) memory both
-    directions. Every other shape — shorter sequences, odd head dims,
-    full (B,H,Lq,Lk) biases, non-TPU backends — takes
-    :func:`attention_blockwise`, the scan-blockwise online-softmax
-    route that is also O(L) memory fwd+bwd. The choice is static
-    (:func:`_route_eligible`): a kernel the rules chose either compiles
-    or fails the caller's compile with Mosaic's message.
-    ``ZOO_TPU_ATTN_FALLBACK=reference`` swaps the blockwise route for the
-    reference math (full probs; runs under ``jax.checkpoint`` once a
-    call's saved probs exceed 512 MB, or always with
-    ``ZOO_TPU_ATTN_REMAT=1``) for A/B runs.
+    whenever the bias is absent or a key-padding bias. Every other shape
+    — shorter sequences, odd head dims, full (B,H,Lq,Lk) biases, non-TPU
+    backends — takes :func:`attention_blockwise`. Both are O(L) memory
+    forward and backward. The choice is static (:func:`_route_eligible`):
+    a kernel the rules chose either compiles or fails the caller's
+    compile with Mosaic's message.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    on_tpu = jax.default_backend() == "tpu" or _interpret_mode()
+    on_tpu = _route.kernel_backend()
     b, h, lq, d = q.shape
     lk, hkv = k.shape[2], k.shape[1]
     if h % hkv:
@@ -997,38 +824,17 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     default_off = q_offset is None or int(q_offset) == lk - lq
     use_kernel = default_off and _route_eligible(on_tpu, kb, lq, lk, d,
                                                  causal, h, hkv)
-    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     if not use_kernel:
         if group > 1:
-            # the XLA routes know one key/value head a query head
+            # the blockwise route knows one key/value head a query head
             k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
-        if os.environ.get("ZOO_TPU_ATTN_FALLBACK", "blockwise") \
-                != "reference":
-            # deliberately NOT forwarding the kernel block sizes: they may
-            # equal L (a 512-seq kernel tile is legal, a 512x512 blockwise
-            # score tile defeats the O(L) contract) — attention_blockwise
-            # picks strictly-smaller blocks itself
-            return attention_blockwise(q, k, v, bias=bias, causal=causal,
-                                       sm_scale=sm_scale,
-                                       q_offset=q_offset)
-        ref = functools.partial(attention_reference, causal=causal,
-                                sm_scale=sm_scale, q_offset=q_offset)
-        # Remat only when the saved L^2 probs are big enough to threaten
-        # HBM (they are saved once per transformer layer): 12 layers x
-        # 768 MB of f32 probs do not fit a 16 GB chip at BERT-base B=64,
-        # while the 512 MB/call threshold keeps B=32 (384 MB x 12) on the
-        # no-recompute path; force with ZOO_TPU_ATTN_REMAT=1/0 for deeper
-        # stacks or smaller chips.
-        probs_bytes = b * h * lq * lk * 4
-        remat_env = os.environ.get("ZOO_TPU_ATTN_REMAT")
-        remat = (probs_bytes >= (512 << 20)) if remat_env is None \
-            else remat_env == "1"
-        if not remat:
-            return ref(q, k, v, bias=bias)
-        if bias is None:
-            return jax.checkpoint(ref)(q, k, v)
-        return jax.checkpoint(lambda q, k, v, b: ref(q, k, v, bias=b))(
-            q, k, v, bias)
+        # deliberately NOT forwarding the kernel block sizes: they may
+        # equal L (a 512-seq kernel tile is legal, a 512x512 blockwise
+        # score tile defeats the O(L) contract) — attention_blockwise
+        # picks strictly-smaller blocks itself
+        return attention_blockwise(q, k, v, bias=bias, causal=causal,
+                                   sm_scale=sm_scale, q_offset=q_offset)
+    block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     qf = q.reshape(b * h, lq, d)
     kf = k.reshape(b * hkv, lk, d)
     vf = v.reshape(b * hkv, lk, d)

@@ -46,12 +46,10 @@ kernels inside it under ``zoo_gdn_scan_fwd`` and ``zoo_gdn_scan_bwd``.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
-from . import attention as _attn
+from . import _route
 from ._vma import out_struct
 
 DEFAULT_CHUNK = 128
@@ -157,28 +155,17 @@ def _step(s, xs):
 
 
 def _kernel_route(l, c, dk, dv) -> bool:
-    """Whether the loop over chunks runs as the Pallas kernels: static, by
-    shape and context, as ``attention._route_eligible`` decides it. On a
-    TPU backend a shape refused at ``l >= KERNEL_REQUIRED_SEQ`` (and not by
-    ``ZOO_TPU_DISABLE_PALLAS``) raises, naming each rule it broke: there
-    the scan is several times slower."""
-    if os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") == "1":
-        return False
-    on_tpu = jax.default_backend() == "tpu" or _attn._interpret_mode()
-    broken = [why for ok, why in (
-        (on_tpu, "no TPU backend (or interpret mode)"),
+    """Whether the loop over chunks runs as the Pallas kernels: this op's
+    shape rules, handed to ``_route.kernel_route`` (static, by shape and
+    context; on a TPU backend a shape refused at ``l >=
+    KERNEL_REQUIRED_SEQ`` raises, naming each rule it broke: there the
+    scan is several times slower)."""
+    return _route.kernel_route("the delta rule", (
+        (_route.kernel_backend(), _route.NO_KERNEL_BACKEND),
         (c == DEFAULT_CHUNK, f"chunk {c} is not {DEFAULT_CHUNK}"),
         (dk % 128 == 0 and dv % 128 == 0,
          f"head sizes {dk} and {dv} are not multiples of 128"),
-    ) if not ok]
-    if not broken and not _attn.mosaic_partition_ok():
-        broken.append("a multi-device jit outside a fully-manual shard_map "
-                      "(Mosaic calls cannot be partitioned)")
-    if broken and l >= _attn.KERNEL_REQUIRED_SEQ and \
-            jax.default_backend() == "tpu":
-        raise ValueError(f"the delta rule at length {l} has no kernel "
-                         f"route: " + "; ".join(broken))
-    return not broken
+    ), length=l)
 
 
 # -- the loop over chunks as two kernels ------------------------------------
@@ -261,7 +248,7 @@ def _scan_call(kernel, name, operands, outs, state, reverse):
         scratch_shapes=[pltpu.VMEM(state, jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_attn._interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
     # the innermost ``zoo_*`` scope says which kernel a tpu_custom_call is
     # (utils.profiling.mosaic_kernel_counts); ``zoo_gdn_scan`` is around it

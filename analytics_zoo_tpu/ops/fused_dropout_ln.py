@@ -25,14 +25,13 @@ semantics.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import _route
 from ._vma import out_struct, psum_grad_like
-from .attention import _interpret_mode, mosaic_partition_ok
 from .layernorm import layer_norm
 
 
@@ -138,7 +137,7 @@ def _dln_forward(x2, r2, bits2, gamma, beta, keep, eps, block_rows):
             out_struct((n, 1), jnp.float32, x2, r2, bits2),
             out_struct((n, 1), jnp.float32, x2, r2, bits2),
         ],
-        interpret=_interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
     # the zoo_* scope names the kernel in optimized HLO (see
     # ops/attention.py _flash_forward)
@@ -170,7 +169,7 @@ def _dln_backward(dy2, z2, bits2, gamma, mean, inv, keep, block_rows):
             out_struct((nblk, 1, d), jnp.float32, dy2, z2, bits2),
             out_struct((nblk, 1, d), jnp.float32, dy2, z2, bits2),
         ],
-        interpret=_interpret_mode(),
+        interpret=_route.interpret_mode(),
     )
     with jax.named_scope("zoo_dln_bwd"):
         return call(dy2, z2, bits2, gamma.reshape(1, d), mean, inv)
@@ -225,12 +224,12 @@ def dropout_add_layer_norm(x, resid, gamma, beta, rng, p_drop,
     d = x.shape[-1]
     n = int(np.prod(x.shape[:-1]))
     block_rows = _pick_rows(n, d, jnp.dtype(x.dtype).itemsize)
-    on_tpu = jax.default_backend() == "tpu" or _interpret_mode()
-    eligible = (on_tpu and keep < 1.0 and d % 128 == 0 and
-                block_rows > 0 and mosaic_partition_ok() and
-                os.environ.get("ZOO_TPU_DISABLE_PALLAS", "0") != "1" and
-                os.environ.get("ZOO_TPU_DISABLE_FUSED_DLN", "0") != "1")
-    if eligible:
+    if _route.kernel_route("dropout+add+LayerNorm", (
+            (_route.kernel_backend(), _route.NO_KERNEL_BACKEND),
+            (keep < 1.0, f"dropout rate {p_drop} keeps every element"),
+            (d % 128 == 0, f"width {d} is not a multiple of 128"),
+            (block_rows > 0, f"no row block divides {n} rows at width {d}"),
+    )):
         bits = jax.random.bits(rng, (n, d), jnp.uint32)
         y = _dln(x.reshape(n, d), resid.reshape(n, d).astype(x.dtype),
                  bits, gamma, beta, keep, eps, block_rows)

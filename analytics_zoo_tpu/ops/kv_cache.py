@@ -14,7 +14,7 @@ module provides the O(L)-per-token alternative:
 - ``cached_attention_step``: one-token attention against the slab — an
   einsum contracting the single query row against S cached keys, masked at
   each sequence's write length. The jaxpr contains no (L, L) contraction;
-  ``decode_step_is_cached`` (bench gate) asserts exactly that.
+  ``decode_step_is_cached`` asserts exactly that.
 
 Cache slabs are preallocated at power-of-two lengths (``pick_cache_bucket``)
 so XLA compiles a small fixed set of decode-step shapes; a sequence that
@@ -348,16 +348,30 @@ def kv_slab_bytes(state: DecodeState) -> int:
     return total
 
 
+def _iter_eqns(jaxpr):
+    """Every eqn of ``jaxpr`` and, recursively, of any sub-jaxpr among
+    its params: scan and while bodies, custom_vjp branches, remat
+    thunks."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            vals = val if isinstance(val, (list, tuple)) else [val]
+            for v in vals:
+                sub = getattr(v, "jaxpr", None)
+                if sub is not None and hasattr(sub, "eqns"):
+                    yield from _iter_eqns(sub)
+                elif hasattr(v, "eqns"):
+                    yield from _iter_eqns(v)
+
+
 def decode_step_is_cached(fn, *args, capacity=None, **kwargs) -> bool:
-    """Jaxpr probe (bench/CI gate): True iff ``fn(*args)`` contains no
+    """Jaxpr probe (the tests' gate): True iff ``fn(*args)`` contains no
     full-sequence attention contraction — no ``dot_general`` (or einsum
     lowering) whose OUTPUT carries two axes of at least the slab capacity.
     The cached step's score tensor is (B, H, S): one S axis. A fallback
     that recomputed attention over the whole history would produce an
     (S, S) score block and trip this.
     """
-    from .attn_smoke import _iter_eqns
-
     if capacity is None:
         raise ValueError("pass capacity= (the slab length S)")
     jaxpr = jax.make_jaxpr(fn, **kwargs)(*args).jaxpr

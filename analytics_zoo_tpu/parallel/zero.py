@@ -14,8 +14,8 @@ parameters replicated but gives each of the ``dp`` data-parallel ranks a
   mu/nu memory per device);
 * updated parameters are **all-gathered** back to replicated.
 
-This module holds the layout plumbing shared by the engine, the tests,
-``bench.py`` and ``zero-smoke``: flat-pad/unpad conversion between the
+This module holds the layout plumbing shared by the engine and the
+tests: flat-pad/unpad conversion between the
 canonical (param-shaped, replicated) representation and the sharded
 flat representation, eligibility classification, and the jaxpr probe
 that pins the collective pattern (reduce-scatter + all-gather present,

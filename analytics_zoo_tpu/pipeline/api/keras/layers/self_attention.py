@@ -436,7 +436,7 @@ class TransformerLayer(KerasLayer):
     # the standard causal flash/blockwise route and stashes every
     # block's projected K/V into preallocated slabs; decode_step then
     # advances one token per call with O(S) cached attention — the
-    # step's jaxpr has no (L, L) contraction (bench generate gate).
+    # step's jaxpr has no (L, L) contraction (``decode_step_is_cached``).
     # Decode is inference-only: no dropout, per-block param layout
     # (pipeline_parallel stacking is a training layout).
 
@@ -571,7 +571,7 @@ class TransformerLayer(KerasLayer):
 
         Row c embeds at position ``lengths + c`` and attends slab keys
         ``<= lengths + c`` (``cached_attention_chunk``) — the jaxpr still
-        carries no (S, S) contraction, so the cached-decode bench gate
+        carries no (S, S) contraction, so ``decode_step_is_cached``
         holds for any C < S.
         """
         from .....ops.kv_cache import cached_attention_chunk

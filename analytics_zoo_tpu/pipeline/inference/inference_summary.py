@@ -9,7 +9,7 @@ Pipeline extension: the serving engine is a three-stage pipeline
 latency reservoirs with p50/p95/p99, plus queue depths, in addition to
 the original per-batch Throughput/LatencyMs scalars.  A summary built
 with ``log_dir=None`` keeps the in-memory statistics without writing
-TensorBoard events (the serving bench and smoke entry use this).
+TensorBoard events (the serving smoke entry uses this).
 """
 
 from __future__ import annotations
@@ -143,7 +143,7 @@ class InferenceSummary:
     def snapshot(self) -> dict:
         """Everything at once: per-stage {count, mean_ms, p50/p95/p99}
         plus the latest queue depths — the observability payload for the
-        bench leg and the smoke entry."""
+        smoke entry."""
         with self._lock:
             stages = dict(self._stages)
             depths = dict(self._queue_depths)

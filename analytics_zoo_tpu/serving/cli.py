@@ -110,11 +110,11 @@ params:
 ## `generate` endpoint with KV-cache decode + continuous batching
 # generate:
 #   slots: 4                 # in-flight sequences (cache slots)
-#   continuous: true         # false = static batching (bench baseline)
+#   continuous: true         # false = static batching (a baseline)
 #   max_len: 1024            # largest prompt+generation a slab can hold
 #   max_new_tokens: 32       # default token budget per request
 #   stop_id: 0               # default stop token (omit for none)
-#   stub_ms_per_step: 1.0    # deterministic stub engine (smoke/bench);
+#   stub_ms_per_step: 1.0    # deterministic stub engine (smoke runs);
 #                            # omit and inject a real engine via
 #                            # ClusterServing.set_generate_engine
 #   ## generative fast path (docs/serving-generate.md#fast-path)
@@ -139,7 +139,7 @@ params:
 
 ## SLO engine (docs/observability.md#slo): declarative objectives with
 ## multi-window error-budget burn-rate alerts, rendered by
-## `zoo-serving top` and gated by the bench soak leg
+## `zoo-serving top`
 # slo:
 #   fast_window_s: 10            # detection window
 #   slow_window_s: 60            # blip-immunity window

@@ -27,8 +27,8 @@ stream read):
    queue backend.
 
 The original single-thread loop survives as ``pipelined=False`` (config
-``params.pipelined``) and is the baseline the ``bench.py`` serving leg
-and the slow comparison test measure against.  Per-stage latency
+``params.pipelined``) and is the baseline the slow comparison test
+measures against.  Per-stage latency
 percentiles, queue depths, and bucket usage are recorded in
 :class:`InferenceSummary` so the overlap is observable.
 
@@ -132,7 +132,7 @@ class _RequestLog:
 class EchoStubModel(AbstractModel):
     """Deterministic stand-in for a real model: sleeps a fixed
     ``ms_per_batch`` (a perfectly flat "device" time) and echoes each
-    row's mean.  Lets fleet workers, smoke tests, and bench legs exercise
+    row's mean.  Lets fleet workers and smoke tests exercise
     the full wire path in subprocesses without a saved model — enabled
     via config ``model.stub_ms_per_batch``."""
 
@@ -193,7 +193,7 @@ class ClusterServingHelper:
         params = config.get("params") or {}
         self.model_path = model.get("path")
         # deterministic echo stub (EchoStubModel) instead of a saved
-        # model — fleet smoke / bench workers (docs/serving-fleet.md)
+        # model — fleet smoke workers (docs/serving-fleet.md)
         raw_stub = model.get("stub_ms_per_batch")
         self.stub_ms_per_batch = None if raw_stub is None else float(raw_stub)
         # transport spec; ZOO_SERVING_TRANSPORT (the CLI's --transport
@@ -280,7 +280,7 @@ class ClusterServingHelper:
         raw_gstop = gen.get("stop_id")
         self.generate_stop_id = None if raw_gstop is None else int(raw_gstop)
         # deterministic stub decode engine (StubDecodeEngine) — fleet
-        # smoke / bench workers, mirrors model.stub_ms_per_batch
+        # smoke workers, mirrors model.stub_ms_per_batch
         raw_gstub = gen.get("stub_ms_per_step")
         self.generate_stub_ms_per_step = \
             None if raw_gstub is None else float(raw_gstub)
@@ -450,7 +450,7 @@ class ClusterServing:
 
     def pipeline_stats(self) -> dict:
         """Counters + per-stage percentiles + queue depths — the payload
-        the bench leg, smoke entry, and tests assert on."""
+        the smoke entry and tests assert on."""
         with self._ctr_lock:
             out = {"records_in": self.records_in,
                    "results_out": self.results_out,

@@ -73,8 +73,8 @@ def autoscale_path(workdir: str) -> str:
 
 def read_autoscale_trace(workdir: str) -> List[dict]:
     """The supervisor's autoscale event trace (scale_up / scale_down
-    rows with backlog, predicted wait, and worker ids) — bench legs and
-    `zoo-serving status` read this."""
+    rows with backlog, predicted wait, and worker ids) — `zoo-serving status`
+    reads this."""
     try:
         with open(autoscale_path(workdir)) as f:
             return json.load(f).get("events", [])

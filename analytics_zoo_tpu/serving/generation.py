@@ -27,7 +27,7 @@ Two engines implement the gang interface: ``TransformerDecodeEngine``
 ``StubDecodeEngine`` (a deterministic CPU stand-in whose decode step
 costs a flat ``ms_per_step`` regardless of gang width — the
 MXU-amortization property that makes continuous batching pay; the
-bench ``generation`` leg and the fast-tier smoke run on it).
+fast-tier smoke runs on it).
 
 On top of the base gang interface the engines expose a **generative
 fast path**, each piece optional and independently degradable:
@@ -776,8 +776,8 @@ class ContinuousBatchScheduler:
     per-token exact.
 
     ``continuous=False`` degrades to static batching — the gang only
-    refills once *every* slot has drained — which is the baseline leg
-    of the bench comparison, not a recommended mode.
+    refills once *every* slot has drained — which is a baseline for
+    comparison, not a recommended mode.
 
     Results leave through ``commit(uri, payload)`` exactly once per
     submitted request: a finished sequence commits ``{"tokens",

@@ -507,7 +507,7 @@ class ModelRegistry:
 
     def routed_versions(self) -> List[ModelVersion]:
         """Every loaded version traffic can currently reach (active +
-        canary per model) — the warmup/bench surface."""
+        canary per model) — the warmup surface."""
         out = []
         with self._lock:
             for name, versions in self._models.items():

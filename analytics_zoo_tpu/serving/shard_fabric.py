@@ -505,17 +505,17 @@ class ShardedStreamQueue(StreamQueue):
 
 class LocalShardFabric:
     """N in-process brokers on one host — `zoo-serving broker --shards
-    N`, tests, and bench arms.  ``base_port=0`` binds ephemeral ports."""
+    N` and tests.  ``base_port=0`` binds ephemeral ports."""
 
     def __init__(self, n: int, host: str = "127.0.0.1", base_port: int = 0,
-                 claim_timeout_s: float = 60.0, op_cost_ms: float = 0.0):
+                 claim_timeout_s: float = 60.0):
         if n < 1:
             raise ValueError("need >= 1 shard")
         self.brokers = [
             StreamQueueBroker(
                 host=host,
                 port=0 if base_port == 0 else base_port + k,
-                claim_timeout_s=claim_timeout_s, op_cost_ms=op_cost_ms)
+                claim_timeout_s=claim_timeout_s)
             for k in range(int(n))]
 
     @property
@@ -547,16 +547,14 @@ class LocalShardFabric:
 
 
 def spawn_broker_proc(port: int, host: str = "127.0.0.1",
-                      claim_timeout_s: float = 60.0,
-                      op_cost_ms: float = 0.0) -> subprocess.Popen:
+                      claim_timeout_s: float = 60.0) -> subprocess.Popen:
     """A broker in its OWN process (``python -m ...socket_queue``) so
     chaos legs can SIGKILL it — an in-process broker thread cannot model
     losing the stream."""
     return subprocess.Popen(
         [sys.executable, "-m", "analytics_zoo_tpu.serving.socket_queue",
          "--host", host, "--port", str(int(port)),
-         "--claim-timeout-s", str(float(claim_timeout_s)),
-         "--op-cost-ms", str(float(op_cost_ms))],
+         "--claim-timeout-s", str(float(claim_timeout_s))],
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
 
 

@@ -151,16 +151,9 @@ class StreamQueueBroker:
     """
 
     def __init__(self, host: str = "127.0.0.1", port: int = 0,
-                 name: str = "image_stream", claim_timeout_s: float = 60.0,
-                 op_cost_ms: float = 0.0):
+                 name: str = "image_stream", claim_timeout_s: float = 60.0):
         self.name = name
         self.claim_timeout_s = float(claim_timeout_s)
-        # stubbed serialized-core cost: sleep this long INSIDE the stream
-        # lock on each data-plane op, so scale-out benches on a 1-core
-        # host can model N brokers on N cores (sleeping releases the GIL,
-        # so two brokers' ops overlap the way two cores would, while one
-        # broker's ops stay serialized on its lock).  0 = off.
-        self.op_cost_ms = float(op_cost_ms)
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)          # stream
         self._results_cv = threading.Condition(self._lock)  # results
@@ -280,8 +273,6 @@ class StreamQueueBroker:
         toks = req.get("toks") or [None] * len(records)
         rids = []
         with self._cv:
-            if self.op_cost_ms:
-                time.sleep(self.op_cost_ms / 1e3)
             for rec, tok in zip(records, toks):
                 if tok is not None and tok in self._tokens:
                     rids.append(self._tokens[tok])   # retried send: dedup
@@ -303,8 +294,6 @@ class StreamQueueBroker:
         max_items = int(req.get("max", 1))
         deadline = time.time() + float(req.get("timeout_ms", 1000)) / 1e3
         with self._cv:
-            if self.op_cost_ms:
-                time.sleep(self.op_cost_ms / 1e3)
             # this connection is now the consumer's lease: its death
             # triggers redelivery of whatever this read hands out
             self._consumer_conn[consumer] = conn_id
@@ -601,15 +590,11 @@ def main(argv=None) -> int:  # pragma: no cover - CLI entry
     ap.add_argument("--host", default="0.0.0.0")
     ap.add_argument("--port", type=int, default=6380)
     ap.add_argument("--claim-timeout-s", type=float, default=60.0)
-    ap.add_argument("--op-cost-ms", type=float, default=0.0,
-                    help="stubbed serialized-core cost per data-plane op "
-                         "(scale-out benches on few-core hosts)")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s broker %(message)s")
     broker = StreamQueueBroker(host=args.host, port=args.port,
-                               claim_timeout_s=args.claim_timeout_s,
-                               op_cost_ms=args.op_cost_ms)
+                               claim_timeout_s=args.claim_timeout_s)
     try:
         broker.run_forever()
     except KeyboardInterrupt:

@@ -274,169 +274,6 @@ class ProfilerHook:
         self.done = True
 
 
-# -- HLO step-time accountant ------------------------------------------------
-#
-# MFU tells you how far from peak a step is; it does not tell you WHERE the
-# gap lives. The accountant walks the optimized HLO of a compiled step and
-# buckets every instruction's output bytes into matmul / conv / relayout
-# (copy+transpose) / elementwise / comms / other — output bytes is the one
-# cost proxy computable from text alone, and it is exactly the quantity a
-# relayout wastes (a copy's entire output is overhead). The headline number
-# is ``relayout_fraction``: bytes produced by copy/transpose ops as a share
-# of all bytes produced, i.e. how much of the step's memory traffic is pure
-# data movement the compiler inserted to fix layouts. Ops tagged with the
-# ``attn_hot`` named scope (every kernel call + residual computation in
-# ops/attention.py) are additionally tracked, so a relayout copy XLA puts
-# inside the attention hot path is counted by name.
-
-_HLO_DTYPE_BYTES = {
-    "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
-    "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3b11fnuz": 1,
-    "f8e4m3fnuz": 1, "f8e5m2fnuz": 1,
-    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
-}
-
-_HLO_BUCKET_BY_OPCODE = {
-    "dot": "matmul",
-    "convolution": "conv",
-    "copy": "relayout", "copy-start": "relayout", "copy-done": "relayout",
-    "transpose": "relayout",
-    "all-reduce": "comms", "all-reduce-start": "comms",
-    "all-reduce-done": "comms", "all-gather": "comms",
-    "all-gather-start": "comms", "all-gather-done": "comms",
-    "all-to-all": "comms", "collective-permute": "comms",
-    "collective-permute-start": "comms", "collective-permute-done": "comms",
-    "reduce-scatter": "comms", "send": "comms", "send-done": "comms",
-    "recv": "comms", "recv-done": "comms",
-    "custom-call": "other", "infeed": "other", "outfeed": "other",
-    "rng": "other", "rng-bit-generator": "other", "fft": "other",
-}
-
-# structural/free ops: no data produced, or their cost is attributed
-# elsewhere (a fusion instruction carries its body's output; `while` just
-# forwards its body's result tuple)
-_HLO_SKIP_OPCODES = {
-    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
-    "while", "call", "conditional", "after-all", "partition-id",
-    "replica-id", "iota", "opt-barrier", "get-dimension-size",
-}
-
-_HLO_INSTR_RE = None
-_HLO_SHAPE_RE = None
-
-
-def _hlo_regexes():
-    global _HLO_INSTR_RE, _HLO_SHAPE_RE
-    if _HLO_INSTR_RE is None:
-        import re
-        _HLO_INSTR_RE = re.compile(
-            r"^\s+(?:ROOT\s+)?%?[^\s=]+\s*=\s*(?P<shape>.+?)\s+"
-            r"(?P<op>[a-z][a-z0-9\-]*)\(")
-        _HLO_SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-    return _HLO_INSTR_RE, _HLO_SHAPE_RE
-
-
-def _hlo_shape_bytes(shape: str) -> int:
-    """Total bytes of an HLO shape string — handles tuples by summing every
-    ``dtype[dims]`` group found."""
-    _, shape_re = _hlo_regexes()
-    total = 0
-    for dtype, dims in shape_re.findall(shape):
-        elem = _HLO_DTYPE_BYTES.get(dtype)
-        if elem is None:
-            continue  # token[...] etc.: no data bytes
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += elem * n
-    return total
-
-
-def hlo_accountant(hlo, hot_scope: str = "attn_hot") -> dict:
-    """Decompose a compiled step's optimized HLO into cost buckets.
-
-    ``hlo``: the HLO text (``compiled.as_text()``), or any object with an
-    ``as_text()`` method (a ``jax.stages.Compiled``). Returns::
-
-        {"total_bytes", "buckets": {bucket: bytes},
-         "fractions": {bucket: share of total_bytes},
-         "relayout_fraction", "op_counts": {bucket: #instructions},
-         "hot_ops", "hot_copy_transpose_ops", "hot_copy_transpose_names"}
-
-    Skips fusion-body computations (their cost is carried by the calling
-    ``fusion`` instruction) but walks every other computation — with
-    ``lax.scan``-fused steps the real work lives in the while-body
-    computation (``%wide.region_*``), not ENTRY.
-    """
-    if hasattr(hlo, "as_text"):
-        hlo = hlo.as_text()
-    instr_re, _ = _hlo_regexes()
-    buckets: dict = {}
-    counts: dict = {}
-    total = 0
-    hot_ops = 0
-    hot_ct_ops = 0
-    hot_ct_names: list = []
-    skip_block = False
-    for line in hlo.splitlines():
-        if line and not line[0].isspace():
-            # computation header (or module header / closing brace at
-            # col 0). Only fusion bodies are skipped — their cost rides on
-            # the calling `fusion` instruction; everything else (ENTRY,
-            # while/scan bodies like %wide.region_N, scalar reduction
-            # combinators) is walked. Combinator bytes are scalars —
-            # counting them is noise-free.
-            name = line.split("(", 1)[0]
-            skip_block = "fused_computation" in name
-            continue
-        if skip_block:
-            continue
-        m = instr_re.match(line)
-        if m is None:
-            continue
-        op = m.group("op")
-        if op in _HLO_SKIP_OPCODES:
-            continue
-        nbytes = _hlo_shape_bytes(m.group("shape"))
-        bucket = _HLO_BUCKET_BY_OPCODE.get(op, "elementwise")
-        buckets[bucket] = buckets.get(bucket, 0) + nbytes
-        counts[bucket] = counts.get(bucket, 0) + 1
-        total += nbytes
-        if hot_scope and (f'/{hot_scope}/' in line or
-                          f'{hot_scope}"' in line):
-            hot_ops += 1
-            if bucket == "relayout":
-                hot_ct_ops += 1
-                if len(hot_ct_names) < 8:
-                    hot_ct_names.append(line.strip().split(" = ")[0])
-    fractions = {k: (v / total if total else 0.0)
-                 for k, v in buckets.items()}
-    return {
-        "total_bytes": total,
-        "buckets": buckets,
-        "fractions": {k: round(v, 4) for k, v in fractions.items()},
-        "relayout_fraction": round(
-            buckets.get("relayout", 0) / total if total else 0.0, 4),
-        "op_counts": counts,
-        "hot_ops": hot_ops,
-        "hot_copy_transpose_ops": hot_ct_ops,
-        "hot_copy_transpose_names": hot_ct_names,
-    }
-
-
-def account_step(fn, *args, **kwargs):
-    """Convenience: AOT-compile ``fn`` (a jitted callable) on ``args`` and
-    run :func:`hlo_accountant` over its optimized HLO. Accepts an already-
-    compiled ``jax.stages.Compiled`` directly."""
-    if hasattr(fn, "as_text"):
-        return hlo_accountant(fn)
-    compiled = fn.lower(*args, **kwargs).compile()
-    return hlo_accountant(compiled)
-
-
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _KERNEL_TAG_RE = re.compile(r"zoo_[a-z0-9_]+")
 

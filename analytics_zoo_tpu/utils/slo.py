@@ -261,8 +261,8 @@ class SloEngine:
     ``record()`` is called once per finished request (from the serving
     writer / shed / dead-letter paths); ``evaluate()`` runs periodically
     (the stats-dump loop) and returns the alerts that *fired* on this
-    pass.  ``status()`` is the JSON-ready view `zoo-serving top` and the
-    soak bench leg render."""
+    pass.  ``status()`` is the JSON-ready view `zoo-serving top`
+    renders."""
 
     def __init__(self, objectives: Sequence[Objective],
                  service: str = "", max_events: int = 65536):

@@ -32,7 +32,7 @@ import numpy as np
 
 SEED = 0
 
-# BERT-base, as bench.py's BERT leg builds it
+# BERT-base at the widths of the `bert_train_l512` cell
 VOCAB, HIDDEN, BLOCKS, HEADS, SEQ, CLASSES = 30522, 768, 12, 12, 512, 2
 BATCH_PER_CHIP = 32
 FUSED_K = 16                 # SPMDTrainer.MULTI_STEP_K, the accelerator auto

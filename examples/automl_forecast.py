@@ -1,8 +1,7 @@
 """AutoML time-series forecasting on the Ray-equivalent runtime.
 
 Reference capability: the off-tree ``automl`` branch advertised in the
-reference README (scalable time-series AutoML; BASELINE.md "AutoML
-forecaster — trials/hour"). Trials (hyperparameter configs for the TCN/LSTM
+reference README (scalable time-series AutoML). Trials (hyperparameter configs for the TCN/LSTM
 forecasters) run as tasks on the RayContext worker pool; the winner is
 refit and used to forecast.
 """

@@ -1,7 +1,7 @@
 """Image-classifier transfer learning: freeze the trunk, retrain the head.
 
-Reference config: BASELINE.md "TFPark KerasModel ResNet-50 fine-tune
-(dogs-vs-cats)" / the ``apps/dogs-vs-cats`` notebook — load a backbone,
+Reference config: the TFPark KerasModel ResNet-50 fine-tune
+(dogs-vs-cats), the ``apps/dogs-vs-cats`` notebook — load a backbone,
 freeze everything below the head, fit a 2-class classifier. Here a small
 zoo backbone on synthetic two-texture images (no download; the reference
 downloads its pretrained snapshot instead), using the GraphNet-parity

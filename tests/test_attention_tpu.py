@@ -66,7 +66,7 @@ def tpu():
                         "on a chip; skipped where there is none")
 def test_flash_kernel_parity_on_tpu_bert_shapes(tpu):
     """fwd+bwd bf16 parity at BERT-base shapes (B=16, L=512) on hardware —
-    exactly the configuration that crashed in BENCH_r02."""
+    exactly the configuration that once crashed on the chip."""
     out = subprocess.run([sys.executable, "-c", _PARITY],
                          capture_output=True, text=True, timeout=900,
                          env=_clean_env())

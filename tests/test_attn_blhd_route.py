@@ -1,20 +1,15 @@
-"""End-to-end blhd attention route + O(L) fallback + HLO accountant.
+"""The attention entry points end to end: the blhd route and the O(L)
+contract (docs/performance.md).
 
-Covers the r6 attention work (docs/performance.md):
-
-- blhd fwd+bwd parity against the reference oracle under a 2-device
-  data-parallel ``shard_map`` mesh, with the backward remat hatch
-  (``ZOO_TPU_FLASH_REMAT``) exercised both ways;
-- the jaxpr property that the scan-blockwise fallback NEVER materializes
-  an (..., L, L) intermediate for L >= 512, and that an ineligible
-  ``flash_attention`` call routes to it (not to the old reference
-  fallback);
-- the HLO step-time accountant: opcode buckets on synthetic HLO text,
-  the ``account_step`` integration, and the hot-path contract (zero
-  copy/transpose ops carrying the ``attn_hot`` scope).
-
-``ops/attn_smoke.py`` re-runs these checks in a process of its own as
-``bench.py``'s attention leg; this file is where tier-1 makes them.
+- blhd forward and backward against the reference oracle under a 2-device
+  data-parallel ``shard_map`` mesh;
+- the blockwise route against the oracle at L=512, forward and every
+  cotangent, the bias's among them;
+- the jaxpr property that the blockwise route NEVER materializes an
+  (..., L, L) intermediate for L >= 512, and that an ineligible
+  ``flash_attention`` call lands on it;
+- the kernels' backward has one implementation: both backward kernels and
+  no (L, L) intermediate, whatever a deleted switch says.
 """
 
 
@@ -24,36 +19,45 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops.attention import (_flash_remat_policy,
-                                             attention_blockwise,
+from analytics_zoo_tpu.ops.attention import (attention_blockwise,
                                              attention_reference,
                                              flash_attention,
                                              flash_attention_blhd)
-from analytics_zoo_tpu.ops.attn_smoke import jaxpr_materializes_lxl
-from analytics_zoo_tpu.utils.profiling import account_step, hlo_accountant
+from analytics_zoo_tpu.ops.kv_cache import _iter_eqns
 
 
 def _rand(key, shape):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32)
 
 
+def jaxpr_materializes_lxl(fn, *args, l=512):
+    """(an intermediate of ``fn``'s jaxpr has both trailing dims >= l, i.e.
+    an (..., L, L) score or probs tensor; a scan is present, the blockwise
+    route's signature; the ``name=`` of every Pallas kernel in it)."""
+    has_lxl = has_scan = False
+    kernels = []
+    for eqn in _iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name == "scan":
+            has_scan = True
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["name"])
+        for var in eqn.outvars:
+            shape = getattr(getattr(var, "aval", None), "shape", ())
+            if len(shape) >= 2 and shape[-1] >= l and shape[-2] >= l:
+                has_lxl = True
+    return has_lxl, has_scan, kernels
+
+
 # ---------------------------------------------------------------------------
-# dp shard_map blhd parity (fwd + bwd), remat hatch both ways
+# dp shard_map blhd parity (fwd + bwd)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("remat", ["save-lse-recompute-probs",
-                                   "full-residual"])
-def test_dp_shard_map_blhd_fwd_bwd_parity(monkeypatch, remat):
+def test_dp_shard_map_blhd_fwd_bwd_parity():
     """grads of the blhd route under a 2-device dp shard_map mesh must
-    match the reference oracle to < 1e-4, whichever backward remat
-    policy is selected."""
+    match the reference oracle to < 1e-4."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     from jax import shard_map
-
-    monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", remat)
-    assert _flash_remat_policy() == (
-        "lse" if remat.startswith("save") else "full")
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
     b, l, h, d = 4, 512, 4, 32
@@ -101,36 +105,24 @@ def test_blockwise_fallback_never_materializes_lxl(l):
         return jax.grad(lambda q: (attention_blockwise(q, k, v)
                                    ** 2).sum())(q)
 
-    lxl, scan = jaxpr_materializes_lxl(g, q, k, v, l=l)
+    lxl, scan, _ = jaxpr_materializes_lxl(g, q, k, v, l=l)
     assert not lxl
     assert scan
 
 
-def test_flash_ineligible_routes_to_blockwise_not_reference(monkeypatch):
+def test_flash_ineligible_routes_to_blockwise_not_reference():
     """On a backend the kernel declines, flash_attention must route to
-    the blockwise fallback (scan, no L x L); the reference stays
-    reachable only through the explicit env hatch — which the probe
-    must flag, proving it can tell the two apart."""
+    the blockwise fallback (scan, no L x L)."""
     l = 512
     q, k, v = (_rand(i, (1, 2, l, 32)) for i in range(3))
     kb = _rand(3, (1, 1, 1, l))
 
-    # a FRESH function object per probe: jax's trace cache is keyed on
-    # (fn, avals), so re-probing the same object after flipping the env
-    # hatch would return the stale route's jaxpr
-    def make_g():
-        def g(q, k, v, kb):
-            return jax.grad(lambda q: (flash_attention(q, k, v, bias=kb)
-                                       ** 2).sum())(q)
-        return g
+    def g(q, k, v, kb):
+        return jax.grad(lambda q: (flash_attention(q, k, v, bias=kb)
+                                   ** 2).sum())(q)
 
-    monkeypatch.delenv("ZOO_TPU_ATTN_FALLBACK", raising=False)
-    lxl, scan = jaxpr_materializes_lxl(make_g(), q, k, v, kb, l=l)
-    assert not lxl and scan
-
-    monkeypatch.setenv("ZOO_TPU_ATTN_FALLBACK", "reference")
-    lxl_ref, _ = jaxpr_materializes_lxl(make_g(), q, k, v, kb, l=l)
-    assert lxl_ref
+    lxl, scan, kernels = jaxpr_materializes_lxl(g, q, k, v, kb, l=l)
+    assert not lxl and scan and not kernels
 
 
 def test_blhd_ineligible_routes_to_blockwise():
@@ -141,98 +133,62 @@ def test_blhd_ineligible_routes_to_blockwise():
         return jax.grad(lambda ql: (flash_attention_blhd(ql, kl, vl)
                                     ** 2).sum())(ql)
 
-    lxl, scan = jaxpr_materializes_lxl(g, ql, kl, vl, l=l)
+    lxl, scan, _ = jaxpr_materializes_lxl(g, ql, kl, vl, l=l)
     assert not lxl and scan
 
 
 # ---------------------------------------------------------------------------
-# HLO accountant
+# the blockwise route against the oracle, every cotangent
 # ---------------------------------------------------------------------------
 
-SYNTH_HLO = """\
-HloModule synth
+@pytest.mark.parametrize("causal,with_bias", [(False, True), (True, False)])
+def test_blockwise_matches_the_oracle_at_512(causal, with_bias):
+    """Forward and the gradients of q, k, v and the key bias at L=512,
+    where the scan runs two blocks."""
+    l = 512
+    args = tuple(_rand(i, (2, 2, l, 32)) for i in range(3))
+    if with_bias:
+        args += (_rand(3, (2, 1, 1, l)),)
 
-ENTRY %main (a: f32[128,128], b: f32[128,128]) -> f32[128,128] {
-  %a = f32[128,128] parameter(0)
-  %b = f32[128,128] parameter(1)
-  %dot.1 = f32[128,128]{1,0} dot(f32[128,128] %a, f32[128,128] %b), metadata={op_name="jit(f)/attn_hot/dot"}
-  %transpose.2 = f32[128,128]{1,0} transpose(f32[128,128]{1,0} %dot.1), dimensions={1,0}, metadata={op_name="jit(f)/attn_hot/transpose"}
-  ROOT %add.3 = f32[128,128]{1,0} add(f32[128,128]{1,0} %transpose.2, f32[128,128] %b)
-}
-"""
+    def out_and_grads(f):
+        def run(*a):
+            loss = lambda *a: (f(*a, causal=causal) ** 2).sum()
+            return f(*a, causal=causal), jax.grad(
+                loss, argnums=tuple(range(len(a))))(*a)
+        return jax.jit(run)(*args)
 
-
-def test_hlo_accountant_synthetic_buckets():
-    acct = hlo_accountant(SYNTH_HLO)
-    # three counted ops, 64 KiB each: parameters are skipped
-    assert acct["total_bytes"] == 3 * 128 * 128 * 4
-    # fractions are rounded to 4 decimals by the accountant
-    assert acct["fractions"]["matmul"] == pytest.approx(1 / 3, abs=1e-3)
-    assert acct["fractions"]["relayout"] == pytest.approx(1 / 3, abs=1e-3)
-    assert acct["fractions"]["elementwise"] == pytest.approx(1 / 3,
-                                                            abs=1e-3)
-    assert acct["relayout_fraction"] == pytest.approx(1 / 3, abs=1e-3)
-    # the dot and the transpose carry the hot scope; only the transpose
-    # is a copy/transpose op
-    assert acct["hot_ops"] == 2
-    assert acct["hot_copy_transpose_ops"] == 1
-    assert "transpose.2" in acct["hot_copy_transpose_names"][0]
-
-
-def test_account_step_integration_buckets_matmul():
-    def f(a, b):
-        return jnp.tanh(a @ b)
-
-    a = _rand(0, (64, 64))
-    b = _rand(1, (64, 64))
-    acct = account_step(jax.jit(f), a, b)
-    assert acct["total_bytes"] > 0
-    # per-bucket fractions are individually rounded to 4 decimals
-    assert sum(acct["fractions"].values()) == pytest.approx(1.0, abs=1e-2)
-    # CPU XLA may lower f32 dots to a library custom-call ("other"); the
-    # dot must land in one of the two, never in relayout
-    assert (acct["buckets"].get("matmul", 0) +
-            acct["buckets"].get("other", 0)) > 0
-    assert 0.0 <= acct["relayout_fraction"] <= 1.0
-
-
-def test_attention_hot_path_has_zero_copy_transpose():
-    """The bench gate's invariant: every op tagged with the attn_hot
-    scope in the compiled grad step is compute, never a copy/transpose
-    relayout."""
-    q, k, v = (_rand(i, (1, 2, 512, 32)) for i in range(3))
-    g = jax.jit(jax.grad(lambda q, k, v: (flash_attention(q, k, v)
-                                          ** 2).sum(), argnums=(0, 1, 2)))
-    acct = account_step(g, q, k, v)
-    assert acct["hot_ops"] > 0
-    assert acct["hot_copy_transpose_ops"] == 0, \
-        acct["hot_copy_transpose_names"]
+    o, g = out_and_grads(attention_blockwise)
+    o_ref, g_ref = out_and_grads(attention_reference)
+    assert float(jnp.abs(o - o_ref).max()) < 2e-5
+    for a, b in zip(g, g_ref):
+        assert float(jnp.abs(a - b).max()) < 5e-4
 
 
 # ---------------------------------------------------------------------------
-# remat policy hatch resolution
+# the kernels' backward has one implementation
 # ---------------------------------------------------------------------------
 
-def test_flash_remat_policy_resolution(monkeypatch):
-    monkeypatch.delenv("ZOO_TPU_FLASH_REMAT", raising=False)
-    monkeypatch.delenv("ZOO_TPU_FLASH_BWD", raising=False)
-    assert _flash_remat_policy() == "lse"
-    monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", "full-residual")
-    assert _flash_remat_policy() == "full"
-    monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", "save-lse-recompute-probs")
-    assert _flash_remat_policy() == "lse"
-    monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", "bogus")
-    with pytest.raises(ValueError):
-        _flash_remat_policy()
+def test_the_backward_is_the_two_kernels_whatever_the_old_switches_say(
+        monkeypatch):
+    """A gradient through the kernel route holds the forward kernel and
+    both backward kernels, found by their ``name=``, and no (L, L)
+    intermediate; ``ZOO_TPU_FLASH_REMAT=full`` and ``ZOO_TPU_FLASH_BWD=xla``
+    once chose a second backward through the reference math, and are
+    names nothing reads now."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", "full")
+    monkeypatch.setenv("ZOO_TPU_FLASH_BWD", "xla")
+    l = 1024          # above the kernels' own 512 x 1024 score tile
+    q = _rand(0, (1, 4, l, 64))
+    k, v = (_rand(i, (1, 2, l, 64)) for i in (1, 2))     # grouped heads
+    kb = _rand(3, (1, 1, 1, l))
 
+    def g(q, k, v, kb):
+        return jax.grad(lambda q, k, v: (flash_attention(
+            q, k, v, bias=kb, causal=True) ** 2).sum(),
+            argnums=(0, 1, 2))(q, k, v)
 
-def test_flash_remat_policy_from_config(monkeypatch):
-    from analytics_zoo_tpu.common.nncontext import (ZooConfig, ZooContext,
-                                                    set_nncontext)
-
-    monkeypatch.delenv("ZOO_TPU_FLASH_REMAT", raising=False)
-    set_nncontext(ZooContext(ZooConfig(flash_remat="full-residual")))
-    try:
-        assert _flash_remat_policy() == "full"
-    finally:
-        set_nncontext(None)
+    lxl, scan, kernels = jaxpr_materializes_lxl(g, q, k, v, kb, l=l)
+    assert sorted(kernels) == ["zoo_flash_bwd_dkv", "zoo_flash_bwd_dq",
+                               "zoo_flash_fwd"]
+    assert not lxl and not scan

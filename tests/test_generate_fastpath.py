@@ -315,7 +315,7 @@ def test_stub_speculative_bit_exact_with_imperfect_draft():
 
 def test_stub_speculative_throughput_uplift():
     """With a cheap accurate draft, tokens/s must beat plain decode by
-    >= 1.5x (the bench gate, pinned here on deterministic costs)."""
+    >= 1.5x (pinned here on deterministic costs)."""
     reqs = lambda: [GenRequest(uri="r", prompt=np.array([100]),
                                max_new_tokens=40)]
     plain, _ = _drive(StubDecodeEngine(ms_per_step=2.0), reqs())
@@ -368,7 +368,7 @@ def test_stub_chunked_prefill_interleaves_decode():
 
 
 def test_stub_chunked_short_stream_gap_bounded():
-    """Quantitative interleave gate (mirrors the bench leg): p99
+    """Quantitative interleave gate: p99
     inter-token gap of the victim stream under a long chunked join
     stays within 1.5x its steady-state gap + one chunk's cost."""
     from analytics_zoo_tpu.utils import telemetry

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from analytics_zoo_tpu.ops import _route as R
 from analytics_zoo_tpu.ops import attention as A
 from analytics_zoo_tpu.ops import delta_rule
 from analytics_zoo_tpu.ops.delta_rule import chunk_gated_delta_rule
@@ -207,14 +208,14 @@ def test_delta_rule_route_is_static_and_fails_loudly_on_the_chip(
     monkeypatch.delenv("ZOO_TPU_DISABLE_PALLAS")
     monkeypatch.delenv("ZOO_TPU_PALLAS_INTERPRET")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: True)
+    monkeypatch.setattr(R, "mosaic_partition_ok", lambda: True)
     assert route(8192, 128, 128, 128)
     with pytest.raises(ValueError, match="chunk 64 is not 128"):
         route(8192, 64, 128, 128)
     with pytest.raises(ValueError, match="head sizes 64 and 128 are not"):
         route(8192, 128, 64, 128)
     assert not route(8191, 64, 128, 128)
-    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: False)
+    monkeypatch.setattr(R, "mosaic_partition_ok", lambda: False)
     with pytest.raises(ValueError, match="multi-device jit"):
         route(8192, 128, 128, 128)
     assert not route(4096, 128, 128, 128)
@@ -330,12 +331,12 @@ def test_a_long_shape_without_a_kernel_fails_loudly_on_the_chip(monkeypatch):
     kb = object()
     assert not A._route_eligible(True, kb, 8192, 8192, 96, True, 16, 2)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: True)
+    monkeypatch.setattr(R, "mosaic_partition_ok", lambda: True)
     with pytest.raises(ValueError, match="head size 96 is not a multiple"):
         A._route_eligible(True, kb, 8192, 8192, 96, True, 16, 2)
     with pytest.raises(ValueError, match="neither absent nor a key-padding"):
         A._route_eligible(True, None, 8192, 8192, 256, True, 16, 2)
-    monkeypatch.setattr(A, "mosaic_partition_ok", lambda: False)
+    monkeypatch.setattr(R, "mosaic_partition_ok", lambda: False)
     with pytest.raises(ValueError, match="multi-device jit"):
         A._route_eligible(True, kb, 8192, 8192, 256, True, 16, 2)
     assert not A._route_eligible(True, kb, 4096, 4096, 96, True, 16, 2)

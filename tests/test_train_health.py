@@ -2,15 +2,13 @@
 
 The detect→dump→halt ladder (pipeline/health.py) end-to-end through the
 REAL trainer with fault-injected NaNs, the EWMA spike math, the latch
-semantics, the HBM breakdown scalars in TrainSummary, the ``zoo-train``
-CLI view, and the bench-history regression reporter
-(scripts/bench-compare).
+semantics, the HBM breakdown scalars in TrainSummary, and the
+``zoo-train`` CLI view.
 """
 
 import glob
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -27,7 +25,6 @@ from analytics_zoo_tpu.pipeline.estimator.estimator import Estimator
 from analytics_zoo_tpu.utils import faults, memory, telemetry, tensorboard
 from analytics_zoo_tpu.utils.profiling import EwmaStd
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _ENV_KEYS = ("ZOO_TPU_TELEMETRY", "ZOO_TPU_TRACE_DIR",
              "ZOO_TPU_TELEMETRY_SERVICE")
@@ -300,71 +297,3 @@ def test_zoo_train_top_empty_dir(tmp_path, capsys):
     rc = train_cli.cmd_top(str(tmp_path), iterations=1)
     assert rc == 0
     assert "no TrainSummary events" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# bench history + scripts/bench-compare
-# ---------------------------------------------------------------------------
-
-BENCH_COMPARE = os.path.join(REPO, "scripts", "bench-compare")
-
-
-def _history(tmp_path, rows):
-    path = tmp_path / "hist.jsonl"
-    path.write_text("".join(json.dumps(r) + "\n" for r in rows))
-    return str(path)
-
-
-def test_bench_compare_flags_regressed_leg(tmp_path, run_python):
-    hist = _history(tmp_path, [
-        {"ts": 1, "iso_ts": "a", "gates_failed": [],
-         "metrics": {"ncf_steps_per_sec": 100.0, "serving_p99_ms": 20.0}},
-        {"ts": 2, "iso_ts": "b", "gates_failed": [],
-         "metrics": {"ncf_steps_per_sec": 50.0, "serving_p99_ms": 19.0}},
-    ])
-    proc = run_python(BENCH_COMPARE, "--history", hist)
-    assert proc.returncode == 0, proc.stderr
-    assert "REGRESSED" in proc.stdout
-    assert "ncf_steps_per_sec" in proc.stdout
-    # --strict turns the flag into a nonzero exit for CI
-    proc = run_python(BENCH_COMPARE, "--history", hist, "--strict")
-    assert proc.returncode == 1
-
-
-def test_bench_compare_clean_and_baseline(tmp_path, run_python):
-    hist = _history(tmp_path, [
-        {"ts": 2, "iso_ts": "b", "gates_failed": [],
-         "metrics": {"ncf_steps_per_sec": 99.0, "serving_p99_ms": 20.5}},
-    ])
-    # single row + --baseline snapshot (raw BENCH_*.json shape)
-    snap = tmp_path / "BENCH_base.json"
-    snap.write_text(json.dumps({"ncf_steps_per_sec": 100.0,
-                                "serving_p99_ms": 20.0,
-                                "bench_gates_failed": []}))
-    proc = run_python(BENCH_COMPARE, "--history", hist, "--baseline",
-                      str(snap), "--strict")
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "no regressions" in proc.stdout
-
-
-def test_bench_appends_history(tmp_path, monkeypatch):
-    """bench.py's _append_history writes one parseable row with the
-    scalar metrics and failed-gate names."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    monkeypatch.setattr(bench, "HISTORY_PATH",
-                        str(tmp_path / "BENCH_HISTORY.jsonl"))
-    monkeypatch.setattr(bench, "RESULT",
-                        {"platform": "cpu", "x_ms": 1.5, "ok": True,
-                         "note": "s"})
-    monkeypatch.setattr(bench, "GATE_FAILURES",
-                        [{"gate": "g", "detail": "d"}])
-    bench._append_history()
-    rows = [json.loads(l) for l in
-            open(tmp_path / "BENCH_HISTORY.jsonl")]
-    assert len(rows) == 1
-    assert rows[0]["metrics"] == {"x_ms": 1.5}   # bools/strings excluded
-    assert rows[0]["gates_failed"] == ["g"]
