@@ -30,18 +30,29 @@ what the MXU multiplies in anyway, and accumulated in float32. The chunk
 is 128 positions: the tiles are then whole (8, 128) float32 tiles and the
 sequential part is half as long as at 64.
 
-The loop over chunks has two carriers of one :func:`_step`. On a TPU
-backend, at chunk 128 and head sizes that are multiples of 128
-(:func:`_kernel_route`: static, by shape and context, loud at 8,192), it
-is a pair of Pallas kernels under one ``custom_vjp``: the grid walks the
-chunks of a block of heads with the float32 states in VMEM, so a state
-never makes the round trip to HBM between chunks; the forward kernel
-writes the state at each chunk's start, and the backward kernel walks the
-chunks from the last to the first with the state's cotangent in VMEM,
-recomputing ``U``. Anywhere else it is ``lax.scan`` and JAX's own
-backward. The chunk-local part is XLA's and differentiated by JAX on both
-routes. The whole op sits under the HLO scope ``zoo_gdn_scan``, the
-kernels inside it under ``zoo_gdn_scan_fwd`` and ``zoo_gdn_scan_bwd``.
+The op has two carriers, chosen once from the shape and the backend
+(:func:`_kernel_route`: static, loud at 8,192). On a TPU backend, at chunk
+128 and head sizes that are multiples of 128, it is four Pallas kernels
+under two ``custom_vjp`` rules, and XLA keeps only the cumulated sum of
+``g`` and the last reshape. The chunk-local pair (``zoo_gdn_local_fwd``,
+``zoo_gdn_local_bwd``; grid head block x sequence x chunk, all parallel)
+reads q, k, v where the layer has them, (B, L, heads x size), and holds
+every C x C tile in VMEM: the decay tile, ``K K^T`` and ``Q K^T``, ``A``,
+the inverse (:func:`_tile_inverse`: the scheme above on tiles that stay
+where they are, the rank-one updates on the sublanes, the doubling on the
+MXU in float32) and ``W = T [beta V | beta gamma K]``; none of them, nor a
+level of the inverse, reaches HBM. Its backward kernel recomputes them and
+is products only: ``dR = T^T dW``, ``dA = -tril(dR W^T, -1)``. The loop's
+pair (``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``) walks the chunks of a
+block of heads with the float32 states in VMEM, so a state never makes the
+round trip to HBM between chunks; the forward kernel writes the state at
+each chunk's start, and the backward kernel walks the chunks from the last
+to the first with the state's cotangent in VMEM, recomputing ``U``.
+Anywhere else (the CPU, another chunk, head sizes like the tests' 16) the
+chunk-local part is :func:`_chunk_local`, XLA-built over a few heads at a
+time and differentiated by JAX, and the loop is ``lax.scan`` over the same
+:func:`_step`. The whole op sits under the HLO scope ``zoo_gdn_scan``, each
+kernel inside it under its own name.
 """
 
 from __future__ import annotations
@@ -256,15 +267,19 @@ def _scan_call(kernel, name, operands, outs, state, reverse):
         return call(*operands)
 
 
+def _most_dividing(n, most):
+    """The largest of 1..``most`` that divides ``n``."""
+    return max(h for h in range(1, most + 1) if n % h == 0)
+
+
 def _state_block(w_v, w_k):
     """(heads, dk, dv) of the states one grid step carries: the most heads,
     up to ``HEAD_BLOCK`` of two-byte operands, that divide the head count.
     One head's 128-wide products do not cover a grid step's overhead, and
     the backward kernel's blocks of eight with their double buffers fill
     most of the 16 MB of VMEM a kernel may use."""
-    n, most = w_k.shape[0], max(1, HEAD_BLOCK * 2 // w_k.dtype.itemsize)
-    return (max(h for h in range(1, most + 1) if n % h == 0),
-            w_k.shape[-1], w_v.shape[-1])
+    most = max(1, HEAD_BLOCK * 2 // w_k.dtype.itemsize)
+    return _most_dividing(w_k.shape[0], most), w_k.shape[-1], w_v.shape[-1]
 
 
 def _lanes(g_end, dv):
@@ -312,41 +327,344 @@ def _scan_kernels_bwd(res, do):
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
+# -- the chunk-local part as two kernels ------------------------------------
+# Grid (head block, sequence, chunk), every axis parallel: a grid step takes
+# the chunk's q, k, v where the caller has them, (B, L, heads x size) with a
+# head a 128-aligned lane slice of the block, and the cumulated log decay
+# and beta of its heads as the rows of one (2 x heads, C) tile. Every C x C
+# tile (decay, scores, ``A``, the inverse and its levels) lives in VMEM and
+# none reaches HBM; the outputs land where the loop's kernels index them,
+# (n, B, Nc, C, .). The backward kernel recomputes the tiles and the
+# inverse and needs no derivative of the inversion scheme: with ``R = [beta
+# V | beta gamma K]`` and ``W = T R``, ``dR = T^T dW`` and ``dA = -tril(dR
+# W^T, -1)``, products only.
+
+def _dot(a, b, ca, cb, exact=False):
+    """The (heads, rows, columns) stacks ``a`` and ``b`` multiplied head by
+    head, ``a``'s matrix axis ``ca`` contracted with ``b``'s ``cb``, float32
+    out; a ``b`` of two axes is shared by the heads. ``exact``: float32
+    operands at float32 accuracy, what ``precision=HIGHEST`` is to XLA
+    (Mosaic's default would round them to bfloat16); else the operands'
+    dtype into the MXU as it is."""
+    batch = ((0,), (0,)) if b.ndim == 3 else ((), ())
+    return jax.lax.dot_general(
+        a, b, (((1 + ca,), (b.ndim - 2 + cb,)), batch),
+        preferred_element_type=jnp.float32,
+        precision=HIGHEST if exact else None)
+
+
+def _tile_iota(c):
+    return (jax.lax.broadcasted_iota(jnp.int32, (c, c), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (c, c), 1))
+
+
+def _dot_exact(a, b):
+    """``a b`` at float32 accuracy, head by head or with one ``b`` for all,
+    for a float32 ``a`` and a ``b`` that bfloat16 holds exactly: ``a`` is
+    split into three bfloat16 terms, stacked along its rows so that ``b``
+    goes into the MXU once, and the three products are added. Half the
+    passes of ``exact=True``, which splits both sides, and what any other
+    ``b`` gets."""
+    if b.dtype != jnp.bfloat16:
+        return _dot(a, b.astype(a.dtype), 1, 0, True)
+    m, terms, rest = a.shape[1], [], a
+    for _ in range(3):
+        terms.append(rest.astype(b.dtype))
+        rest = rest - terms[-1].astype(a.dtype)
+    out = _dot(jnp.concatenate(terms, 1), b, 1, 0)
+    return out[:, :m] + out[:, m:2 * m] + out[:, 2 * m:]
+
+
+def _tile_inverse(a):
+    """``(I + a)^-1`` of strictly lower-triangular (heads, C, C) float32
+    tiles, the scheme of :func:`unit_lower_inverse` on tiles that stay
+    where they are: the 16 x 16 diagonal blocks by forward substitution,
+    column by column (15 rank-one updates, all blocks of all heads at once,
+    on the sublanes), then doubled up, ``[[P, 0], [-R A21 P, R]]``, with
+    the blocks' columns picked by masks and their rows by slices of whole
+    sublane tiles: ``X_low (A_low . M_s) X`` puts ``R A21 P`` where it
+    belongs because ``X`` is block-diagonal."""
+    n, c, _ = a.shape
+    row, col = _tile_iota(c)
+    shift = BASE.bit_length() - 1
+    # every diagonal block's columns, repeated along the lanes: lane j of
+    # row r holds a[r, 16 (r // 16) + j % 16]
+    d = _dot_exact(jnp.where(row >> shift == col >> shift, a, 0.0),
+                   ((row ^ col) & (BASE - 1) == 0).astype(jnp.bfloat16))
+    d = d.reshape(n, c // BASE, BASE, c)
+    x = jnp.broadcast_to((row == col).astype(a.dtype), a.shape).reshape(
+        d.shape)
+    for j in range(BASE - 1):
+        # rows up to j of a block are done: from row 8 on, its lower half
+        lo = 8 * (j // 8)
+        new = x[:, :, lo:] - d[:, :, lo:, j:j + 1] * x[:, :, j:j + 1, :]
+        x = jnp.concatenate([x[:, :, :lo], new], 2) if lo else new
+    x, s = x.reshape(a.shape), BASE
+    half = [jax.lax.broadcasted_iota(jnp.int32, (c // 2, c), i) for i in (0, 1)]
+    while s < c:                                  # s -> 2s
+        low = lambda t: jnp.concatenate(          # the rows of the lower
+            [t[..., r:r + s, :] for r in range(s, c, 2 * s)], -2)  # blocks
+        # row i of those is in block 2 (i // s) + 1: A21 is the block before
+        a21 = jnp.where(half[1] >> shift == half[0] >> shift << 1, low(a), 0.0)
+        y, zero = _dot(a21, x, 1, 0, True), jnp.zeros((n, s, c), a.dtype)
+        y = jnp.concatenate([t for r in range(0, c // 2, s)
+                             for t in (zero, y[:, r:r + s])], 1)
+        z = low(x) - _dot(low(x), y, 1, 0, True)
+        x = jnp.concatenate([t for r in range(0, c // 2, s) for t in (
+            x[:, 2 * r:2 * r + s], z[:, r:r + s])], 1)
+        s, shift = 2 * s, shift + 1
+    return x
+
+
+GROUP = 4
+
+
+def _local_operands(q_ref, k_ref, v_ref, gb_ref, hs, hb, dk, dv):
+    """The operands of heads ``hs`` of a grid step's ``hb`` as stacks: q,
+    k (heads, C, dk) and v (heads, C, dv) from the heads' lane slices; the
+    cumulated log decay as columns (heads, C, 1) and as rows (heads, 1,
+    C), beta as columns and as rows."""
+    heads = lambda f: jnp.stack([f(h) for h in hs])
+    gb = gb_ref[...]                              # (2 hb, C): rows
+    cols = gb.T                                   # (C, 2 hb): columns
+    return (heads(lambda h: q_ref[:, h * dk:(h + 1) * dk]),
+            heads(lambda h: k_ref[:, h * dk:(h + 1) * dk]),
+            heads(lambda h: v_ref[:, h * dv:(h + 1) * dv]),
+            heads(lambda h: cols[:, h:h + 1]), heads(lambda h: gb[h:h + 1]),
+            heads(lambda h: cols[:, hb + h:hb + h + 1]),
+            heads(lambda h: gb[hb + h:hb + h + 1]))
+
+
+def _local_tiles(k, gcol, grow, bcol):
+    """What both chunk-local kernels start from: the decay tiles, ``K
+    K^T``, ``A``'s inverse and the mask it was cut with."""
+    row, col = _tile_iota(k.shape[1])
+    # exp of a difference that is <= 0 wherever it is kept: no overflow
+    decay = jnp.exp(jnp.where(row >= col, gcol - grow, -jnp.inf))
+    kk = _dot(k, k, 1, 1)
+    strict = row > col
+    return decay, kk, strict, _tile_inverse(
+        jnp.where(strict, kk * decay * bcol, 0.0))
+
+
+def _written(t, k, v, brow, grow):
+    """``W = T [beta V | beta gamma K]`` in float32, with the gates on
+    ``T``'s columns so that v and k go into the product as they are."""
+    return (_dot_exact(t * brow, v),
+            _dot_exact(t * (brow * jnp.exp(grow)), k))
+
+
+def _head_groups(hb):
+    """The heads of a grid step a few at a time, stacked: independent
+    products side by side keep the four MXUs fed, where one head's chain
+    of dependent ones would use them in turn."""
+    g = _most_dividing(hb, GROUP)
+    return [range(h, h + g) for h in range(0, hb, g)]
+
+
+def _local_fwd_kernel(q_ref, k_ref, v_ref, gb_ref, wv_ref, wk_ref, qk_ref,
+                      qin_ref, kout_ref):
+    hb, c, dk = wk_ref.shape
+    dv, mm = wv_ref.shape[-1], wk_ref.dtype
+    for hs in _head_groups(hb):
+        q, k, v, gcol, grow, bcol, brow = _local_operands(
+            q_ref, k_ref, v_ref, gb_ref, hs, hb, dk, dv)
+        decay, _, _, t = _local_tiles(k, gcol, grow, bcol)
+        gamma = jnp.exp(gcol)
+        at = slice(hs[0], hs[-1] + 1)
+        w_v, w_k = _written(t, k, v, brow, grow)
+        wv_ref[at], wk_ref[at] = w_v, w_k.astype(mm)
+        qk_ref[at] = (_dot(q, k, 1, 1) * decay).astype(mm)
+        qin_ref[at] = (q * gamma).astype(mm)
+        kout_ref[at] = (k * jnp.exp(gcol[:, c - 1:] - gcol)).astype(mm)
+
+
+def _local_bwd_kernel(q_ref, k_ref, v_ref, gb_ref, dwv_ref, dwk_ref, dqk_ref,
+                      dqin_ref, dkout_ref, dq_ref, dk_ref, dv_ref, dgb_ref,
+                      col_scr):
+    """``_local_fwd_kernel`` transposed. Products as forward: float32
+    accuracy wherever the inverse is a factor, else operands in their
+    dtype and float32 accumulation. The gates' cotangents that come out as
+    columns are gathered in ``col_scr`` and transposed once, to rows."""
+    hb, c, dk = dwk_ref.shape
+    dv, f32, mm = dwv_ref.shape[-1], jnp.float32, dwk_ref.dtype
+    lanes = lambda x: jnp.sum(x, axis=-1, keepdims=True)        # (., C, 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    for hs in _head_groups(hb):
+        q, k, v, gcol, grow, bcol, brow = _local_operands(
+            q_ref, k_ref, v_ref, gb_ref, hs, hb, dk, dv)
+        decay, kk, strict, t = _local_tiles(k, gcol, grow, bcol)
+        gamma = jnp.exp(gcol)
+        bg = bcol * gamma
+        at = slice(hs[0], hs[-1] + 1)
+        w_v, w_k = _written(t, k, v, brow, grow)
+        tt = jnp.swapaxes(t, 1, 2)
+        dr_v = _dot(tt, dwv_ref[at], 1, 0, True)                # T^T dW
+        dr_k = _dot_exact(tt, dwk_ref[at])
+        da = jnp.where(strict, -_dot(
+            jnp.concatenate([dr_v, dr_k], 2),
+            jnp.concatenate([w_v, w_k], 2), 1, 1, True), 0.0)
+        dqk = dqk_ref[at].astype(f32)
+        dscore = (dqk * decay).astype(mm)         # decay: nought above
+        dkk = (da * decay * bcol).astype(mm)
+        dak = da * kk * decay
+        # through the decay tile, exp(gc_i - gc_j): rows add, columns take
+        e = dak * bcol + dqk * _dot(q, k, 1, 1) * decay
+        dq_in, dk_out = dqin_ref[at].astype(f32), dkout_ref[at].astype(f32)
+        out = jnp.exp(gcol[:, c - 1:] - gcol)
+        ko = dk_out * out
+        dq = (_dot(dscore, k, 1, 0) + dq_in * gamma).astype(mm)
+        dk_ = (_dot(dscore, q, 0, 0) + _dot(dkk, k, 1, 0) +
+               _dot(dkk, k, 0, 0) + bg * dr_k + ko).astype(mm)
+        dv_ = (bcol * dr_v).astype(mm)
+        # k_out = k exp(gc_last - gc): each row takes, the last adds all
+        dgcol = lanes(e) + lanes(k * (bg * dr_k - ko) + dq_in * q * gamma) + \
+            jnp.where(last, lanes(jnp.sum(ko * k, axis=1, keepdims=True)), 0.0)
+        dbcol = lanes(dr_v * v) + lanes(dr_k * k * gamma) + lanes(dak)
+        dgrow = -jnp.sum(e, axis=1, keepdims=True)              # (., 1, C)
+        for i, h in enumerate(hs):
+            dq_ref[:, h * dk:(h + 1) * dk] = dq[i]
+            dk_ref[:, h * dk:(h + 1) * dk] = dk_[i]
+            dv_ref[:, h * dv:(h + 1) * dv] = dv_[i]
+            col_scr[:, h:h + 1] = dgcol[i]
+            col_scr[:, hb + h:hb + h + 1] = dbcol[i]
+            dgb_ref[h:h + 1] = dgrow[i]
+    dgb_ref[hb:] = jnp.zeros((hb, c), f32)
+    dgb_ref[...] += col_scr[...].T[:2 * hb]
+
+
+def _local_call(kernel, name, q, k, v, gb, tiles, outs, scratch=()):
+    """``kernel`` over (head block, sequence, chunk), all parallel. ``q``,
+    ``k``, ``v``: (B, L, n, size), read as (B, L, n x size) in blocks of a
+    chunk by a head block's lanes; ``gb``: (head blocks, B, Nc, 2 x heads a
+    block, C); ``tiles``: (n, B, Nc, C, .), where the loop's kernels read
+    them. ``outs`` names each output by the operand it is shaped and
+    blocked like, with its dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, b, nc, hb2, c = gb.shape
+    hb = hb2 // 2
+    flat = lambda t: t.reshape(t.shape[:2] + (-1,)) if t.ndim == 4 else t
+
+    def spec(t):
+        if t.ndim == 4:
+            return pl.BlockSpec((None, c, hb * t.shape[3]),
+                                lambda i, j, m: (j, m, i))
+        lead = (None,) if t is gb else (hb,)
+        return pl.BlockSpec(lead + (None, None) + t.shape[3:],
+                            lambda i, j, m: (i, j, m, 0, 0))
+
+    operands = (q, k, v, gb) + tuple(tiles)
+    call = pl.pallas_call(
+        kernel,
+        name=name,
+        grid=(nb, b, nc),
+        in_specs=[spec(t) for t in operands],
+        out_specs=[spec(t) for t, _ in outs],
+        out_shape=[out_struct(flat(t).shape, dtype, *operands)
+                   for t, dtype in outs],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=_route.interpret_mode(),
+    )
+    with jax.named_scope(name):
+        return [o.reshape(t.shape) for o, (t, _) in zip(
+            call(*(flat(t) for t in operands)), outs)]
+
+
+def _gate_rows(gc, beta):
+    """The cumulated log decay and beta, (n, B, Nc, C) each, as the rows
+    the chunk-local kernels read: (head blocks, B, Nc, 2 x heads a block,
+    C), a block's decays above its betas. A head block is the most heads
+    up to ``HEAD_BLOCK`` that divide the head count."""
+    n = gc.shape[0]
+    hb = _most_dividing(n, HEAD_BLOCK)
+    blocks = lambda t: jnp.moveaxis(
+        t.reshape((n // hb, hb) + t.shape[1:]), 1, 3)
+    return jnp.concatenate([blocks(gc), blocks(beta)], 3)
+
+
+def _gate_columns(gb):
+    """``_gate_rows`` back: (n, B, Nc, C) twice."""
+    nb, b, nc, hb2, c = gb.shape
+    heads = lambda t: jnp.moveaxis(t, 3, 1).reshape(-1, b, nc, c)
+    return heads(gb[:, :, :, :hb2 // 2]), heads(gb[:, :, :, hb2 // 2:])
+
+
+def _local_forward(q, k, v, gb):
+    b, l, n, dk = q.shape
+    dv, c, mm, f32 = v.shape[-1], gb.shape[-1], v.dtype, jnp.float32
+    tile = lambda cols, dtype: (jax.ShapeDtypeStruct(
+        (n, b, l // c, c, cols), dtype), dtype)
+    return tuple(_local_call(
+        _local_fwd_kernel, "zoo_gdn_local_fwd", q, k, v, gb, (),
+        [tile(dv, f32), tile(dk, mm), tile(c, mm), tile(dk, mm),
+         tile(dk, mm)]))
+
+
+@jax.custom_vjp
+def _local_kernels(q, k, v, gc, beta):
+    """``_chunk_local``'s first five outputs from q, k, v (B, L, n, .) in
+    one dtype, L whole chunks, and the cumulated log decay and beta (n, B,
+    Nc, C) in float32."""
+    return _local_forward(q, k, v, _gate_rows(gc, beta))
+
+
+def _local_kernels_fwd(q, k, v, gc, beta):
+    gb = _gate_rows(gc, beta)
+    return _local_forward(q, k, v, gb), (q, k, v, gb)
+
+
+def _local_kernels_bwd(res, cts):
+    q, k, v, gb = res
+    with jax.named_scope("zoo_gdn_scan"):         # as ``_scan_kernels_bwd``
+        dq, dk, dv, dgb = _local_call(
+            _local_bwd_kernel, "zoo_gdn_local_bwd", q, k, v, gb, cts,
+            [(t, t.dtype) for t in res], scratch=[(gb.shape[-1],) * 2])
+        return (dq, dk, dv) + _gate_columns(dgb)
+
+
+_local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
+
+
 def _chunked(q, k, v, g, beta, c):
     b, l, n, dk = q.shape
     dv = v.shape[-1]
-    f32 = jnp.float32
+    f32, mm = jnp.float32, v.dtype                        # MXU operands
     pad = (-l) % c
     nc = (l + pad) // c
+    padded = lambda t, dtype: jnp.pad(
+        t.astype(dtype), ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
 
     def chunks(t, dtype):
         """(B, L, n, ...) -> (n, B, Nc, C, ...)."""
-        t = jnp.pad(t.astype(dtype), ((0, 0), (0, pad)) + ((0, 0),) *
-                    (t.ndim - 2))
-        t = t.reshape((b, nc, c) + t.shape[2:])
-        return jnp.moveaxis(t, 3, 0)
+        t = padded(t, dtype)
+        return jnp.moveaxis(t.reshape((b, nc, c) + t.shape[2:]), 3, 0)
 
-    mm = v.dtype                                          # MXU operands
-    local = (chunks(q, mm), chunks(k, mm), chunks(v, mm), chunks(g, f32),
-             chunks(beta, f32))
-    hb = HEAD_BLOCK if n % HEAD_BLOCK == 0 else n
-    # a few heads at a time and recomputed in the backward pass: the
-    # C x C tiles and the levels of the inverse, several times the
-    # operands' size, then exist for those heads only
-    split = lambda t: t.reshape((n // hb, hb) + t.shape[1:])
-    join = lambda t: t.reshape((n,) + t.shape[2:])
-    w_v, w_k, qk, q_in, k_out, g_end = (join(t) for t in jax.lax.map(
-        jax.checkpoint(lambda xs: _chunk_local(*xs)),
-        tuple(split(t) for t in local)))
-
-    xs = (w_v, w_k, qk, q_in, k_out, g_end[..., None, None])
     if _kernel_route(l, c, dk, dv):
-        o = _scan_kernels(*xs)                            # (n, B, Nc, C, dv)
+        gc = jnp.cumsum(chunks(g, f32), axis=-1)
+        xs = _local_kernels(padded(q, mm), padded(k, mm), padded(v, mm), gc,
+                            chunks(beta, f32))
+        o = _scan_kernels(*xs, jnp.exp(gc[..., -1])[..., None, None])
     else:
+        local = (chunks(q, mm), chunks(k, mm), chunks(v, mm),
+                 chunks(g, f32), chunks(beta, f32))
+        hb = HEAD_BLOCK if n % HEAD_BLOCK == 0 else n
+        # a few heads at a time and recomputed in the backward pass: the
+        # C x C tiles and the levels of the inverse, several times the
+        # operands' size, then exist for those heads only
+        split = lambda t: t.reshape((n // hb, hb) + t.shape[1:])
+        join = lambda t: t.reshape((n,) + t.shape[2:])
+        *xs, g_end = (join(t) for t in jax.lax.map(
+            jax.checkpoint(lambda xs: _chunk_local(*xs)),
+            tuple(split(t) for t in local)))
         time_first = lambda t: jnp.moveaxis(t, 2, 0)      # the chunk axis
-        _, o = jax.lax.scan(_step, jnp.zeros((n, b, dk, dv), f32),
-                            tuple(time_first(t) for t in xs))
-        o = jnp.moveaxis(o, 0, 2)
+        _, o = jax.lax.scan(
+            _step, jnp.zeros((n, b, dk, dv), f32),
+            tuple(time_first(t) for t in (*xs, g_end[..., None, None])))
+        o = jnp.moveaxis(o, 0, 2)                 # (n, B, Nc, C, dv)
     # (n, B, Nc, C, dv) -> (B, L, n, dv)
     o = o.reshape(n, b, nc * c, dv)[:, :, :l]
     return jnp.moveaxis(o, 0, 2).astype(v.dtype)

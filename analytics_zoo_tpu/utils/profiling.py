@@ -288,8 +288,9 @@ def mosaic_kernel_counts(hlo) -> dict:
     ``jax.named_scope`` stack at the call site. Every ``pallas_call`` in
     ``ops/`` therefore sits in a ``zoo_*`` scope (``zoo_flash_fwd``,
     ``zoo_flash_bwd_dq``, ``zoo_flash_bwd_dkv``, ``zoo_dln_fwd``,
-    ``zoo_dln_bwd``, ``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``), and the
-    innermost such scope is the tag. A custom
+    ``zoo_dln_bwd``, ``zoo_gdn_local_fwd``, ``zoo_gdn_local_bwd``,
+    ``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``), and the innermost such
+    scope is the tag. A custom
     call outside any ``zoo_*`` scope counts under ``"untagged"``.
 
     ``hlo``: HLO text (``compiled.as_text()``) or an object with

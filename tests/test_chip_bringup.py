@@ -108,10 +108,11 @@ def test_grouped_wide_heads_cross_lower_at_the_hybrid_cells_shape(on_tpu):
 
 def test_delta_rule_kernels_cross_lower_at_the_hybrid_cells_shape(on_tpu):
     """A DeltaNet block of `qwen3next_pretrain_l8192`: one sequence of
-    8,192, 32 heads of 128 by 128, 64 chunks. Both kernels lower, their
-    blocks on the arrays where the chunk-local part leaves them, and each
-    call's name carries ``zoo_gdn_scan`` (what the benchmark's scope
-    metrics match) around its own tag."""
+    8,192, 32 heads of 128 by 128, 64 chunks. All four kernels lower: the
+    chunk-local pair reading q, k, v where the layer has them and writing
+    where the loop's pair reads, and each call's name carries
+    ``zoo_gdn_scan`` (what the benchmark's scope metrics match) around
+    its own tag. Nothing of the op loops outside a kernel."""
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
 
     s = jax.ShapeDtypeStruct
@@ -123,17 +124,20 @@ def test_delta_rule_kernels_cross_lower_at_the_hybrid_cells_shape(on_tpu):
 
     mlir = _tpu_lowered(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                         x, x, x, gate, gate)
-    assert _kernel_names(mlir) == ["zoo_gdn_scan_bwd", "zoo_gdn_scan_fwd"]
-    assert "32x1x64x128x128xbf16" in mlir and "stablehlo.while" in mlir
+    assert _kernel_names(mlir) == ["zoo_gdn_local_bwd", "zoo_gdn_local_fwd",
+                                   "zoo_gdn_scan_bwd", "zoo_gdn_scan_fwd"]
+    assert "1x8192x4096xbf16" in mlir and "32x1x64x128x128xbf16" in mlir
+    assert "stablehlo.while" not in mlir
     sites = _call_sites(mlir)
     assert mosaic_kernel_counts("\n".join(sites)) == {
-        "zoo_gdn_scan_fwd": 1, "zoo_gdn_scan_bwd": 1}
+        "zoo_gdn_local_fwd": 1, "zoo_gdn_scan_fwd": 1,
+        "zoo_gdn_scan_bwd": 1, "zoo_gdn_local_bwd": 1}
     assert all("zoo_gdn_scan" in re.findall(r"zoo_[a-z0-9_]+", site)
                for site in sites)
-    # the scan is the other route, and only the chunk-local map loops
     forward = _tpu_mlir(lambda *a: G.chunk_gated_delta_rule(*a),
                         x, x, x, gate, gate)
-    assert forward.count("stablehlo.while") == 1
+    assert _kernel_names(forward) == ["zoo_gdn_local_fwd", "zoo_gdn_scan_fwd"]
+    assert "stablehlo.while" not in forward
 
 
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):

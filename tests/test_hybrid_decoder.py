@@ -159,25 +159,120 @@ def test_a_bfloat16_state_or_a_missing_decay_is_caught(monkeypatch, route):
                theirs) > 2.9
 
 
+def _chunked_inputs(args, c=128):
+    """``gdn_inputs`` as ``_chunked`` hands them on: the length padded to
+    whole chunks, and the chunked view (n, B, Nc, C, ...)."""
+    b, l = args[0].shape[:2]
+    pad = (-l) % c
+    padded = [jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+              for t in args]
+    chunks = lambda t: jnp.moveaxis(
+        t.reshape((b, (l + pad) // c, c) + t.shape[2:]), 3, 0)
+    return padded, chunks
+
+
+@pytest.mark.parametrize("heads,length", [(8, 300), (3, 512), (16, 128)])
+def test_chunk_local_kernels_give_the_chunk_local_part(monkeypatch, heads,
+                                                       length):
+    """``zoo_gdn_local_fwd`` in interpret mode against ``_chunk_local``, the
+    XLA carrier of the same six outputs: a head count that fills a head
+    block, one that does not, two head blocks; a padded tail. And its
+    backward kernel against JAX's derivative of the carrier, by every
+    operand, under random cotangents of all six."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    (q, k, v, g, beta), chunks = _chunked_inputs(
+        gdn_inputs(l=length, n=heads, dk=128, dv=128))
+
+    def carrier(q, k, v, g, beta):
+        return delta_rule._chunk_local(*(chunks(t) for t in (
+            q, k, v, g, beta)))
+
+    def kernels(q, k, v, g, beta):
+        gc = jnp.cumsum(chunks(g), -1)
+        return delta_rule._local_kernels(q, k, v, gc, chunks(beta)) + (
+            jnp.exp(gc[..., -1]),)
+
+    theirs = jax.jit(carrier)(q, k, v, g, beta)
+    cos = [x_of(t.shape, 20 + i) for i, t in enumerate(theirs)]
+    both = lambda f: jax.jit(lambda *a: (f(*a), jax.grad(
+        lambda *a: sum(jnp.sum(o * co) for o, co in zip(f(*a), cos)),
+        argnums=(0, 1, 2, 3, 4))(*a)))(q, k, v, g, beta)
+    (ours, g_ours), (theirs, g_theirs) = both(kernels), both(carrier)
+    assert len(ours) == 6
+    for a, b in zip(ours, theirs):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert float(jnp.abs(a - b).max()) < FWD * float(jnp.abs(b).max())
+    assert max(rel(a, b) for a, b in zip(g_ours, g_theirs)) < GRAD
+
+
+def test_chunk_local_kernels_keep_float32_where_bfloat16_goes_in(
+        monkeypatch):
+    """bfloat16 q, k, v, the cell's dtype: the kernel then multiplies the
+    inverse with v and k as they are and splits only its own side into
+    three bfloat16 terms. ``W_v`` stays float32 and within float32
+    round-off of the carrier's ``precision=HIGHEST`` product on the same
+    operands; a product that rounded the inverse to bfloat16 would miss by
+    a thousand times that. The other outputs are bfloat16 on both sides."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    (q, k, v, g, beta), chunks = _chunked_inputs(
+        gdn_inputs(l=128, n=4, dk=128, dv=128))
+    q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    theirs = jax.jit(lambda *a: delta_rule._chunk_local(*(
+        chunks(t) for t in a)))(q, k, v, g, beta)
+    ours = jax.jit(lambda q, k, v, g, beta: delta_rule._local_kernels(
+        q, k, v, jnp.cumsum(chunks(g), -1), chunks(beta)))(q, k, v, g, beta)
+    assert ours[0].dtype == jnp.float32 and rel(ours[0], theirs[0]) < 1e-6
+    for a, b in zip(ours[1:], theirs[1:]):
+        assert a.dtype == jnp.bfloat16 and rel(a, b) < 2 ** -9
+
+
+def test_the_inverse_in_vmem_is_the_block_inverse(monkeypatch):
+    """Where the inverse's entries are hardest to get: beta = 1, g = 0 and
+    one key repeated through each chunk, so every entry of ``A`` under the
+    diagonal is 1 and a power series would cancel thousands against one
+    another. With v the identity the kernel's ``W_v`` is the inverse
+    itself. And random tiles, against ``unit_lower_inverse`` both."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    c, n = 128, 2
+    k = jnp.broadcast_to(gdn_inputs(l=1, n=n, dk=c)[1], (1, c, n, c))
+    v = jnp.broadcast_to(jnp.eye(c)[None, :, None], (1, c, n, c))
+    zero = jnp.zeros((n, 1, 1, c))
+    t = jax.jit(delta_rule._local_kernels)(k, k, v, zero, 1.0 + zero)[0]
+    a = jnp.tril(jnp.einsum("lnd,mnd->nlm", k[0], k[0]), -1)
+    assert float(jnp.abs(a - jnp.tril(jnp.ones((c, c)), -1)).max()) < 1e-5
+    rand = 0.2 * jnp.tril(x_of((3, c, c), 5), -1)
+    block = jax.jit(delta_rule.unit_lower_inverse)
+    for ours, a in ((t[:, 0, 0], a),
+                    (jax.jit(delta_rule._tile_inverse)(rand), rand)):
+        np.testing.assert_allclose(ours, block(a), atol=2e-5)
+        np.testing.assert_allclose(ours @ (a + jnp.eye(c)), jnp.broadcast_to(
+            jnp.eye(c), a.shape), atol=2e-5)
+
+
 @pytest.mark.parametrize("heads,length", [(8, 300), (3, 512)])
 def test_delta_rule_kernels_are_the_recurrence(monkeypatch, heads, length):
-    """The loop over chunks as the two Pallas kernels, in interpret mode,
-    at the head sizes they take: a full block of heads with a padded tail,
-    and a head count the block does not divide with whole chunks. Forward
-    and all five gradients against one position at a time, and equal to
-    the scan route (the same ``_step``; the backward by hand against
-    JAX's) to float32 round-off."""
+    """The whole op as its four Pallas kernels, in interpret mode, at the
+    head sizes they take: a full block of heads with a padded tail, and a
+    head count the block does not divide with whole chunks. Forward and
+    all five gradients against one position at a time, and equal to the
+    XLA route (the same ``_step`` and the same block inverse, in another
+    order; the backward by hand against JAX's) to float32 round-off."""
     monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
     args = gdn_inputs(l=length, n=heads, dk=128, dv=128)
     co = x_of(args[2].shape, 9)
     calls = []
-    real = delta_rule._scan_call
+    real, real_local = delta_rule._scan_call, delta_rule._local_call
     monkeypatch.setattr(delta_rule, "_scan_call", lambda *a: calls.append(
         (a[1], a[4][0])) or real(*a))
+    monkeypatch.setattr(
+        delta_rule, "_local_call", lambda *a, **kw: calls.append(
+            (a[1], a[5].shape[3] // 2)) or real_local(*a, **kw))
     ours, g = out_and_grads(lambda *a: chunk_gated_delta_rule(*a), co, *args)
-    # (the output's own call, then the gradient's two)
-    assert calls == [("zoo_gdn_scan_fwd", min(heads, 4))] * 2 + [
-        ("zoo_gdn_scan_bwd", min(heads, 4))]
+    # (the output's own calls, then the gradient's)
+    assert calls == [("zoo_gdn_local_fwd", min(heads, 8)),
+                     ("zoo_gdn_scan_fwd", min(heads, 4))] * 2 + [
+        ("zoo_gdn_scan_bwd", min(heads, 4)),
+        ("zoo_gdn_local_bwd", min(heads, 8))]
     theirs, gr = out_and_grads(
         lambda *a: ref.delta_rule_recurrence(*a, inner=8), co, *args)
     assert float(jnp.abs(ours - theirs).max()) < FWD * float(
@@ -185,8 +280,8 @@ def test_delta_rule_kernels_are_the_recurrence(monkeypatch, heads, length):
     assert max(rel(a, b) for a, b in zip(g, gr)) < GRAD
     monkeypatch.setenv("ZOO_TPU_DISABLE_PALLAS", "1")
     scan, gs = out_and_grads(lambda *a: chunk_gated_delta_rule(*a), co, *args)
-    assert len(calls) == 3
-    assert max(rel(a, b) for a, b in zip((ours,) + g, (scan,) + gs)) < 1e-6
+    assert len(calls) == 6
+    assert max(rel(a, b) for a, b in zip((ours,) + g, (scan,) + gs)) < 2e-6
 
 
 def test_delta_rule_route_is_static_and_fails_loudly_on_the_chip(

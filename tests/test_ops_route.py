@@ -86,7 +86,8 @@ def test_kernel_or_xla_is_one_decision(monkeypatch, op, env, kernel):
     if kernel:
         assert found == {"attention": ["zoo_flash_fwd"],
                          "dln": ["zoo_dln_fwd"],
-                         "delta_rule": ["zoo_gdn_scan_fwd"]}[op]
+                         "delta_rule": ["zoo_gdn_local_fwd",
+                                        "zoo_gdn_scan_fwd"]}[op]
 
 
 @pytest.mark.parametrize("op,build,why", [
