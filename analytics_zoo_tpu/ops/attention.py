@@ -147,7 +147,7 @@ def _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale, block_k,
 
     like = (q, k, v) if bias is None else (q, k, v, bias)
     init = tuple(vary_like(x, *like) for x in (
-        jnp.zeros((b, h, lq, d), jnp.float32),
+        jnp.zeros((b, h, lq, v.shape[-1]), jnp.float32),
         jnp.full((b, h, lq, 1), -jnp.inf, jnp.float32),
         jnp.zeros((b, h, lq, 1), jnp.float32)))
     (acc, m, l), _ = jax.lax.scan(step, init, jnp.arange(nb))
@@ -433,13 +433,14 @@ def _kv_spec(block_k, d, group):
 
 def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
                    block_q=None, block_k=None, group=1):
-    """Returns (o, lse) with o: (BH, Lq, d), lse: (BH, Lq, 1) f32. k and v
-    hold BH / ``group`` heads."""
+    """Returns (o, lse) with o: (BH, Lq, dv), lse: (BH, Lq, 1) f32. k and v
+    hold BH / ``group`` heads; v's head size ``dv`` may differ from q's and
+    k's ``d`` (latent attention: keys of 192, values of 128)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     num_q = pl.cdiv(lq, block_q)
     num_k = pl.cdiv(lk, block_k)
@@ -461,24 +462,24 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _kv_spec(block_k, d, group),
-            _kv_spec(block_k, d, group),
+            _kv_spec(block_k, dv, group),
             _bias_specs_3d(num_heads, block_k),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             # lse as (BH, Lq, 1): lane dim 1 == array dim → legal blocks,
             # and the (block_q, 1) layout broadcasts directly against
             # (block_q, block_k) score tiles in the backward kernels.
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            out_struct((bh, lq, d), q.dtype, q, k, v, kbias),
+            out_struct((bh, lq, dv), q.dtype, q, k, v, kbias),
             out_struct((bh, lq, 1), jnp.float32, q, k, v, kbias),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -608,7 +609,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     num_q = pl.cdiv(lq, block_q)
     num_k = pl.cdiv(lk, block_k)
@@ -621,6 +622,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
     qkv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    do_spec_q = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
     qkv_spec_k = _kv_spec(block_k, d, group)
     row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
@@ -631,9 +633,9 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             q_offset=lk - lq),
         name="zoo_flash_bwd_dq",
         grid=(bh, num_q, num_k),
-        in_specs=[qkv_spec_q, qkv_spec_k, qkv_spec_k,
+        in_specs=[qkv_spec_q, qkv_spec_k, _kv_spec(block_k, dv, group),
                   _bias_specs_3d(num_heads, block_k),
-                  qkv_spec_q, row_spec_q, row_spec_q],
+                  do_spec_q, row_spec_q, row_spec_q],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=out_struct((bh, lq, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -648,14 +650,11 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     # (accumulation axis).
     # (with grouped heads the grid runs over the key/value heads, and its
     # innermost axis over ``group`` query heads' blocks in turn)
-    kv_spec_k = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    if group == 1:
-        kv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-        row_spec = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
-    else:
-        q_at = lambda b, j, i, g=group, n=num_q: (b * g + i // n, i % n, 0)
-        kv_spec_q = pl.BlockSpec((1, block_q, d), q_at)
-        row_spec = pl.BlockSpec((1, block_q, 1), q_at)
+    kv_spec = lambda size: pl.BlockSpec((1, block_k, size),
+                                        lambda b, j, i: (b, j, 0))
+    q_at = (lambda b, j, i: (b, i, 0)) if group == 1 else \
+        (lambda b, j, i, g=group, n=num_q: (b * g + i // n, i % n, 0))
+    q_spec = lambda size: pl.BlockSpec((1, block_q, size), q_at)
     kv_heads = num_heads // group
     dkv_call = pl.pallas_call(
         functools.partial(
@@ -664,23 +663,22 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             q_offset=lk - lq, group=group),
         name="zoo_flash_bwd_dkv",
         grid=(bh // group, num_k, group * num_q),
-        in_specs=[kv_spec_q, kv_spec_k, kv_spec_k,
+        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv),
                   pl.BlockSpec((1, 1, block_k),
                                lambda b, j, i, h=kv_heads: (b // h, 0, j)),
-                  kv_spec_q, row_spec, row_spec],
+                  q_spec(dv), q_spec(1), q_spec(1)],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            kv_spec(d), kv_spec(dv),
             pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b, 0, j)),
         ],
         out_shape=[
             out_struct((bh // group, lk, d), k.dtype, q, k, v, do),
-            out_struct((bh // group, lk, d), v.dtype, q, k, v, do),
+            out_struct((bh // group, lk, dv), v.dtype, q, k, v, do),
             out_struct((bh // group, 1, lk), jnp.float32, q, k, v, do),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -742,7 +740,7 @@ KERNEL_MIN_SEQ = 512
 
 
 def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
-                    kv_heads=1) -> bool:
+                    kv_heads=1, dv=None) -> bool:
     """Whether a call runs the Pallas kernels: this op's shape rules,
     handed to ``_route.kernel_route``, which adds what every op shares
     (``ZOO_TPU_DISABLE_PALLAS``, the partition check, the loud failure on
@@ -751,9 +749,11 @@ def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
     compiles it inside the caller's jit; if Mosaic refuses, the compiler's
     error surfaces there (no probe, no reroute).
 
-    What the kernels take: a head size that is a multiple of 64 (64 and
-    256 run in the benchmark's cells; Mosaic pads the lane dim at 64, and
-    above 128 the key blocks are capped at 512 rows); query and key
+    What the kernels take: a head size that is a multiple of 64 (64, 192
+    and 256 run in the benchmark's cells; Mosaic pads the lane dim at 64,
+    and above 128 the key blocks are capped at 512 rows) and a value head
+    size ``dv`` of its own under the same rule (None: the keys'; latent
+    attention has keys of 192 and values of 128); query and key
     lengths that are multiples of 128, no shorter than 128; no bias or a
     key-padding bias (``kb``); ``heads`` query heads over ``kv_heads``
     key/value heads where the first is a whole multiple of the second (1,
@@ -771,6 +771,8 @@ def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
         (lq >= 128 and lk >= 128 and lq % 128 == 0 and lk % 128 == 0,
          f"lengths {lq} x {lk} are not multiples of 128 from 128 up"),
         (d % 64 == 0, f"head size {d} is not a multiple of 64"),
+        ((dv or d) % 64 == 0,
+         f"value head size {dv} is not a multiple of 64"),
         (kv_heads >= 1 and heads % max(kv_heads, 1) == 0,
          f"{heads} query heads are not a whole multiple of {kv_heads} "
          f"key/value heads"),
@@ -797,8 +799,10 @@ def flash_attention_blhd(q, k, v, bias=None, causal=False, sm_scale=None,
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
                     block_q=None, block_k=None, q_offset=None):
-    """q: (B, H, L, D); k, v: (B, Hkv, L, D) with H a whole multiple of
-    Hkv (consecutive query heads share a key/value head) -> (B, H, L, D).
+    """q: (B, H, L, D); k: (B, Hkv, L, D); v: (B, Hkv, L, Dv) with H a
+    whole multiple of Hkv (consecutive query heads share a key/value head)
+    -> (B, H, L, Dv). Dv is D unless the caller's values are narrower or
+    wider than its keys (latent attention: 192 and 128).
 
     Sequences of L >= KERNEL_MIN_SEQ route to the Pallas kernels on TPU
     (or interpreter mode when ``ZOO_TPU_PALLAS_INTERPRET=1`` on CPU)
@@ -822,8 +826,9 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     # wrappers hardcode the bottom-right alignment, so those shapes take
     # the blockwise route (which threads the offset explicitly)
     default_off = q_offset is None or int(q_offset) == lk - lq
+    dv = v.shape[-1]
     use_kernel = default_off and _route_eligible(on_tpu, kb, lq, lk, d,
-                                                 causal, h, hkv)
+                                                 causal, h, hkv, dv)
     if not use_kernel:
         if group > 1:
             # the blockwise route knows one key/value head a query head
@@ -837,7 +842,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     qf = q.reshape(b * h, lq, d)
     kf = k.reshape(b * hkv, lk, d)
-    vf = v.reshape(b * hkv, lk, d)
+    vf = v.reshape(b * hkv, lk, dv)
     o = _flash_attention_bhld(qf, kf, vf, kb, h, causal, sm_scale,
                               block_q, block_k, group)
-    return o.reshape(b, h, lq, d)
+    return o.reshape(b, h, lq, dv)

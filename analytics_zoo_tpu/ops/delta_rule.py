@@ -53,9 +53,21 @@ chunk-local part is :func:`_chunk_local`, XLA-built over a few heads at a
 time and differentiated by JAX, and the loop is ``lax.scan`` over the same
 :func:`_step`. The whole op sits under the HLO scope ``zoo_gdn_scan``, each
 kernel inside it under its own name.
+
+The same entry takes a decay per key channel, ``g`` of (B, L, n, dk):
+Kimi Delta Attention (Kimi Linear, arXiv:2510.26692), under the scope
+``zoo_kda_scan`` with kernels of its own names (``zoo_kda_local_fwd``,
+``zoo_kda_local_bwd``, ``zoo_kda_scan_fwd``, ``zoo_kda_scan_bwd``) on the
+same route. The loop over chunks is the scalar rule's, the chunk's decay a
+column over the state's rows; the chunk-local part is its own (further
+down: the decay sits inside the contraction, so the score tiles are built
+level by level, each product relative to a reference row). The two rules
+share the inverse, ``_step``, the state block and both call wrappers.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -68,11 +80,19 @@ DEFAULT_CHUNK = 128
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size: int = DEFAULT_CHUNK):
     """q, k: (B, L, n, dk), already normalised and scaled; v: (B, L, n, dv);
-    g (log decay, <= 0) and beta: (B, L, n). Returns (B, L, n, dv) in
-    ``v``'s dtype. ``chunk_size`` need not divide L: the tail is padded
-    with positions that write nothing (k = 0, beta = 0, g = 0)."""
-    with jax.named_scope("zoo_gdn_scan"):
-        return _chunked(q, k, v, g, beta, int(chunk_size))
+    beta: (B, L, n); g (log decay, <= 0): (B, L, n), one a head and
+    position, or (B, L, n, dk), one a key channel besides (Kimi Delta
+    Attention; HLO scope ``zoo_kda_scan``). Returns (B, L, n, dv) in ``v``'s
+    dtype. ``chunk_size`` need not divide L: the tail is padded with
+    positions that write nothing (k = 0, beta = 0, g = 0)."""
+    per_channel = g.ndim == 4
+    with jax.named_scope(_name(per_channel, "scan")):
+        return _chunked(q, k, v, g, beta, int(chunk_size), per_channel)
+
+
+def _name(per_channel: bool, part: str) -> str:
+    """The op's HLO scope (``part`` ``"scan"``) and its kernels' names."""
+    return ("zoo_kda_" if per_channel else "zoo_gdn_") + part
 
 
 BASE = 16
@@ -188,8 +208,20 @@ def _kernel_route(l, c, dk, dv) -> bool:
 # operands; that one walks the chunks from the last to the first with the
 # state's cotangent in the scratch and recomputes ``u``.
 
+def _turned(x):
+    """A (1, d) row as a (d, 1) column, or back: a select against the unit
+    diagonal and one reduction, which Mosaic takes at any d."""
+    d = max(x.shape)
+    row, col = _tile_iota(d)
+    return jnp.sum(jnp.where(row == col, x, 0.0), axis=x.shape.index(1) ^ 1,
+                   keepdims=True)
+
+
 def _scan_fwd_kernel(wv_ref, wk_ref, qk_ref, qin_ref, kout_ref, g_ref,
-                     o_ref, s0_ref, s_scr):
+                     o_ref, s0_ref, s_scr, *, per_channel):
+    """``g_ref``: a head's decay over the chunk as a row, (1, dv) of one
+    number or, ``per_channel``, (1, dk) of a number a key channel, which
+    scales the state's rows."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
@@ -200,12 +232,12 @@ def _scan_fwd_kernel(wv_ref, wk_ref, qk_ref, qin_ref, kout_ref, g_ref,
         s0_ref[h] = s_scr[h]
         s_scr[h], o_ref[h] = _step(s_scr[h], (
             wv_ref[h], wk_ref[h], qk_ref[h], qin_ref[h], kout_ref[h],
-            g_ref[h]))
+            _turned(g_ref[h]) if per_channel else g_ref[h]))
 
 
 def _scan_bwd_kernel(do_ref, s0_ref, wv_ref, wk_ref, qk_ref, qin_ref,
                      kout_ref, g_ref, dwv_ref, dwk_ref, dqk_ref, dqin_ref,
-                     dkout_ref, dg_ref, ds_scr):
+                     dkout_ref, dg_ref, ds_scr, *, per_channel):
     """``_step`` transposed: from the chunk's output cotangent and the
     cotangent ``ds`` of the state at its end, the operands' cotangents and
     that of the state at its start. Products as forward: operands in
@@ -231,9 +263,13 @@ def _scan_bwd_kernel(do_ref, s0_ref, wv_ref, wk_ref, qk_ref, qin_ref,
         dqk_ref[h] = dot(do, um, 1, 1).astype(mm)
         dqin_ref[h] = dot(do, sm, 1, 1).astype(mm)
         dkout_ref[h] = dot(um, dsm, 1, 1).astype(mm)
-        dg_ref[h] = jnp.sum(s * ds, axis=0, keepdims=True)      # (1, dv)
-        ds_scr[h] = ds * g_ref[h] + dot(q_in, do, 0, 0) - \
-            dot(w_k, dum, 0, 0)
+        if per_channel:                            # (dk, 1), kept as a row
+            dg_ref[h] = _turned(jnp.sum(s * ds, axis=1, keepdims=True))
+            g_end = _turned(g_ref[h])
+        else:
+            dg_ref[h] = jnp.sum(s * ds, axis=0, keepdims=True)  # (1, dv)
+            g_end = g_ref[h]
+        ds_scr[h] = ds * g_end + dot(q_in, do, 0, 0) - dot(w_k, dum, 0, 0)
 
 
 def _scan_call(kernel, name, operands, outs, state, reverse):
@@ -282,46 +318,53 @@ def _state_block(w_v, w_k):
     return _most_dividing(w_k.shape[0], most), w_k.shape[-1], w_v.shape[-1]
 
 
-def _lanes(g_end, dv):
-    """(n, B, Nc, 1, 1) -> (n, B, Nc, 1, dv): a row the kernels broadcast
-    over a state's sublanes."""
-    return jnp.broadcast_to(g_end, g_end.shape[:-1] + (dv,))
+def _lanes(g_end, dv, per_channel):
+    """The chunk's decay as the row the loop's kernels read: (n, B, Nc, 1,
+    1) -> (n, B, Nc, 1, dv), broadcast over a state's sublanes; a decay per
+    channel, (n, B, Nc, 1, dk), is that row already."""
+    return g_end if per_channel else jnp.broadcast_to(
+        g_end, g_end.shape[:-1] + (dv,))
 
 
-def _scan_forward(w_v, w_k, qk, q_in, k_out, g_end):
+def _scan_forward(w_v, w_k, qk, q_in, k_out, g_end, per_channel):
     """The chunks' outputs (n, B, Nc, C, dv) and the float32 state at each
-    chunk's start (n, B, Nc, dk, dv)."""
+    chunk's start (n, B, Nc, dk, dv). ``g_end``: (n, B, Nc, 1, 1) or,
+    ``per_channel``, (n, B, Nc, 1, dk)."""
     c, dv = w_v.shape[3:]
     return _scan_call(
-        _scan_fwd_kernel, "zoo_gdn_scan_fwd",
-        (w_v, w_k, qk, q_in, k_out, _lanes(g_end, dv)),
+        functools.partial(_scan_fwd_kernel, per_channel=per_channel),
+        _name(per_channel, "scan_fwd"),
+        (w_v, w_k, qk, q_in, k_out, _lanes(g_end, dv, per_channel)),
         [(c, dv, w_k.dtype), (w_k.shape[-1], dv, jnp.float32)],
         _state_block(w_v, w_k), False)
 
 
-@jax.custom_vjp
-def _scan_kernels(w_v, w_k, qk, q_in, k_out, g_end):
-    return _scan_forward(w_v, w_k, qk, q_in, k_out, g_end)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _scan_kernels(w_v, w_k, qk, q_in, k_out, g_end, per_channel):
+    return _scan_forward(w_v, w_k, qk, q_in, k_out, g_end, per_channel)[0]
 
 
 def _scan_kernels_fwd(*xs):
     o, s0 = _scan_forward(*xs)
-    return o, xs + (s0,)
+    return o, xs[:-1] + (s0,)
 
 
-def _scan_kernels_bwd(res, do):
+def _scan_kernels_bwd(per_channel, res, do):
     w_v, w_k, qk, q_in, k_out, g_end, s0 = res
     c, dv = w_v.shape[3:]
     dk, mm, f32 = w_k.shape[-1], w_k.dtype, jnp.float32
     # the scope again: how much of the forward's name stack reaches a
     # backward rule depends on the transforms around it
-    with jax.named_scope("zoo_gdn_scan"):
+    with jax.named_scope(_name(per_channel, "scan")):
         *grads, dg = _scan_call(
-            _scan_bwd_kernel, "zoo_gdn_scan_bwd",
-            (do, s0, w_v, w_k, qk, q_in, k_out, _lanes(g_end, dv)),
+            functools.partial(_scan_bwd_kernel, per_channel=per_channel),
+            _name(per_channel, "scan_bwd"),
+            (do, s0, w_v, w_k, qk, q_in, k_out,
+             _lanes(g_end, dv, per_channel)),
             [(c, dv, f32), (c, dk, mm), (c, c, mm), (c, dk, mm),
-             (c, dk, mm), (1, dv, f32)], _state_block(w_v, w_k), True)
-        return (*grads, dg.sum(-1, keepdims=True))
+             (c, dk, mm), (1, dk if per_channel else dv, f32)],
+            _state_block(w_v, w_k), True)
+        return (*grads, dg if per_channel else dg.sum(-1, keepdims=True))
 
 
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
@@ -533,18 +576,18 @@ def _local_bwd_kernel(q_ref, k_ref, v_ref, gb_ref, dwv_ref, dwk_ref, dqk_ref,
     dgb_ref[...] += col_scr[...].T[:2 * hb]
 
 
-def _local_call(kernel, name, q, k, v, gb, tiles, outs, scratch=()):
-    """``kernel`` over (head block, sequence, chunk), all parallel. ``q``,
-    ``k``, ``v``: (B, L, n, size), read as (B, L, n x size) in blocks of a
-    chunk by a head block's lanes; ``gb``: (head blocks, B, Nc, 2 x heads a
-    block, C); ``tiles``: (n, B, Nc, C, .), where the loop's kernels read
-    them. ``outs`` names each output by the operand it is shaped and
-    blocked like, with its dtype."""
+def _local_call(kernel, name, seqs, gb, tiles, outs, scratch=()):
+    """``kernel`` over (head block, sequence, chunk), all parallel.
+    ``seqs``: arrays of (B, L, n, size), read as (B, L, n x size) in blocks
+    of a chunk by a head block's lanes; ``gb``: (head blocks, B, Nc, rows,
+    C), a head block's gates as rows; ``tiles``: (n, B, Nc, C, .), where
+    the loop's kernels read them. ``outs`` names each output by the operand
+    it is shaped and blocked like, with its dtype."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nb, b, nc, hb2, c = gb.shape
-    hb = hb2 // 2
+    nb, b, nc, _, c = gb.shape
+    hb = seqs[0].shape[2] // nb
     flat = lambda t: t.reshape(t.shape[:2] + (-1,)) if t.ndim == 4 else t
 
     def spec(t):
@@ -555,7 +598,7 @@ def _local_call(kernel, name, q, k, v, gb, tiles, outs, scratch=()):
         return pl.BlockSpec(lead + (None, None) + t.shape[3:],
                             lambda i, j, m: (i, j, m, 0, 0))
 
-    operands = (q, k, v, gb) + tuple(tiles)
+    operands = tuple(seqs) + (gb,) + tuple(tiles)
     call = pl.pallas_call(
         kernel,
         name=name,
@@ -574,23 +617,26 @@ def _local_call(kernel, name, q, k, v, gb, tiles, outs, scratch=()):
             call(*(flat(t) for t in operands)), outs)]
 
 
-def _gate_rows(gc, beta):
-    """The cumulated log decay and beta, (n, B, Nc, C) each, as the rows
-    the chunk-local kernels read: (head blocks, B, Nc, 2 x heads a block,
-    C), a block's decays above its betas. A head block is the most heads
-    up to ``HEAD_BLOCK`` that divide the head count."""
-    n = gc.shape[0]
+def _gate_rows(*gates):
+    """Gates of (n, B, Nc, C) each (the cumulated log decay and beta; beta
+    alone where the decay is per channel) as the rows the chunk-local
+    kernels read: (head blocks, B, Nc, gates x heads a block, C), a
+    block's first gate above its second. A head block is the most heads up
+    to ``HEAD_BLOCK`` that divide the head count."""
+    n = gates[0].shape[0]
     hb = _most_dividing(n, HEAD_BLOCK)
     blocks = lambda t: jnp.moveaxis(
         t.reshape((n // hb, hb) + t.shape[1:]), 1, 3)
-    return jnp.concatenate([blocks(gc), blocks(beta)], 3)
+    return jnp.concatenate([blocks(t) for t in gates], 3)
 
 
-def _gate_columns(gb):
-    """``_gate_rows`` back: (n, B, Nc, C) twice."""
-    nb, b, nc, hb2, c = gb.shape
+def _gate_columns(gb, count):
+    """``_gate_rows`` back: ``count`` arrays of (n, B, Nc, C)."""
+    nb, b, nc, rows, c = gb.shape
+    hb = rows // count
     heads = lambda t: jnp.moveaxis(t, 3, 1).reshape(-1, b, nc, c)
-    return heads(gb[:, :, :, :hb2 // 2]), heads(gb[:, :, :, hb2 // 2:])
+    return tuple(heads(gb[:, :, :, i * hb:(i + 1) * hb])
+                 for i in range(count))
 
 
 def _local_forward(q, k, v, gb):
@@ -599,7 +645,7 @@ def _local_forward(q, k, v, gb):
     tile = lambda cols, dtype: (jax.ShapeDtypeStruct(
         (n, b, l // c, c, cols), dtype), dtype)
     return tuple(_local_call(
-        _local_fwd_kernel, "zoo_gdn_local_fwd", q, k, v, gb, (),
+        _local_fwd_kernel, "zoo_gdn_local_fwd", (q, k, v), gb, (),
         [tile(dv, f32), tile(dk, mm), tile(c, mm), tile(dk, mm),
          tile(dk, mm)]))
 
@@ -621,15 +667,257 @@ def _local_kernels_bwd(res, cts):
     q, k, v, gb = res
     with jax.named_scope("zoo_gdn_scan"):         # as ``_scan_kernels_bwd``
         dq, dk, dv, dgb = _local_call(
-            _local_bwd_kernel, "zoo_gdn_local_bwd", q, k, v, gb, cts,
+            _local_bwd_kernel, "zoo_gdn_local_bwd", (q, k, v), gb, cts,
             [(t, t.dtype) for t in res], scratch=[(gb.shape[-1],) * 2])
-        return (dq, dk, dv) + _gate_columns(dgb)
+        return (dq, dk, dv) + _gate_columns(dgb, 2)
 
 
 _local_kernels.defvjp(_local_kernels_fwd, _local_kernels_bwd)
 
 
-def _chunked(q, k, v, g, beta, c):
+# -- a decay per key channel (Kimi Delta Attention) ---------------------------
+# ``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T``
+# with ``g_t`` a vector over the key's channels. With ``G`` the sum of g
+# since the chunk's start, the chunk-local part has the scalar rule's form,
+#
+#     A_ij = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])   (j < i),
+#     W = (I + A)^-1 [beta V | beta (K . exp G)],
+#     QK_ij = sum_d q_i[d] k_j[d] exp(G_i[d] - G_j[d])          (j <= i),
+#     q_in = Q . exp G,  k_out = K . exp(G_C - G),  the chunk's decay exp G_C,
+#
+# and the loop over chunks is the scalar rule's own (``_step`` and the two
+# kernels of the loop, the chunk's decay a column over the state's rows).
+# But the decay now sits inside the contraction: there is no C x C tile to
+# multiply a plain ``K K^T`` with, and ``exp(-G_j)`` alone overflows. The
+# products are formed relative to a reference row (:func:`_kda_factors`): a
+# pair (i, j) lies either in one 16-row block (the tile inverse's blocks),
+# where both sides are taken relative to the block's first row, the key
+# side's exponent then being positive and bounded by 15 positions of decay
+# (clamped at ``KDA_CLAMP``: exact while a channel keeps more than e^-80 of
+# itself over 16 positions); or, at one level s = 16, 32, .. C/2, i lies in
+# the upper and j in the lower half of a block of 2s, where both sides are
+# taken relative to the upper half's first row and every exponent is <= 0.
+# One masked product a level and side: log2(C/16) + 1 products where the
+# scalar rule has one.
+
+KDA_CLAMP = 80.0
+
+
+def _kda_base(c):
+    """The rows of a diagonal block: ``BASE`` where it cuts the chunk into
+    a power of two of blocks, else the whole chunk."""
+    m = c // BASE
+    return BASE if c % BASE == 0 and m & (m - 1) == 0 else c
+
+
+def _block_of(index, size):
+    return index >> (size.bit_length() - 1) if size & (size - 1) == 0 \
+        else index // size
+
+
+def _kda_factors(gc):
+    """For the cumulated log decay (heads, C, dk): per level the factors of
+    the query-or-key side and of the key side, (heads, C, dk) and <= 1
+    except inside a diagonal block, and the (C, C) masks of the pairs the
+    level covers, without and with the diagonal. The reference rows carry
+    no gradient: they cancel in every product."""
+    n, c, dk = gc.shape
+    base = _kda_base(c)
+    row, col = _tile_iota(c)
+
+    def ref(size, first):           # the reference row of each ``size`` rows
+        return jax.lax.stop_gradient(jnp.concatenate(
+            [jnp.broadcast_to(gc[:, r:r + 1], (n, size, dk))
+             for r in range(first, c, size)], 1))
+
+    d0 = gc - ref(base, 0)                                        # <= 0
+    same = _block_of(row, base) == _block_of(col, base)
+    out = [(jnp.exp(d0), jnp.exp(jnp.minimum(-d0, KDA_CLAMP)),
+            same & (row > col), same & (row >= col))]
+    s = base
+    upper = lambda s: _block_of(row[:, :1], s) & 1 == 1           # (C, 1)
+    while s < c:                    # i in the upper half, j in the lower
+        d = gc - ref(2 * s, s)      # <= 0 in the upper half, >= 0 below
+        e = jnp.exp(jnp.where(upper(s), d, -d))
+        pair = (_block_of(row, s) & 1 == 1) & \
+            (_block_of(col, s) == _block_of(row, s) - 1)
+        out.append((e, e, pair, pair))
+        s *= 2
+    return out
+
+
+def _kda_scores(q, k, factors):
+    """``sum_d k_i k_j exp(G_i - G_j)`` for j < i and the same of q for
+    j <= i, float32 (heads, C, C): a level's two products share the key
+    side, so they are one product of (2C, dk) by (dk, C)."""
+    c, mm = k.shape[1], k.dtype
+    kk = qk = 0.0
+    for left, right, strict, lower in factors:
+        both = _dot(jnp.concatenate(
+            [(q * left).astype(mm), (k * left).astype(mm)], 1),
+            (k * right).astype(mm), 1, 1)
+        qk = qk + jnp.where(lower, both[:, :c], 0.0)
+        kk = kk + jnp.where(strict, both[:, c:], 0.0)
+    return kk, qk
+
+
+def _kda_tiles(q, k, v, gc, bcol, brow, inverse):
+    """What both chunk-local kernels and the XLA carrier start from, for
+    stacks of heads: the levels' factors, the two score tiles, the inverse
+    of ``I + A``, ``exp G``, ``K . exp G`` and the written values' two
+    parts in float32."""
+    row, col = _tile_iota(q.shape[1])
+    factors = _kda_factors(gc)
+    kk, qk = _kda_scores(q, k, factors)
+    t = inverse(jnp.where(row > col, kk * bcol, 0.0))
+    grow = jnp.exp(gc)
+    kg = (k * grow).astype(v.dtype)
+    return factors, kk, qk, t, grow, kg, _dot_exact(t * brow, v), \
+        _dot_exact(t * brow, kg)
+
+
+def _kda_local(q, k, v, gc, bcol, brow, inverse):
+    """The chunk-local part for stacks of heads: q, k (heads, C, dk) and v
+    (heads, C, dv) in one dtype, the cumulated log decay (heads, C, dk) and
+    beta as columns (heads, C, 1) and rows (heads, 1, C) in float32.
+    ``inverse``: of ``I + A``. What the chunk-local forward kernel runs on
+    a few heads in VMEM and the XLA carrier on every chunk at once."""
+    mm, c = v.dtype, q.shape[1]
+    _, _, qk, _, grow, _, w_v, w_k = _kda_tiles(q, k, v, gc, bcol, brow,
+                                                inverse)
+    return (w_v, w_k.astype(mm), qk.astype(mm), (q * grow).astype(mm),
+            (k * jnp.exp(gc[:, c - 1:] - gc)).astype(mm))
+
+
+def _kda_chunk_local(q, k, v, g, beta):
+    """``_chunk_local`` for a decay per channel: (heads, B, Nc, C, .)
+    operands, ``g`` (heads, B, Nc, C, dk)."""
+    gc = jnp.cumsum(g, axis=-2)
+    flat = lambda t: t.reshape((-1,) + t.shape[-2:])
+    outs = _kda_local(flat(q), flat(k), flat(v), flat(gc),
+                      flat(beta[..., None]), flat(beta[..., None, :]),
+                      unit_lower_inverse)
+    return tuple(t.reshape(q.shape[:3] + t.shape[1:]) for t in outs) + \
+        (jnp.exp(gc[..., -1, :]),)
+
+
+def _kda_operands(q_ref, k_ref, v_ref, g_ref, b_ref, hs, dk, dv):
+    """The operands of heads ``hs`` of a grid step as stacks: q, k, the
+    cumulated log decay (heads, C, dk) and v (heads, C, dv) from the
+    heads' lane slices; beta as columns and as rows."""
+    heads = lambda f: jnp.stack([f(h) for h in hs])
+    rows = b_ref[...]                             # (hb, C)
+    cols = rows.T
+    lanes = lambda ref, d: heads(lambda h: ref[:, h * d:(h + 1) * d])
+    return (lanes(q_ref, dk), lanes(k_ref, dk), lanes(v_ref, dv),
+            lanes(g_ref, dk), heads(lambda h: cols[:, h:h + 1]),
+            heads(lambda h: rows[h:h + 1]))
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, *out_refs):
+    hb, c, dk = out_refs[1].shape
+    dv = out_refs[0].shape[-1]
+    for hs in _head_groups(hb):
+        outs = _kda_local(*_kda_operands(q_ref, k_ref, v_ref, g_ref, b_ref,
+                                         hs, dk, dv), _tile_inverse)
+        for ref, t in zip(out_refs, outs):
+            ref[slice(hs[0], hs[-1] + 1)] = t
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, dwv_ref, dwk_ref,
+                    dqk_ref, dqin_ref, dkout_ref, dq_ref, dk_ref, dv_ref,
+                    dg_ref, db_ref, col_scr):
+    """``_kda_fwd_kernel`` transposed, products only. Through a level's
+    product ``mask . (X . left)(K . right)^T`` with cotangent ``P``: the
+    sides' cotangents are ``P (K . right)`` and ``P^T (X . left)``, each
+    goes to its operand through the factor and to ``G`` through the
+    factor's sign (the query-or-key side rises with ``G``, the key side
+    falls). beta's cotangent comes out as columns, gathered in ``col_scr``
+    and transposed once."""
+    hb, c, dk = dwk_ref.shape
+    dv, f32, mm = dwv_ref.shape[-1], jnp.float32, dwk_ref.dtype
+    lanes = lambda x: jnp.sum(x, axis=-1, keepdims=True)        # (., C, 1)
+    row, col = _tile_iota(c)
+    last = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) == c - 1
+    for hs in _head_groups(hb):
+        q, k, v, gc, bcol, brow = _kda_operands(
+            q_ref, k_ref, v_ref, g_ref, b_ref, hs, dk, dv)
+        at = slice(hs[0], hs[-1] + 1)
+        factors, kk, _, t, grow, kg, w_v, w_k = _kda_tiles(
+            q, k, v, gc, bcol, brow, _tile_inverse)
+        out = jnp.exp(gc[:, c - 1:] - gc)
+        tt = jnp.swapaxes(t, 1, 2)
+        dr_v = _dot(tt, dwv_ref[at], 1, 0, True)                # T^T dW
+        dr_k = _dot_exact(tt, dwk_ref[at])
+        da = jnp.where(row > col, -_dot(
+            jnp.concatenate([dr_v, dr_k], 2),
+            jnp.concatenate([w_v, w_k], 2), 1, 1, True), 0.0)
+        dbcol = lanes(dr_v * v) + lanes(dr_k * kg) + lanes(da * kk)
+        p_kk, p_qk = (da * bcol).astype(mm), dqk_ref[at]
+        dq_in, dk_out = dqin_ref[at].astype(f32), dkout_ref[at].astype(f32)
+        dkg, ko = bcol * dr_k, dk_out * out
+        dq, dk_ = dq_in * grow, dkg * grow + ko
+        # k_out = k exp(G_C - G): each row takes, the last adds all
+        dg = k * (dkg * grow - ko) + dq_in * q * grow + jnp.where(
+            last, jnp.sum(ko * k, axis=1, keepdims=True), 0.0)
+        for left, right, strict, lower in factors:
+            ql, kl = (q * left).astype(mm), (k * left).astype(mm)
+            ps = jnp.concatenate(
+                [jnp.where(lower, p_qk, jnp.zeros_like(p_qk)),
+                 jnp.where(strict, p_kk, jnp.zeros_like(p_kk))], 1)
+            d_left = _dot(ps, (k * right).astype(mm), 1, 0)    # (., 2C, dk)
+            d_right = _dot(ps, jnp.concatenate([ql, kl], 1), 0, 0)
+            dql, dkl = d_left[:, :c] * left, d_left[:, c:] * left
+            dkr = d_right * right
+            dq, dk_ = dq + dql, dk_ + dkl + dkr
+            dg = dg + dql * q + (dkl - dkr) * k
+        dv_ = (bcol * dr_v).astype(mm)
+        for i, h in enumerate(hs):
+            dq_ref[:, h * dk:(h + 1) * dk] = dq[i].astype(mm)
+            dk_ref[:, h * dk:(h + 1) * dk] = dk_[i].astype(mm)
+            dv_ref[:, h * dv:(h + 1) * dv] = dv_[i]
+            dg_ref[:, h * dk:(h + 1) * dk] = dg[i]
+            col_scr[:, h:h + 1] = dbcol[i]
+    db_ref[...] = col_scr[...].T[:hb]
+
+
+def _kda_forward(q, k, v, gc, bb):
+    b, l, n, dk = q.shape
+    dv, c, mm, f32 = v.shape[-1], bb.shape[-1], v.dtype, jnp.float32
+    tile = lambda cols, dtype: (jax.ShapeDtypeStruct(
+        (n, b, l // c, c, cols), dtype), dtype)
+    return tuple(_local_call(
+        _kda_fwd_kernel, "zoo_kda_local_fwd", (q, k, v, gc), bb, (),
+        [tile(dv, f32), tile(dk, mm), tile(c, mm), tile(dk, mm),
+         tile(dk, mm)]))
+
+
+@jax.custom_vjp
+def _kda_kernels(q, k, v, gc, beta):
+    """``_kda_chunk_local``'s first five outputs from q, k, v (B, L, n, .)
+    in one dtype, L whole chunks, the log decay cumulated inside each chunk
+    (B, L, n, dk) and beta (n, B, Nc, C), both float32."""
+    return _kda_forward(q, k, v, gc, _gate_rows(beta))
+
+
+def _kda_kernels_fwd(q, k, v, gc, beta):
+    bb = _gate_rows(beta)
+    return _kda_forward(q, k, v, gc, bb), (q, k, v, gc, bb)
+
+
+def _kda_kernels_bwd(res, cts):
+    q, k, v, gc, bb = res
+    with jax.named_scope("zoo_kda_scan"):         # as ``_scan_kernels_bwd``
+        *grads, dbb = _local_call(
+            _kda_bwd_kernel, "zoo_kda_local_bwd", (q, k, v, gc), bb, cts,
+            [(t, t.dtype) for t in res], scratch=[(bb.shape[-1],) * 2])
+        return (*grads, *_gate_columns(dbb, 1))
+
+
+_kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
+
+
+def _chunked(q, k, v, g, beta, c, per_channel=False):
     b, l, n, dk = q.shape
     dv = v.shape[-1]
     f32, mm = jnp.float32, v.dtype                        # MXU operands
@@ -644,10 +932,17 @@ def _chunked(q, k, v, g, beta, c):
         return jnp.moveaxis(t.reshape((b, nc, c) + t.shape[2:]), 3, 0)
 
     if _kernel_route(l, c, dk, dv):
-        gc = jnp.cumsum(chunks(g, f32), axis=-1)
-        xs = _local_kernels(padded(q, mm), padded(k, mm), padded(v, mm), gc,
-                            chunks(beta, f32))
-        o = _scan_kernels(*xs, jnp.exp(gc[..., -1])[..., None, None])
+        if per_channel:                           # cumulated where it lies
+            gc = jnp.cumsum(padded(g, f32).reshape(b, nc, c, n, dk), axis=2)
+            xs = _kda_kernels(padded(q, mm), padded(k, mm), padded(v, mm),
+                              gc.reshape(b, nc * c, n, dk), chunks(beta, f32))
+            g_end = jnp.exp(jnp.moveaxis(gc[:, :, -1], 2, 0))[..., None, :]
+        else:
+            gc = jnp.cumsum(chunks(g, f32), axis=-1)
+            xs = _local_kernels(padded(q, mm), padded(k, mm), padded(v, mm),
+                                gc, chunks(beta, f32))
+            g_end = jnp.exp(gc[..., -1])[..., None, None]
+        o = _scan_kernels(*xs, g_end, per_channel)
     else:
         local = (chunks(q, mm), chunks(k, mm), chunks(v, mm),
                  chunks(g, f32), chunks(beta, f32))
@@ -657,24 +952,27 @@ def _chunked(q, k, v, g, beta, c):
         # operands' size, then exist for those heads only
         split = lambda t: t.reshape((n // hb, hb) + t.shape[1:])
         join = lambda t: t.reshape((n,) + t.shape[2:])
+        one = _kda_chunk_local if per_channel else _chunk_local
         *xs, g_end = (join(t) for t in jax.lax.map(
-            jax.checkpoint(lambda xs: _chunk_local(*xs)),
+            jax.checkpoint(lambda xs: one(*xs)),
             tuple(split(t) for t in local)))
+        # the chunk's decay against the state (n, B, dk, dv): its rows
+        g_end = g_end[..., None] if per_channel else g_end[..., None, None]
         time_first = lambda t: jnp.moveaxis(t, 2, 0)      # the chunk axis
         _, o = jax.lax.scan(
             _step, jnp.zeros((n, b, dk, dv), f32),
-            tuple(time_first(t) for t in (*xs, g_end[..., None, None])))
+            tuple(time_first(t) for t in (*xs, g_end)))
         o = jnp.moveaxis(o, 0, 2)                 # (n, B, Nc, C, dv)
     # (n, B, Nc, C, dv) -> (B, L, n, dv)
     o = o.reshape(n, b, nc * c, dv)[:, :, :l]
     return jnp.moveaxis(o, 0, 2).astype(v.dtype)
 
 
-def causal_depthwise_conv(x, w):
+def causal_depthwise_conv(x, w, scope="zoo_gdn_conv"):
     """``y_t = sum_j w[:, j] x_{t-(K-1)+j}`` over (B, L, channels) with
     ``w`` of (channels, K): the short convolution in front of the delta
-    rule. K shifted multiply-adds, under the HLO scope ``zoo_gdn_conv``."""
-    with jax.named_scope("zoo_gdn_conv"):
+    rule. K shifted multiply-adds, under the HLO scope ``scope``."""
+    with jax.named_scope(scope):
         width = w.shape[1]
         xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
         return sum(xp[:, j:j + x.shape[1]] * w[:, j].astype(x.dtype)

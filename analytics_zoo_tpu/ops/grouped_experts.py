@@ -30,6 +30,21 @@ import numpy as np
 DEFAULT_TILE = 256
 
 
+def expected_tile(n_tokens: int, top_k: int, n_routed: int) -> int:
+    """The rows of a tile for an expert layer that gets ``n_tokens`` a
+    call: the smallest of 256, 512, 1024, ... that holds one and a half
+    times the assignments an expert gets when the routing is even. An
+    expert whose count lies about a tile's rows makes one tile or two from
+    one step to the next (Kimi Linear's share: 256 to 340 assignments a
+    sequence, and the trip count at 256 moved a run's time by half a per
+    cent from seed to seed, PERF.md §6, PR 33); padding a tile costs less
+    than a second trip."""
+    tile = DEFAULT_TILE
+    while 2 * tile < 3 * n_tokens * top_k // n_routed:
+        tile *= 2
+    return tile
+
+
 class RouteTables(NamedTuple):
     """Assignments sorted by held expert (the others last) and, per tile,
     its expert, where its rows start in the sorted order and how many of

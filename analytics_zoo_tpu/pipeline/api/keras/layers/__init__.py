@@ -31,8 +31,9 @@ from .wrappers import Bidirectional, KerasLayerWrapper, TimeDistributed
 from .advanced_activations import (ELU, LeakyReLU, PReLU, RReLU, Softmax,
                                    SReLU, ThresholdedReLU)
 from .moe import SparseMoE
-from .hybrid_decoder import (GatedAttention, GatedDeltaNet, HeldExpertsMoE,
-                             HybridDecoder, LMHeadLoss)
+from .hybrid_decoder import (GatedAttention, GatedDeltaNet, GatedMLP,
+                             HeldExpertsMoE, HybridDecoder,
+                             KimiDeltaAttention, LatentAttention, LMHeadLoss)
 from .crf import CRF
 
 # Convenience aliases matching Keras-2-style names used around the reference

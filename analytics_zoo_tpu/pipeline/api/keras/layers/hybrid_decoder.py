@@ -1,8 +1,10 @@
 """Decoder blocks of the kind today's open hybrid models use: a token
-mixer chosen per block from a layer pattern (Gated DeltaNet linear
-attention, or gated softmax attention with grouped query heads and
-partial rotary positions), zero-centred RMSNorm, and a dropless expert
-layer that is told which experts it holds.
+mixer chosen per block from a layer pattern (Gated DeltaNet or Kimi Delta
+Attention linear attention; gated softmax attention with grouped query
+heads and partial rotary positions, or latent attention without
+positions), zero-centred RMSNorm, and a dropless expert layer that is told
+which experts it holds and how its router scores, or a dense gated MLP in
+the leading blocks.
 
 Rebuild-scope new work (the reference framework has none of these). The
 layers are the usual stateless descriptions, so each can stand alone in a
@@ -18,7 +20,8 @@ left out. On one chip it runs without its exchange; nothing stands in for
 the absent chips.
 
 HLO scopes (docs/observability.md#names): ``zoo_gdn_conv``,
-``zoo_gdn_scan``, ``zoo_gated_attn``, ``zoo_moe_route``,
+``zoo_gdn_scan``, ``zoo_gated_attn``, ``zoo_kda_conv``, ``zoo_kda_scan``,
+``zoo_mla_proj``, ``zoo_mla_attn``, ``zoo_dense_mlp``, ``zoo_moe_route``,
 ``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_lm_loss``.
 """
 
@@ -33,16 +36,18 @@ import jax.numpy as jnp
 from .....ops.attention import flash_attention
 from .....ops.delta_rule import (DEFAULT_CHUNK, causal_depthwise_conv,
                                  chunk_gated_delta_rule)
-from .....ops.grouped_experts import (DEFAULT_TILE, grouped_experts,
+from .....ops.grouped_experts import (expected_tile, grouped_experts,
                                       route_tables)
 from ..engine.base import KerasLayer
 
 LINEAR, FULL = "linear_attention", "full_attention"
+KDA, LATENT = "kimi_delta_attention", "latent_attention"
 # what a layer with routing reports each step; the trainer sums the
 # ``_total`` names over a dispatch's steps and publishes them as counters
 # (a gauge keeps its last value): pipeline/engine.py ``_collect_step_stats``
 MOE_STATS = ("zoo_moe_assignments_total", "zoo_moe_assignments_held_total",
-             "zoo_moe_dropped_total", "zoo_moe_held_load_max_over_mean")
+             "zoo_moe_dropped_total", "zoo_moe_held_load_max_over_mean",
+             "zoo_moe_tiles_total")
 
 
 def _normal(rng, shape, std=0.02):
@@ -182,17 +187,171 @@ class GatedDeltaNet(KerasLayer):
         return y.reshape(b, l, vd) @ params["w_out"]
 
 
-class HeldExpertsMoE(KerasLayer):
-    """Softmax-routed, dropless mixture of gated-MLP experts of which this
-    layer holds ``n_held`` (experts ``first_expert .. first_expert + n_held
-    - 1`` of the router's ``n_routed``), plus one shared expert behind a
-    sigmoid gate (``shared_size`` 0: none).
+def _l2(t):
+    return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
 
-    The router, the ``top_k`` and the normalisation are over all
+
+def decay_bias(rng, shape):
+    """A bias whose softplus is log-uniform on (0.001, 0.1): a position
+    then keeps ``exp(-A * that)`` of a channel, between almost all and a
+    fifth at ``A`` = 16."""
+    dt = jnp.exp(jax.random.uniform(rng, shape, jnp.float32,
+                                    math.log(1e-3), math.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class KimiDeltaAttention(KerasLayer):
+    """Kimi Delta Attention token mixer (Kimi Linear, arXiv:2510.26692):
+    one projection to query, key and value (columns ``[q | k | v]``), a
+    short causal depthwise convolution and SiLU on each; l2-normalised
+    query and key heads, the query scaled by ``head_dim ** -0.5``; ``beta =
+    sigmoid(W_b x)`` a head; a log decay a head and key channel, ``g =
+    -exp(A_log) softplus(W_f2 (W_f1 x) + dt_bias)`` in float32, through a
+    projection of rank ``head_dim``; the delta rule with that decay in
+    chunks (``ops/delta_rule.py``, which is exact while a channel keeps
+    more than e^-80 of itself over 16 positions: ``KDA_CLAMP``); a
+    per-head RMSNorm gated by ``sigmoid(W_g2 (W_g1 x))``; the output
+    projection. (B, L, H) -> (B, L, H)."""
+
+    def __init__(self, n_head: int, head_dim: int, conv_width: int = 4,
+                 eps: float = 1e-5, chunk_size: int = DEFAULT_CHUNK,
+                 input_shape=None, name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        self.n, self.d, self.conv_width = n_head, head_dim, conv_width
+        self.eps, self.chunk_size = eps, chunk_size
+
+    def build(self, rng, input_shape):
+        h, nd, rank = int(input_shape[-1]), self.n * self.d, self.d
+        r = jax.random.split(rng, 10)
+        return {"w_qkv": _normal(r[0], (h, 3 * nd)),
+                "conv_w": _normal(r[1], (3 * nd, self.conv_width)),
+                "w_b": _normal(r[2], (h, self.n)),
+                "w_f1": _normal(r[3], (h, rank)),
+                "w_f2": _normal(r[4], (rank, nd)),
+                "A_log": jnp.log(jax.random.uniform(
+                    r[5], (self.n,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": decay_bias(r[9], (nd,)),
+                "w_g1": _normal(r[6], (h, rank)),
+                "w_g2": _normal(r[7], (rank, nd)),
+                "norm_w": jnp.ones((self.d,)),
+                "w_o": _normal(r[8], (nd, h))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        b, l, _ = x.shape
+        n, d, f32 = self.n, self.d, jnp.float32
+        heads = lambda t: t.reshape(b, l, n, d)
+        mixed = jax.nn.silu(causal_depthwise_conv(
+            x @ params["w_qkv"], params["conv_w"], "zoo_kda_conv"))
+        q, k, v = (heads(mixed[..., i * n * d:(i + 1) * n * d])
+                   for i in range(3))
+        beta = jax.nn.sigmoid((x @ params["w_b"]).astype(f32))
+        a = ((x @ params["w_f1"]) @ params["w_f2"]).astype(f32)
+        g = -jnp.exp(params["A_log"].astype(f32))[:, None] * heads(
+            jax.nn.softplus(a + params["dt_bias"].astype(f32)))
+        o = chunk_gated_delta_rule(
+            (_l2(q.astype(f32)) / math.sqrt(d)).astype(x.dtype),
+            _l2(k.astype(f32)).astype(x.dtype), v, g, beta,
+            self.chunk_size).astype(f32)
+        o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + self.eps)
+        gate = ((x @ params["w_g1"]) @ params["w_g2"]).astype(f32)
+        y = (o * jax.nn.sigmoid(heads(gate))).astype(x.dtype)
+        return y.reshape(b, l, n * d) @ params["w_o"]
+
+
+class LatentAttention(KerasLayer):
+    """Causal multi-head latent attention without positions: queries of
+    ``nope_dim + rope_dim`` a head straight from x; keys and values through
+    a latent of ``kv_rank`` (``[c | k_r] = W_kva x``, ``[k_n | v] = W_kvb
+    RMSNorm(c)``), a head's key ``[k_n | k_r]`` with ``k_r`` (``rope_dim``
+    wide) shared by all heads and no rotary applied to it; softmax at
+    ``(nope_dim + rope_dim) ** -0.5`` through the flash kernels, whose
+    values are ``v_dim`` wide beside keys of another width. (B, L, H) ->
+    (B, L, H); no bias anywhere."""
+
+    def __init__(self, n_head: int, nope_dim: int, rope_dim: int,
+                 v_dim: int, kv_rank: int, eps: float = 1e-5,
+                 input_shape=None, name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        self.n, self.nope, self.rope, self.dv, self.rank = n_head, \
+            nope_dim, rope_dim, v_dim, kv_rank
+        self.eps = eps
+
+    def build(self, rng, input_shape):
+        h, n = int(input_shape[-1]), self.n
+        r = jax.random.split(rng, 4)
+        return {"w_q": _normal(r[0], (h, n * (self.nope + self.rope))),
+                "w_kva": _normal(r[1], (h, self.rank + self.rope)),
+                "kv_norm": jnp.zeros((self.rank,)),
+                "w_kvb": _normal(r[2], (self.rank, n * (self.nope + self.dv))),
+                "w_o": _normal(r[3], (n * self.dv, h))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        b, l, _ = x.shape
+        n, nope, rope, dv = self.n, self.nope, self.rope, self.dv
+        with jax.named_scope("zoo_mla_proj"):
+            q = (x @ params["w_q"]).reshape(b, l, n, nope + rope)
+            kva = x @ params["w_kva"]
+            kv = (rms_norm(kva[..., :self.rank], params["kv_norm"], self.eps)
+                  @ params["w_kvb"]).reshape(b, l, n, nope + dv)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                kva[:, :, None, self.rank:], (b, l, n, rope))], -1)
+        tr = lambda t: t.transpose(0, 2, 1, 3)
+        with jax.named_scope("zoo_mla_attn"):
+            o = tr(flash_attention(tr(q), tr(k), tr(kv[..., nope:]),
+                                   causal=True,
+                                   sm_scale=1.0 / math.sqrt(nope + rope)))
+        with jax.named_scope("zoo_mla_proj"):
+            return o.reshape(b, l, n * dv) @ params["w_o"]
+
+
+class GatedMLP(KerasLayer):
+    """``W_down(SiLU(W_gate x) * W_up x)``: the dense feed-forward of a
+    decoder's leading blocks. (..., H) -> (..., H); HLO scope
+    ``zoo_dense_mlp``."""
+
+    def __init__(self, intermediate_size: int, input_shape=None,
+                 name: Optional[str] = None, **kwargs):
+        super().__init__(input_shape=input_shape, name=name)
+        self.intermediate_size = intermediate_size
+
+    def build(self, rng, input_shape):
+        h, f = int(input_shape[-1]), self.intermediate_size
+        r = jax.random.split(rng, 3)
+        return {"w_gate": _normal(r[0], (h, f)), "w_up": _normal(r[1], (h, f)),
+                "w_down": _normal(r[2], (f, h))}
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        f32 = jnp.float32
+        with jax.named_scope("zoo_dense_mlp"):
+            a = jnp.dot(x, params["w_gate"], preferred_element_type=f32)
+            u = jnp.dot(x, params["w_up"], preferred_element_type=f32)
+            return (jax.nn.silu(a) * u).astype(x.dtype) @ params["w_down"]
+
+
+class HeldExpertsMoE(KerasLayer):
+    """Dropless mixture of gated-MLP experts of which this layer holds
+    ``n_held`` (experts ``first_expert .. first_expert + n_held - 1`` of the
+    router's ``n_routed``), plus one shared expert (``shared_size`` 0:
+    none) behind a sigmoid gate (``shared_gate``) or added as it is.
+
+    The router's form is the configuration's. ``scoring`` ``"softmax"``:
+    the scores are the softmax of the router's outputs; ``"sigmoid"``:
+    their sigmoid, each expert by itself. The ``top_k`` experts are the
+    largest of the scores plus, with ``select_bias``, a bias an expert
+    (parameter ``router_bias``, which takes no gradient: whoever balances
+    the load sets it); the weights are the scores at the chosen experts
+    (never the bias), divided by their sum (``norm_topk``) and times
+    ``routed_scale``. Scores, choice and normalisation are over all
     ``n_routed`` outputs; the layer computes the part of the routed sum
     its own experts give, for every assignment that lands on them: there
     is no capacity and nothing is dropped (``ops/grouped_experts.py``).
-    With ``n_held == n_routed`` it is the whole layer. Stateful only in
+    With ``n_held == n_routed`` it is the whole layer. ``tile``: the rows
+    of a tile of the expert loop; left out, what the call's tokens make
+    of it (``expected_tile``). Stateful only in
     that it reports its routing each step (``MOE_STATS``).
     (..., H) -> (..., H)."""
 
@@ -200,9 +359,15 @@ class HeldExpertsMoE(KerasLayer):
 
     def __init__(self, n_routed: int, n_held: int, intermediate_size: int,
                  top_k: int, shared_size: int = 0, first_expert: int = 0,
-                 norm_topk: bool = True, tile: int = DEFAULT_TILE,
+                 norm_topk: bool = True, tile: Optional[int] = None,
+                 scoring: str = "softmax", select_bias: bool = False,
+                 routed_scale: float = 1.0, shared_gate: bool = True,
                  input_shape=None, name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r}")
+        self.scoring, self.select_bias = scoring, select_bias
+        self.routed_scale, self.shared_gate = routed_scale, shared_gate
         if not 0 <= first_expert <= first_expert + n_held <= n_routed:
             raise ValueError(f"experts {first_expert}..{first_expert + n_held}"
                              f" of {n_routed}")
@@ -222,11 +387,14 @@ class HeldExpertsMoE(KerasLayer):
                   "w_gate": _normal(r[1], (e, h, f)),
                   "w_up": _normal(r[2], (e, h, f)),
                   "w_down": _normal(r[3], (e, f, h))}
+        if self.select_bias:
+            params["router_bias"] = jnp.zeros((self.n_routed,))
         if fs:
             params.update(s_gate=_normal(r[4], (h, fs)),
                           s_up=_normal(r[5], (h, fs)),
-                          s_down=_normal(r[6], (fs, h)),
-                          s_gate_w=_normal(r[7], (h,)))
+                          s_down=_normal(r[6], (fs, h)))
+            if self.shared_gate:
+                params["s_gate_w"] = _normal(r[7], (h,))
         self._annotate(router=("embed", None),
                        w_gate=("expert", "embed", "mlp"),
                        w_up=("expert", "embed", "mlp"),
@@ -245,39 +413,61 @@ class HeldExpertsMoE(KerasLayer):
         with jax.named_scope("zoo_moe_route"):
             logits = jnp.dot(flat, params["router"],
                              preferred_element_type=f32)
-            top_w, top_i = jax.lax.top_k(jax.nn.softmax(logits, -1),
-                                         self.top_k)
+            scores = jax.nn.softmax(logits, -1) \
+                if self.scoring == "softmax" else jax.nn.sigmoid(logits)
+            if self.select_bias:
+                _, top_i = jax.lax.top_k(scores + jax.lax.stop_gradient(
+                    params["router_bias"].astype(f32)), self.top_k)
+                top_w = jnp.take_along_axis(scores, top_i, -1)
+            else:
+                top_w, top_i = jax.lax.top_k(scores, self.top_k)
             if self.norm_topk:
                 top_w = top_w / jnp.sum(top_w, -1, keepdims=True)
+            top_w = top_w * self.routed_scale
+            tile = self.tile or expected_tile(flat.shape[0], self.top_k,
+                                              self.n_routed)
             tables = route_tables(top_i, self.first_expert, self.n_held,
-                                  self.tile)
+                                  tile)
             held = jnp.sum(tables.counts).astype(f32)
             counts = tables.counts.astype(f32)
             stats = dict(zip(MOE_STATS, (
                 jnp.asarray(float(top_i.size), f32), held,
                 held - jnp.sum(tables.tile_rows).astype(f32),
-                jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9))))
+                jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
+                tables.n_tiles.astype(f32))))
         out = grouped_experts(flat, params["w_gate"], params["w_up"],
-                              params["w_down"], top_w, tables, self.tile)
+                              params["w_down"], top_w, tables, tile)
         if self.shared_size:
             with jax.named_scope("zoo_moe_shared"):
                 a = jnp.dot(flat, params["s_gate"],
                             preferred_element_type=f32)
                 u = jnp.dot(flat, params["s_up"], preferred_element_type=f32)
                 y = (jax.nn.silu(a) * u).astype(x.dtype) @ params["s_down"]
-                gate = jax.nn.sigmoid(jnp.dot(
-                    flat, params["s_gate_w"], preferred_element_type=f32))
-                out = out + (y * gate[:, None].astype(x.dtype))
+                if self.shared_gate:
+                    gate = jax.nn.sigmoid(jnp.dot(
+                        flat, params["s_gate_w"], preferred_element_type=f32))
+                    y = y * gate[:, None].astype(x.dtype)
+                out = out + y
         return out.reshape(x.shape), {"step_stats": stats}
+
+
+MIXERS = {LINEAR: GatedDeltaNet, FULL: GatedAttention,
+          KDA: KimiDeltaAttention, LATENT: LatentAttention}
+
+
+def _ff_key(ff) -> str:
+    return "moe" if ff.has_state else "mlp"
 
 
 class HybridDecoder(KerasLayer):
     """Token ids (B, L) -> hidden states (B, L, H): an embedding, then
-    ``layer_types`` blocks (each ``"linear_attention"`` or
-    ``"full_attention"``) of ``h = x + Mixer(N(x))``, ``y = h +
-    Experts(N(h))``, then a final norm. ``mixers`` and ``moe`` hold the
-    keyword arguments of :class:`GatedDeltaNet`, :class:`GatedAttention`
-    (by layer type) and :class:`HeldExpertsMoE`. Each block is recomputed
+    ``layer_types`` blocks (each one of ``MIXERS``' names) of ``h = x +
+    Mixer(N(x))``, ``y = h + FF(N(h))``, then a final norm. ``mixers``
+    holds the keyword arguments of the mixers' classes by layer type,
+    ``moe`` those of :class:`HeldExpertsMoE`; the first ``dense_blocks``
+    blocks have a :class:`GatedMLP` of ``dense_size`` for their ``FF``
+    (parameters under ``"mlp"``), the others the expert layer
+    (``"moe"``). Each block is recomputed
     in the backward pass, so a step keeps one block's activations and every
     block's input; ``remat_rows`` sequences of the batch go through a block
     at a time (None: all at once), which bounds those activations by the
@@ -288,18 +478,21 @@ class HybridDecoder(KerasLayer):
     def __init__(self, vocab: int, hidden_size: int,
                  layer_types: Sequence[str], mixers: dict, moe: dict,
                  eps: float = 1e-6, remat_rows: Optional[int] = None,
+                 dense_blocks: int = 0, dense_size: int = 0,
                  input_shape=None,
                  name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
-        unknown = set(layer_types) - {LINEAR, FULL}
+        unknown = set(layer_types) - set(MIXERS)
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         self.vocab, self.hidden_size, self.eps = vocab, hidden_size, eps
         self.remat_rows = remat_rows
         self.layer_types = tuple(layer_types)
         self.blocks = [
-            ((GatedAttention if kind == FULL else GatedDeltaNet)(
-                eps=eps, name=f"{self.name}_mixer{i}", **mixers[kind]),
+            (MIXERS[kind](eps=eps, name=f"{self.name}_mixer{i}",
+                          **mixers[kind]),
+             GatedMLP(dense_size, name=f"{self.name}_mlp{i}")
+             if i < dense_blocks else
              HeldExpertsMoE(name=f"{self.name}_moe{i}", **moe))
             for i, kind in enumerate(self.layer_types)]
 
@@ -312,22 +505,23 @@ class HybridDecoder(KerasLayer):
         keys = jax.random.split(rng, 2 * len(self.blocks) + 1)
         params = {"embed": _normal(keys[-1], (self.vocab, h)),
                   "final_norm": jnp.zeros((h,))}
-        for i, (mixer, moe) in enumerate(self.blocks):
+        for i, (mixer, ff) in enumerate(self.blocks):
             params[f"block{i}"] = {
                 "norm1": jnp.zeros((h,)),
                 "mixer": mixer.build(keys[2 * i], shape),
                 "norm2": jnp.zeros((h,)),
-                "moe": moe.build(keys[2 * i + 1], shape)}
+                _ff_key(ff): ff.build(keys[2 * i + 1], shape)}
         return params
 
     def init_state(self, input_shape):
-        return {f"block{i}": moe.init_state(None)
-                for i, (_, moe) in enumerate(self.blocks)}
+        return {f"block{i}": ff.init_state(None) if ff.has_state else {}
+                for i, (_, ff) in enumerate(self.blocks)}
 
     def _block(self, i, p, x):
-        mixer, moe = self.blocks[i]
+        mixer, ff = self.blocks[i]
         h = x + mixer.call(p["mixer"], rms_norm(x, p["norm1"], self.eps))
-        y, state = moe.call(p["moe"], rms_norm(h, p["norm2"], self.eps))
+        out = ff.call(p[_ff_key(ff)], rms_norm(h, p["norm2"], self.eps))
+        y, state = out if ff.has_state else (out, {})
         return h + y, state
 
     def call(self, params, inputs, training: bool = False, state=None,
@@ -352,9 +546,10 @@ class HybridDecoder(KerasLayer):
                     jax.checkpoint(fn), x.reshape((b // rows, rows) +
                                                   x.shape[1:]))
                 x = x.reshape((b,) + x.shape[2:])
-                state = {"step_stats": {
+                state = {name: {
                     k: v.sum() if k.endswith("_total") else v.max()
-                    for k, v in state["step_stats"].items()}}
+                    for k, v in stats.items()}
+                    for name, stats in state.items()}
             new_state[f"block{i}"] = state
         return rms_norm(x, params["final_norm"], self.eps), new_state
 

@@ -289,7 +289,8 @@ def mosaic_kernel_counts(hlo) -> dict:
     ``ops/`` therefore sits in a ``zoo_*`` scope (``zoo_flash_fwd``,
     ``zoo_flash_bwd_dq``, ``zoo_flash_bwd_dkv``, ``zoo_dln_fwd``,
     ``zoo_dln_bwd``, ``zoo_gdn_local_fwd``, ``zoo_gdn_local_bwd``,
-    ``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``), and the innermost such
+    ``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``, and the same four under
+    ``zoo_kda_`` for a decay per channel), and the innermost such
     scope is the tag. A custom
     call outside any ``zoo_*`` scope counts under ``"untagged"``.
 

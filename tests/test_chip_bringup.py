@@ -140,6 +140,91 @@ def test_delta_rule_kernels_cross_lower_at_the_hybrid_cells_shape(on_tpu):
     assert "stablehlo.while" not in forward
 
 
+def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
+    """A block of `kimilinear_pretrain_l8192`: one sequence of 8,192, 32
+    heads of 128. Kimi Delta Attention's four kernels lower under their own
+    names inside ``zoo_kda_scan`` (what `mosaic_kernel_counts` and the
+    benchmark's scope metrics match), the log decay read as (B, L, heads x
+    128) beside q, k, v; nothing of the op loops outside a kernel. Latent
+    attention's flash kernels lower with keys of 192 and values of 128."""
+    from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
+
+    s = jax.ShapeDtypeStruct
+    x = s((1, 8192, 32, 128), jnp.bfloat16)
+    decay, beta = s((1, 8192, 32, 128), jnp.float32), \
+        s((1, 8192, 32), jnp.float32)
+
+    def loss(*a):
+        return (G.chunk_gated_delta_rule(*a).astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_lowered(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        x, x, x, decay, beta)
+    assert _kernel_names(mlir) == ["zoo_kda_local_bwd", "zoo_kda_local_fwd",
+                                   "zoo_kda_scan_bwd", "zoo_kda_scan_fwd"]
+    assert "1x8192x4096xf32" in mlir and "32x1x64x128x128xbf16" in mlir
+    assert "stablehlo.while" not in mlir
+    sites = _call_sites(mlir)
+    assert mosaic_kernel_counts("\n".join(sites)) == {
+        "zoo_kda_local_fwd": 1, "zoo_kda_scan_fwd": 1,
+        "zoo_kda_scan_bwd": 1, "zoo_kda_local_bwd": 1}
+    assert all("zoo_kda_scan" in re.findall(r"zoo_[a-z0-9_]+", site)
+               for site in sites)
+
+    q = s((1, 32, 8192, 192), jnp.bfloat16)
+    v = s((1, 32, 8192, 128), jnp.bfloat16)
+
+    def attn(q, k, v):
+        return (A.flash_attention(q, k, v, causal=True, sm_scale=192 ** -0.5)
+                .astype(jnp.float32) ** 2).sum()
+
+    mlir = _tpu_mlir(jax.grad(attn, argnums=(0, 1, 2)), q, q, v)
+    assert _kernel_names(mlir) == [
+        "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
+    assert "32x8192x192" in mlir and "32x8192x128" in mlir
+
+
+def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
+    """Five blocks as `kimilinear_pretrain_l8192` has them (a dense KDA
+    block, KDA, KDA, latent attention, KDA; heads of 128, keys of 192 and
+    values of 128) at a small width, a block and sequence recomputed at a
+    time: the loss's gradient lowered for the TPU holds, a KDA block, the
+    chunk-local and the loop's forward kernel twice (the forward pass and
+    the block's recomputation) and each backward kernel once, and the
+    flash kernels 2, 1, 1: the `mosaic_kernel_counts` a run on the chip is
+    held to."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers import hybrid_decoder as hd
+    from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
+
+    kinds = [hd.KDA, hd.KDA, hd.KDA, hd.LATENT, hd.KDA]
+    decoder = hd.HybridDecoder(
+        vocab=256, hidden_size=128, layer_types=kinds,
+        mixers={hd.KDA: dict(n_head=2, head_dim=128),
+                hd.LATENT: dict(n_head=2, nope_dim=128, rope_dim=64,
+                                v_dim=128, kv_rank=64)},
+        moe=dict(n_routed=8, n_held=2, intermediate_size=64, top_k=2,
+                 shared_size=64, scoring="sigmoid", select_bias=True,
+                 routed_scale=2.446, shared_gate=False, tile=64),
+        dense_blocks=1, dense_size=256, eps=1e-5, remat_rows=1,
+        name="decoder")
+    head = hd.LMHeadLoss(256, 256)
+    params = jax.eval_shape(lambda: (
+        decoder.build(jax.random.PRNGKey(0), (None, 512)),
+        head.build(jax.random.PRNGKey(1), [(None, 512, 128), (None, 512)])))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params)
+    ids = jax.ShapeDtypeStruct((2, 512), jnp.int32)
+
+    def loss(params, tokens, targets):
+        hidden, _ = decoder.call(params[0], tokens)
+        return head.call(params[1], [hidden, targets]).mean()
+
+    sites = _call_sites(_tpu_lowered(jax.grad(loss), params, ids, ids))
+    assert mosaic_kernel_counts("\n".join(sites)) == {
+        "zoo_kda_local_fwd": 8, "zoo_kda_scan_fwd": 8,
+        "zoo_kda_scan_bwd": 4, "zoo_kda_local_bwd": 4,
+        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq": 1, "zoo_flash_bwd_dkv": 1}
+
+
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
     """The layer's default entry: (B, L, H, d) in, the bhld kernels
     underneath."""
@@ -260,9 +345,18 @@ def test_mosaic_kernel_counts_reads_scope_tags():
         '"jit(f)/pallas_call"}',
         '  %z = f32[8]{0} custom-call(%a), custom_call_target="Sharding", '
         'metadata={op_name="jit(f)/zoo_dln_fwd/x"}',
+        '  %k.1 = bf16[32,1,64,128,128]{4,3,2,1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/while/body/checkpoint/zoo_kda_scan/zoo_kda_local_fwd/'
+        'pallas_call"}',
+        '  %k.2 = f32[1,8192,4096]{2,1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/transpose(jvp(zoo_kda_scan))/zoo_kda_scan/'
+        'zoo_kda_local_bwd/pallas_call"}',
     ])
     assert mosaic_kernel_counts(hlo) == {
-        "zoo_flash_fwd": 1, "zoo_flash_bwd_dq": 2, "untagged": 1}
+        "zoo_flash_fwd": 1, "zoo_flash_bwd_dq": 2, "untagged": 1,
+        "zoo_kda_local_fwd": 1, "zoo_kda_local_bwd": 1}
 
 
 def test_peak_flops_is_keyed_by_exact_device_kind():

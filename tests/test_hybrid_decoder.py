@@ -266,7 +266,7 @@ def test_delta_rule_kernels_are_the_recurrence(monkeypatch, heads, length):
         (a[1], a[4][0])) or real(*a))
     monkeypatch.setattr(
         delta_rule, "_local_call", lambda *a, **kw: calls.append(
-            (a[1], a[5].shape[3] // 2)) or real_local(*a, **kw))
+            (a[1], a[3].shape[3] // 2)) or real_local(*a, **kw))
     ours, g = out_and_grads(lambda *a: chunk_gated_delta_rule(*a), co, *args)
     # (the output's own calls, then the gradient's)
     assert calls == [("zoo_gdn_local_fwd", min(heads, 8)),

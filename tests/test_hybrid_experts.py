@@ -102,6 +102,26 @@ def moe_layer(first=2, held=4, tile=8, sz=SZ):
         shared_size=sz["shared_width"], tile=tile)
 
 
+@pytest.mark.parametrize("tokens,top_k,routed,tile", [
+    (8192, 10, 512, 256),       # the Qwen3-Next share: 160 an expert
+    (8192, 8, 256, 512),        # the Kimi Linear share: 256 an expert
+    (16384, 8, 256, 1024), (96, 4, 16, 256)])
+def test_the_tile_follows_from_the_calls_tokens(tokens, top_k, routed, tile):
+    """One and a half times an expert's even share, in 256, 512, ...: the
+    two cells' shares get the tiles they were measured at, and a layer
+    given no tile computes what a layer given one does."""
+    from analytics_zoo_tpu.ops.grouped_experts import expected_tile
+    assert expected_tile(tokens, top_k, routed) == tile
+    if tokens > 96:
+        return
+    _, w = weights()
+    p, x = w["blocks"][1]["moe"], x_of((2, 48, 64), 6)
+    given, _ = jax.jit(moe_layer().call)(p, x)
+    derived, state = jax.jit(moe_layer(tile=None).call)(p, x)
+    assert rel(derived, given) < 1e-6
+    assert float(state["step_stats"]["zoo_moe_dropped_total"]) == 0
+
+
 def test_expert_layer_matches_the_reference_and_reports_its_routing():
     sz, w = weights()
     p = w["blocks"][1]["moe"]

@@ -43,10 +43,10 @@ def _shape(*dims):
     return jax.ShapeDtypeStruct(dims, jnp.float32)
 
 
-def _attention(length, d=64):
+def _attention(length, d=64, dv=None):
     x = _shape(1, 2, length, d)
     return (lambda q, k, v: A.flash_attention(q, k, v, causal=True)), \
-        (x, x, x)
+        (x, x, _shape(1, 2, length, dv or d))
 
 
 def _dln(rows, d=128):
@@ -55,13 +55,18 @@ def _dln(rows, d=128):
         x, r, g, b, jax.random.key(0), 0.1)), (x, x, w, w)
 
 
-def _delta_rule(length, chunk=128):
+def _delta_rule(length, chunk=128, per_channel=False):
     qk, gb = _shape(1, length, 2, 128), _shape(1, length, 2)
     return (lambda q, k, v, g, b: chunk_gated_delta_rule(
-        q, k, v, g, b, chunk)), (qk, qk, qk, gb, gb)
+        q, k, v, g, b, chunk)), (qk, qk, qk, qk if per_channel else gb, gb)
 
 
-OPS = {"attention": _attention, "dln": _dln, "delta_rule": _delta_rule}
+OPS = {"attention": _attention, "dln": _dln, "delta_rule": _delta_rule,
+       # the shapes Kimi Linear brought: a decay per key channel, and
+       # values narrower than keys
+       "delta_rule_per_channel": functools.partial(_delta_rule,
+                                                   per_channel=True),
+       "latent_attention": functools.partial(_attention, d=192, dv=128)}
 
 
 def _kernels(fn, args):
@@ -85,9 +90,12 @@ def test_kernel_or_xla_is_one_decision(monkeypatch, op, env, kernel):
     assert bool(found) == kernel, found
     if kernel:
         assert found == {"attention": ["zoo_flash_fwd"],
+                         "latent_attention": ["zoo_flash_fwd"],
                          "dln": ["zoo_dln_fwd"],
                          "delta_rule": ["zoo_gdn_local_fwd",
-                                        "zoo_gdn_scan_fwd"]}[op]
+                                        "zoo_gdn_scan_fwd"],
+                         "delta_rule_per_channel": [
+                             "zoo_kda_local_fwd", "zoo_kda_scan_fwd"]}[op]
 
 
 @pytest.mark.parametrize("op,build,why", [
@@ -96,6 +104,11 @@ def test_kernel_or_xla_is_one_decision(monkeypatch, op, env, kernel):
     ("delta_rule", lambda: _delta_rule(8192, chunk=64),
      "the delta rule at length 8192 has no kernel route: chunk 64"),
     ("dln", lambda: _dln(8192, d=96), None),
+    ("delta_rule_per_channel",
+     lambda: _delta_rule(8192, chunk=64, per_channel=True),
+     "the delta rule at length 8192 has no kernel route: chunk 64"),
+    ("latent_attention", lambda: _attention(8192, d=192, dv=96),
+     "attention at length 8192 has no kernel route: value head size 96"),
 ])
 def test_a_long_call_without_a_kernel_is_loud_on_the_chip(monkeypatch, op,
                                                           build, why):
@@ -119,6 +132,7 @@ BLOCKS = [
     # (lq, lk, head size) -> (block_q, block_k); the two cells' shapes first
     ((512, 512, 64), (512, 512)),             # bert_train_l512
     ((8192, 8192, 256), (512, 512)),          # qwen3next_pretrain_l8192
+    ((8192, 8192, 192), (512, 512)),          # kimilinear_pretrain_l8192
     ((8192, 8192, 64), (512, 1024)),
     ((640, 640, 64), (128, 128)),             # only 128 divides it
     ((128, 2048, None), (128, 1024)),
