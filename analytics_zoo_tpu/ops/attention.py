@@ -10,8 +10,10 @@ forward and backward, and which one is decided from its shape
 - the Pallas kernels: blockwise online softmax so the L×L score matrix
   never reaches HBM, wide MXU tiles (up to 512×1024, :func:`_resolve_blocks`,
   a function of the shape), bf16 MXU dots with f32 accumulation. The
-  forward kernel saves the per-row log-sum-exp; two backward kernels (dq;
-  dk, dv and the bias) rebuild each score block from it. The kernels take an
+  forward kernel saves the per-row log-sum-exp; the backward rebuilds
+  each score block from it, in one kernel (dq, dk, dv and the bias) where
+  a key/value head's dq stays in VMEM and else in two (dq; dk, dv and the
+  bias): :func:`_dq_stays_in_vmem`. The kernels take an
   optional *key bias*, an additive (B, Lk) bias broadcast over heads and
   query positions — the shape of the BERT padding-mask bias
   ``(1-mask)*-10000`` (self_attention.py) — and grouped query heads over
@@ -490,9 +492,10 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
 
 
 # ---------------------------------------------------------------------------
-# Dedicated backward kernels (two-pass recompute, standard flash scheme):
-# scores are rebuilt blockwise from (q, k, bias) and normalized with the
-# saved per-row lse, so backward is O(L) memory like forward.
+# Dedicated backward kernels (recompute, standard flash scheme): scores are
+# rebuilt blockwise from (q, k, bias) and normalized with the saved per-row
+# lse, so backward is O(L) memory like forward. Two passes (a dq kernel, a
+# dk/dv/bias kernel), or one fused kernel where dq stays in VMEM.
 # ---------------------------------------------------------------------------
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
@@ -602,9 +605,89 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
         db_ref[0] = db_scr[...].astype(db_ref.dtype)
 
 
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
+                            delta_ref, dk_ref, dv_ref, db_ref, dq_ref,
+                            dk_scr, dv_scr, db_scr, dq_scr, *, sm_scale,
+                            causal, block_q, block_k, num_q_blocks,
+                            num_k_blocks, q_offset=0, group=1):
+    """The dkv kernel's grid and body plus dq: each score tile is rebuilt
+    once and feeds all four cotangents. dq of the ``group`` query heads
+    that read this key/value head, ``(group * lq, d)`` float32, stays in a
+    scratch over the head's whole sweep of key and query blocks (that it
+    fits is :func:`_dq_stays_in_vmem`'s rule) and is written once."""
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(1)
+    step = pl.program_id(2)
+    qi = step if group == 1 else step % num_q_blocks
+    last_step = group * num_q_blocks - 1
+
+    @pl.when(step == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+        db_scr[...] = jnp.zeros_like(db_scr)
+
+    @pl.when((ki == 0) & (step == 0))
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def _compute():
+        q = q_ref[0]                                # (block_q, d)
+        k = k_ref[0]                                # (block_k, d)
+        v = v_ref[0]
+        do = do_ref[0]                              # (block_q, d)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s = s + kb_ref[0].astype(jnp.float32)
+        if causal:
+            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+        p = jnp.exp(s - lse_ref[0])                 # (block_q, block_k)
+        dv_scr[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)     # (block_k, d)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)     # (block_q, block_k)
+        ds = p * (dp - delta_ref[0])
+        dk_scr[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
+        # query head step // num_q's block qi, in dq seen as
+        # (group * lq, d): consecutive query heads share this k/v head
+        rows = pl.ds(pl.multiple_of(step * block_q, block_q), block_q)
+        dq_scr[rows, :] += jax.lax.dot(
+            ds.astype(k.dtype), k,
+            preferred_element_type=jnp.float32) * sm_scale
+
+    if causal:
+        pl.when(q_offset + (qi + 1) * block_q - 1 >= ki * block_k)(_compute)
+    else:
+        _compute()
+
+    @pl.when(step == last_step)
+    def _finalize():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        db_ref[0] = db_scr[...].astype(db_ref.dtype)
+
+    @pl.when((ki == num_k_blocks - 1) & (step == last_step))
+    def _finalize_dq():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
 def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
                     block_q=None, block_k=None, group=1):
-    """Blockwise dq/dk/dv/dbias. Returns grads matching (q, k, v, kbias)."""
+    """Blockwise dq/dk/dv/dbias. Returns grads matching (q, k, v, kbias).
+    One kernel where a key/value head's dq stays in VMEM
+    (:func:`_dq_stays_in_vmem`), else one for dq and one for dk, dv and
+    the bias."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -621,47 +704,16 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         axis=-1, keepdims=True)
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
-    qkv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    do_spec_q = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
-    qkv_spec_k = _kv_spec(block_k, d, group)
-    row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-
-    dq_call = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_k_blocks=num_k,
-            q_offset=lk - lq),
-        name="zoo_flash_bwd_dq",
-        grid=(bh, num_q, num_k),
-        in_specs=[qkv_spec_q, qkv_spec_k, _kv_spec(block_k, dv, group),
-                  _bias_specs_3d(num_heads, block_k),
-                  do_spec_q, row_spec_q, row_spec_q],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=out_struct((bh, lq, d), q.dtype, q, k, v, do),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_route.interpret_mode(),
-    )
-    with jax.named_scope("zoo_flash_bwd_dq"):
-        dq = dq_call(q, k, v, kbias3, do, lse, delta)
-
-    # dk/dv/dbias: grid transposed — k blocks parallel, q blocks innermost
-    # (accumulation axis).
-    # (with grouped heads the grid runs over the key/value heads, and its
-    # innermost axis over ``group`` query heads' blocks in turn)
+    # dk/dv/dbias: k blocks parallel, q blocks innermost (accumulation
+    # axis). With grouped heads the grid runs over the key/value heads, and
+    # its innermost axis over ``group`` query heads' blocks in turn.
     kv_spec = lambda size: pl.BlockSpec((1, block_k, size),
                                         lambda b, j, i: (b, j, 0))
     q_at = (lambda b, j, i: (b, i, 0)) if group == 1 else \
         (lambda b, j, i, g=group, n=num_q: (b * g + i // n, i % n, 0))
     q_spec = lambda size: pl.BlockSpec((1, block_q, size), q_at)
     kv_heads = num_heads // group
-    dkv_call = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, num_q_blocks=num_q,
-            q_offset=lk - lq, group=group),
-        name="zoo_flash_bwd_dkv",
+    dkv = dict(
         grid=(bh // group, num_k, group * num_q),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv),
                   pl.BlockSpec((1, 1, block_k),
@@ -681,12 +733,67 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             pltpu.VMEM((block_k, dv), jnp.float32),
             pltpu.VMEM((1, block_k), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_route.interpret_mode(),
     )
-    with jax.named_scope("zoo_flash_bwd_dkv"):
-        dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)
+    kernel_args = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                       block_k=block_k, q_offset=lk - lq)
+
+    if _dq_stays_in_vmem(group, lq, d):
+        # dq as (kv heads, group * lq, d): a free reshape of (bh, lq, d),
+        # and one block a key/value head, resident over its whole sweep
+        fused_call = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_fused_kernel, num_q_blocks=num_q,
+                num_k_blocks=num_k, group=group, **kernel_args),
+            # the name holds ``bwd_dq``: the benchmark's flash rooflines
+            # search a trace for zoo_flash_(fwd|bwd_dq|bwd_dkv)
+            name="zoo_flash_bwd_dq_dkv",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            **dict(
+                dkv,
+                out_specs=dkv["out_specs"] + [pl.BlockSpec(
+                    (1, group * lq, d), lambda b, j, i: (b, 0, 0))],
+                out_shape=dkv["out_shape"] + [out_struct(
+                    (bh // group, group * lq, d), q.dtype, q, k, v, do)],
+                scratch_shapes=dkv["scratch_shapes"] + [
+                    pltpu.VMEM((group * lq, d), jnp.float32)]))
+        with jax.named_scope("zoo_flash_bwd_dq_dkv"):
+            dk, dv, db, dq = fused_call(q, k, v, kbias3, do, lse, delta)
+        dq = dq.reshape(bh, lq, d)
+    else:
+        qkv_spec_q = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+        do_spec_q = pl.BlockSpec((1, block_q, dv),
+                                 lambda b, i, j: (b, i, 0))
+        row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+        dq_call = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, num_k_blocks=num_k,
+                              **kernel_args),
+            name="zoo_flash_bwd_dq",
+            grid=(bh, num_q, num_k),
+            in_specs=[qkv_spec_q, _kv_spec(block_k, d, group),
+                      _kv_spec(block_k, dv, group),
+                      _bias_specs_3d(num_heads, block_k),
+                      do_spec_q, row_spec_q, row_spec_q],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda b, i, j: (b, i, 0)),
+            out_shape=out_struct((bh, lq, d), q.dtype, q, k, v, do),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_route.interpret_mode(),
+        )
+        with jax.named_scope("zoo_flash_bwd_dq"):
+            dq = dq_call(q, k, v, kbias3, do, lse, delta)
+        dkv_call = pl.pallas_call(
+            functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=num_q,
+                              group=group, **kernel_args),
+            name="zoo_flash_bwd_dkv",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            **dkv)
+        with jax.named_scope("zoo_flash_bwd_dkv"):
+            dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)
     # bias grad: the (B, Lk) key bias broadcasts over heads and query
     # rows, so its cotangent sums ds over both — rows inside the kernel,
     # heads here.
@@ -710,8 +817,9 @@ def _flash_fwd_rule(q, k, v, kbias, num_heads, causal, sm_scale,
 
 def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, group,
                     res, do):
-    """The one backward: the two Pallas kernels, rebuilding score blocks
-    from (q, k, bias) and the saved lse (O(L) memory)."""
+    """The one backward: Pallas kernels (one or two, by the shape:
+    :func:`_flash_backward`) rebuilding score blocks from (q, k, bias) and
+    the saved lse (O(L) memory)."""
     q, k, v, kbias, o, lse = res
     return _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal,
                            sm_scale, block_q, block_k, group)
@@ -737,6 +845,24 @@ def _as_key_bias(bias, b, lk) -> Optional[jnp.ndarray]:
 # ``ZOO_TPU_FORCE_PALLAS=1``). No cell of the benchmark sits below it, so
 # the kernels have not been measured there.
 KERNEL_MIN_SEQ = 512
+
+
+# The fused backward keeps a key/value head's whole dq, float32 with the
+# lanes padded to 128, in a VMEM scratch, and its double-buffered output
+# block beside it: at 2 MiB of scratch, 4 MiB in all for bf16 operands (6
+# for float32) beside four float32 score tiles of at most (512, 1024), 8
+# MiB, inside the 16 MiB scoped VMEM (Mosaic takes the limit's shapes,
+# 4,096 positions of 64 and of 128, in either dtype). BERT at 512 and 2,048
+# positions fits (256 KiB, 1 MiB); 8,192 positions do not (8 heads of 256 a
+# key/value head: 64 MiB; one of 192: 8 MiB).
+FUSED_BWD_DQ_BYTES = 2 * 1024 * 1024
+
+
+def _dq_stays_in_vmem(group, lq, d) -> bool:
+    """Whether the backward is the one fused kernel: a function of the
+    call's shape, as :func:`_resolve_blocks` is. Else dq accumulates over
+    key blocks in a kernel of its own, and dk, dv over query blocks."""
+    return group * lq * max(d, 128) * 4 <= FUSED_BWD_DQ_BYTES
 
 
 def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,

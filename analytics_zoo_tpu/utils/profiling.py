@@ -287,7 +287,8 @@ def mosaic_kernel_counts(hlo) -> dict:
     kernel name; what survives is the ``op_name`` metadata, i.e. the
     ``jax.named_scope`` stack at the call site. Every ``pallas_call`` in
     ``ops/`` therefore sits in a ``zoo_*`` scope (``zoo_flash_fwd``,
-    ``zoo_flash_bwd_dq``, ``zoo_flash_bwd_dkv``, ``zoo_dln_fwd``,
+    ``zoo_flash_bwd_dq``, ``zoo_flash_bwd_dkv`` or the fused
+    ``zoo_flash_bwd_dq_dkv``, ``zoo_dln_fwd``,
     ``zoo_dln_bwd``, ``zoo_gdn_local_fwd``, ``zoo_gdn_local_bwd``,
     ``zoo_gdn_scan_fwd``, ``zoo_gdn_scan_bwd``, and the same four under
     ``zoo_kda_`` for a decay per channel), and the innermost such

@@ -48,8 +48,8 @@ PROMPT_LENS = (24, 40, 200, 512)   # buckets 128, 128, 256, 1024
 # seen on a v5e is 0.002; a wrong cache row or mask moves logits by tenths.
 LOGIT_ATOL = 0.02
 
-KERNELS = ("zoo_flash_fwd", "zoo_flash_bwd_dq", "zoo_flash_bwd_dkv",
-           "zoo_dln_fwd", "zoo_dln_bwd")
+KERNELS = ("zoo_flash_fwd", "zoo_flash_bwd_dq_dkv", "zoo_dln_fwd",
+           "zoo_dln_bwd")
 
 
 def log(msg):

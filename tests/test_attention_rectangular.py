@@ -9,6 +9,8 @@ masked top-left aligned and the router rejected causal lq != lk outright.
 These tests pin the rectangular contract on all three layers: the
 blockwise impl, the blhd/bhld entry points, and the interpret-mode
 Pallas kernels (fwd + bwd) now that the router admits causal lq <= lk.
+The kernels' backward is one fused kernel or two by a rule of the shape;
+both are held to the oracle and to each other here.
 """
 
 import numpy as np
@@ -153,6 +155,89 @@ def test_pallas_kernel_rectangular_causal_interpret(monkeypatch, lq, lk):
     assert float(jnp.abs(dq.reshape(b, h, lq, d) - gq).max()) < 1e-4
     assert float(jnp.abs(dk.reshape(b, h, lk, d) - gk).max()) < 1e-4
     assert float(jnp.abs(dv.reshape(b, h, lk, d) - gv).max()) < 1e-4
+
+
+# ---------------------------------------------------------------------------
+# the fused backward: one kernel for dq, dk, dv and the bias, where a
+# key/value head's dq stays in VMEM
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("b,h,hkv,lq,lk,d,dv,causal,bias,block", [
+    (2, 2, 2, 512, 512, 64, 64, False, True, None),    # BERT: one tile a head
+    (1, 4, 1, 512, 512, 64, 64, True, False, None),    # 4 heads a k/v head
+    (1, 2, 2, 512, 512, 192, 128, True, False, None),  # values narrower
+    # 4 x 4 tiles a head: dq accumulates across key blocks, dk and dv
+    # across query blocks and the group's heads
+    (2, 4, 1, 512, 512, 64, 64, False, True, 128),
+    (1, 4, 2, 512, 512, 64, 64, True, True, 128),
+    (1, 2, 1, 256, 512, 64, 64, True, False, 128),     # lq < lk, causal
+])
+def test_fused_backward_matches_the_reference_and_the_two_kernels(
+        monkeypatch, b, h, hkv, lq, lk, d, dv, causal, bias, block):
+    """dq, dk, dv and the key bias's cotangent of the one fused kernel
+    against :func:`attention_reference`'s and against the two-kernel
+    backward on the same operands (the rule's limit set to 0: the call
+    the hybrid cells' shapes get). Same products, same roundings, same
+    order of accumulation: the two agree to float32 rounding."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    from analytics_zoo_tpu.ops import attention as A
+
+    group = h // hkv
+    q = _rand(0, (b, h, lq, d))
+    k = _rand(1, (b, hkv, lk, d))
+    v = _rand(2, (b, hkv, lk, dv))
+    kb = _rand(3, (b, lk)) if bias else jnp.zeros((b, lk), jnp.float32)
+    do = _rand(4, (b, h, lq, dv))
+    sm = 1.0 / np.sqrt(d)
+
+    def ref_loss(q, k, v, kb):
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        return (attention_reference(q, k, v, bias=kb[:, None, None, :],
+                                    causal=causal, sm_scale=sm) * do).sum()
+
+    want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2, 3)))(q, k, v, kb)
+
+    flat = lambda t: t.reshape((-1,) + t.shape[2:])
+    qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(do)
+    o, lse = jax.jit(lambda q, k, v, kb: A._flash_forward(
+        q, k, v, kb, h, causal, sm, block, block, group))(qf, kf, vf, kb)
+
+    def backward(limit):
+        monkeypatch.setattr(A, "FUSED_BWD_DQ_BYTES", limit)
+        fn = lambda *a: A._flash_backward(*a, h, causal, sm, block, block,
+                                          group)
+        names = [e.params["name"] for e in jax.make_jaxpr(fn)(
+            qf, kf, vf, kb, o, lse, dof).eqns
+            if e.primitive.name == "pallas_call"]
+        return names, jax.jit(fn)(qf, kf, vf, kb, o, lse, dof)
+
+    assert A._dq_stays_in_vmem(group, lq, d)
+    names, fused = backward(A.FUSED_BWD_DQ_BYTES)
+    assert names == ["zoo_flash_bwd_dq_dkv"]
+    names, two = backward(0)
+    assert names == ["zoo_flash_bwd_dq", "zoo_flash_bwd_dkv"]
+    for got, ref, other in zip(fused, want, two):
+        assert got.shape == other.shape and got.dtype == other.dtype
+        assert float(jnp.abs(got - other).max()) <= 1e-5
+        assert float(jnp.abs(got.reshape(ref.shape) - ref).max()) < 2e-4
+    if bias:
+        assert float(jnp.abs(want[3]).max()) > 1e-2    # a cotangent to see
+
+
+@pytest.mark.parametrize("group,lq,d,fused", [
+    (1, 512, 64, True),       # BERT at 512: 256 KiB with the lanes padded
+    (1, 2048, 64, True),      # BERT at 2,048: 1 MiB
+    (1, 4096, 64, True),      # the limit itself, 2 MiB
+    (1, 4096, 192, False),
+    (8, 8192, 256, False),    # Qwen3-Next's gated attention: 64 MiB
+    (1, 8192, 192, False),    # Kimi Linear's latent attention: 6.3 MiB
+])
+def test_the_backward_is_fused_where_a_heads_dq_stays_in_vmem(group, lq, d,
+                                                              fused):
+    """The rule is a function of the call's shape and of nothing else."""
+    from analytics_zoo_tpu.ops.attention import _dq_stays_in_vmem
+
+    assert _dq_stays_in_vmem(group, lq, d) is fused
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,9 @@ contract (docs/performance.md).
 - the jaxpr property that the blockwise route NEVER materializes an
   (..., L, L) intermediate for L >= 512, and that an ineligible
   ``flash_attention`` call lands on it;
-- the kernels' backward has one implementation: both backward kernels and
-  no (L, L) intermediate, whatever a deleted switch says.
+- the kernels' backward has one implementation: the backward kernels the
+  shape's rule gives and no (L, L) intermediate, whatever a deleted switch
+  says.
 """
 
 
@@ -168,20 +169,28 @@ def test_blockwise_matches_the_oracle_at_512(causal, with_bias):
 # the kernels' backward has one implementation
 # ---------------------------------------------------------------------------
 
-def test_the_backward_is_the_two_kernels_whatever_the_old_switches_say(
-        monkeypatch):
+@pytest.mark.parametrize("l,backward", [
+    # 2 query heads a key/value head: 2 x 1024 x 128 lanes x 4 = 1 MiB of
+    # dq stays in VMEM, 2 x 8192 x 128 x 4 = 8 MiB does not
+    (1024, ["zoo_flash_bwd_dq_dkv"]),
+    (8192, ["zoo_flash_bwd_dkv", "zoo_flash_bwd_dq"]),
+])
+def test_the_backward_is_the_kernels_whatever_the_old_switches_say(
+        monkeypatch, l, backward):
     """A gradient through the kernel route holds the forward kernel and
-    both backward kernels, found by their ``name=``, and no (L, L)
-    intermediate; ``ZOO_TPU_FLASH_REMAT=full`` and ``ZOO_TPU_FLASH_BWD=xla``
-    once chose a second backward through the reference math, and are
-    names nothing reads now."""
+    the backward the shape's rule gives (one fused kernel, or one for dq
+    and one for dk, dv and the bias), found by their ``name=``, and no
+    (L, L) intermediate; ``ZOO_TPU_FLASH_REMAT=full`` and
+    ``ZOO_TPU_FLASH_BWD=xla`` once chose a second backward through the
+    reference math, and are names nothing reads now."""
     monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setenv("ZOO_TPU_FLASH_REMAT", "full")
     monkeypatch.setenv("ZOO_TPU_FLASH_BWD", "xla")
-    l = 1024          # above the kernels' own 512 x 1024 score tile
-    q = _rand(0, (1, 4, l, 64))
-    k, v = (_rand(i, (1, 2, l, 64)) for i in (1, 2))     # grouped heads
-    kb = _rand(3, (1, 1, 1, l))
+    # 1024 is above the kernels' own 512 x 1024 score tile
+    s = jax.ShapeDtypeStruct
+    q = s((1, 4, l, 64), jnp.float32)
+    k = v = s((1, 2, l, 64), jnp.float32)                # grouped heads
+    kb = s((1, 1, 1, l), jnp.float32)
 
     def g(q, k, v, kb):
         return jax.grad(lambda q, k, v: (flash_attention(
@@ -189,6 +198,5 @@ def test_the_backward_is_the_two_kernels_whatever_the_old_switches_say(
             argnums=(0, 1, 2))(q, k, v)
 
     lxl, scan, kernels = jaxpr_materializes_lxl(g, q, k, v, kb, l=l)
-    assert sorted(kernels) == ["zoo_flash_bwd_dkv", "zoo_flash_bwd_dq",
-                               "zoo_flash_fwd"]
+    assert sorted(kernels) == backward + ["zoo_flash_fwd"]
     assert not lxl and not scan

@@ -81,16 +81,17 @@ def test_flash_attention_cross_lowers_for_tpu(on_tpu, b, h, l, d, causal):
         return (A.flash_attention(q, k, v, bias=bias, causal=causal)
                 .astype(jnp.float32) ** 2).sum()
 
+    # a head's dq stays in VMEM at these lengths: the backward is one kernel
     mlir = _tpu_mlir(jax.grad(loss, argnums=(0, 1, 2)), q, q, q, bias)
-    assert _kernel_names(mlir) == [
-        "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
-    assert mlir.count("tpu_custom_call") == 3
+    assert _kernel_names(mlir) == ["zoo_flash_bwd_dq_dkv", "zoo_flash_fwd"]
+    assert mlir.count("tpu_custom_call") == 2
 
 
 def test_grouped_wide_heads_cross_lower_at_the_hybrid_cells_shape(on_tpu):
     """The gated-attention block of `qwen3next_pretrain_l8192`: one
     sequence of 8,192, 16 query heads of 256 over 2 key/value heads,
-    causal, no bias; forward and both backward kernels, k and v unrepeated
+    causal, no bias; forward and both backward kernels (8 heads' dq of
+    8,192 x 256 float32 is 64 MiB: no fused backward), k and v unrepeated
     (the kernels index the shared head themselves)."""
     s = jax.ShapeDtypeStruct
     q = s((1, 16, 8192, 256), jnp.bfloat16)
@@ -146,7 +147,9 @@ def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
     names inside ``zoo_kda_scan`` (what `mosaic_kernel_counts` and the
     benchmark's scope metrics match), the log decay read as (B, L, heads x
     128) beside q, k, v; nothing of the op loops outside a kernel. Latent
-    attention's flash kernels lower with keys of 192 and values of 128."""
+    attention's flash kernels lower with keys of 192 and values of 128: a
+    head's dq is 8 MiB with the lanes padded, so the backward stays two
+    kernels, and a block recomputed holds them 2, 1, 1."""
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
 
     s = jax.ShapeDtypeStruct
@@ -181,6 +184,11 @@ def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
     assert _kernel_names(mlir) == [
         "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
     assert "32x8192x192" in mlir and "32x8192x128" in mlir
+    sites = _call_sites(_tpu_lowered(
+        jax.value_and_grad(jax.checkpoint(attn), argnums=(0, 1, 2)),
+        q, q, v))
+    assert mosaic_kernel_counts("\n".join(sites)) == {
+        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq": 1, "zoo_flash_bwd_dkv": 1}
 
 
 def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
@@ -190,8 +198,11 @@ def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
     time: the loss's gradient lowered for the TPU holds, a KDA block, the
     chunk-local and the loop's forward kernel twice (the forward pass and
     the block's recomputation) and each backward kernel once, and the
-    flash kernels 2, 1, 1: the `mosaic_kernel_counts` a run on the chip is
-    held to."""
+    flash forward kernel twice. At the 512 positions lowered here a head's
+    dq stays in VMEM and the flash backward is the one fused kernel; at the
+    cell's 8,192 it is two
+    (`test_kimi_linear_kernels_cross_lower_at_the_cells_shape`): 2, 1, 1 is
+    the `mosaic_kernel_counts` a run on the chip is held to."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import hybrid_decoder as hd
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
 
@@ -222,7 +233,7 @@ def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
     assert mosaic_kernel_counts("\n".join(sites)) == {
         "zoo_kda_local_fwd": 8, "zoo_kda_scan_fwd": 8,
         "zoo_kda_scan_bwd": 4, "zoo_kda_local_bwd": 4,
-        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq": 1, "zoo_flash_bwd_dkv": 1}
+        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq_dkv": 1}
 
 
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
@@ -235,6 +246,28 @@ def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
         lambda q, k, v, b: A.flash_attention_blhd(q, k, v, bias=b),
         q, q, q, bias)
     assert _kernel_names(mlir) == ["zoo_flash_fwd"]
+
+
+def test_every_flash_kernels_name_is_found_by_the_rooflines_pattern():
+    """`flash_attn_roofline` sums the device time of the ops whose name
+    `re.search` finds its ``pattern`` in: a kernel of ``ops/attention.py``
+    under a name the pattern misses would drop out of the roofline's
+    seconds and raise the share. Every ``name=`` and every scope the
+    module can emit is found, and each call sits in the scope of its
+    name."""
+    import json
+
+    with open(os.path.join(REPO, "analytics_zoo_tpu/ops/attention.py")) as f:
+        source = f.read()
+    with open(os.path.join(
+            REPO, "benchmark/metrics/flash_attn_roofline.json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    names = re.findall(r'name="(zoo_[a-z_]+)"', source)
+    scopes = re.findall(r'named_scope\("(zoo_[a-z_]+)"\)', source)
+    assert sorted(names) == sorted(scopes) == [
+        "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_bwd_dq_dkv",
+        "zoo_flash_fwd"]
+    assert all(re.search(pattern, name) for name in names)
 
 
 @pytest.mark.parametrize("n,d,dtype", [
@@ -323,8 +356,9 @@ def test_interpret_mode_on_tpu_raises(monkeypatch):
 
 
 def test_mosaic_kernel_counts_reads_scope_tags():
-    """Two instructions as a v5e's optimized HLO prints them (bodies
-    cut), one untagged custom call, one unrelated instruction."""
+    """Instructions as a v5e's optimized HLO prints them (bodies cut), one
+    untagged custom call, one unrelated instruction; the fused flash
+    backward's tag, which begins with the dq kernel's, is its own."""
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
 
     hlo = "\n".join([
@@ -340,6 +374,9 @@ def test_mosaic_kernel_counts_reads_scope_tags():
         '  %x.3 = bf16[24,512,64]{2,1,0} custom-call(%a), '
         'custom_call_target="tpu_custom_call", metadata={op_name='
         '"jit(f)/while/body/zoo_flash_bwd_dq/pallas_call"}',
+        '  %x.4 = bf16[24,512,64]{2,1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/while/body/zoo_flash_bwd_dq_dkv/pallas_call"}',
         '  %y = f32[8]{0} custom-call(%a), '
         'custom_call_target="tpu_custom_call", metadata={op_name='
         '"jit(f)/pallas_call"}',
@@ -356,6 +393,7 @@ def test_mosaic_kernel_counts_reads_scope_tags():
     ])
     assert mosaic_kernel_counts(hlo) == {
         "zoo_flash_fwd": 1, "zoo_flash_bwd_dq": 2, "untagged": 1,
+        "zoo_flash_bwd_dq_dkv": 1,
         "zoo_kda_local_fwd": 1, "zoo_kda_local_bwd": 1}
 
 

@@ -91,7 +91,7 @@ def test_first_nonzero_exit_code_wins(tmp_path):
     script = _write(tmp_path, "codes.py", """
         import os, sys, time
         rank = int(os.environ["ZOO_TPU_PROCESS_ID"])
-        time.sleep(0.1 * rank)
+        time.sleep(1.0 * rank)    # a loaded host starts a rank late by tenths
         sys.exit([5, 9][rank])
     """)
     cap = io.StringIO()
