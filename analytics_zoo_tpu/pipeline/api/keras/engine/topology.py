@@ -347,6 +347,18 @@ class KerasNet(KerasLayer):
     def get_params(self):
         return self._params_tuple()[0]
 
+    def get_state(self):
+        """The layers' non-trainable state, by layer name: what a training
+        step writes without a gradient (docs/training.md)."""
+        return self._params_tuple()[1]
+
+    def set_state(self, state):
+        """Replace the layers' state with a tree of ``get_state``'s
+        structure; the weights stay."""
+        trainer = self._ensure_trainer()
+        trainer.set_state(state)
+        self._built_params = (trainer.params, trainer.net_state)
+
     # -- persistence ---------------------------------------------------
     def save_model(self, path, weight_path=None, over_write=False):
         """Saves architecture (definition JSON: layer classes + captured

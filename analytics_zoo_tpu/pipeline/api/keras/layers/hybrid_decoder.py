@@ -1,17 +1,20 @@
 """Decoder blocks of the kind today's open hybrid models use: a token
 mixer chosen per block from a layer pattern (Gated DeltaNet or Kimi Delta
 Attention linear attention; gated softmax attention with grouped query
-heads and partial rotary positions, or latent attention without
-positions), zero-centred RMSNorm, and a dropless expert layer that is told
-which experts it holds and how its router scores, or a dense gated MLP in
-the leading blocks.
+heads and partial rotary positions, or latent attention with or without
+positions and a query rank), zero-centred RMSNorm, and a dropless expert
+layer that is told which experts it holds, how its router scores and
+whether it balances its selection bias, or a dense gated MLP in the
+leading blocks; optionally a multi-token-prediction module behind the
+stack, which shares the embedding and the head.
 
 Rebuild-scope new work (the reference framework has none of these). The
 layers are the usual stateless descriptions, so each can stand alone in a
 ``Model``; :class:`HybridDecoder` stacks them as ``h = x + Mixer(N(x))``,
 ``y = h + Experts(N(h))`` behind a token embedding and recomputes per
 block, and :class:`LMHeadLoss` closes a language model without ever
-holding the (tokens x vocabulary) logits.
+holding the (tokens x vocabulary) logits, for the stack's stream and, under
+the same head, the prediction module's.
 
 The expert layer follows the usual expert-parallel cut: the router keeps
 its published width and its experts per token, the chip computes its own
@@ -22,7 +25,8 @@ the absent chips.
 HLO scopes (docs/observability.md#names): ``zoo_gdn_conv``,
 ``zoo_gdn_scan``, ``zoo_gated_attn``, ``zoo_kda_conv``, ``zoo_kda_scan``,
 ``zoo_mla_proj``, ``zoo_mla_attn``, ``zoo_dense_mlp``, ``zoo_moe_route``,
-``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_lm_loss``.
+``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_moe_bias``, ``zoo_mtp``,
+``zoo_lm_loss``.
 """
 
 from __future__ import annotations
@@ -48,6 +52,10 @@ KDA, LATENT = "kimi_delta_attention", "latent_attention"
 MOE_STATS = ("zoo_moe_assignments_total", "zoo_moe_assignments_held_total",
              "zoo_moe_dropped_total", "zoo_moe_held_load_max_over_mean",
              "zoo_moe_tiles_total")
+# what a router that balances its bias adds: the largest count of its
+# experts over their mean, which is what the rule acts on
+ROUTER_LOAD = "zoo_moe_router_load_max_over_mean"
+MTP_LOSS = "zoo_mtp_loss"
 
 
 def _normal(rng, shape, std=0.02):
@@ -61,17 +69,27 @@ def rms_norm(x, w, eps):
     return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def partial_rotary(x, rot: int, theta: float):
-    """Rotate-half rotary positions on the first ``rot`` of each head's
-    dimensions of (B, L, heads, d); position t is row t."""
+def partial_rotary(x, rot: int, theta: float, interleave: bool = False):
+    """Rotary positions on the first ``rot`` of each head's dimensions of
+    (B, L, heads, d); position t is row t. Column i of the first half is
+    paired with column i of the second (rotate-half), or with
+    ``interleave`` columns 2i and 2i+1 are a pair; pair i turns by ``t *
+    theta ** (-2i / rot)``, in float32."""
     length = x.shape[1]
     inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
     ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
     xr = x[..., :rot].astype(jnp.float32)
-    half = jnp.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]], -1)
-    return jnp.concatenate([(xr * cos + half * sin).astype(x.dtype),
+    if interleave:
+        twice = lambda t: jnp.repeat(t, 2, -1)
+        other = jnp.stack([-xr[..., 1::2], xr[..., 0::2]], -1).reshape(
+            xr.shape)
+    else:
+        twice = lambda t: jnp.concatenate([t] * 2, -1)
+        other = jnp.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]],
+                                -1)
+    cos = twice(jnp.cos(ang))[None, :, None, :]
+    sin = twice(jnp.sin(ang))[None, :, None, :]
+    return jnp.concatenate([(xr * cos + other * sin).astype(x.dtype),
                             x[..., rot:]], -1)
 
 
@@ -261,27 +279,39 @@ class KimiDeltaAttention(KerasLayer):
 
 
 class LatentAttention(KerasLayer):
-    """Causal multi-head latent attention without positions: queries of
-    ``nope_dim + rope_dim`` a head straight from x; keys and values through
-    a latent of ``kv_rank`` (``[c | k_r] = W_kva x``, ``[k_n | v] = W_kvb
-    RMSNorm(c)``), a head's key ``[k_n | k_r]`` with ``k_r`` (``rope_dim``
-    wide) shared by all heads and no rotary applied to it; softmax at
-    ``(nope_dim + rope_dim) ** -0.5`` through the flash kernels, whose
-    values are ``v_dim`` wide beside keys of another width. (B, L, H) ->
-    (B, L, H); no bias anywhere."""
+    """Causal multi-head latent attention: queries of ``nope_dim +
+    rope_dim`` a head, straight from x or, with ``q_rank``, through a
+    normed latent (``[q_n | q_r] = W_qb RMSNorm(W_qa x)``); keys and values
+    through a latent of ``kv_rank`` (``[c | k_r] = W_kva x``, ``[k_n | v] =
+    W_kvb RMSNorm(c)``), a head's key ``[k_n | k_r]`` with ``k_r``
+    (``rope_dim`` wide) shared by all heads. With ``rope_theta`` rotary
+    positions turn each head's ``q_r`` and the shared ``k_r`` (neighbouring
+    columns a pair with ``rope_interleave``, else the two halves); without,
+    no positions at all. Softmax at ``(nope_dim + rope_dim) ** -0.5``
+    through the flash kernels, whose values are ``v_dim`` wide beside keys
+    of another width. (B, L, H) -> (B, L, H); no bias anywhere."""
 
     def __init__(self, n_head: int, nope_dim: int, rope_dim: int,
                  v_dim: int, kv_rank: int, eps: float = 1e-5,
+                 q_rank: Optional[int] = None,
+                 rope_theta: Optional[float] = None,
+                 rope_interleave: bool = True,
                  input_shape=None, name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
         self.n, self.nope, self.rope, self.dv, self.rank = n_head, \
             nope_dim, rope_dim, v_dim, kv_rank
-        self.eps = eps
+        self.eps, self.q_rank = eps, q_rank
+        self.rope_theta, self.rope_interleave = rope_theta, rope_interleave
 
     def build(self, rng, input_shape):
         h, n = int(input_shape[-1]), self.n
-        r = jax.random.split(rng, 4)
-        return {"w_q": _normal(r[0], (h, n * (self.nope + self.rope))),
+        qd = n * (self.nope + self.rope)
+        r = jax.random.split(rng, 5 if self.q_rank else 4)
+        query = {"w_qa": _normal(r[4], (h, self.q_rank)),
+                 "q_norm": jnp.zeros((self.q_rank,)),
+                 "w_qb": _normal(r[0], (self.q_rank, qd))} \
+            if self.q_rank else {"w_q": _normal(r[0], (h, qd))}
+        return {**query,
                 "w_kva": _normal(r[1], (h, self.rank + self.rope)),
                 "kv_norm": jnp.zeros((self.rank,)),
                 "w_kvb": _normal(r[2], (self.rank, n * (self.nope + self.dv))),
@@ -292,12 +322,23 @@ class LatentAttention(KerasLayer):
         b, l, _ = x.shape
         n, nope, rope, dv = self.n, self.nope, self.rope, self.dv
         with jax.named_scope("zoo_mla_proj"):
-            q = (x @ params["w_q"]).reshape(b, l, n, nope + rope)
+            if self.q_rank:
+                q = rms_norm(x @ params["w_qa"], params["q_norm"],
+                             self.eps) @ params["w_qb"]
+            else:
+                q = x @ params["w_q"]
+            q = q.reshape(b, l, n, nope + rope)
             kva = x @ params["w_kva"]
             kv = (rms_norm(kva[..., :self.rank], params["kv_norm"], self.eps)
                   @ params["w_kvb"]).reshape(b, l, n, nope + dv)
+            k_r = kva[:, :, None, self.rank:]
+            if self.rope_theta:
+                turn = lambda t: partial_rotary(t, rope, self.rope_theta,
+                                                self.rope_interleave)
+                q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
+                k_r = turn(k_r)
             k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-                kva[:, :, None, self.rank:], (b, l, n, rope))], -1)
+                k_r, (b, l, n, rope))], -1)
         tr = lambda t: t.transpose(0, 2, 1, 3)
         with jax.named_scope("zoo_mla_attn"):
             o = tr(flash_attention(tr(q), tr(k), tr(kv[..., nope:]),
@@ -341,19 +382,25 @@ class HeldExpertsMoE(KerasLayer):
     The router's form is the configuration's. ``scoring`` ``"softmax"``:
     the scores are the softmax of the router's outputs; ``"sigmoid"``:
     their sigmoid, each expert by itself. The ``top_k`` experts are the
-    largest of the scores plus, with ``select_bias``, a bias an expert
-    (parameter ``router_bias``, which takes no gradient: whoever balances
-    the load sets it); the weights are the scores at the chosen experts
-    (never the bias), divided by their sum (``norm_topk``) and times
-    ``routed_scale``. Scores, choice and normalisation are over all
-    ``n_routed`` outputs; the layer computes the part of the routed sum
-    its own experts give, for every assignment that lands on them: there
-    is no capacity and nothing is dropped (``ops/grouped_experts.py``).
+    largest of the scores plus, with ``select_bias``, a bias an expert;
+    the weights are the scores at the chosen experts (never the bias),
+    divided by their sum (``norm_topk``) and times ``routed_scale``. The
+    bias takes no gradient. Without ``bias_update_rate`` it is the
+    parameter ``router_bias`` and stays where it was put; with it, it is
+    state of the layer (``router_bias`` beside ``step_stats``, no leaf of
+    the optimizer) that a training step balances by rule (Wang et al.,
+    arXiv:2408.15664): after the step's routing, with ``c_i`` the
+    assignments expert i of all ``n_routed`` got, ``b_i += rate *
+    sign(mean(c) - c_i)``, and the next step routes with the new bias.
+    Scores, choice and normalisation are over all ``n_routed`` outputs;
+    the layer computes the part of the routed sum its own experts give,
+    for every assignment that lands on them: there is no capacity and
+    nothing is dropped (``ops/grouped_experts.py``).
     With ``n_held == n_routed`` it is the whole layer. ``tile``: the rows
     of a tile of the expert loop; left out, what the call's tokens make
-    of it (``expected_tile``). Stateful only in
-    that it reports its routing each step (``MOE_STATS``).
-    (..., H) -> (..., H)."""
+    of it (``expected_tile``). Its state: what it reports of its routing
+    each step (``MOE_STATS``; a balanced router ``ROUTER_LOAD`` too) and
+    the balanced bias. (..., H) -> (..., H)."""
 
     has_state = True
 
@@ -362,11 +409,15 @@ class HeldExpertsMoE(KerasLayer):
                  norm_topk: bool = True, tile: Optional[int] = None,
                  scoring: str = "softmax", select_bias: bool = False,
                  routed_scale: float = 1.0, shared_gate: bool = True,
+                 bias_update_rate: Optional[float] = None,
                  input_shape=None, name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}")
+        if bias_update_rate is not None and not select_bias:
+            raise ValueError("a bias update rate without a selection bias")
         self.scoring, self.select_bias = scoring, select_bias
+        self.bias_update_rate = bias_update_rate
         self.routed_scale, self.shared_gate = routed_scale, shared_gate
         if not 0 <= first_expert <= first_expert + n_held <= n_routed:
             raise ValueError(f"experts {first_expert}..{first_expert + n_held}"
@@ -387,7 +438,7 @@ class HeldExpertsMoE(KerasLayer):
                   "w_gate": _normal(r[1], (e, h, f)),
                   "w_up": _normal(r[2], (e, h, f)),
                   "w_down": _normal(r[3], (e, f, h))}
-        if self.select_bias:
+        if self.select_bias and not self.balanced:
             params["router_bias"] = jnp.zeros((self.n_routed,))
         if fs:
             params.update(s_gate=_normal(r[4], (h, fs)),
@@ -401,12 +452,45 @@ class HeldExpertsMoE(KerasLayer):
                        w_down=("expert", "mlp", "embed"))
         return params
 
+    @property
+    def balanced(self) -> bool:
+        return self.bias_update_rate is not None
+
     def init_state(self, input_shape):
-        return {"step_stats": {k: jnp.zeros((), jnp.float32)
-                               for k in MOE_STATS}}
+        stats = {k: jnp.zeros((), jnp.float32) for k in MOE_STATS}
+        if not self.balanced:
+            return {"step_stats": stats}
+        stats[ROUTER_LOAD] = jnp.zeros((), jnp.float32)
+        return {"step_stats": stats,
+                "router_bias": jnp.zeros((self.n_routed,), jnp.float32)}
 
     def call(self, params, inputs, training: bool = False, state=None,
              **kwargs):
+        if not self.balanced:
+            out, stats, _ = self.routed(params, inputs)
+            return out, {"step_stats": stats}
+        bias = state["router_bias"]
+        out, stats, counts = self.routed(params, inputs, bias)
+        return out, self.after_step(bias, stats, counts, training)
+
+    def after_step(self, bias, stats, counts, training: bool) -> dict:
+        """A balanced router's state once a step's ``counts`` (assignments
+        an expert, all ``n_routed``, all of the step's tokens) are in: the
+        bias moved by the rule (training only) and what the rule acts
+        on."""
+        with jax.named_scope("zoo_moe_bias"):
+            mean = jnp.mean(counts)
+            stats = dict(stats)
+            stats[ROUTER_LOAD] = jnp.max(counts) / jnp.maximum(mean, 1e-9)
+            if training:
+                bias = bias + self.bias_update_rate * jnp.sign(mean - counts)
+        return {"step_stats": stats, "router_bias": bias}
+
+    def routed(self, params, inputs, bias=None):
+        """(output, ``MOE_STATS``, counts): the layer on ``inputs`` with
+        the selection bias ``bias`` (None: the parameter, if any); counts
+        are the assignments each of the router's experts got, for a
+        balanced router, else None."""
         x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
         flat = x.reshape(-1, x.shape[-1])
         f32 = jnp.float32
@@ -416,8 +500,10 @@ class HeldExpertsMoE(KerasLayer):
             scores = jax.nn.softmax(logits, -1) \
                 if self.scoring == "softmax" else jax.nn.sigmoid(logits)
             if self.select_bias:
+                if bias is None:
+                    bias = params["router_bias"]
                 _, top_i = jax.lax.top_k(scores + jax.lax.stop_gradient(
-                    params["router_bias"].astype(f32)), self.top_k)
+                    bias.astype(f32)), self.top_k)
                 top_w = jnp.take_along_axis(scores, top_i, -1)
             else:
                 top_w, top_i = jax.lax.top_k(scores, self.top_k)
@@ -435,6 +521,11 @@ class HeldExpertsMoE(KerasLayer):
                 held - jnp.sum(tables.tile_rows).astype(f32),
                 jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
                 tables.n_tiles.astype(f32))))
+        router_counts = None
+        if self.balanced:
+            with jax.named_scope("zoo_moe_bias"):
+                router_counts = jnp.sum((top_i.reshape(-1, 1) == jnp.arange(
+                    self.n_routed)).astype(f32), 0)
         out = grouped_experts(flat, params["w_gate"], params["w_up"],
                               params["w_down"], top_w, tables, tile)
         if self.shared_size:
@@ -448,7 +539,7 @@ class HeldExpertsMoE(KerasLayer):
                         flat, params["s_gate_w"], preferred_element_type=f32))
                     y = y * gate[:, None].astype(x.dtype)
                 out = out + y
-        return out.reshape(x.shape), {"step_stats": stats}
+        return out.reshape(x.shape), stats, router_counts
 
 
 MIXERS = {LINEAR: GatedDeltaNet, FULL: GatedAttention,
@@ -471,7 +562,19 @@ class HybridDecoder(KerasLayer):
     in the backward pass, so a step keeps one block's activations and every
     block's input; ``remat_rows`` sequences of the batch go through a block
     at a time (None: all at once), which bounds those activations by the
-    rows and not by the batch."""
+    rows and not by the batch. An expert layer that balances its selection
+    bias gets the counts of the whole batch, summed over those passes, and
+    its bias moves once a step.
+
+    ``mtp_layer`` (one of ``MIXERS``' names; None: none) adds one
+    multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437 §2.2,
+    depth 1) and makes the layer [token ids, next ids] -> (hidden, module's
+    hidden): with ``h_i`` the stack's output before the final norm, ``h'_i
+    = W_eh [N_e(Emb(next_i)) ; N_h(h_i)]`` under the stack's own embedding,
+    one more block of that mixer and an expert layer, and a final norm of
+    its own (parameters under ``"mtp"``, HLO scope ``zoo_mtp``). What
+    :class:`LMHeadLoss` makes of the second stream, under the one head, is
+    the loss of predicting the id after next."""
 
     has_state = True
 
@@ -479,10 +582,12 @@ class HybridDecoder(KerasLayer):
                  layer_types: Sequence[str], mixers: dict, moe: dict,
                  eps: float = 1e-6, remat_rows: Optional[int] = None,
                  dense_blocks: int = 0, dense_size: int = 0,
-                 input_shape=None,
+                 mtp_layer: Optional[str] = None, input_shape=None,
                  name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
         unknown = set(layer_types) - set(MIXERS)
+        if mtp_layer:
+            unknown |= {mtp_layer} - set(MIXERS)
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         self.vocab, self.hidden_size, self.eps = vocab, hidden_size, eps
@@ -495,63 +600,117 @@ class HybridDecoder(KerasLayer):
              if i < dense_blocks else
              HeldExpertsMoE(name=f"{self.name}_moe{i}", **moe))
             for i, kind in enumerate(self.layer_types)]
+        self.mtp_block = None
+        if mtp_layer:
+            self.mtp_block = (
+                MIXERS[mtp_layer](eps=eps, name=f"{self.name}_mtp_mixer",
+                                  **mixers[mtp_layer]),
+                HeldExpertsMoE(name=f"{self.name}_mtp_moe", **moe))
+            self.num_outputs = 2
 
     def compute_output_shape(self, input_shape):
-        return tuple(input_shape) + (self.hidden_size,)
+        if self.mtp_block is None:
+            return tuple(input_shape) + (self.hidden_size,)
+        return [tuple(input_shape[0]) + (self.hidden_size,)] * 2
+
+    def _build_block(self, mixer_key, ff_key, mixer, ff):
+        h = self.hidden_size
+        shape = (None, None, h)
+        return {"norm1": jnp.zeros((h,)),
+                "mixer": mixer.build(mixer_key, shape),
+                "norm2": jnp.zeros((h,)),
+                _ff_key(ff): ff.build(ff_key, shape)}
 
     def build(self, rng, input_shape):
         h = self.hidden_size
-        shape = (None, None, h)
         keys = jax.random.split(rng, 2 * len(self.blocks) + 1)
         params = {"embed": _normal(keys[-1], (self.vocab, h)),
                   "final_norm": jnp.zeros((h,))}
         for i, (mixer, ff) in enumerate(self.blocks):
-            params[f"block{i}"] = {
-                "norm1": jnp.zeros((h,)),
-                "mixer": mixer.build(keys[2 * i], shape),
-                "norm2": jnp.zeros((h,)),
-                _ff_key(ff): ff.build(keys[2 * i + 1], shape)}
+            params[f"block{i}"] = self._build_block(
+                keys[2 * i], keys[2 * i + 1], mixer, ff)
+        if self.mtp_block is not None:
+            keys = jax.random.split(jax.random.fold_in(rng, 1), 3)
+            params["mtp"] = {
+                "norm_e": jnp.zeros((h,)), "norm_h": jnp.zeros((h,)),
+                "w_eh": _normal(keys[2], (2 * h, h)),
+                "block": self._build_block(keys[0], keys[1],
+                                           *self.mtp_block),
+                "final_norm": jnp.zeros((h,))}
         return params
 
     def init_state(self, input_shape):
-        return {f"block{i}": ff.init_state(None) if ff.has_state else {}
-                for i, (_, ff) in enumerate(self.blocks)}
+        state = {f"block{i}": ff.init_state(None) if ff.has_state else {}
+                 for i, (_, ff) in enumerate(self.blocks)}
+        if self.mtp_block is not None:
+            state["mtp"] = self.mtp_block[1].init_state(None)
+        return state
 
-    def _block(self, i, p, x):
-        mixer, ff = self.blocks[i]
+    def _block(self, mixer, ff, p, x, bias=None):
         h = x + mixer.call(p["mixer"], rms_norm(x, p["norm1"], self.eps))
-        out = ff.call(p[_ff_key(ff)], rms_norm(h, p["norm2"], self.eps))
-        y, state = out if ff.has_state else (out, {})
+        n = rms_norm(h, p["norm2"], self.eps)
+        if not ff.has_state:
+            return h + ff.call(p[_ff_key(ff)], n), {}
+        y, stats, counts = ff.routed(p[_ff_key(ff)], n, bias)
+        state = {"step_stats": stats}
+        if counts is not None:         # a router that balances its bias
+            state["router_counts"] = counts
         return h + y, state
 
-    def call(self, params, inputs, training: bool = False, state=None,
-             **kwargs):
-        tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
-        x = params["embed"][tokens.astype(jnp.int32)]
+    def _recomputed(self, mixer, ff, p, x, state, training):
+        """``_block`` on (B, L, H), ``remat_rows`` sequences at a time and
+        recomputed in the backward pass; the block's state after it."""
         b = x.shape[0]
         rows = self.remat_rows or b
         if b % rows:
             raise ValueError(f"remat_rows {rows} does not divide the batch "
                              f"{b}")
+        bias = (state or {}).get("router_bias")
+        fn = lambda x: self._block(mixer, ff, p, x, bias)
+        if rows == b:
+            x, new = jax.checkpoint(fn)(x)
+        else:
+            # in turn, so that the compiler cannot overlap two
+            # recomputations
+            x, new = jax.lax.map(
+                jax.checkpoint(fn), x.reshape((b // rows, rows) +
+                                              x.shape[1:]))
+            x = x.reshape((b,) + x.shape[2:])
+            new = {name: {
+                k: v.sum() if k.endswith("_total") else v.max()
+                for k, v in part.items()} if name == "step_stats"
+                else part.sum(0) for name, part in new.items()}
+        if "router_counts" in new:
+            new = ff.after_step(bias, new["step_stats"],
+                                new["router_counts"], training)
+        return x, new
+
+    def call(self, params, inputs, training: bool = False, state=None,
+             **kwargs):
+        tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
+        x = params["embed"][tokens.astype(jnp.int32)]
+        state = state or {}
         new_state = {}
-        for i in range(len(self.blocks)):
-            p = params[f"block{i}"]
-            fn = lambda x, i=i, p=p: self._block(i, p, x)
-            if rows == b:
-                x, state = jax.checkpoint(fn)(x)
-            else:
-                # in turn, so that the compiler cannot overlap two
-                # recomputations
-                x, state = jax.lax.map(
-                    jax.checkpoint(fn), x.reshape((b // rows, rows) +
-                                                  x.shape[1:]))
-                x = x.reshape((b,) + x.shape[2:])
-                state = {name: {
-                    k: v.sum() if k.endswith("_total") else v.max()
-                    for k, v in stats.items()}
-                    for name, stats in state.items()}
-            new_state[f"block{i}"] = state
-        return rms_norm(x, params["final_norm"], self.eps), new_state
+        for i, (mixer, ff) in enumerate(self.blocks):
+            x, new_state[f"block{i}"] = self._recomputed(
+                mixer, ff, params[f"block{i}"], x,
+                state.get(f"block{i}"), training)
+        out = rms_norm(x, params["final_norm"], self.eps)
+        if self.mtp_block is None:
+            return out, new_state
+        m = params["mtp"]
+
+        @jax.checkpoint
+        def joined(nxt, x):
+            both = jnp.concatenate([rms_norm(nxt, m["norm_e"], self.eps),
+                                    rms_norm(x, m["norm_h"], self.eps)], -1)
+            return both @ m["w_eh"]
+
+        with jax.named_scope("zoo_mtp"):
+            y = joined(params["embed"][inputs[1].astype(jnp.int32)], x)
+            y, new_state["mtp"] = self._recomputed(
+                *self.mtp_block, m["block"], y, state.get("mtp"), training)
+            return (out, rms_norm(y, m["final_norm"], self.eps)), new_state
 
 
 class LMHeadLoss(KerasLayer):
@@ -561,12 +720,22 @@ class LMHeadLoss(KerasLayer):
     recomputed in the backward pass, so no (tokens x vocabulary) array
     outlives a block. Train it with the ``identity`` objective: the mean
     over the batch is then the mean next-token loss. HLO scope
-    ``zoo_lm_loss``."""
+    ``zoo_lm_loss``.
+
+    With ``mtp_weight`` the inputs are [hidden, targets, a prediction
+    module's hidden, its targets] and the output the first stream's loss
+    plus ``mtp_weight`` times the second's, under the one head (whose
+    gradient is then the sum over both streams); the second stream's loss
+    blocks lie under ``zoo_mtp`` and its mean over the batch is reported a
+    step as the gauge ``zoo_mtp_loss``."""
 
     def __init__(self, vocab: int, block_tokens: int = 2048,
+                 mtp_weight: Optional[float] = None,
                  input_shape=None, name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
         self.vocab, self.block_tokens = vocab, block_tokens
+        self.mtp_weight = mtp_weight
+        self.has_state = mtp_weight is not None
 
     def compute_output_shape(self, input_shape):
         return (input_shape[0][0],)
@@ -574,11 +743,13 @@ class LMHeadLoss(KerasLayer):
     def build(self, rng, input_shape):
         return {"head": _normal(rng, (int(input_shape[0][-1]), self.vocab))}
 
-    def call(self, params, inputs, training: bool = False, **kwargs):
-        hidden, targets = inputs
+    def init_state(self, input_shape):
+        return {"step_stats": {MTP_LOSS: jnp.zeros((), jnp.float32)}} \
+            if self.has_state else {}
+
+    def _stream(self, head, hidden, targets):
         b, l, h = hidden.shape
         blk = math.gcd(l, self.block_tokens)
-        head = params["head"]
 
         @jax.checkpoint
         def one(carry, xs):
@@ -595,3 +766,12 @@ class LMHeadLoss(KerasLayer):
             total, _ = jax.lax.scan(one, jnp.zeros((b,), jnp.float32),
                                     (hid, tgt))
             return total / l
+
+    def call(self, params, inputs, training: bool = False, **kwargs):
+        loss = self._stream(params["head"], inputs[0], inputs[1])
+        if self.mtp_weight is None:
+            return loss
+        with jax.named_scope("zoo_mtp"):
+            mtp = self._stream(params["head"], inputs[2], inputs[3])
+        return loss + self.mtp_weight * mtp, {
+            "step_stats": {MTP_LOSS: jnp.mean(mtp)}}

@@ -545,6 +545,21 @@ class SPMDTrainer:
         if self.opt_state is None:
             self.opt_state = self._fresh_opt_state()
 
+    def set_state(self, state):
+        """Replace the layers' non-trainable state (what a step writes
+        without a gradient: running statistics, a router's balanced
+        bias), placed as ``_place_state`` places it; the parameters and
+        the optimizer's state stay where they are."""
+        self.ensure_initialized()
+        have, want = jax.tree.structure(self.net_state), \
+            jax.tree.structure(state)
+        if have != want:
+            raise ValueError(f"the layers' state is {have}, not {want}")
+        repl = self.ctx.replicated_sharding()
+        self.net_state = jax.tree.map(
+            lambda leaf, old: jax.device_put(
+                np.asarray(leaf, old.dtype), repl), state, self.net_state)
+
     # ------------------------------------------------------------------
     # compiled steps
     # ------------------------------------------------------------------
