@@ -36,11 +36,29 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from . import _route
 from ._vma import out_struct, vary_like
 
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
+
+# What a forward rule's products are called, kernel or XLA carrier: the
+# output, and the row statistics the backward normalizes with. A
+# ``jax.checkpoint`` whose policy saves these names keeps them across the
+# recomputation and does not run the forward again (``HybridDecoder``);
+# anywhere else the names do nothing.
+FLASH_RESIDUAL_NAMES = ("zoo_flash_out", "zoo_flash_lse")
+
+
+def _name_residuals(o, *rows):
+    """A forward's output and its row statistics (``(..., Lq, 1)``
+    float32) under ``FLASH_RESIDUAL_NAMES``, the statistics dense as
+    ``(..., Lq)``: a trailing dimension of 1 is padded to 128 lanes in
+    HBM, and what a checkpoint policy keeps lives across a whole block."""
+    out_name, lse_name = FLASH_RESIDUAL_NAMES
+    return (checkpoint_name(o, out_name),) + tuple(
+        checkpoint_name(jnp.squeeze(r, -1), lse_name) for r in rows)
 
 
 # ---------------------------------------------------------------------------
@@ -281,14 +299,16 @@ def _blockwise_fwd_rule(q, k, v, bias, causal, sm_scale, block_q, block_k,
     # Residuals are the flash set: inputs + (o, m, l).
     o, m, l = _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale,
                                   block_k, q_offset)
+    o, m, l = _name_residuals(o, m, l)
     return o, (q, k, v, bias, o, m, l)
 
 
 def _blockwise_bwd_rule(causal, sm_scale, block_q, block_k, q_offset, res,
                         do):
     q, k, v, bias, o, m, l = res
-    return _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal,
-                               sm_scale, block_q, block_k, q_offset)
+    return _blockwise_bwd_impl(q, k, v, bias, o, m[..., None], l[..., None],
+                               do, causal, sm_scale, block_q, block_k,
+                               q_offset)
 
 
 _attention_blockwise.defvjp(_blockwise_fwd_rule, _blockwise_bwd_rule)
@@ -810,8 +830,9 @@ def _flash_attention_bhld(q, k, v, kbias, num_heads, causal, sm_scale,
 
 def _flash_fwd_rule(q, k, v, kbias, num_heads, causal, sm_scale,
                     block_q=None, block_k=None, group=1):
-    o, lse = _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                            block_q, block_k, group)
+    o, lse = _name_residuals(*_flash_forward(
+        q, k, v, kbias, num_heads, causal, sm_scale, block_q, block_k,
+        group))
     return o, (q, k, v, kbias, o, lse)
 
 
@@ -821,8 +842,8 @@ def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, group,
     :func:`_flash_backward`) rebuilding score blocks from (q, k, bias) and
     the saved lse (O(L) memory)."""
     q, k, v, kbias, o, lse = res
-    return _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal,
-                           sm_scale, block_q, block_k, group)
+    return _flash_backward(q, k, v, kbias, o, lse[..., None], do, num_heads,
+                           causal, sm_scale, block_q, block_k, group)
 
 
 _flash_attention_bhld.defvjp(_flash_fwd_rule, _flash_bwd_rule)

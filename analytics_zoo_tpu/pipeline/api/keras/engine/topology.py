@@ -340,9 +340,16 @@ class KerasNet(KerasLayer):
             new_leaves = [jnp.asarray(w, l.dtype) if hasattr(l, "dtype")
                           else w for w, l in zip(weights, leaves)]
             new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
-            self._built_params = (new_params, state)
-            if self.trainer is not None:
+            if self.trainer is None:
+                self._built_params = (new_params, state)
+            else:
+                # the trainer places copies of its own: hold those, or a
+                # second set of the weights stays on the device until the
+                # next fit returns (a model sized to fill the chip cannot
+                # load its step beside it)
                 self.trainer.set_params(new_params, state)
+                self._built_params = (self.trainer.params,
+                                      self.trainer.net_state)
 
     def get_params(self):
         return self._params_tuple()[0]

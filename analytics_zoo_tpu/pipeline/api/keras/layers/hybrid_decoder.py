@@ -37,7 +37,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from .....ops.attention import flash_attention
+from .....ops.attention import FLASH_RESIDUAL_NAMES, flash_attention
 from .....ops.delta_rule import (DEFAULT_CHUNK, causal_depthwise_conv,
                                  chunk_gated_delta_rule)
 from .....ops.grouped_experts import (expected_tile, grouped_experts,
@@ -560,7 +560,9 @@ class HybridDecoder(KerasLayer):
     (parameters under ``"mlp"``), the others the expert layer
     (``"moe"``). Each block is recomputed
     in the backward pass, so a step keeps one block's activations and every
-    block's input; ``remat_rows`` sequences of the batch go through a block
+    block's input and, of its attention, the output and the row
+    log-sum-exp (``FLASH_RESIDUAL_NAMES``: the forward kernel is not run a
+    second time); ``remat_rows`` sequences of the batch go through a block
     at a time (None: all at once), which bounds those activations by the
     rows and not by the batch. An expert layer that balances its selection
     bias gets the counts of the whole batch, summed over those passes, and
@@ -666,15 +668,18 @@ class HybridDecoder(KerasLayer):
             raise ValueError(f"remat_rows {rows} does not divide the batch "
                              f"{b}")
         bias = (state or {}).get("router_bias")
-        fn = lambda x: self._block(mixer, ff, p, x, bias)
+        # O(L^2) work for O(L) bytes: the one thing not worth recomputing
+        fn = jax.checkpoint(
+            lambda x: self._block(mixer, ff, p, x, bias),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_RESIDUAL_NAMES))
         if rows == b:
-            x, new = jax.checkpoint(fn)(x)
+            x, new = fn(x)
         else:
             # in turn, so that the compiler cannot overlap two
             # recomputations
             x, new = jax.lax.map(
-                jax.checkpoint(fn), x.reshape((b // rows, rows) +
-                                              x.shape[1:]))
+                fn, x.reshape((b // rows, rows) + x.shape[1:]))
             x = x.reshape((b,) + x.shape[2:])
             new = {name: {
                 k: v.sum() if k.endswith("_total") else v.max()

@@ -141,7 +141,10 @@ def test_delta_rule_kernels_cross_lower_at_the_hybrid_cells_shape(on_tpu):
     assert "stablehlo.while" not in forward
 
 
-def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
+@pytest.mark.parametrize("kept,forwards", [((), 2),
+                                           (A.FLASH_RESIDUAL_NAMES, 1)])
+def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu, kept,
+                                                            forwards):
     """A block of `kimilinear_pretrain_l8192`: one sequence of 8,192, 32
     heads of 128. Kimi Delta Attention's four kernels lower under their own
     names inside ``zoo_kda_scan`` (what `mosaic_kernel_counts` and the
@@ -149,7 +152,10 @@ def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
     128) beside q, k, v; nothing of the op loops outside a kernel. Latent
     attention's flash kernels lower with keys of 192 and values of 128: a
     head's dq is 8 MiB with the lanes padded, so the backward stays two
-    kernels, and a block recomputed holds them 2, 1, 1."""
+    kernels. Recomputed with nothing kept the call holds them 2, 1, 1;
+    under the policy `HybridDecoder` gives its blocks (``kept``: the names
+    of the forward's output and log-sum-exp) 1, 1, 1: the policy is what
+    takes the second forward away."""
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
 
     s = jax.ShapeDtypeStruct
@@ -184,11 +190,13 @@ def test_kimi_linear_kernels_cross_lower_at_the_cells_shape(on_tpu):
     assert _kernel_names(mlir) == [
         "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_fwd"]
     assert "32x8192x192" in mlir and "32x8192x128" in mlir
+    recomputed = jax.checkpoint(
+        attn, policy=jax.checkpoint_policies.save_only_these_names(*kept))
     sites = _call_sites(_tpu_lowered(
-        jax.value_and_grad(jax.checkpoint(attn), argnums=(0, 1, 2)),
-        q, q, v))
+        jax.value_and_grad(recomputed, argnums=(0, 1, 2)), q, q, v))
     assert mosaic_kernel_counts("\n".join(sites)) == {
-        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq": 1, "zoo_flash_bwd_dkv": 1}
+        "zoo_flash_fwd": forwards, "zoo_flash_bwd_dq": 1,
+        "zoo_flash_bwd_dkv": 1}
 
 
 def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
@@ -198,10 +206,11 @@ def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
     time: the loss's gradient lowered for the TPU holds, a KDA block, the
     chunk-local and the loop's forward kernel twice (the forward pass and
     the block's recomputation) and each backward kernel once, and the
-    flash forward kernel twice. At the 512 positions lowered here a head's
-    dq stays in VMEM and the flash backward is the one fused kernel; at the
-    cell's 8,192 it is two
-    (`test_kimi_linear_kernels_cross_lower_at_the_cells_shape`): 2, 1, 1 is
+    flash forward kernel once: a recomputed block keeps its output and
+    log-sum-exp (``FLASH_RESIDUAL_NAMES``). At the 512 positions lowered
+    here a head's dq stays in VMEM and the flash backward is the one fused
+    kernel; at the cell's 8,192 it is two
+    (`test_kimi_linear_kernels_cross_lower_at_the_cells_shape`): 1, 1, 1 is
     the `mosaic_kernel_counts` a run on the chip is held to."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import hybrid_decoder as hd
     from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
@@ -233,7 +242,7 @@ def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
     assert mosaic_kernel_counts("\n".join(sites)) == {
         "zoo_kda_local_fwd": 8, "zoo_kda_scan_fwd": 8,
         "zoo_kda_scan_bwd": 4, "zoo_kda_local_bwd": 4,
-        "zoo_flash_fwd": 2, "zoo_flash_bwd_dq_dkv": 1}
+        "zoo_flash_fwd": 1, "zoo_flash_bwd_dq_dkv": 1}
 
 
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):

@@ -95,6 +95,26 @@ def test_weights_roundtrip(tmp_path):
     assert np.allclose(preds3, 0.0)
 
 
+def test_set_weights_keeps_one_copy_on_the_device():
+    """With a trainer, the weights the model holds after ``set_weights``
+    are the trainer's placed arrays themselves, not a second set beside
+    them: a model sized to fill the chip has no room for both when its
+    first step is loaded."""
+    import jax
+
+    x, y = _xor_data(64)
+    model = Sequential()
+    model.add(Dense(4, activation="relu", input_shape=(8,)))
+    model.add(Dense(1))
+    model.compile(optimizer="sgd", loss="mse")
+    model.fit(x, y, batch_size=32, nb_epoch=1)
+    model.set_weights([np.ones_like(w) for w in model.get_weights()])
+    held = jax.tree.leaves(model._built_params[0])
+    placed = jax.tree.leaves(model.trainer.params)
+    assert all(a is b for a, b in zip(held, placed))
+    assert np.allclose(model.get_weights()[0], 1.0)
+
+
 def test_shared_layer_weight_sharing():
     shared = Dense(6)
     a = Input(shape=(3,))

@@ -437,3 +437,133 @@ def test_a_long_shape_without_a_kernel_fails_loudly_on_the_chip(monkeypatch):
     assert not A._route_eligible(True, kb, 4096, 4096, 96, True, 16, 2)
     monkeypatch.setenv("ZOO_TPU_DISABLE_PALLAS", "1")
     assert not A._route_eligible(True, kb, 8192, 8192, 96, True, 16, 2)
+
+
+# -- what a recomputed block keeps -------------------------------------------
+
+ATTENTION = {
+    # keys of 128 + 64, values of 128
+    "latent": (hd.LATENT, dict(n_head=2, nope_dim=128, rope_dim=64,
+                               v_dim=128, kv_rank=32), 2, 128),
+    "gated": (hd.FULL, dict(n_head=4, n_kv_head=2, head_dim=64,
+                            rotary_dim=16), 4, 64),
+}
+
+
+@pytest.fixture(params=["kernels", "carrier"])
+def route(request, monkeypatch):
+    """The flash kernels in the Pallas interpreter, or the XLA carrier."""
+    if request.param == "kernels":
+        monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+        monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
+    else:
+        monkeypatch.delenv("ZOO_TPU_PALLAS_INTERPRET", raising=False)
+    return request.param
+
+
+def attention_stack(mixer, rows, batch=2, length=128):
+    """Two blocks of one attention mixer (a dense and an expert layer), and
+    the gradient of a loss on their output by the parameters."""
+    kind, args, _, _ = ATTENTION[mixer]
+    decoder = hd.HybridDecoder(
+        vocab=64, hidden_size=32, layer_types=[kind, kind],
+        mixers={kind: args}, dense_blocks=1, dense_size=32, remat_rows=rows,
+        moe=dict(n_routed=4, n_held=2, intermediate_size=16, top_k=2,
+                 tile=64))
+    params = decoder.build(jax.random.PRNGKey(0), (None, length))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (batch, length), 0, 64)
+
+    def grads():
+        # a function of its own a call: jit keeps no program of another
+        # policy
+        return jax.grad(lambda p: (decoder.call(p, ids)[0] ** 2).mean())
+    return decoder, params, grads
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def flash_forwards(jaxpr, route):
+    """How often a program holds the flash forward: the kernel by its
+    name; the carrier as the scan that takes a row maximum and has no loop
+    inside it (the backward's scan takes no maximum; ``lax.map``'s holds
+    the block's loops)."""
+    if route == "kernels":
+        return sum(eqn.params["name"] == "zoo_flash_fwd"
+                   for eqn in _equations(jaxpr)
+                   if eqn.primitive.name == "pallas_call")
+
+    def is_forward(scan):
+        body = scan.params["jaxpr"].jaxpr
+        return any(e.primitive.name == "reduce_max" for e in body.eqns) \
+            and not any(e.primitive.name in ("scan", "while")
+                        for e in _equations(body))
+    return sum(is_forward(eqn) for eqn in _equations(jaxpr)
+               if eqn.primitive.name == "scan")
+
+
+@pytest.mark.parametrize("rows", [None, 1])
+@pytest.mark.parametrize("mixer", list(ATTENTION))
+def test_a_recomputed_block_runs_the_flash_forward_once(monkeypatch, route,
+                                                        mixer, rows):
+    """The gradient of two recomputed attention blocks holds the flash
+    forward twice, once a block; with the names taken from the policy, four
+    times. So the names reach the policy, whole batch and under
+    ``lax.map``."""
+    _, params, grads = attention_stack(mixer, rows)
+    assert flash_forwards(jax.make_jaxpr(grads())(params).jaxpr, route) == 2
+    monkeypatch.setattr(hd, "FLASH_RESIDUAL_NAMES", ())
+    assert flash_forwards(jax.make_jaxpr(grads())(params).jaxpr, route) == 4
+
+
+@pytest.mark.parametrize("rows", [None, 1])
+@pytest.mark.parametrize("mixer", list(ATTENTION))
+def test_kept_residuals_are_the_recomputed_ones(monkeypatch, route, mixer,
+                                                rows):
+    """Gradients with the forward's output and log-sum-exp kept are bit
+    for bit those with both computed again."""
+    _, params, grads = attention_stack(mixer, rows)
+    kept = jax.jit(grads())(params)
+    monkeypatch.setattr(hd, "FLASH_RESIDUAL_NAMES", ())
+    again = jax.jit(grads())(params)
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool((a == b).all()) and bool(jnp.isfinite(a).all()),
+        kept, again)))
+    assert max(float(jnp.abs(g).max()) for g in jax.tree.leaves(kept)) > 0
+
+
+@pytest.mark.parametrize("rows", [None, 1])
+@pytest.mark.parametrize("mixer", list(ATTENTION))
+def test_a_recomputed_block_keeps_its_input_and_two_named_arrays(
+        route, mixer, rows):
+    """Besides its input, a block keeps the attention's output in the
+    operands' type and the row statistics in float32, dense: B x H x L
+    elements (the kernel's log-sum-exp; the carrier's maximum and
+    denominator), not the (B x H, L, 1) the kernel writes, whose last
+    dimension HBM pads to 128 lanes."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    batch, length = 2, 128
+    _, _, heads, dv = ATTENTION[mixer]
+    decoder, params, _ = attention_stack(mixer, rows, batch, length)
+    params = jax.tree.map(lambda t: t.astype(jnp.bfloat16), params)
+    x = x_of((batch, length, 32)).astype(jnp.bfloat16)
+    block = lambda x: decoder._recomputed(
+        *decoder.blocks[1], params["block1"], x, None, False)[0]
+    kept = [(aval.size, aval.dtype, why) for aval, why in saved_residuals(
+        block, x) if "from a constant" not in why]   # the parameters
+    rows_kept = batch * heads * length
+    statistics = 1 if route == "kernels" else 2
+    assert sorted((size, str(dtype)) for size, dtype, _ in kept) == sorted(
+        [(x.size, "bfloat16"), (rows_kept * dv, "bfloat16")] +
+        [(rows_kept, "float32")] * statistics)
+    if rows is None:        # under lax.map the names are the scan's outputs
+        assert sum(A.FLASH_RESIDUAL_NAMES[1] in why
+                   for _, _, why in kept) == statistics
