@@ -23,10 +23,13 @@ left out. On one chip it runs without its exchange; nothing stands in for
 the absent chips.
 
 HLO scopes (docs/observability.md#names): ``zoo_gdn_conv``,
-``zoo_gdn_scan``, ``zoo_gated_attn``, ``zoo_kda_conv``, ``zoo_kda_scan``,
+``zoo_gdn_scan``, ``zoo_kda_conv``, ``zoo_kda_scan``, ``zoo_mixer_proj``
+(a linear or gated attention mixer's work outside its core op and
+convolution), ``zoo_attn_core`` (gated attention's flash call),
 ``zoo_mla_proj``, ``zoo_mla_attn``, ``zoo_dense_mlp``, ``zoo_moe_route``,
-``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_moe_bias``, ``zoo_mtp``,
-``zoo_lm_loss``.
+``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_moe_bias``, ``zoo_embed``,
+``zoo_norm`` (the residual stream: block norms, residual adds, final
+norms), ``zoo_mtp``, ``zoo_lm_loss``.
 """
 
 from __future__ import annotations
@@ -125,7 +128,8 @@ class GatedAttention(KerasLayer):
         x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
         b, l, _ = x.shape
         n, nkv, d = self.n_head, self.n_kv_head, self.head_dim
-        with jax.named_scope("zoo_gated_attn"):
+        tr = lambda t: t.transpose(0, 2, 1, 3)
+        with jax.named_scope("zoo_mixer_proj"):
             qg = (x @ params["w_q"]).reshape(b, l, n, 2 * d)
             q, gate = qg[..., :d], qg[..., d:].reshape(b, l, n * d)
             k = (x @ params["w_k"]).reshape(b, l, nkv, d)
@@ -134,10 +138,12 @@ class GatedAttention(KerasLayer):
                                self.rotary_dim, self.rope_theta)
             k = partial_rotary(rms_norm(k, params["k_norm"], self.eps),
                                self.rotary_dim, self.rope_theta)
-            tr = lambda t: t.transpose(0, 2, 1, 3)
-            o = tr(flash_attention(tr(q), tr(k), tr(v), causal=True,
-                                   sm_scale=1.0 / math.sqrt(d)))
-            o = o.reshape(b, l, n * d) * jax.nn.sigmoid(
+            q, k, v = tr(q), tr(k), tr(v)
+        with jax.named_scope("zoo_attn_core"):
+            o = flash_attention(q, k, v, causal=True,
+                                sm_scale=1.0 / math.sqrt(d))
+        with jax.named_scope("zoo_mixer_proj"):
+            o = tr(o).reshape(b, l, n * d) * jax.nn.sigmoid(
                 gate.astype(jnp.float32)).astype(x.dtype)
             return o @ params["w_o"]
 
@@ -183,26 +189,29 @@ class GatedDeltaNet(KerasLayer):
         nk, nv, dk, dv = self.nk, self.nv, self.dk, self.dv
         kd, vd = nk * dk, nv * dv
         f32 = jnp.float32
-        qkvz = x @ params["w_qkvz"]
-        mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
-        ba = (x @ params["w_ba"]).astype(f32)
-        beta = jax.nn.sigmoid(ba[..., :nv])
-        g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
-            ba[..., nv:] + params["dt_bias"].astype(f32))
-        mixed = jax.nn.silu(causal_depthwise_conv(mixed, params["conv_w"]))
-        q = mixed[..., :kd].reshape(b, l, nk, dk).astype(f32)
-        k = mixed[..., kd:2 * kd].reshape(b, l, nk, dk).astype(f32)
-        v = mixed[..., 2 * kd:].reshape(b, l, nv, dv)
-        l2 = lambda t: t * jax.lax.rsqrt(
-            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-        rep = lambda t: jnp.repeat(t.astype(x.dtype), nv // nk, axis=2)
-        o = chunk_gated_delta_rule(rep(l2(q) / math.sqrt(dk)), rep(l2(k)), v,
-                                   g, beta, self.chunk_size).astype(f32)
-        o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
-            jnp.mean(o * o, -1, keepdims=True) + self.eps)
-        y = (o * jax.nn.silu(z.reshape(b, l, nv, dv).astype(f32))).astype(
-            x.dtype)
-        return y.reshape(b, l, vd) @ params["w_out"]
+        with jax.named_scope("zoo_mixer_proj"):
+            qkvz = x @ params["w_qkvz"]
+            mixed, z = qkvz[..., :2 * kd + vd], qkvz[..., 2 * kd + vd:]
+            ba = (x @ params["w_ba"]).astype(f32)
+            beta = jax.nn.sigmoid(ba[..., :nv])
+            g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., nv:] + params["dt_bias"].astype(f32))
+        mixed = causal_depthwise_conv(mixed, params["conv_w"])
+        with jax.named_scope("zoo_mixer_proj"):
+            mixed = jax.nn.silu(mixed)
+            q = mixed[..., :kd].reshape(b, l, nk, dk).astype(f32)
+            k = mixed[..., kd:2 * kd].reshape(b, l, nk, dk).astype(f32)
+            v = mixed[..., 2 * kd:].reshape(b, l, nv, dv)
+            rep = lambda t: jnp.repeat(t.astype(x.dtype), nv // nk, axis=2)
+            q, k = rep(_l2(q) / math.sqrt(dk)), rep(_l2(k))
+        o = chunk_gated_delta_rule(q, k, v, g, beta, self.chunk_size)
+        with jax.named_scope("zoo_mixer_proj"):
+            o = o.astype(f32)
+            o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
+                jnp.mean(o * o, -1, keepdims=True) + self.eps)
+            y = (o * jax.nn.silu(z.reshape(b, l, nv, dv).astype(f32))
+                 ).astype(x.dtype)
+            return y.reshape(b, l, vd) @ params["w_out"]
 
 
 def _l2(t):
@@ -259,23 +268,27 @@ class KimiDeltaAttention(KerasLayer):
         b, l, _ = x.shape
         n, d, f32 = self.n, self.d, jnp.float32
         heads = lambda t: t.reshape(b, l, n, d)
-        mixed = jax.nn.silu(causal_depthwise_conv(
-            x @ params["w_qkv"], params["conv_w"], "zoo_kda_conv"))
-        q, k, v = (heads(mixed[..., i * n * d:(i + 1) * n * d])
-                   for i in range(3))
-        beta = jax.nn.sigmoid((x @ params["w_b"]).astype(f32))
-        a = ((x @ params["w_f1"]) @ params["w_f2"]).astype(f32)
-        g = -jnp.exp(params["A_log"].astype(f32))[:, None] * heads(
-            jax.nn.softplus(a + params["dt_bias"].astype(f32)))
-        o = chunk_gated_delta_rule(
-            (_l2(q.astype(f32)) / math.sqrt(d)).astype(x.dtype),
-            _l2(k.astype(f32)).astype(x.dtype), v, g, beta,
-            self.chunk_size).astype(f32)
-        o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
-            jnp.mean(o * o, -1, keepdims=True) + self.eps)
-        gate = ((x @ params["w_g1"]) @ params["w_g2"]).astype(f32)
-        y = (o * jax.nn.sigmoid(heads(gate))).astype(x.dtype)
-        return y.reshape(b, l, n * d) @ params["w_o"]
+        with jax.named_scope("zoo_mixer_proj"):
+            mixed = x @ params["w_qkv"]
+        mixed = causal_depthwise_conv(mixed, params["conv_w"], "zoo_kda_conv")
+        with jax.named_scope("zoo_mixer_proj"):
+            mixed = jax.nn.silu(mixed)
+            q, k, v = (heads(mixed[..., i * n * d:(i + 1) * n * d])
+                       for i in range(3))
+            beta = jax.nn.sigmoid((x @ params["w_b"]).astype(f32))
+            a = ((x @ params["w_f1"]) @ params["w_f2"]).astype(f32)
+            g = -jnp.exp(params["A_log"].astype(f32))[:, None] * heads(
+                jax.nn.softplus(a + params["dt_bias"].astype(f32)))
+            q = (_l2(q.astype(f32)) / math.sqrt(d)).astype(x.dtype)
+            k = _l2(k.astype(f32)).astype(x.dtype)
+        o = chunk_gated_delta_rule(q, k, v, g, beta, self.chunk_size)
+        with jax.named_scope("zoo_mixer_proj"):
+            o = o.astype(f32)
+            o = params["norm_w"].astype(f32) * o * jax.lax.rsqrt(
+                jnp.mean(o * o, -1, keepdims=True) + self.eps)
+            gate = ((x @ params["w_g1"]) @ params["w_g2"]).astype(f32)
+            y = (o * jax.nn.sigmoid(heads(gate))).astype(x.dtype)
+            return y.reshape(b, l, n * d) @ params["w_o"]
 
 
 class LatentAttention(KerasLayer):
@@ -492,7 +505,8 @@ class HeldExpertsMoE(KerasLayer):
         are the assignments each of the router's experts got, for a
         balanced router, else None."""
         x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
-        flat = x.reshape(-1, x.shape[-1])
+        with jax.named_scope("zoo_norm"):     # the residual stream's tokens
+            flat = x.reshape(-1, x.shape[-1])
         f32 = jnp.float32
         with jax.named_scope("zoo_moe_route"):
             logits = jnp.dot(flat, params["router"],
@@ -539,7 +553,8 @@ class HeldExpertsMoE(KerasLayer):
                         flat, params["s_gate_w"], preferred_element_type=f32))
                     y = y * gate[:, None].astype(x.dtype)
                 out = out + y
-        return out.reshape(x.shape), stats, router_counts
+        with jax.named_scope("zoo_norm"):
+            return out.reshape(x.shape), stats, router_counts
 
 
 MIXERS = {LINEAR: GatedDeltaNet, FULL: GatedAttention,
@@ -648,16 +663,25 @@ class HybridDecoder(KerasLayer):
             state["mtp"] = self.mtp_block[1].init_state(None)
         return state
 
+    def _norm(self, x, w):
+        with jax.named_scope("zoo_norm"):
+            return rms_norm(x, w, self.eps)
+
     def _block(self, mixer, ff, p, x, bias=None):
-        h = x + mixer.call(p["mixer"], rms_norm(x, p["norm1"], self.eps))
-        n = rms_norm(h, p["norm2"], self.eps)
+        y = mixer.call(p["mixer"], self._norm(x, p["norm1"]))
+        with jax.named_scope("zoo_norm"):
+            h = x + y
+        n = self._norm(h, p["norm2"])
+        state = {}
         if not ff.has_state:
-            return h + ff.call(p[_ff_key(ff)], n), {}
-        y, stats, counts = ff.routed(p[_ff_key(ff)], n, bias)
-        state = {"step_stats": stats}
-        if counts is not None:         # a router that balances its bias
-            state["router_counts"] = counts
-        return h + y, state
+            y = ff.call(p[_ff_key(ff)], n)
+        else:
+            y, stats, counts = ff.routed(p[_ff_key(ff)], n, bias)
+            state = {"step_stats": stats}
+            if counts is not None:     # a router that balances its bias
+                state["router_counts"] = counts
+        with jax.named_scope("zoo_norm"):
+            return h + y, state
 
     def _recomputed(self, mixer, ff, p, x, state, training):
         """``_block`` on (B, L, H), ``remat_rows`` sequences at a time and
@@ -678,9 +702,11 @@ class HybridDecoder(KerasLayer):
         else:
             # in turn, so that the compiler cannot overlap two
             # recomputations
-            x, new = jax.lax.map(
-                fn, x.reshape((b // rows, rows) + x.shape[1:]))
-            x = x.reshape((b,) + x.shape[2:])
+            with jax.named_scope("zoo_norm"):
+                x = x.reshape((b // rows, rows) + x.shape[1:])
+            x, new = jax.lax.map(fn, x)
+            with jax.named_scope("zoo_norm"):
+                x = x.reshape((b,) + x.shape[2:])
             new = {name: {
                 k: v.sum() if k.endswith("_total") else v.max()
                 for k, v in part.items()} if name == "step_stats"
@@ -693,29 +719,35 @@ class HybridDecoder(KerasLayer):
     def call(self, params, inputs, training: bool = False, state=None,
              **kwargs):
         tokens = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
-        x = params["embed"][tokens.astype(jnp.int32)]
+        embed = lambda ids: params["embed"][ids.astype(jnp.int32)]
+        with jax.named_scope("zoo_embed"):
+            x = embed(tokens)
         state = state or {}
         new_state = {}
         for i, (mixer, ff) in enumerate(self.blocks):
             x, new_state[f"block{i}"] = self._recomputed(
                 mixer, ff, params[f"block{i}"], x,
                 state.get(f"block{i}"), training)
-        out = rms_norm(x, params["final_norm"], self.eps)
+        out = self._norm(x, params["final_norm"])
         if self.mtp_block is None:
             return out, new_state
         m = params["mtp"]
 
         @jax.checkpoint
         def joined(nxt, x):
-            both = jnp.concatenate([rms_norm(nxt, m["norm_e"], self.eps),
-                                    rms_norm(x, m["norm_h"], self.eps)], -1)
+            with jax.named_scope("zoo_norm"):
+                both = jnp.concatenate([rms_norm(nxt, m["norm_e"], self.eps),
+                                        rms_norm(x, m["norm_h"], self.eps)],
+                                       -1)
             return both @ m["w_eh"]
 
         with jax.named_scope("zoo_mtp"):
-            y = joined(params["embed"][inputs[1].astype(jnp.int32)], x)
+            with jax.named_scope("zoo_embed"):
+                nxt = embed(inputs[1])
+            y = joined(nxt, x)
             y, new_state["mtp"] = self._recomputed(
                 *self.mtp_block, m["block"], y, state.get("mtp"), training)
-            return (out, rms_norm(y, m["final_norm"], self.eps)), new_state
+            return (out, self._norm(y, m["final_norm"])), new_state
 
 
 class LMHeadLoss(KerasLayer):

@@ -269,10 +269,26 @@ class TransformerLayer(KerasLayer):
         b, l, h = x.shape
         nh = self.n_head
         d = h // nh
-        qkv = jnp.matmul(x, p["qkv_w"].astype(x.dtype)) + \
-            p["qkv_b"].astype(x.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("zoo_mixer_proj"):
+            qkv = jnp.matmul(x, p["qkv_w"].astype(x.dtype)) + \
+                p["qkv_b"].astype(x.dtype)
+            q, k, v = (t.reshape(b, l, nh, d)
+                       for t in jnp.split(qkv, 3, axis=-1))
+        with jax.named_scope("zoo_attn_core"):
+            o = self._attention_core(q, k, v, mask_bias)
+        with jax.named_scope("zoo_mixer_proj"):
+            o = o.reshape(b, l, h)
+            if rng is not None:
+                rng, sub = jax.random.split(rng)
+                o = _dropout(o, self.attn_p_drop, sub, training)
+            return jnp.matmul(o, p["proj_w"].astype(x.dtype)) + \
+                p["proj_b"].astype(x.dtype)
 
+    def _attention_core(self, q, k, v, mask_bias):
+        """Softmax attention of (B, L, heads, head size) queries, keys and
+        values, the same out: the flash kernels (or the blockwise route),
+        across the ``seq`` mesh axis where there is one."""
+        b, l, nh, _ = q.shape
         sp = self._seq_parallel()
         if sp > 1 and l % sp == 0:
             # sequence parallelism over the 'seq' mesh axis: ulysses
@@ -300,23 +316,21 @@ class TransformerLayer(KerasLayer):
                     (b, l)).astype(jnp.float32)
             sp_attn = ulysses_attention_sharded if use_ulysses \
                 else ring_attention_sharded
-            qh, kh, vh = (t.reshape(b, l, nh, d).transpose(0, 2, 1, 3)
-                          for t in (q, k, v))
+            qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
             o = sp_attn(qh, kh, vh, get_nncontext().mesh,
                         causal=not self.bidirectional,
                         kbias=kb).transpose(0, 2, 1, 3)
         else:
-            q4, k4, v4 = (t.reshape(b, l, nh, d) for t in (q, k, v))
             attn = functools.partial(flash_attention_blhd,
                                      causal=not self.bidirectional)
             dp = _dp_mesh(b)
             if dp is None:
-                o = attn(q4, k4, v4, bias=mask_bias)
+                o = attn(q, k, v, bias=mask_bias)
             else:
                 from jax.sharding import PartitionSpec as P
                 p4 = P("data", None, None, None)
                 # check_vma=False: see _dp_dropout_add_ln
-                operands = [q4, k4, v4]
+                operands = [q, k, v]
                 in_specs = [p4, p4, p4]
                 if mask_bias is not None:
                     operands.append(mask_bias)
@@ -331,12 +345,6 @@ class TransformerLayer(KerasLayer):
                 o = jax.shard_map(
                     body, mesh=dp, in_specs=tuple(in_specs),
                     out_specs=p4, check_vma=False)(*operands)
-        o = o.reshape(b, l, h)
-        if rng is not None:
-            rng, sub = jax.random.split(rng)
-            o = _dropout(o, self.attn_p_drop, sub, training)
-        o = jnp.matmul(o, p["proj_w"].astype(x.dtype)) + \
-            p["proj_b"].astype(x.dtype)
         return o
 
     def _block(self, p, x, mask_bias, rng, training):
@@ -348,20 +356,23 @@ class TransformerLayer(KerasLayer):
         if rng is not None:
             r1, r2, r3 = jax.random.split(rng, 3)
         a = self._attention(p, x, mask_bias, r1, training)
-        n = _dp_dropout_add_ln(a, x, p["ln1_g"], p["ln1_b"], r2,
-                               self.hidden_p_drop, training)
+        with jax.named_scope("zoo_norm"):
+            n = _dp_dropout_add_ln(a, x, p["ln1_g"], p["ln1_b"], r2,
+                                   self.hidden_p_drop, training)
         m = self._ffn(p, n, training)
-        return _dp_dropout_add_ln(m, n, p["ln2_g"], p["ln2_b"], r3,
-                                  self.hidden_p_drop, training)
+        with jax.named_scope("zoo_norm"):
+            return _dp_dropout_add_ln(m, n, p["ln2_g"], p["ln2_b"], r3,
+                                      self.hidden_p_drop, training)
 
     def _ffn(self, p, n, training):
         if self.moe_experts:
             return self._moe.call(p["moe"], n, training=training)
-        m = jnp.matmul(n, p["mlp_in_w"].astype(n.dtype)) + \
-            p["mlp_in_b"].astype(n.dtype)
-        m = self._gelu(m)
-        return jnp.matmul(m, p["mlp_out_w"].astype(n.dtype)) + \
-            p["mlp_out_b"].astype(n.dtype)
+        with jax.named_scope("zoo_dense_mlp"):
+            m = jnp.matmul(n, p["mlp_in_w"].astype(n.dtype)) + \
+                p["mlp_in_b"].astype(n.dtype)
+            m = self._gelu(m)
+            return jnp.matmul(m, p["mlp_out_w"].astype(n.dtype)) + \
+                p["mlp_out_b"].astype(n.dtype)
 
     def _embed(self, params, inputs, rng, training):
         if self.embedding_layer is not None:
@@ -377,10 +388,11 @@ class TransformerLayer(KerasLayer):
         return e, None
 
     def _pooler(self, params, x):
-        first = x[:, 0]
-        return jnp.tanh(jnp.matmul(first, params["pooler_w"]
-                                   .astype(x.dtype)) +
-                        params["pooler_b"].astype(x.dtype))
+        with jax.named_scope("zoo_head"):
+            first = x[:, 0]
+            return jnp.tanh(jnp.matmul(first, params["pooler_w"]
+                                       .astype(x.dtype)) +
+                            params["pooler_b"].astype(x.dtype))
 
     def _call_pp(self, params, e, mask_bias, rng, training):
         """Run the block trunk as a GPipe pipeline over the 'pipe' mesh
@@ -611,10 +623,11 @@ class TransformerLayer(KerasLayer):
         return self.lm_logits(params, x), state
 
     def call(self, params, inputs, training=False, rng=None, **kw):
-        e, mask_bias = self._embed(params, inputs, rng, training)
-        if rng is not None:
-            rng, sub = jax.random.split(rng)
-            e = _dropout(e, self.hidden_p_drop, sub, training)
+        with jax.named_scope("zoo_embed"):
+            e, mask_bias = self._embed(params, inputs, rng, training)
+            if rng is not None:
+                rng, sub = jax.random.split(rng)
+                e = _dropout(e, self.hidden_p_drop, sub, training)
         if "blocks" in params:         # GPipe layout (pipeline_parallel>1)
             x = self._call_pp(params, e, mask_bias, rng, training)
             return (x, self._pooler(params, x))

@@ -566,13 +566,17 @@ class SPMDTrainer:
     def _loss_and_preds(self, params, net_state, batch, rng, training):
         xs, y, w = batch
         if self.compute_dtype is not None:
-            params = _cast_tree(params, self.compute_dtype)
-            xs = _cast_tree(xs, self.compute_dtype)
+            # the mixed-precision policy: the master weights' cast (its
+            # transpose casts the gradient back) and the float inputs'
+            with jax.named_scope("zoo_optimizer"):
+                params = _cast_tree(params, self.compute_dtype)
+                xs = _cast_tree(xs, self.compute_dtype)
         preds, new_state = self.apply_fn(params, list(xs), net_state,
                                          training, rng)
-        preds_f = jax.tree.map(lambda p: p.astype(jnp.float32), preds)
-        loss = self.loss_fn(preds_f, y, w) if y is not None else \
-            self.loss_fn(preds_f, None, w)
+        with jax.named_scope("zoo_loss"):
+            preds_f = jax.tree.map(lambda p: p.astype(jnp.float32), preds)
+            loss = self.loss_fn(preds_f, y, w) if y is not None else \
+                self.loss_fn(preds_f, None, w)
         return loss, (preds_f, new_state)
 
     def _train_root_key(self):
@@ -634,8 +638,9 @@ class SPMDTrainer:
             sw = jnp.sum(w.astype(jnp.float32)) if w is not None \
                 else jnp.asarray(
                     float(jax.tree.leaves(batch[0])[0].shape[0]))
-            return (loss * sw, jax.tree.map(lambda g: g * sw, grads),
-                    sw, new_state)
+            with jax.named_scope("zoo_optimizer"):
+                grads = jax.tree.map(lambda g: g * sw, grads)
+            return loss * sw, grads, sw, new_state
 
         micro = self._split_microbatches(batch, accum)
         mb_len = micro[0][0].shape[1]
@@ -650,11 +655,13 @@ class SPMDTrainer:
             w = mbatch[2]
             sw = jnp.sum(w.astype(jnp.float32)) if w is not None \
                 else jnp.asarray(float(mb_len))
-            g_acc = jax.tree.map(lambda a, g: a + g * sw, g_acc, grads)
+            with jax.named_scope("zoo_optimizer"):
+                g_acc = jax.tree.map(lambda a, g: a + g * sw, g_acc, grads)
             return (g_acc, loss_acc + loss * sw, w_acc + sw, state), None
 
-        init = (jax.tree.map(jnp.zeros_like, params), jnp.zeros(()),
-                jnp.zeros(()), net_state)
+        with jax.named_scope("zoo_optimizer"):
+            init = (jax.tree.map(jnp.zeros_like, params), jnp.zeros(()),
+                    jnp.zeros(()), net_state)
         (g_acc, loss_acc, w_acc, new_state), _ = jax.lax.scan(
             body, init, (jnp.arange(accum), micro))
         return loss_acc, g_acc, w_acc, new_state
@@ -666,8 +673,9 @@ class SPMDTrainer:
         loss_sum, g_sum, w_acc, new_state = self._weighted_grad_sums(
             params, net_state, batch, rng, accum)
         denom = jnp.maximum(w_acc, 1e-12)
-        return (loss_sum / denom,
-                jax.tree.map(lambda g: g / denom, g_sum), new_state)
+        with jax.named_scope("zoo_optimizer"):
+            grads = jax.tree.map(lambda g: g / denom, g_sum)
+        return loss_sum / denom, grads, new_state
 
     def _zero_step_body(self, params, opt_state, net_state, batch, step):
         """ZeRO stage-1 step (traced): the whole fwd/bwd/update runs in
@@ -723,43 +731,44 @@ class SPMDTrainer:
                 params, net_state, batch, rng, accum)
             denom = jnp.maximum(jax.lax.psum(mass, "data"), 1e-12)
             loss = jax.lax.psum(loss_sum, "data") / denom
-            # reduce-scatter the weighted gradient sums, normalize the
-            # local shard: each rank now holds 1/dp of the GLOBAL mean
-            # gradient — no rank ever materializes the full reduced grad
-            g_sh = jax.tree.map(
-                lambda g: jax.lax.psum_scatter(
-                    pad_flat(g), "data", scatter_dimension=0,
-                    tiled=True) / denom, g_sum)
-            if frozen:
-                g_sh = {k: (jax.tree.map(jnp.zeros_like, g)
-                            if k in frozen else g)
-                        for k, g in g_sh.items()}
-            gnorm = None
-            if want_gnorm:
-                sq = sum(jnp.vdot(g, g)
-                         for g in jax.tree.leaves(g_sh)) + jnp.zeros(())
-                gnorm = jnp.sqrt(jax.lax.psum(sq, "data"))
-            g_sh, gnorm = self.clipping.apply_with_norm(
-                g_sh, precomputed_norm=gnorm)
-            rank = jax.lax.axis_index("data")
-            p_sh = jax.tree.map(
-                lambda p: jax.lax.dynamic_slice_in_dim(
-                    pad_flat(p), rank * (zero_part.padded_size(
-                        int(np.prod(p.shape, dtype=np.int64)), dp) // dp),
-                    zero_part.padded_size(
-                        int(np.prod(p.shape, dtype=np.int64)), dp) // dp),
-                params)
-            updates, new_opt = self.tx.update(g_sh, opt_state, p_sh)
-            if frozen:
-                updates = {k: (jax.tree.map(jnp.zeros_like, u)
-                               if k in frozen else u)
-                           for k, u in updates.items()}
-            p_new = optax.apply_updates(p_sh, updates)
-            new_params = jax.tree.map(
-                lambda pl, p: jax.lax.all_gather(
-                    pl, "data", tiled=True)[:int(np.prod(
-                        p.shape, dtype=np.int64))].reshape(p.shape),
-                p_new, params)
+            with jax.named_scope("zoo_optimizer"):
+                # reduce-scatter the weighted gradient sums, normalize the
+                # local shard: each rank now holds 1/dp of the GLOBAL mean
+                # gradient — no rank ever materializes the full reduced grad
+                g_sh = jax.tree.map(
+                    lambda g: jax.lax.psum_scatter(
+                        pad_flat(g), "data", scatter_dimension=0,
+                        tiled=True) / denom, g_sum)
+                if frozen:
+                    g_sh = {k: (jax.tree.map(jnp.zeros_like, g)
+                                if k in frozen else g)
+                            for k, g in g_sh.items()}
+                gnorm = None
+                if want_gnorm:
+                    sq = sum(jnp.vdot(g, g)
+                             for g in jax.tree.leaves(g_sh)) + jnp.zeros(())
+                    gnorm = jnp.sqrt(jax.lax.psum(sq, "data"))
+                g_sh, gnorm = self.clipping.apply_with_norm(
+                    g_sh, precomputed_norm=gnorm)
+                rank = jax.lax.axis_index("data")
+                p_sh = jax.tree.map(
+                    lambda p: jax.lax.dynamic_slice_in_dim(
+                        pad_flat(p), rank * (zero_part.padded_size(
+                            int(np.prod(p.shape, dtype=np.int64)), dp) // dp),
+                        zero_part.padded_size(
+                            int(np.prod(p.shape, dtype=np.int64)), dp) // dp),
+                    params)
+                updates, new_opt = self.tx.update(g_sh, opt_state, p_sh)
+                if frozen:
+                    updates = {k: (jax.tree.map(jnp.zeros_like, u)
+                                   if k in frozen else u)
+                               for k, u in updates.items()}
+                p_new = optax.apply_updates(p_sh, updates)
+                new_params = jax.tree.map(
+                    lambda pl, p: jax.lax.all_gather(
+                        pl, "data", tiled=True)[:int(np.prod(
+                            p.shape, dtype=np.int64))].reshape(p.shape),
+                    p_new, params)
             # keep non-trainable state replicated: each rank updated BN
             # stats from its local shard of the batch — average them (the
             # replicated path's stats see the full batch instead; the
@@ -802,28 +811,29 @@ class SPMDTrainer:
             (loss, (_, new_state)), grads = jax.value_and_grad(
                 lambda p: self._loss_and_preds(p, net_state, batch, rng,
                                                True), has_aux=True)(params)
-        if self.frozen_names:
-            grads = {k: (jax.tree.map(jnp.zeros_like, g)
-                         if k in self.frozen_names else g)
-                     for k, g in grads.items()}
-        grads, gnorm = self.clipping.apply_with_norm(grads)
-        updates, opt_state = self.tx.update(grads, opt_state, params)
-        if self._zero_mode == "gspmd" and \
-                self._zero_gspmd_shardings is not None:
-            # ZeRO gspmd mode: pin the moment outputs to their widened
-            # (data-sharded) layouts so input/output shardings stay
-            # identical under donation — one drifting leaf re-creates the
-            # per-dispatch reshard described at _place_state
-            opt_state = jax.lax.with_sharding_constraint(
-                opt_state, self._zero_gspmd_shardings)
-        if self.frozen_names:
-            # zeroed grads are not enough: stateful transforms (Adam
-            # moments accumulated pre-freeze, weight decay) still emit
-            # nonzero updates — frozen params must not move at all
-            updates = {k: (jax.tree.map(jnp.zeros_like, u)
-                           if k in self.frozen_names else u)
-                       for k, u in updates.items()}
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("zoo_optimizer"):
+            if self.frozen_names:
+                grads = {k: (jax.tree.map(jnp.zeros_like, g)
+                             if k in self.frozen_names else g)
+                         for k, g in grads.items()}
+            grads, gnorm = self.clipping.apply_with_norm(grads)
+            updates, opt_state = self.tx.update(grads, opt_state, params)
+            if self._zero_mode == "gspmd" and \
+                    self._zero_gspmd_shardings is not None:
+                # ZeRO gspmd mode: pin the moment outputs to their widened
+                # (data-sharded) layouts so input/output shardings stay
+                # identical under donation — one drifting leaf re-creates the
+                # per-dispatch reshard described at _place_state
+                opt_state = jax.lax.with_sharding_constraint(
+                    opt_state, self._zero_gspmd_shardings)
+            if self.frozen_names:
+                # zeroed grads are not enough: stateful transforms (Adam
+                # moments accumulated pre-freeze, weight decay) still emit
+                # nonzero updates — frozen params must not move at all
+                updates = {k: (jax.tree.map(jnp.zeros_like, u)
+                               if k in self.frozen_names else u)
+                           for k, u in updates.items()}
+            params = optax.apply_updates(params, updates)
         # logs carries only what a consumer reads (the fit loop and the
         # scan body use just the loss). A grad_norm output used to ride
         # along "for free": in the fused k-step path XLA dead-code
@@ -849,7 +859,8 @@ class SPMDTrainer:
             # into the extra global-norm reduce otherwise.
             if gnorm is None and bool(getattr(
                     self.ctx.config, "health_grad_sentinel", False)):
-                gnorm = optax.global_norm(grads)
+                with jax.named_scope("zoo_optimizer"):
+                    gnorm = optax.global_norm(grads)
             bad = ~jnp.isfinite(loss)
             if gnorm is not None:
                 bad = bad | ~jnp.isfinite(gnorm)
