@@ -18,6 +18,14 @@ forward and backward, and which one is decided from its shape
   query positions — the shape of the BERT padding-mask bias
   ``(1-mask)*-10000`` (self_attention.py) — and grouped query heads over
   fewer key/value heads.
+
+  A causal call's grid walks only the live (query block, key block)
+  pairs, those on or below the diagonal (:func:`_causal_walk`), and the
+  kernels read each step's blocks from the walk's scalar-prefetched
+  tables. A grid over the whole rectangle skipped a dead pair's work but
+  its index maps still named new blocks, so each dead step fetched them
+  and waited with nothing to hide the wait behind. Non-causal calls keep
+  the rectangle.
 - :func:`attention_blockwise`: the same scheme as a ``lax.scan`` in plain
   XLA, for every shape the kernels decline (full (B,H,Lq,Lk) biases, odd
   dims, short or non-TPU runs, an explicit ``q_offset``).
@@ -38,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ..utils import telemetry
 from . import _route
 from ._vma import out_struct, vary_like
 
@@ -343,62 +352,53 @@ def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
 # Pallas flash attention (forward; backward via custom_vjp recompute)
 # ---------------------------------------------------------------------------
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref, m_scr,
-                      l_scr, acc_scr, *, sm_scale, causal, block_q, block_k,
-                      num_k_blocks, q_offset=0):
+def _flash_fwd_kernel(pos, q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
+                      m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
+                      block_k, q_offset=0):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, first, last = pos
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        # dots take q/k/v in their native dtype (bf16 on the hot path) with
-        # f32 accumulation via preferred_element_type — casting the inputs
-        # to f32 first forces the MXU onto its f32 path
-        q = q_ref[0]                               # (block_q, d)
-        k = k_ref[0]                               # (block_k, d)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        # additive key bias (padding mask), broadcast over query rows
-        s = s + kb_ref[0].astype(jnp.float32)      # (1, block_k) -> rows
-        if causal:
-            # bottom-right alignment: query row i attends keys <= i + offset
-            # where offset = lk - lq; offset 0 recovers square-L masking,
-            # offset > 0 is the decode shape (short q vs long cached k).
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        correction = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_cur = correction * l_prev + p.sum(axis=-1, keepdims=True)
-        # p rounds to the value dtype for the MXU (standard flash scheme;
-        # the accumulator stays f32)
-        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_cur
-        l_scr[...] = l_cur
-
+    # dots take q/k/v in their native dtype (bf16 on the hot path) with
+    # f32 accumulation via preferred_element_type — casting the inputs
+    # to f32 first forces the MXU onto its f32 path
+    q = q_ref[0]                                   # (block_q, d)
+    k = k_ref[0]                                   # (block_k, d)
+    v = v_ref[0]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    # additive key bias (padding mask), broadcast over query rows
+    s = s + kb_ref[0].astype(jnp.float32)          # (1, block_k) -> rows
     if causal:
-        from jax.experimental import pallas as pl  # noqa: F811
-        # skip fully-masked k-blocks above the (offset-shifted) diagonal
-        pl.when(ki * block_k <= q_offset + (qi + 1) * block_q - 1)(_compute)
-    else:
-        _compute()
+        # bottom-right alignment: query row i attends keys <= i + offset
+        # where offset = lk - lq; offset 0 recovers square-L masking,
+        # offset > 0 is the decode shape (short q vs long cached k).
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+    m_prev = m_scr[...]
+    l_prev = l_scr[...]
+    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    correction = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur)
+    l_cur = correction * l_prev + p.sum(axis=-1, keepdims=True)
+    # p rounds to the value dtype for the MXU (standard flash scheme; the
+    # accumulator stays f32)
+    acc_scr[...] = acc_scr[...] * correction + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_cur
+    l_scr[...] = l_cur
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
@@ -453,6 +453,85 @@ def _kv_spec(block_k, d, group):
                         lambda b, i, j, g=group: (b // g, j, 0))
 
 
+def _causal_walk(lq, lk, block_q, block_k, group=1, key_outer=False):
+    """The grid steps a causal call's kernel takes: the live (query
+    block, key block) pairs, in the order the rectangle of every pair
+    visits them, as int32 tables ``(outer, inner, first, last)``. A pair
+    is live when its key block starts at or before the last key its query
+    block's last row sees (bottom-right alignment, ``lk - lq``); every
+    other pair's scores are all masked. The forward and dq kernels walk
+    query blocks outer and key blocks inner; with ``key_outer`` (dk, dv)
+    key blocks are outer and the inner index runs over ``group`` query
+    heads' query blocks in turn (``g * num_q + qi``). ``first`` and
+    ``last`` mark the steps that open and close a run of one outer
+    block, where a kernel starts and writes its accumulators. With lq <=
+    lk (the route's rule) every run has a step: key block 0 is live for
+    every query block, the last query block for every key block."""
+    num_q, num_k = -(-lq // block_q), -(-lk // block_k)
+    live = (np.arange(num_k)[None, :] * block_k
+            <= lk - lq + (np.arange(num_q)[:, None] + 1) * block_q - 1)
+    if key_outer:
+        live = np.tile(live.T, (1, group))
+    outer, inner = np.nonzero(live)             # row-major: rectangle order
+    turn = outer[1:] != outer[:-1]
+    first = np.concatenate([[True], turn])
+    last = np.concatenate([turn, [True]])
+    return tuple(a.astype(np.int32) for a in (outer, inner, first, last))
+
+
+def _flash_call(kernel, *, name, grid, in_specs, out_specs, scratch_shapes,
+                semantics, walk=None, **kw):
+    """One flash kernel's ``pallas_call``. ``kernel`` takes its grid
+    position ``(outer, inner, first, last)`` before its refs, and the
+    index maps of ``in_specs``/``out_specs`` take (row, outer, inner).
+    Without a walk the grid is the rectangle ``grid`` = (rows, outer,
+    inner). With a causal walk (:func:`_causal_walk`) it is (rows, live
+    steps): Pallas prefetches the walk's tables into SMEM and every index
+    map reads outer and inner from them, so a dead pair is neither
+    fetched nor a step. Counts the steps walked and those left out."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, num_outer, num_inner = grid
+    if walk is None:
+        def body(*refs):
+            i, j = pl.program_id(1), pl.program_id(2)
+            kernel((i, j, j == 0, j == num_inner - 1), *refs)
+
+        call = pl.pallas_call(
+            body, name=name, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=semantics), **kw)
+        steps = rows * num_outer * num_inner
+    else:
+        def body(outer, inner, first, last, *refs):
+            t = pl.program_id(1)
+            kernel((outer[t], inner[t], first[t] == 1, last[t] == 1), *refs)
+
+        def walked(spec):
+            at = spec.index_map
+            return pl.BlockSpec(
+                spec.block_shape,
+                lambda b, t, outer, inner, *_: at(b, outer[t], inner[t]))
+
+        call = functools.partial(pl.pallas_call(
+            body, name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(walk), grid=(rows, len(walk[0])),
+                in_specs=[walked(s) for s in in_specs],
+                out_specs=[walked(s) for s in out_specs],
+                scratch_shapes=scratch_shapes),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(semantics[0], "arbitrary")),
+            **kw), *walk)
+        steps = rows * len(walk[0])
+    telemetry.counter("zoo_flash_grid_steps_total", kernel=name).inc(steps)
+    telemetry.counter("zoo_flash_grid_steps_skipped_total", kernel=name).inc(
+        rows * num_outer * num_inner - steps)
+    return call
+
+
 def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
                    block_q=None, block_k=None, group=1):
     """Returns (o, lse) with o: (BH, Lq, dv), lse: (BH, Lq, 1) f32. k and v
@@ -469,18 +548,18 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
 
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, num_k_blocks=num_k,
-        q_offset=lk - lq)
+        block_q=block_q, block_k=block_k, q_offset=lk - lq)
 
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
     # named scope: optimized HLO keeps no kernel name, only op_name
     # metadata; the ``zoo_*`` tag says which kernel a tpu_custom_call is
     # (utils.profiling.mosaic_kernel_counts).
-    call = pl.pallas_call(
+    call = _flash_call(
         kernel,
         name="zoo_flash_fwd",
         grid=(bh, num_q, num_k),
+        walk=_causal_walk(lq, lk, block_q, block_k) if causal else None,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _kv_spec(block_k, d, group),
@@ -503,8 +582,7 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        semantics=("parallel", "parallel", "arbitrary"),
         interpret=_route.interpret_mode(),
     )
     with jax.named_scope("zoo_flash_fwd"):
@@ -518,186 +596,167 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
 # dk/dv/bias kernel), or one fused kernel where dq stays in VMEM.
 # ---------------------------------------------------------------------------
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
+def _flash_bwd_dq_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, sm_scale, causal,
-                         block_q, block_k, num_k_blocks, q_offset=0):
+                         block_q, block_k, q_offset=0):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, first, last = pos
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        # native-dtype (bf16) MXU dots with f32 accumulation — see the
-        # forward kernel note; ds rounds to bf16 for the final dot, the
-        # standard flash backward scheme
-        q = q_ref[0]                                # (block_q, d)
-        k = k_ref[0]                                # (block_k, d)
-        v = v_ref[0]
-        do = do_ref[0]                              # (block_q, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = s + kb_ref[0].astype(jnp.float32)
-        if causal:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0])                 # (block_q, block_k)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (block_q, block_k)
-        ds = p * (dp - delta_ref[0])                # delta: (block_q, 1)
-        dq_scr[...] += jax.lax.dot(
-            ds.astype(k.dtype), k,
-            preferred_element_type=jnp.float32) * sm_scale
-
+    # native-dtype (bf16) MXU dots with f32 accumulation — see the forward
+    # kernel note; ds rounds to bf16 for the final dot, the standard flash
+    # backward scheme
+    q = q_ref[0]                                    # (block_q, d)
+    k = k_ref[0]                                    # (block_k, d)
+    v = v_ref[0]
+    do = do_ref[0]                                  # (block_q, d)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    s = s + kb_ref[0].astype(jnp.float32)
     if causal:
-        pl.when(ki * block_k <= q_offset + (qi + 1) * block_q - 1)(_compute)
-    else:
-        _compute()
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_q, block_k)
+    ds = p * (dp - delta_ref[0])                    # delta: (block_q, 1)
+    dq_scr[...] += jax.lax.dot(
+        ds.astype(k.dtype), k,
+        preferred_element_type=jnp.float32) * sm_scale
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
+def _flash_bwd_dkv_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, db_ref, dk_scr, dv_scr,
                           db_scr, *, sm_scale, causal, block_q, block_k,
                           num_q_blocks, q_offset=0, group=1):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    # the innermost axis walks the query blocks of every query head that
+    # the inner index walks the query blocks of every query head that
     # reads this key/value head: ``group`` heads, one after the other
-    step = pl.program_id(2)
+    ki, step, first, last = pos
     qi = step if group == 1 else step % num_q_blocks
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
         db_scr[...] = jnp.zeros_like(db_scr)
 
-    def _compute():
-        q = q_ref[0]                                # (block_q, d)
-        k = k_ref[0]                                # (block_k, d)
-        v = v_ref[0]
-        do = do_ref[0]                              # (block_q, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = s + kb_ref[0].astype(jnp.float32)
-        if causal:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0])                 # (block_q, block_k)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (block_k, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (block_q, block_k)
-        ds = p * (dp - delta_ref[0])
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
-
+    q = q_ref[0]                                    # (block_q, d)
+    k = k_ref[0]                                    # (block_k, d)
+    v = v_ref[0]
+    do = do_ref[0]                                  # (block_q, d)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    s = s + kb_ref[0].astype(jnp.float32)
     if causal:
-        pl.when(q_offset + (qi + 1) * block_q - 1 >= ki * block_k)(_compute)
-    else:
-        _compute()
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
+    dv_scr[...] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_k, d)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_q, block_k)
+    ds = p * (dp - delta_ref[0])
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
 
-    @pl.when(step == group * num_q_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         db_ref[0] = db_scr[...].astype(db_ref.dtype)
 
 
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
-                            delta_ref, dk_ref, dv_ref, db_ref, dq_ref,
-                            dk_scr, dv_scr, db_scr, dq_scr, *, sm_scale,
-                            causal, block_q, block_k, num_q_blocks,
+def _flash_bwd_fused_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref,
+                            lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
+                            dq_ref, dk_scr, dv_scr, db_scr, dq_scr, *,
+                            sm_scale, causal, block_q, block_k, num_q_blocks,
                             num_k_blocks, q_offset=0, group=1):
     """The dkv kernel's grid and body plus dq: each score tile is rebuilt
     once and feeds all four cotangents. dq of the ``group`` query heads
     that read this key/value head, ``(group * lq, d)`` float32, stays in a
     scratch over the head's whole sweep of key and query blocks (that it
-    fits is :func:`_dq_stays_in_vmem`'s rule) and is written once."""
+    fits is :func:`_dq_stays_in_vmem`'s rule) and is written once: the
+    sweep opens with the first run of key block 0 and closes with the
+    last run's last step (every key block has a live step, the last
+    query block's)."""
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    step = pl.program_id(2)
+    ki, step, first, last = pos
     qi = step if group == 1 else step % num_q_blocks
-    last_step = group * num_q_blocks - 1
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
         db_scr[...] = jnp.zeros_like(db_scr)
 
-    @pl.when((ki == 0) & (step == 0))
+    @pl.when((ki == 0) & first)
     def _init_dq():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        q = q_ref[0]                                # (block_q, d)
-        k = k_ref[0]                                # (block_k, d)
-        v = v_ref[0]
-        do = do_ref[0]                              # (block_q, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = s + kb_ref[0].astype(jnp.float32)
-        if causal:
-            q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0])                 # (block_q, block_k)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (block_k, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # (block_q, block_k)
-        ds = p * (dp - delta_ref[0])
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
-        # query head step // num_q's block qi, in dq seen as
-        # (group * lq, d): consecutive query heads share this k/v head
-        rows = pl.ds(pl.multiple_of(step * block_q, block_q), block_q)
-        dq_scr[rows, :] += jax.lax.dot(
-            ds.astype(k.dtype), k,
-            preferred_element_type=jnp.float32) * sm_scale
-
+    q = q_ref[0]                                    # (block_q, d)
+    k = k_ref[0]                                    # (block_k, d)
+    v = v_ref[0]
+    do = do_ref[0]                                  # (block_q, d)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    s = s + kb_ref[0].astype(jnp.float32)
     if causal:
-        pl.when(q_offset + (qi + 1) * block_q - 1 >= ki * block_k)(_compute)
-    else:
-        _compute()
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
+    dv_scr[...] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_k, d)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_q, block_k)
+    ds = p * (dp - delta_ref[0])
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
+    # query head step // num_q's block qi, in dq seen as (group * lq, d):
+    # consecutive query heads share this k/v head
+    rows = pl.ds(pl.multiple_of(step * block_q, block_q), block_q)
+    dq_scr[rows, :] += jax.lax.dot(
+        ds.astype(k.dtype), k,
+        preferred_element_type=jnp.float32) * sm_scale
 
-    @pl.when(step == last_step)
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         db_ref[0] = db_scr[...].astype(db_ref.dtype)
 
-    @pl.when((ki == num_k_blocks - 1) & (step == last_step))
+    @pl.when((ki == num_k_blocks - 1) & last)
     def _finalize_dq():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -735,6 +794,8 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     kv_heads = num_heads // group
     dkv = dict(
         grid=(bh // group, num_k, group * num_q),
+        walk=(_causal_walk(lq, lk, block_q, block_k, group, key_outer=True)
+              if causal else None),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv),
                   pl.BlockSpec((1, 1, block_k),
                                lambda b, j, i, h=kv_heads: (b // h, 0, j)),
@@ -761,15 +822,14 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     if _dq_stays_in_vmem(group, lq, d):
         # dq as (kv heads, group * lq, d): a free reshape of (bh, lq, d),
         # and one block a key/value head, resident over its whole sweep
-        fused_call = pl.pallas_call(
+        fused_call = _flash_call(
             functools.partial(
                 _flash_bwd_fused_kernel, num_q_blocks=num_q,
                 num_k_blocks=num_k, group=group, **kernel_args),
             # the name holds ``bwd_dq``: the benchmark's flash rooflines
             # search a trace for zoo_flash_(fwd|bwd_dq|bwd_dkv)
             name="zoo_flash_bwd_dq_dkv",
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            semantics=("parallel", "arbitrary", "arbitrary"),
             **dict(
                 dkv,
                 out_specs=dkv["out_specs"] + [pl.BlockSpec(
@@ -786,31 +846,29 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         do_spec_q = pl.BlockSpec((1, block_q, dv),
                                  lambda b, i, j: (b, i, 0))
         row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-        dq_call = pl.pallas_call(
-            functools.partial(_flash_bwd_dq_kernel, num_k_blocks=num_k,
-                              **kernel_args),
+        dq_call = _flash_call(
+            functools.partial(_flash_bwd_dq_kernel, **kernel_args),
             name="zoo_flash_bwd_dq",
             grid=(bh, num_q, num_k),
+            walk=_causal_walk(lq, lk, block_q, block_k) if causal else None,
             in_specs=[qkv_spec_q, _kv_spec(block_k, d, group),
                       _kv_spec(block_k, dv, group),
                       _bias_specs_3d(num_heads, block_k),
                       do_spec_q, row_spec_q, row_spec_q],
-            out_specs=pl.BlockSpec((1, block_q, d),
-                                   lambda b, i, j: (b, i, 0)),
-            out_shape=out_struct((bh, lq, d), q.dtype, q, k, v, do),
+            out_specs=[pl.BlockSpec((1, block_q, d),
+                                    lambda b, i, j: (b, i, 0))],
+            out_shape=[out_struct((bh, lq, d), q.dtype, q, k, v, do)],
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            semantics=("parallel", "parallel", "arbitrary"),
             interpret=_route.interpret_mode(),
         )
         with jax.named_scope("zoo_flash_bwd_dq"):
-            dq = dq_call(q, k, v, kbias3, do, lse, delta)
-        dkv_call = pl.pallas_call(
+            dq, = dq_call(q, k, v, kbias3, do, lse, delta)
+        dkv_call = _flash_call(
             functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=num_q,
                               group=group, **kernel_args),
             name="zoo_flash_bwd_dkv",
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            semantics=("parallel", "parallel", "arbitrary"),
             **dkv)
         with jax.named_scope("zoo_flash_bwd_dkv"):
             dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)

@@ -119,42 +119,78 @@ def test_flash_blhd_entry_rectangular_causal(lq, lk):
 # Pallas kernels in interpret mode: bottom-right-aligned causal mask
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("lq,lk", [(128, 256), (128, 512), (256, 512)])
-def test_pallas_kernel_rectangular_causal_interpret(monkeypatch, lq, lk):
-    """The kernel mask uses q_offset = lk - lq; fwd and both backward
-    kernels must match the reference on rectangular causal shapes
-    (interpret mode — numerics only, not Mosaic layouts, which the
-    hardware-gated tests own)."""
-    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
-    from analytics_zoo_tpu.ops.attention import (_flash_backward,
-                                                 _flash_forward)
+def _rectangle(lq, lk, block_q, block_k, group=1, key_outer=False):
+    """Every (query block, key block) pair in the rectangle's order, as the
+    tables of :func:`_causal_walk`: the grid before causal calls walked
+    only the live pairs."""
+    num_q, num_k = lq // block_q, lk // block_k
+    n_outer, n_inner = (num_k, group * num_q) if key_outer else (num_q, num_k)
+    outer, inner = np.divmod(np.arange(n_outer * n_inner), n_inner)
+    return tuple(a.astype(np.int32) for a in (
+        outer, inner, inner == 0, inner == n_inner - 1))
 
-    b, h, d = 1, 2, 64
+
+@pytest.mark.parametrize("lq,lk,blocks,d,dv", [
+    pytest.param(128, 256, (128, 128), 64, 64, id="128-256"),
+    pytest.param(128, 512, (128, 128), 64, 64, id="128-512"),
+    pytest.param(256, 512, (128, 128), 64, 64, id="256-512"),
+    pytest.param(512, 512, (128, 128), 64, 64, id="square-4x4"),
+    pytest.param(512, 1024, (128, 256), 64, 64, id="unequal-blocks"),
+    pytest.param(512, 512, (128, 128), 192, 128, id="keys192-values128"),
+])
+def test_pallas_kernel_rectangular_causal_interpret(monkeypatch, lq, lk,
+                                                    blocks, d, dv):
+    """The kernel mask uses q_offset = lk - lq; the forward and both
+    backwards (one fused kernel, and dq beside dk/dv) must match the
+    reference on causal shapes, the key bias's cotangent included. The
+    grid walks only the live blocks: over the whole rectangle, whose
+    extra tiles are all masked and add exact zeros, every output is the
+    same bit for bit (interpret mode — numerics only, not Mosaic layouts,
+    which the hardware-gated tests own)."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    from analytics_zoo_tpu.ops import attention as A
+
+    b, h = 1, 2
+    bq, bk = blocks
     q = _rand(0, (b, h, lq, d))
     k = _rand(1, (b, h, lk, d))
-    v = _rand(2, (b, h, lk, d))
-    kb = jnp.zeros((b, lk), jnp.float32)
+    v = _rand(2, (b, h, lk, dv))
+    kb = _rand(3, (b, lk))
+    do = _rand(4, (b, h, lq, dv))
     sm = 1.0 / np.sqrt(d)
 
-    qf = q.reshape(b * h, lq, d)
-    kf = k.reshape(b * h, lk, d)
-    vf = v.reshape(b * h, lk, d)
-    o, lse = jax.jit(lambda q, k, v, kb: _flash_forward(
-        q, k, v, kb, h, True, sm, 128, 128))(qf, kf, vf, kb)
-    ref = jax.jit(lambda q, k, v: attention_reference(
-        q, k, v, causal=True))(q, k, v)
-    assert float(jnp.abs(o.reshape(b, h, lq, d) - ref).max()) < 1e-5
+    def ref_loss(q, k, v, kb):
+        return (attention_reference(q, k, v, bias=kb[:, None, None, :],
+                                    causal=True, sm_scale=sm) * do).sum()
 
-    gq, gk, gv = jax.jit(jax.grad(
-        lambda q, k, v: (attention_reference(q, k, v, causal=True)
-                         ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
-    do = (2 * o).astype(o.dtype)
-    dq, dk, dv, _ = jax.jit(lambda q, k, v, kb, o, lse, do: _flash_backward(
-        q, k, v, kb, o, lse, do, h, True, sm, 128, 128))(
-            qf, kf, vf, kb, o, lse, do)
-    assert float(jnp.abs(dq.reshape(b, h, lq, d) - gq).max()) < 1e-4
-    assert float(jnp.abs(dk.reshape(b, h, lk, d) - gk).max()) < 1e-4
-    assert float(jnp.abs(dv.reshape(b, h, lk, d) - gv).max()) < 1e-4
+    ref = jax.jit(lambda q, k, v, kb: attention_reference(
+        q, k, v, bias=kb[:, None, None, :], causal=True,
+        sm_scale=sm))(q, k, v, kb)
+    want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2, 3)))(q, k, v, kb)
+
+    flat = lambda t: t.reshape((-1,) + t.shape[2:])
+    qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(do)
+
+    def run(limit):
+        monkeypatch.setattr(A, "FUSED_BWD_DQ_BYTES", limit)
+        o, lse = jax.jit(lambda q, k, v, kb: A._flash_forward(
+            q, k, v, kb, h, True, sm, bq, bk))(qf, kf, vf, kb)
+        grads = jax.jit(lambda *a: A._flash_backward(
+            *a, h, True, sm, bq, bk))(qf, kf, vf, kb, o, lse, dof)
+        return (o, lse) + grads
+
+    for limit in (A.FUSED_BWD_DQ_BYTES, 0):
+        walked = run(limit)
+        o, dq, dk, dv_, dkb = (walked[0],) + walked[2:]
+        assert float(jnp.abs(o.reshape(ref.shape) - ref).max()) < 1e-5
+        for got, exp in zip((dq, dk, dv_, dkb), want):
+            assert float(jnp.abs(got.reshape(exp.shape) - exp).max()) < 1e-4
+        with monkeypatch.context() as m:
+            m.setattr(A, "_causal_walk", _rectangle)
+            rect = run(limit)
+        for got, full in zip(walked, rect):
+            assert np.array_equal(np.asarray(got), np.asarray(full))
+    assert float(jnp.abs(want[3]).max()) > 1e-2        # a cotangent to see
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +207,11 @@ def test_pallas_kernel_rectangular_causal_interpret(monkeypatch, lq, lk):
     (2, 4, 1, 512, 512, 64, 64, False, True, 128),
     (1, 4, 2, 512, 512, 64, 64, True, True, 128),
     (1, 2, 1, 256, 512, 64, 64, True, False, 128),     # lq < lk, causal
+    # 4 x 4 tiles, 10 of them live, with a bias
+    (2, 2, 2, 512, 512, 64, 64, True, True, 128),
+    (1, 4, 2, 1024, 1024, 64, 64, True, True, (256, 128)),  # unequal blocks
+    (1, 2, 1, 512, 1024, 64, 64, True, False, (128, 256)),
+    (1, 2, 2, 512, 512, 192, 128, True, True, 128),    # latent attention's
 ])
 def test_fused_backward_matches_the_reference_and_the_two_kernels(
         monkeypatch, b, h, hkv, lq, lk, d, dv, causal, bias, block):
@@ -183,6 +224,7 @@ def test_fused_backward_matches_the_reference_and_the_two_kernels(
     from analytics_zoo_tpu.ops import attention as A
 
     group = h // hkv
+    bq, bk = block if isinstance(block, tuple) else (block, block)
     q = _rand(0, (b, h, lq, d))
     k = _rand(1, (b, hkv, lk, d))
     v = _rand(2, (b, hkv, lk, dv))
@@ -200,12 +242,11 @@ def test_fused_backward_matches_the_reference_and_the_two_kernels(
     flat = lambda t: t.reshape((-1,) + t.shape[2:])
     qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(do)
     o, lse = jax.jit(lambda q, k, v, kb: A._flash_forward(
-        q, k, v, kb, h, causal, sm, block, block, group))(qf, kf, vf, kb)
+        q, k, v, kb, h, causal, sm, bq, bk, group))(qf, kf, vf, kb)
 
     def backward(limit):
         monkeypatch.setattr(A, "FUSED_BWD_DQ_BYTES", limit)
-        fn = lambda *a: A._flash_backward(*a, h, causal, sm, block, block,
-                                          group)
+        fn = lambda *a: A._flash_backward(*a, h, causal, sm, bq, bk, group)
         names = [e.params["name"] for e in jax.make_jaxpr(fn)(
             qf, kf, vf, kb, o, lse, dof).eqns
             if e.primitive.name == "pallas_call"]
@@ -238,6 +279,111 @@ def test_the_backward_is_fused_where_a_heads_dq_stays_in_vmem(group, lq, d,
     from analytics_zoo_tpu.ops.attention import _dq_stays_in_vmem
 
     assert _dq_stays_in_vmem(group, lq, d) is fused
+
+
+# ---------------------------------------------------------------------------
+# the causal walk: a causal call's grid holds only the live blocks
+# ---------------------------------------------------------------------------
+
+def _live_walk(lq, lk, block_q, block_k, group, key_outer):
+    """The walk from the mask itself: the (outer, inner) pairs whose tile
+    holds a score the causal mask keeps, in the rectangle's order, and
+    the steps that open and close each outer block's run."""
+    num_q, num_k = lq // block_q, lk // block_k
+    tiles = np.tril(np.ones((lq, lk), bool), lk - lq).reshape(
+        num_q, block_q, num_k, block_k).any(axis=(1, 3))
+    if key_outer:
+        pairs = [(ki, g * num_q + qi) for ki in range(num_k)
+                 for g in range(group) for qi in range(num_q)
+                 if tiles[qi, ki]]
+    else:
+        pairs = [(qi, ki) for qi in range(num_q) for ki in range(num_k)
+                 if tiles[qi, ki]]
+    outer = [p[0] for p in pairs]
+    n = len(pairs)
+    first = [t == 0 or outer[t - 1] != outer[t] for t in range(n)]
+    last = [t == n - 1 or outer[t + 1] != outer[t] for t in range(n)]
+    return [np.asarray(c, np.int32) for c in
+            (outer, [p[1] for p in pairs], first, last)]
+
+
+@pytest.mark.parametrize("b,h,hkv,lq,lk,d,dv,causal,asked,steps", [
+    # Kimi Linear's and JoyAI's latent attention: 16 x 16 tiles of 512 a
+    # head, 136 live, in each of the three kernels
+    pytest.param(2, 32, 32, 8192, 8192, 192, 128, True, None,
+                 {"zoo_flash_fwd": (64, 136), "zoo_flash_bwd_dq": (64, 136),
+                  "zoo_flash_bwd_dkv": (64, 136)}, id="latent-attention"),
+    # Qwen3-Next's gated attention: dk, dv walk 8 query heads' 136
+    pytest.param(2, 16, 2, 8192, 8192, 256, 256, True, None,
+                 {"zoo_flash_fwd": (32, 136), "zoo_flash_bwd_dq": (32, 136),
+                  "zoo_flash_bwd_dkv": (4, 1088)}, id="gated-attention"),
+    pytest.param(1, 2, 2, 256, 512, 64, 64, True, (128, 128),
+                 {"zoo_flash_fwd": (2, 7),
+                  "zoo_flash_bwd_dq_dkv": (2, 7)}, id="rectangular"),
+    # the widest tiles, 512 x 1024: 20 of 32
+    pytest.param(1, 2, 2, 4096, 4096, 64, 64, True, None,
+                 {"zoo_flash_fwd": (2, 20),
+                  "zoo_flash_bwd_dq_dkv": (2, 20)}, id="unequal-blocks"),
+    pytest.param(2, 12, 12, 512, 512, 64, 64, False, None,
+                 {"zoo_flash_fwd": (24, 1),
+                  "zoo_flash_bwd_dq_dkv": (24, 1)}, id="non-causal"),
+])
+def test_a_causal_call_walks_only_the_live_blocks(b, h, hkv, lq, lk, d, dv,
+                                                  causal, asked, steps):
+    """Traced, not run: each kernel's grid and scalar-prefetched tables as
+    the ``pallas_call`` holds them, against the walk read off the mask,
+    and the two counters. A non-causal call keeps the rectangle and takes
+    no tables. Blocks are the call's own unless ``asked``."""
+    from analytics_zoo_tpu.ops import attention as A
+    from analytics_zoo_tpu.utils import telemetry
+
+    group = h // hkv
+    bq, bk = asked or (None, None)
+    S = jax.ShapeDtypeStruct
+    args = (S((b * h, lq, d), jnp.bfloat16), S((b * hkv, lk, d), jnp.bfloat16),
+            S((b * hkv, lk, dv), jnp.bfloat16), S((b, lk), jnp.float32),
+            S((b * h, lq, dv), jnp.bfloat16), S((b * h, lq, 1), jnp.float32),
+            S((b * h, lq, dv), jnp.bfloat16))
+
+    def both(q, k, v, kb, o, lse, do):
+        return (A._flash_forward(q, k, v, kb, h, causal, 0.1, bq, bk, group),
+                A._flash_backward(q, k, v, kb, o, lse, do, h, causal, 0.1,
+                                  bq, bk, group))
+
+    def counts():
+        return {(kind, name): telemetry.counter(
+            f"zoo_flash_grid_steps{kind}_total", kernel=name).value
+            for kind in ("", "_skipped") for name in steps}
+
+    before = counts()
+    closed = jax.make_jaxpr(both)(*args)
+    after = counts()
+    consts = dict(zip(closed.jaxpr.constvars, closed.consts))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert sorted(e.params["name"] for e in calls) == sorted(steps)
+    bq, bk = A._resolve_blocks(lq, lk, bq, bk, d)
+    num_q, num_k = lq // bq, lk // bk
+    for e in calls:
+        name = e.params["name"]
+        grid_mapping = e.params["grid_mapping"]
+        rows, per_row = steps[name]
+        key_outer = name != "zoo_flash_fwd" and name != "zoo_flash_bwd_dq"
+        rectangle = num_q * num_k * (group if key_outer else 1)
+        if causal:
+            assert grid_mapping.grid == (rows, per_row)
+            assert grid_mapping.num_index_operands == 4
+            tables = [np.asarray(consts[x]) for x in e.invars[:4]]
+            for got, want in zip(tables, _live_walk(lq, lk, bq, bk, group,
+                                                    key_outer)):
+                assert got.dtype == np.int32
+                np.testing.assert_array_equal(got, want)
+        else:
+            assert grid_mapping.num_index_operands == 0
+            assert grid_mapping.grid == ((rows, num_k, num_q) if key_outer
+                                         else (rows, num_q, num_k))
+        assert after[("", name)] - before[("", name)] == rows * per_row
+        assert after[("_skipped", name)] - before[("_skipped", name)] == \
+            rows * (rectangle - per_row)
 
 
 # ---------------------------------------------------------------------------
