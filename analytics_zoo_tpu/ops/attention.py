@@ -25,7 +25,9 @@ forward and backward, and which one is decided from its shape
   tables. A grid over the whole rectangle skipped a dead pair's work but
   its index maps still named new blocks, so each dead step fetched them
   and waited with nothing to hide the wait behind. Non-causal calls keep
-  the rectangle.
+  the rectangle. Where the shape makes it pay
+  (:func:`_masks_only_straddling_tiles`), the forward builds the causal
+  mask only on the tiles that straddle the diagonal.
 - :func:`attention_blockwise`: the same scheme as a ``lax.scan`` in plain
   XLA, for every shape the kernels decline (full (B,H,Lq,Lk) biases, odd
   dims, short or non-TPU runs, an explicit ``q_offset``).
@@ -352,9 +354,81 @@ def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
 # Pallas flash attention (forward; backward via custom_vjp recompute)
 # ---------------------------------------------------------------------------
 
+def _tile_straddles(qi, ki, block_q, block_k, q_offset):
+    """Whether the causal mask changes a score of the (qi, ki) tile: some
+    key of the tile lies past the last key its first query row sees
+    (bottom-right alignment: row r sees keys <= ``q_offset`` + r). Every
+    other live tile lies wholly on or below the diagonal, and masking it
+    is the identity. Plain arithmetic on ints, numpy arrays or a kernel's
+    traced scalars."""
+    return ki * block_k + block_k - 1 > q_offset + qi * block_q
+
+
+def _masks_only_straddling_tiles(block_k, dv) -> bool:
+    """Whether the forward kernel builds the causal mask only on the tiles
+    that straddle the diagonal, in a body of its own beside one without
+    it, rather than on every tile in one body: a function of the shape, as
+    :func:`_resolve_blocks` is. The mask itself costs little: in one body
+    its vector work fills slots beside the MXU's. What two bodies change
+    is the schedule: the scores are whole before the softmax starts, which
+    Mosaic packs into fewer bundles only where the key block is at most
+    512 and the value head at most 128 wide (compiled for a v5e: 4-17%
+    fewer a tile below the diagonal with keys of 128 to 256; 19-31% more
+    with values of 192 or 256, or with key blocks of 1024). Timed on a
+    v5e at two shapes, both with 512-key blocks: keys 192 and values 128
+    (two bodies 11% faster) and 256/256 (29% slower). The bound on the key
+    block, and d=64 (the decode engine's causal prefill, which the rule
+    sends to two bodies), rest on bundle counts alone and are unverified
+    in time. The backward kernels wait on the MXU's issue slots and took
+    more bundles with two bodies at every shape tried: they mask every
+    tile in one body."""
+    return block_k <= 512 and dv <= 128
+
+
+def _with_score_tile(q, k, kb_ref, qi, ki, then, *, sm_scale, causal,
+                     block_q, block_k, q_offset, straddling_only=False):
+    """Build the (block_q, block_k) float32 score tile of q and k, with
+    the key bias added and in a causal call the mask, and hand it to
+    ``then``. With ``straddling_only``
+    (:func:`_masks_only_straddling_tiles`) the mask is built only where
+    the tile straddles the diagonal (:func:`_tile_straddles`, a scalar
+    test a grid step), and the other tiles take a body without the
+    iotas, compare and select."""
+    from jax.experimental import pallas as pl
+
+    # dots take q/k in their native dtype (bf16 on the hot path) with f32
+    # accumulation via preferred_element_type — casting the inputs to f32
+    # first forces the MXU onto its f32 path
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    # additive key bias (padding mask), broadcast over query rows
+    s = s + kb_ref[0].astype(jnp.float32)          # (1, block_k) -> rows
+    if not causal:
+        then(s)
+        return
+
+    def masked(s):
+        # bottom-right alignment: query row i attends keys <= i + offset
+        # where offset = lk - lq; offset 0 recovers square-L masking,
+        # offset > 0 is the decode shape (short q vs long cached k).
+        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        return jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+
+    if not straddling_only:
+        then(masked(s))
+        return
+    straddles = _tile_straddles(qi, ki, block_q, block_k, q_offset)
+    pl.when(straddles)(lambda: then(masked(s)))
+    pl.when(jnp.logical_not(straddles))(lambda: then(s))
+
+
 def _flash_fwd_kernel(pos, q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
                       m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
-                      block_k, q_offset=0):
+                      block_k, q_offset=0, straddling_only=False):
     from jax.experimental import pallas as pl
 
     qi, ki, first, last = pos
@@ -365,38 +439,27 @@ def _flash_fwd_kernel(pos, q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # dots take q/k/v in their native dtype (bf16 on the hot path) with
-    # f32 accumulation via preferred_element_type — casting the inputs
-    # to f32 first forces the MXU onto its f32 path
     q = q_ref[0]                                   # (block_q, d)
     k = k_ref[0]                                   # (block_k, d)
     v = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    # additive key bias (padding mask), broadcast over query rows
-    s = s + kb_ref[0].astype(jnp.float32)          # (1, block_k) -> rows
-    if causal:
-        # bottom-right alignment: query row i attends keys <= i + offset
-        # where offset = lk - lq; offset 0 recovers square-L masking,
-        # offset > 0 is the decode shape (short q vs long cached k).
-        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    correction = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur)
-    l_cur = correction * l_prev + p.sum(axis=-1, keepdims=True)
-    # p rounds to the value dtype for the MXU (standard flash scheme; the
-    # accumulator stays f32)
-    acc_scr[...] = acc_scr[...] * correction + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_cur
-    l_scr[...] = l_cur
+
+    def accumulate(s):
+        m_prev = m_scr[...]
+        l_prev = l_scr[...]
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        correction = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_cur = correction * l_prev + p.sum(axis=-1, keepdims=True)
+        # p rounds to the value dtype for the MXU (standard flash scheme;
+        # the accumulator stays f32)
+        acc_scr[...] = acc_scr[...] * correction + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_cur
+        l_scr[...] = l_cur
+
+    _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
+                     causal=causal, block_q=block_q, block_k=block_k,
+                     q_offset=q_offset, straddling_only=straddling_only)
 
     @pl.when(last)
     def _finalize():
@@ -480,7 +543,7 @@ def _causal_walk(lq, lk, block_q, block_k, group=1, key_outer=False):
 
 
 def _flash_call(kernel, *, name, grid, in_specs, out_specs, scratch_shapes,
-                semantics, walk=None, **kw):
+                semantics, walk=None, masked=0, **kw):
     """One flash kernel's ``pallas_call``. ``kernel`` takes its grid
     position ``(outer, inner, first, last)`` before its refs, and the
     index maps of ``in_specs``/``out_specs`` take (row, outer, inner).
@@ -488,7 +551,9 @@ def _flash_call(kernel, *, name, grid, in_specs, out_specs, scratch_shapes,
     inner). With a causal walk (:func:`_causal_walk`) it is (rows, live
     steps): Pallas prefetches the walk's tables into SMEM and every index
     map reads outer and inner from them, so a dead pair is neither
-    fetched nor a step. Counts the steps walked and those left out."""
+    fetched nor a step. Counts the steps walked, those left out, and
+    those whose tile straddles the diagonal (``masked`` a row,
+    :func:`_causal_steps`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -529,7 +594,28 @@ def _flash_call(kernel, *, name, grid, in_specs, out_specs, scratch_shapes,
     telemetry.counter("zoo_flash_grid_steps_total", kernel=name).inc(steps)
     telemetry.counter("zoo_flash_grid_steps_skipped_total", kernel=name).inc(
         rows * num_outer * num_inner - steps)
+    telemetry.counter("zoo_flash_grid_steps_masked_total", kernel=name).inc(
+        rows * masked)
     return call
+
+
+def _causal_steps(causal, lq, lk, block_q, block_k, group=1,
+                  key_outer=False, straddling_only=False):
+    """``_flash_call``'s ``walk`` and ``masked`` for a call: a causal
+    call's walk (:func:`_causal_walk`) and how many of a row's steps
+    build the mask: every step, or with ``straddling_only`` (the
+    forward's, so query blocks outer) those whose tile straddles the
+    diagonal (:func:`_tile_straddles`). A non-causal call walks the
+    rectangle and masks nothing."""
+    if not causal:
+        return dict(walk=None, masked=0)
+    walk = _causal_walk(lq, lk, block_q, block_k, group, key_outer)
+    if not straddling_only:
+        return dict(walk=walk, masked=len(walk[0]))
+    assert not key_outer, "only the forward masks straddling tiles alone"
+    qi, ki = walk[:2]
+    return dict(walk=walk, masked=int(np.count_nonzero(_tile_straddles(
+        qi, ki, block_q, block_k, lk - lq))))
 
 
 def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
@@ -546,9 +632,11 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
     num_q = pl.cdiv(lq, block_q)
     num_k = pl.cdiv(lk, block_k)
 
+    straddling_only = _masks_only_straddling_tiles(block_k, dv)
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, q_offset=lk - lq)
+        block_q=block_q, block_k=block_k, q_offset=lk - lq,
+        straddling_only=straddling_only)
 
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
@@ -559,7 +647,8 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
         kernel,
         name="zoo_flash_fwd",
         grid=(bh, num_q, num_k),
-        walk=_causal_walk(lq, lk, block_q, block_k) if causal else None,
+        **_causal_steps(causal, lq, lk, block_q, block_k,
+                        straddling_only=straddling_only),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _kv_spec(block_k, d, group),
@@ -614,28 +703,49 @@ def _flash_bwd_dq_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
     k = k_ref[0]                                    # (block_k, d)
     v = v_ref[0]
     do = do_ref[0]                                  # (block_q, d)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    s = s + kb_ref[0].astype(jnp.float32)
-    if causal:
-        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (block_q, block_k)
-    ds = p * (dp - delta_ref[0])                    # delta: (block_q, 1)
-    dq_scr[...] += jax.lax.dot(
-        ds.astype(k.dtype), k,
-        preferred_element_type=jnp.float32) * sm_scale
+    # dO·Vᵀ needs no score: issued before the score tile's chain, it fills
+    # the MXU's slots while the chain waits on QKᵀ
+    dp = _dp(do, v)                                 # (block_q, block_k)
+
+    def accumulate(s):
+        p = jnp.exp(s - lse_ref[0])                 # (block_q, block_k)
+        ds = p * (dp - delta_ref[0])                # delta: (block_q, 1)
+        dq_scr[...] += jax.lax.dot(
+            ds.astype(k.dtype), k,
+            preferred_element_type=jnp.float32) * sm_scale
+
+    _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
+                     causal=causal, block_q=block_q, block_k=block_k,
+                     q_offset=q_offset)
 
     @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dp(do, v):
+    """dO·Vᵀ of a tile, (block_q, block_k) float32."""
+    return jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dkv_step(q, v, do, lse_ref, delta_ref, dk_scr, dv_scr, db_scr, s,
+              sm_scale, dp=None):
+    """What a score tile adds to dk, dv and the bias cotangent's rows;
+    returns ds. ``dp``: dO·Vᵀ where the kernel issued it already, else it
+    is issued after pᵀ·dO."""
+    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
+    dv_scr[...] += jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)         # (block_k, d)
+    if dp is None:
+        dp = _dp(do, v)                             # (block_q, block_k)
+    ds = p * (dp - delta_ref[0])
+    dk_scr[...] += jax.lax.dot_general(
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
+    return ds
 
 
 def _flash_bwd_dkv_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
@@ -659,28 +769,17 @@ def _flash_bwd_dkv_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
     k = k_ref[0]                                    # (block_k, d)
     v = v_ref[0]
     do = do_ref[0]                                  # (block_q, d)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    s = s + kb_ref[0].astype(jnp.float32)
-    if causal:
-        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
-    dv_scr[...] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (block_k, d)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (block_q, block_k)
-    ds = p * (dp - delta_ref[0])
-    dk_scr[...] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
+    # as in the dq kernel: dO·Vᵀ first. The fused kernel keeps it after
+    # pᵀ·dO: hoisted, BERT's fused kernel compiled to 3,119 bundle lines
+    # for 2,960 (a v5e's compiler), not timed
+    dp = _dp(do, v)
+
+    _with_score_tile(
+        q, k, kb_ref, qi, ki,
+        lambda s: _dkv_step(q, v, do, lse_ref, delta_ref, dk_scr, dv_scr,
+                            db_scr, s, sm_scale, dp),
+        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+        q_offset=q_offset)
 
     @pl.when(last)
     def _finalize():
@@ -721,34 +820,20 @@ def _flash_bwd_fused_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref,
     k = k_ref[0]                                    # (block_k, d)
     v = v_ref[0]
     do = do_ref[0]                                  # (block_q, d)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    s = s + kb_ref[0].astype(jnp.float32)
-    if causal:
-        q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
-    p = jnp.exp(s - lse_ref[0])                     # (block_q, block_k)
-    dv_scr[...] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (block_k, d)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # (block_q, block_k)
-    ds = p * (dp - delta_ref[0])
-    dk_scr[...] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    db_scr[...] += ds.sum(axis=0, keepdims=True)   # (1, block_k)
-    # query head step // num_q's block qi, in dq seen as (group * lq, d):
-    # consecutive query heads share this k/v head
-    rows = pl.ds(pl.multiple_of(step * block_q, block_q), block_q)
-    dq_scr[rows, :] += jax.lax.dot(
-        ds.astype(k.dtype), k,
-        preferred_element_type=jnp.float32) * sm_scale
+
+    def accumulate(s):
+        ds = _dkv_step(q, v, do, lse_ref, delta_ref, dk_scr, dv_scr, db_scr,
+                       s, sm_scale)
+        # query head step // num_q's block qi, in dq seen as (group * lq,
+        # d): consecutive query heads share this k/v head
+        rows = pl.ds(pl.multiple_of(step * block_q, block_q), block_q)
+        dq_scr[rows, :] += jax.lax.dot(
+            ds.astype(k.dtype), k,
+            preferred_element_type=jnp.float32) * sm_scale
+
+    _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
+                     causal=causal, block_q=block_q, block_k=block_k,
+                     q_offset=q_offset)
 
     @pl.when(last)
     def _finalize():
@@ -794,8 +879,8 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     kv_heads = num_heads // group
     dkv = dict(
         grid=(bh // group, num_k, group * num_q),
-        walk=(_causal_walk(lq, lk, block_q, block_k, group, key_outer=True)
-              if causal else None),
+        **_causal_steps(causal, lq, lk, block_q, block_k, group,
+                        key_outer=True),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv),
                   pl.BlockSpec((1, 1, block_k),
                                lambda b, j, i, h=kv_heads: (b // h, 0, j)),
@@ -850,7 +935,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
             name="zoo_flash_bwd_dq",
             grid=(bh, num_q, num_k),
-            walk=_causal_walk(lq, lk, block_q, block_k) if causal else None,
+            **_causal_steps(causal, lq, lk, block_q, block_k),
             in_specs=[qkv_spec_q, _kv_spec(block_k, d, group),
                       _kv_spec(block_k, dv, group),
                       _bias_specs_3d(num_heads, block_k),

@@ -387,6 +387,207 @@ def test_a_causal_call_walks_only_the_live_blocks(b, h, hkv, lq, lk, d, dv,
 
 
 # ---------------------------------------------------------------------------
+# the score tile: the causal mask only where a tile straddles the diagonal
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lq,lk,block_q,block_k", [
+    pytest.param(1024, 1024, 128, 128, id="square-8x8"),
+    pytest.param(256, 1024, 128, 128, id="offset"),
+    pytest.param(1024, 1024, 128, 256, id="128x256"),
+    pytest.param(4096, 4096, 512, 1024, id="512x1024"),
+    pytest.param(512, 1024, 256, 128, id="offset-256x128"),
+    pytest.param(512, 512, 512, 512, id="one-tile"),
+    # blocks of no lane width: a key one past a row's limit straddles
+    pytest.param(6, 9, 2, 3, id="unaligned"),
+])
+def test_a_tile_straddles_the_diagonal_where_the_mask_changes_a_score(
+        lq, lk, block_q, block_k):
+    """:func:`_tile_straddles` against the mask read off the iotas: over
+    every live tile of the walk, the predicate holds exactly where some
+    score of the tile is masked. With blocks of unequal size the
+    straddling tiles are not those with qi == ki."""
+    from analytics_zoo_tpu.ops import attention as A
+
+    off = lk - lq
+    qi, ki, _, _ = A._causal_walk(lq, lk, block_q, block_k)
+    rows = np.arange(block_q)[:, None]
+    cols = np.arange(block_k)[None, :]
+    masked = [not np.all(off + i * block_q + rows >= j * block_k + cols)
+              for i, j in zip(qi, ki)]
+    got = A._tile_straddles(qi, ki, block_q, block_k, off)
+    np.testing.assert_array_equal(got, masked)
+    assert all(A._tile_straddles(int(i), int(j), block_q, block_k, off) == m
+               for i, j, m in zip(qi, ki, masked))
+    assert any(masked)
+    if lq == lk and lq > block_q:
+        assert not all(masked)
+    if block_q != block_k:
+        assert list(qi[np.asarray(masked)]) != list(ki[np.asarray(masked)])
+
+
+@pytest.mark.parametrize("h,hkv,lq,lk,d,dv,blocks", [
+    pytest.param(2, 2, 512, 512, 64, 64, (128, 128), id="square"),
+    pytest.param(4, 2, 512, 512, 64, 64, (128, 128), id="groups-2"),
+    pytest.param(2, 2, 512, 512, 192, 128, (128, 128),
+                 id="keys192-values128"),
+    pytest.param(4, 2, 512, 512, 192, 128, (256, 128),
+                 id="keys192-groups-2-256x128"),
+    pytest.param(2, 1, 256, 512, 64, 64, (128, 256), id="offset-128x256"),
+    # values of 192: the forward masks every tile in one body
+    pytest.param(2, 2, 512, 512, 192, 192, (128, 128), id="values192"),
+])
+def test_a_call_without_a_bias_is_the_call_with_a_zero_bias_to_the_bit(
+        monkeypatch, h, hkv, lq, lk, d, dv, blocks):
+    """A causal call with ``bias=None`` gives the bits of the same call
+    with an explicit all-zero key bias, through the one fused backward
+    and the two-kernel backward, and matches
+    :func:`attention_reference`. The forward kernel gives the same bits
+    with the mask only on the tiles that straddle the diagonal (two
+    bodies) as with every tile masked in one body, whichever its rule
+    of the shape picks."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
+    from analytics_zoo_tpu.ops import attention as A
+
+    b = 1
+    group = h // hkv
+    bq, bk = blocks
+    q = _rand(0, (b, h, lq, d))
+    k = _rand(1, (b, hkv, lk, d))
+    v = _rand(2, (b, hkv, lk, dv))
+    do = _rand(4, (b, h, lq, dv))
+    zeros = jnp.zeros((b, lk), jnp.float32)
+    sm = 1.0 / np.sqrt(d)
+
+    flat = lambda t: t.reshape((-1,) + t.shape[2:])
+    qf, kf, vf = flat(q), flat(k), flat(v)
+
+    def forward(two):
+        with monkeypatch.context() as m:
+            m.setattr(A, "_masks_only_straddling_tiles",
+                      lambda block_k, dv: two)
+            return jax.jit(lambda q, k, v: A._flash_forward(
+                q, k, v, zeros, h, True, sm, bq, bk, group))(qf, kf, vf)
+
+    for got, want in zip(forward(True), forward(False)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+    def entry(bias):
+        def loss(q, k, v):
+            return (A.flash_attention(q, k, v, bias=bias, causal=True,
+                                      block_q=bq, block_k=bk) * do).sum()
+        o = jax.jit(lambda q, k, v: A.flash_attention(
+            q, k, v, bias=bias, causal=True, block_q=bq,
+            block_k=bk))(q, k, v)
+        return (o,) + jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    for limit in (A.FUSED_BWD_DQ_BYTES, 0):
+        monkeypatch.setattr(A, "FUSED_BWD_DQ_BYTES", limit)
+        bare, zero = entry(None), entry(zeros[:, None, None, :])
+        for got, want in zip(bare, zero):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+
+    def ref_loss(q, k, v):
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+        return (attention_reference(q, k, v, causal=True,
+                                    sm_scale=sm) * do).sum()
+
+    rep = lambda t: jnp.repeat(t, group, axis=1)
+    want = (jax.jit(lambda q, k, v: attention_reference(
+        q, rep(k), rep(v), causal=True, sm_scale=sm))(q, k, v),) + \
+        jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
+    for got, exp, tol in zip(bare, want, (1e-5, 2e-4, 2e-4, 2e-4)):
+        assert float(jnp.abs(got - exp).max()) < tol
+
+
+@pytest.mark.parametrize("block_k,dv,two_bodies", [
+    (512, 128, True),     # JoyAI's and Kimi Linear's latent attention
+    (512, 64, True),      # the decode engine's causal prefill at 512
+    (512, 256, False),    # Qwen3-Next's gated attention
+    (512, 192, False),
+    (1024, 128, False),   # heads of at most 128 at 1,024-multiple lengths
+    (1024, 64, False),
+])
+def test_the_forward_masks_only_straddling_tiles_by_a_rule_of_the_shape(
+        block_k, dv, two_bodies):
+    """Two bodies where Mosaic compiles them to fewer bundles than one:
+    key blocks of at most 512 and values of at most 128."""
+    from analytics_zoo_tpu.ops.attention import _masks_only_straddling_tiles
+
+    assert _masks_only_straddling_tiles(block_k, dv) is two_bodies
+
+
+@pytest.mark.parametrize("b,h,hkv,lq,lk,d,dv,causal,asked,masked", [
+    # JoyAI's and Kimi Linear's latent attention: the forward masks the
+    # 16 of a head's 136 live tiles that straddle the diagonal, 64 heads;
+    # the backward kernels mask every tile they walk
+    pytest.param(2, 32, 32, 8192, 8192, 192, 128, True, None,
+                 {"zoo_flash_fwd": 1024, "zoo_flash_bwd_dq": 8704,
+                  "zoo_flash_bwd_dkv": 8704}, id="latent-attention"),
+    # Qwen3-Next's gated attention: values of 256, one body everywhere
+    pytest.param(2, 16, 2, 8192, 8192, 256, 256, True, None,
+                 {"zoo_flash_fwd": 4352, "zoo_flash_bwd_dq": 4352,
+                  "zoo_flash_bwd_dkv": 4352}, id="gated-attention"),
+    # keys offset by 256, tiles of 128: 2 of 7 a head straddle
+    pytest.param(1, 2, 2, 256, 512, 64, 64, True, (128, 128),
+                 {"zoo_flash_fwd": 4, "zoo_flash_bwd_dq_dkv": 14},
+                 id="rectangular"),
+    # 512 x 1024 tiles: one body, 20 of 32 a head
+    pytest.param(1, 2, 2, 4096, 4096, 64, 64, True, None,
+                 {"zoo_flash_fwd": 40, "zoo_flash_bwd_dq_dkv": 40},
+                 id="unequal-blocks"),
+    pytest.param(2, 12, 12, 512, 512, 64, 64, False, None,
+                 {"zoo_flash_fwd": 0, "zoo_flash_bwd_dq_dkv": 0},
+                 id="non-causal"),
+])
+def test_a_causal_call_masks_only_the_tiles_that_straddle(
+        b, h, hkv, lq, lk, d, dv, causal, asked, masked):
+    """Traced, not run, with the zero key bias that a call without a
+    bias takes: ``zoo_flash_grid_steps_masked_total`` counts rows times
+    the walked steps that build the causal mask, each kernel once a
+    trace: where the forward takes two bodies, the tiles whose mask,
+    read off the iotas, is not all true; else every live tile."""
+    from analytics_zoo_tpu.ops import attention as A
+    from analytics_zoo_tpu.utils import telemetry
+
+    group = h // hkv
+    bq, bk = asked or (None, None)
+    S = jax.ShapeDtypeStruct
+    args = (S((b * h, lq, d), jnp.bfloat16), S((b * hkv, lk, d), jnp.bfloat16),
+            S((b * hkv, lk, dv), jnp.bfloat16),
+            S((b * h, lq, dv), jnp.bfloat16), S((b * h, lq, 1), jnp.float32),
+            S((b * h, lq, dv), jnp.bfloat16), S((b, lk), jnp.float32))
+
+    def both(q, k, v, o, lse, do, kb):
+        return (A._flash_forward(q, k, v, kb, h, causal, 0.1, bq, bk,
+                                 group),
+                A._flash_backward(q, k, v, kb, o, lse, do, h, causal, 0.1,
+                                  bq, bk, group))
+
+    def counts():
+        return {name: telemetry.counter("zoo_flash_grid_steps_masked_total",
+                                        kernel=name).value
+                for name in masked}
+
+    before = counts()
+    jax.make_jaxpr(both)(*args)
+    after = counts()
+    assert {n: after[n] - before[n] for n in masked} == masked
+    if causal:
+        rq, rk = A._resolve_blocks(lq, lk, bq, bk, d)
+        tiles = np.tril(np.ones((lq, lk), bool), lk - lq).reshape(
+            lq // rq, rq, lk // rk, rk)
+        live = tiles.any(axis=(1, 3))
+        straddling = live & ~tiles.all(axis=(1, 3))
+        two = A._masks_only_straddling_tiles(rk, dv)
+        assert masked["zoo_flash_fwd"] == b * h * (
+            straddling if two else live).sum()
+        for name, n in masked.items():
+            if name != "zoo_flash_fwd":
+                assert n == b * h * live.sum()
+
+
+# ---------------------------------------------------------------------------
 # routing: causal lq <= lk is kernel-eligible, lq > lk is not
 # ---------------------------------------------------------------------------
 
