@@ -25,9 +25,11 @@ forward and backward, and which one is decided from its shape
   tables. A grid over the whole rectangle skipped a dead pair's work but
   its index maps still named new blocks, so each dead step fetched them
   and waited with nothing to hide the wait behind. Non-causal calls keep
-  the rectangle. Where the shape makes it pay
-  (:func:`_masks_only_straddling_tiles`), the forward builds the causal
-  mask only on the tiles that straddle the diagonal.
+  the rectangle. A causal call with a sliding ``window`` (each row sees
+  its ``window`` latest keys) walks only the band's blocks, under kernel
+  names of its own. Where the shape makes it pay
+  (:func:`_masks_only_straddling_tiles`), the forward builds the mask
+  only on the tiles that straddle an edge of the band.
 - :func:`attention_blockwise`: the same scheme as a ``lax.scan`` in plain
   XLA, for every shape the kernels decline (full (B,H,Lq,Lk) biases, odd
   dims, short or non-TPU runs, an explicit ``q_offset``).
@@ -72,12 +74,35 @@ def _name_residuals(o, *rows):
         checkpoint_name(jnp.squeeze(r, -1), lse_name) for r in rows)
 
 
+def _band(window, causal, lq, lk):
+    """A call's sliding window, checked: ``window`` keys a row, its own
+    included, and so meaningful only with ``causal``. A window of ``lk``
+    keys or more hides no key from any row and is plain causal (None)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(
+            f"window {window} at lengths {lq} x {lk}: a window is the band "
+            f"of a causal call and holds at least one key"
+            + ("" if causal else "; this call is not causal"))
+    return None if window >= lk else int(window)
+
+
+def _keep(q_pos, k_pos, window):
+    """Which scores of (query position, key position) the causal mask, and
+    with a ``window`` its band, keep (bottom-right aligned)."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
+
+
 # ---------------------------------------------------------------------------
 # Reference implementation: the tests' oracle
 # ---------------------------------------------------------------------------
 
 def attention_reference(q, k, v, bias=None, causal=False, sm_scale=None,
-                        q_offset=None):
+                        q_offset=None, window=None):
     """The oracle the tests compare every route against, and nothing
     else: plain softmax(QK^T)V holding the full (B, H, Lq, Lk)
     probabilities. No route of :func:`flash_attention` ends here.
@@ -88,7 +113,11 @@ def attention_reference(q, k, v, bias=None, causal=False, sm_scale=None,
     ``q_offset`` (row i attends keys <= q_offset + i). None keeps the
     bottom-right alignment ``lk - lq`` — the decode/prefill default.
     An explicit smaller offset is the chunked-prefill shape: a chunk of
-    rows mid-prompt attending a key buffer that extends past it."""
+    rows mid-prompt attending a key buffer that extends past it.
+
+    ``window`` (causal only): row i also sees no key more than ``window``
+    - 1 positions before its own, ``window`` keys with itself."""
+    window = _band(window, causal, q.shape[-2], k.shape[-2])
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -99,6 +128,8 @@ def attention_reference(q, k, v, bias=None, causal=False, sm_scale=None,
         lq, lk = logits.shape[-2], logits.shape[-1]
         off = lk - lq if q_offset is None else int(q_offset)
         mask = jnp.tril(jnp.ones((lq, lk), bool), k=off)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((lq, lk), bool), k=off - window)
         logits = jnp.where(mask, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -136,7 +167,7 @@ def _bw_bias_block(bias, start, size, axis, full):
 
 
 def _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale, block_k,
-                        q_offset=None):
+                        q_offset=None, window=None):
     """Returns (o, m, l) with o: (B, H, Lq, d) and the per-row softmax
     max/denominator (B, H, Lq, 1) f32. m and l are kept separate (not
     folded into lse = m + log l): on a fully-masked causal row m is the
@@ -165,7 +196,7 @@ def _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale, block_k,
                 jnp.int32, (lq, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (lq, block_k), 1)
-            s = jnp.where((q_pos >= k_pos)[None, None], s,
+            s = jnp.where(_keep(q_pos, k_pos, window)[None, None], s,
                           DEFAULT_MASK_VALUE)
         m_cur = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         correction = jnp.exp(m - m_cur)
@@ -188,7 +219,7 @@ def _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale, block_k,
 
 
 def _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal, sm_scale,
-                        block_q, block_k, q_offset=None):
+                        block_q, block_k, q_offset=None, window=None):
     """Single-pass blockwise dq/dk/dv/dbias: ONE scan over key blocks
     rebuilds each (B, H, Lq, block_k) score tile exactly once — with the
     saved row max/denominator (p = exp(s - m) / l, the lse split, see
@@ -239,7 +270,7 @@ def _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal, sm_scale,
                 jnp.int32, (lq, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (lq, block_k), 1)
-            mask = (q_pos >= k_pos)[None, None]
+            mask = _keep(q_pos, k_pos, window)[None, None]
             s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
         pu = jnp.exp(s - m)
         dv_j = jnp.einsum("bhqk,bhqd->bhkd", pu, dof,
@@ -296,37 +327,38 @@ def _blockwise_bwd_impl(q, k, v, bias, o, m, l, do, causal, sm_scale,
             dbias)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _attention_blockwise(q, k, v, bias, causal, sm_scale, block_q,
-                         block_k, q_offset):
+                         block_k, q_offset, window):
     return _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale, block_k,
-                               q_offset)[0]
+                               q_offset, window)[0]
 
 
 def _blockwise_fwd_rule(q, k, v, bias, causal, sm_scale, block_q, block_k,
-                        q_offset):
+                        q_offset, window):
     # custom_vjp (not AD through the scan): jax would otherwise save every
     # per-step score block as a residual — O(L^2) again, just chunked.
     # Residuals are the flash set: inputs + (o, m, l).
     o, m, l = _blockwise_fwd_impl(q, k, v, bias, causal, sm_scale,
-                                  block_k, q_offset)
+                                  block_k, q_offset, window)
     o, m, l = _name_residuals(o, m, l)
     return o, (q, k, v, bias, o, m, l)
 
 
-def _blockwise_bwd_rule(causal, sm_scale, block_q, block_k, q_offset, res,
-                        do):
+def _blockwise_bwd_rule(causal, sm_scale, block_q, block_k, q_offset,
+                        window, res, do):
     q, k, v, bias, o, m, l = res
     return _blockwise_bwd_impl(q, k, v, bias, o, m[..., None], l[..., None],
                                do, causal, sm_scale, block_q, block_k,
-                               q_offset)
+                               q_offset, window)
 
 
 _attention_blockwise.defvjp(_blockwise_fwd_rule, _blockwise_bwd_rule)
 
 
 def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
-                        block_q=None, block_k=None, q_offset=None):
+                        block_q=None, block_k=None, q_offset=None,
+                        window=None):
     """O(L)-memory XLA attention: q,k,v (B, H, L, D) -> (B, H, L, D).
 
     ``lax.scan`` over key blocks with online softmax in forward and a
@@ -334,10 +366,12 @@ def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
     ``attention_reference`` numerically while never materializing a
     (B, H, Lq, Lk) tensor in either direction for L >= 256. This is the
     route of every call the Pallas kernels do not take; block sizes
-    follow :func:`_fallback_block` unless given."""
+    follow :func:`_fallback_block` unless given. ``window``: as
+    :func:`attention_reference`'s."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     lq, lk = q.shape[2], k.shape[2]
+    window = _band(window, causal, lq, lk)
     if bias is not None and bias.ndim != 4:
         bias = bias.reshape((1,) * (4 - bias.ndim) + tuple(bias.shape))
     bq, bk = _fallback_block(lq), _fallback_block(lk)
@@ -347,21 +381,26 @@ def attention_blockwise(q, k, v, bias=None, causal=False, sm_scale=None,
         bk = block_k
     off = None if q_offset is None else int(q_offset)
     return _attention_blockwise(q, k, v, bias, causal, sm_scale, bq, bk,
-                                off)
+                                off, window)
 
 
 # ---------------------------------------------------------------------------
 # Pallas flash attention (forward; backward via custom_vjp recompute)
 # ---------------------------------------------------------------------------
 
-def _tile_straddles(qi, ki, block_q, block_k, q_offset):
+def _tile_straddles(qi, ki, block_q, block_k, q_offset, window=None):
     """Whether the causal mask changes a score of the (qi, ki) tile: some
     key of the tile lies past the last key its first query row sees
-    (bottom-right alignment: row r sees keys <= ``q_offset`` + r). Every
-    other live tile lies wholly on or below the diagonal, and masking it
-    is the identity. Plain arithmetic on ints, numpy arrays or a kernel's
-    traced scalars."""
-    return ki * block_k + block_k - 1 > q_offset + qi * block_q
+    (bottom-right alignment: row r sees keys <= ``q_offset`` + r), or,
+    with a ``window``, before the first key its last row sees (keys >
+    ``q_offset`` + r - ``window``). Every other live tile lies wholly
+    inside the band, and masking it is the identity. Plain arithmetic on
+    ints, numpy arrays or a kernel's traced scalars."""
+    upper = ki * block_k + block_k - 1 > q_offset + qi * block_q
+    if window is None:
+        return upper
+    return upper | (ki * block_k <= q_offset + qi * block_q + block_q - 1 -
+                    window)
 
 
 def _masks_only_straddling_tiles(block_k, dv) -> bool:
@@ -386,13 +425,14 @@ def _masks_only_straddling_tiles(block_k, dv) -> bool:
 
 
 def _with_score_tile(q, k, kb_ref, qi, ki, then, *, sm_scale, causal,
-                     block_q, block_k, q_offset, straddling_only=False):
+                     block_q, block_k, q_offset, straddling_only=False,
+                     window=None):
     """Build the (block_q, block_k) float32 score tile of q and k, with
-    the key bias added and in a causal call the mask, and hand it to
-    ``then``. With ``straddling_only``
+    the key bias added and in a causal call the mask (with a ``window``,
+    the band's), and hand it to ``then``. With ``straddling_only``
     (:func:`_masks_only_straddling_tiles`) the mask is built only where
-    the tile straddles the diagonal (:func:`_tile_straddles`, a scalar
-    test a grid step), and the other tiles take a body without the
+    the tile straddles an edge of the band (:func:`_tile_straddles`, a
+    scalar test a grid step), and the other tiles take a body without the
     iotas, compare and select."""
     from jax.experimental import pallas as pl
 
@@ -416,19 +456,20 @@ def _with_score_tile(q, k, kb_ref, qi, ki, then, *, sm_scale, causal,
             jnp.int32, (block_q, block_k), 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        return jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+        return jnp.where(_keep(q_pos, k_pos, window), s, DEFAULT_MASK_VALUE)
 
     if not straddling_only:
         then(masked(s))
         return
-    straddles = _tile_straddles(qi, ki, block_q, block_k, q_offset)
+    straddles = _tile_straddles(qi, ki, block_q, block_k, q_offset, window)
     pl.when(straddles)(lambda: then(masked(s)))
     pl.when(jnp.logical_not(straddles))(lambda: then(s))
 
 
 def _flash_fwd_kernel(pos, q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
                       m_scr, l_scr, acc_scr, *, sm_scale, causal, block_q,
-                      block_k, q_offset=0, straddling_only=False):
+                      block_k, q_offset=0, straddling_only=False,
+                      window=None):
     from jax.experimental import pallas as pl
 
     qi, ki, first, last = pos
@@ -459,7 +500,8 @@ def _flash_fwd_kernel(pos, q_ref, k_ref, v_ref, kb_ref, o_ref, lse_ref,
 
     _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
                      causal=causal, block_q=block_q, block_k=block_k,
-                     q_offset=q_offset, straddling_only=straddling_only)
+                     q_offset=q_offset, straddling_only=straddling_only,
+                     window=window)
 
     @pl.when(last)
     def _finalize():
@@ -516,23 +558,34 @@ def _kv_spec(block_k, d, group):
                         lambda b, i, j, g=group: (b // g, j, 0))
 
 
-def _causal_walk(lq, lk, block_q, block_k, group=1, key_outer=False):
+def _causal_walk(lq, lk, block_q, block_k, group=1, key_outer=False,
+                 window=None):
     """The grid steps a causal call's kernel takes: the live (query
     block, key block) pairs, in the order the rectangle of every pair
     visits them, as int32 tables ``(outer, inner, first, last)``. A pair
     is live when its key block starts at or before the last key its query
-    block's last row sees (bottom-right alignment, ``lk - lq``); every
-    other pair's scores are all masked. The forward and dq kernels walk
-    query blocks outer and key blocks inner; with ``key_outer`` (dk, dv)
-    key blocks are outer and the inner index runs over ``group`` query
-    heads' query blocks in turn (``g * num_q + qi``). ``first`` and
-    ``last`` mark the steps that open and close a run of one outer
-    block, where a kernel starts and writes its accumulators. With lq <=
-    lk (the route's rule) every run has a step: key block 0 is live for
-    every query block, the last query block for every key block."""
+    block's last row sees (bottom-right alignment, ``lk - lq``) and, with
+    a ``window``, ends at or after the first key its first row sees (the
+    band's lower edge); every other pair's scores are all masked. The
+    forward and dq kernels walk query blocks outer and key blocks inner;
+    with ``key_outer`` (dk, dv) key blocks are outer and the inner index
+    runs over ``group`` query heads' query blocks in turn (``g * num_q +
+    qi``). ``first`` and ``last`` mark the steps that open and close a
+    run of one outer block, where a kernel starts and writes its
+    accumulators. With lq <= lk (the route's rule) every query block's
+    run has a step, the block holding its rows' own positions. So does
+    every key block's but where a window leaves keys before the first
+    row's band (lq < lk): such a block keeps one step with the last query
+    block, whose tile is masked whole and adds nothing, so that its
+    cotangents are written."""
     num_q, num_k = -(-lq // block_q), -(-lk // block_k)
-    live = (np.arange(num_k)[None, :] * block_k
-            <= lk - lq + (np.arange(num_q)[:, None] + 1) * block_q - 1)
+    qs, ks = np.arange(num_q)[:, None], np.arange(num_k)[None, :]
+    live = ks * block_k <= lk - lq + (qs + 1) * block_q - 1
+    if window is not None:
+        live &= ks * block_k + block_k - 1 >= lk - lq + qs * block_q - \
+            window + 1
+        if key_outer:
+            live[-1] |= ~live.any(axis=0)
     if key_outer:
         live = np.tile(live.T, (1, group))
     outer, inner = np.nonzero(live)             # row-major: rectangle order
@@ -600,29 +653,37 @@ def _flash_call(kernel, *, name, grid, in_specs, out_specs, scratch_shapes,
 
 
 def _causal_steps(causal, lq, lk, block_q, block_k, group=1,
-                  key_outer=False, straddling_only=False):
+                  key_outer=False, straddling_only=False, window=None):
     """``_flash_call``'s ``walk`` and ``masked`` for a call: a causal
-    call's walk (:func:`_causal_walk`) and how many of a row's steps
-    build the mask: every step, or with ``straddling_only`` (the
-    forward's, so query blocks outer) those whose tile straddles the
-    diagonal (:func:`_tile_straddles`). A non-causal call walks the
-    rectangle and masks nothing."""
+    call's walk (:func:`_causal_walk`, a ``window``'s band) and how many
+    of a row's steps build the mask: every step, or with
+    ``straddling_only`` (the forward's, so query blocks outer) those whose
+    tile straddles an edge of the band (:func:`_tile_straddles`). A
+    non-causal call walks the rectangle and masks nothing."""
     if not causal:
         return dict(walk=None, masked=0)
-    walk = _causal_walk(lq, lk, block_q, block_k, group, key_outer)
+    walk = _causal_walk(lq, lk, block_q, block_k, group, key_outer, window)
     if not straddling_only:
         return dict(walk=walk, masked=len(walk[0]))
     assert not key_outer, "only the forward masks straddling tiles alone"
     qi, ki = walk[:2]
     return dict(walk=walk, masked=int(np.count_nonzero(_tile_straddles(
-        qi, ki, block_q, block_k, lk - lq))))
+        qi, ki, block_q, block_k, lk - lq, window))))
+
+
+def _kernel_name(kind, window):
+    """``zoo_flash_<kind>``, and for a call with a window
+    ``zoo_flash_window_<kind>``: the device trace and the walk's counters
+    tell a window's kernels from the full calls' by name."""
+    return f"zoo_flash_{'window_' if window is not None else ''}{kind}"
 
 
 def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                   block_q=None, block_k=None, group=1):
+                   block_q=None, block_k=None, group=1, window=None):
     """Returns (o, lse) with o: (BH, Lq, dv), lse: (BH, Lq, 1) f32. k and v
     hold BH / ``group`` heads; v's head size ``dv`` may differ from q's and
-    k's ``d`` (latent attention: keys of 192, values of 128)."""
+    k's ``d`` (latent attention: keys of 192, values of 128). ``window``:
+    a causal call's band (:func:`_band`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -636,7 +697,7 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, q_offset=lk - lq,
-        straddling_only=straddling_only)
+        straddling_only=straddling_only, window=window)
 
     kbias3 = kbias.reshape(kbias.shape[0], 1, lk)
 
@@ -645,10 +706,10 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
     # (utils.profiling.mosaic_kernel_counts).
     call = _flash_call(
         kernel,
-        name="zoo_flash_fwd",
+        name=_kernel_name("fwd", window),
         grid=(bh, num_q, num_k),
         **_causal_steps(causal, lq, lk, block_q, block_k,
-                        straddling_only=straddling_only),
+                        straddling_only=straddling_only, window=window),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             _kv_spec(block_k, d, group),
@@ -674,7 +735,7 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
         semantics=("parallel", "parallel", "arbitrary"),
         interpret=_route.interpret_mode(),
     )
-    with jax.named_scope("zoo_flash_fwd"):
+    with jax.named_scope(_kernel_name("fwd", window)):
         return call(q, k, v, kbias3)
 
 
@@ -687,7 +748,7 @@ def _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
 
 def _flash_bwd_dq_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, sm_scale, causal,
-                         block_q, block_k, q_offset=0):
+                         block_q, block_k, q_offset=0, window=None):
     from jax.experimental import pallas as pl
 
     qi, ki, first, last = pos
@@ -716,7 +777,7 @@ def _flash_bwd_dq_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
 
     _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
                      causal=causal, block_q=block_q, block_k=block_k,
-                     q_offset=q_offset)
+                     q_offset=q_offset, window=window)
 
     @pl.when(last)
     def _finalize():
@@ -751,7 +812,7 @@ def _dkv_step(q, v, do, lse_ref, delta_ref, dk_scr, dv_scr, db_scr, s,
 def _flash_bwd_dkv_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, db_ref, dk_scr, dv_scr,
                           db_scr, *, sm_scale, causal, block_q, block_k,
-                          num_q_blocks, q_offset=0, group=1):
+                          num_q_blocks, q_offset=0, group=1, window=None):
     from jax.experimental import pallas as pl
 
     # the inner index walks the query blocks of every query head that
@@ -779,7 +840,7 @@ def _flash_bwd_dkv_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref, lse_ref,
         lambda s: _dkv_step(q, v, do, lse_ref, delta_ref, dk_scr, dv_scr,
                             db_scr, s, sm_scale, dp),
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
-        q_offset=q_offset)
+        q_offset=q_offset, window=window)
 
     @pl.when(last)
     def _finalize():
@@ -792,15 +853,15 @@ def _flash_bwd_fused_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref,
                             lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
                             dq_ref, dk_scr, dv_scr, db_scr, dq_scr, *,
                             sm_scale, causal, block_q, block_k, num_q_blocks,
-                            num_k_blocks, q_offset=0, group=1):
+                            num_k_blocks, q_offset=0, group=1, window=None):
     """The dkv kernel's grid and body plus dq: each score tile is rebuilt
     once and feeds all four cotangents. dq of the ``group`` query heads
     that read this key/value head, ``(group * lq, d)`` float32, stays in a
     scratch over the head's whole sweep of key and query blocks (that it
     fits is :func:`_dq_stays_in_vmem`'s rule) and is written once: the
     sweep opens with the first run of key block 0 and closes with the
-    last run's last step (every key block has a live step, the last
-    query block's)."""
+    last run's last step (every key block has a step:
+    :func:`_causal_walk`)."""
     from jax.experimental import pallas as pl
 
     ki, step, first, last = pos
@@ -833,7 +894,7 @@ def _flash_bwd_fused_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref,
 
     _with_score_tile(q, k, kb_ref, qi, ki, accumulate, sm_scale=sm_scale,
                      causal=causal, block_q=block_q, block_k=block_k,
-                     q_offset=q_offset)
+                     q_offset=q_offset, window=window)
 
     @pl.when(last)
     def _finalize():
@@ -847,7 +908,7 @@ def _flash_bwd_fused_kernel(pos, q_ref, k_ref, v_ref, kb_ref, do_ref,
 
 
 def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
-                    block_q=None, block_k=None, group=1):
+                    block_q=None, block_k=None, group=1, window=None):
     """Blockwise dq/dk/dv/dbias. Returns grads matching (q, k, v, kbias).
     One kernel where a key/value head's dq stays in VMEM
     (:func:`_dq_stays_in_vmem`), else one for dq and one for dk, dv and
@@ -880,7 +941,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     dkv = dict(
         grid=(bh // group, num_k, group * num_q),
         **_causal_steps(causal, lq, lk, block_q, block_k, group,
-                        key_outer=True),
+                        key_outer=True, window=window),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv),
                   pl.BlockSpec((1, 1, block_k),
                                lambda b, j, i, h=kv_heads: (b // h, 0, j)),
@@ -902,7 +963,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         interpret=_route.interpret_mode(),
     )
     kernel_args = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                       block_k=block_k, q_offset=lk - lq)
+                       block_k=block_k, q_offset=lk - lq, window=window)
 
     if _dq_stays_in_vmem(group, lq, d):
         # dq as (kv heads, group * lq, d): a free reshape of (bh, lq, d),
@@ -913,7 +974,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
                 num_k_blocks=num_k, group=group, **kernel_args),
             # the name holds ``bwd_dq``: the benchmark's flash rooflines
             # search a trace for zoo_flash_(fwd|bwd_dq|bwd_dkv)
-            name="zoo_flash_bwd_dq_dkv",
+            name=_kernel_name("bwd_dq_dkv", window),
             semantics=("parallel", "arbitrary", "arbitrary"),
             **dict(
                 dkv,
@@ -923,7 +984,7 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
                     (bh // group, group * lq, d), q.dtype, q, k, v, do)],
                 scratch_shapes=dkv["scratch_shapes"] + [
                     pltpu.VMEM((group * lq, d), jnp.float32)]))
-        with jax.named_scope("zoo_flash_bwd_dq_dkv"):
+        with jax.named_scope(_kernel_name("bwd_dq_dkv", window)):
             dk, dv, db, dq = fused_call(q, k, v, kbias3, do, lse, delta)
         dq = dq.reshape(bh, lq, d)
     else:
@@ -933,9 +994,10 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
         row_spec_q = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
         dq_call = _flash_call(
             functools.partial(_flash_bwd_dq_kernel, **kernel_args),
-            name="zoo_flash_bwd_dq",
+            name=_kernel_name("bwd_dq", window),
             grid=(bh, num_q, num_k),
-            **_causal_steps(causal, lq, lk, block_q, block_k),
+            **_causal_steps(causal, lq, lk, block_q, block_k,
+                            window=window),
             in_specs=[qkv_spec_q, _kv_spec(block_k, d, group),
                       _kv_spec(block_k, dv, group),
                       _bias_specs_3d(num_heads, block_k),
@@ -947,15 +1009,15 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
             semantics=("parallel", "parallel", "arbitrary"),
             interpret=_route.interpret_mode(),
         )
-        with jax.named_scope("zoo_flash_bwd_dq"):
+        with jax.named_scope(_kernel_name("bwd_dq", window)):
             dq, = dq_call(q, k, v, kbias3, do, lse, delta)
         dkv_call = _flash_call(
             functools.partial(_flash_bwd_dkv_kernel, num_q_blocks=num_q,
                               group=group, **kernel_args),
-            name="zoo_flash_bwd_dkv",
+            name=_kernel_name("bwd_dkv", window),
             semantics=("parallel", "parallel", "arbitrary"),
             **dkv)
-        with jax.named_scope("zoo_flash_bwd_dkv"):
+        with jax.named_scope(_kernel_name("bwd_dkv", window)):
             dk, dv, db = dkv_call(q, k, v, kbias3, do, lse, delta)
     # bias grad: the (B, Lk) key bias broadcasts over heads and query
     # rows, so its cotangent sums ds over both — rows inside the kernel,
@@ -964,29 +1026,29 @@ def _flash_backward(q, k, v, kbias, o, lse, do, num_heads, causal, sm_scale,
     return dq, dk, dv, dkb
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_attention_bhld(q, k, v, kbias, num_heads, causal, sm_scale,
-                          block_q=None, block_k=None, group=1):
+                          block_q=None, block_k=None, group=1, window=None):
     return _flash_forward(q, k, v, kbias, num_heads, causal, sm_scale,
-                          block_q, block_k, group)[0]
+                          block_q, block_k, group, window)[0]
 
 
 def _flash_fwd_rule(q, k, v, kbias, num_heads, causal, sm_scale,
-                    block_q=None, block_k=None, group=1):
+                    block_q=None, block_k=None, group=1, window=None):
     o, lse = _name_residuals(*_flash_forward(
         q, k, v, kbias, num_heads, causal, sm_scale, block_q, block_k,
-        group))
+        group, window))
     return o, (q, k, v, kbias, o, lse)
 
 
 def _flash_bwd_rule(num_heads, causal, sm_scale, block_q, block_k, group,
-                    res, do):
+                    window, res, do):
     """The one backward: Pallas kernels (one or two, by the shape:
     :func:`_flash_backward`) rebuilding score blocks from (q, k, bias) and
     the saved lse (O(L) memory)."""
     q, k, v, kbias, o, lse = res
     return _flash_backward(q, k, v, kbias, o, lse[..., None], do, num_heads,
-                           causal, sm_scale, block_q, block_k, group)
+                           causal, sm_scale, block_q, block_k, group, window)
 
 
 _flash_attention_bhld.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1030,7 +1092,7 @@ def _dq_stays_in_vmem(group, lq, d) -> bool:
 
 
 def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
-                    kv_heads=1, dv=None) -> bool:
+                    kv_heads=1, dv=None, window=None) -> bool:
     """Whether a call runs the Pallas kernels: this op's shape rules,
     handed to ``_route.kernel_route``, which adds what every op shares
     (``ZOO_TPU_DISABLE_PALLAS``, the partition check, the loud failure on
@@ -1053,7 +1115,9 @@ def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
     (offset = lk - lq, matching the reference), but lq > lk would leave
     the leading query rows fully masked (their softmax degenerates to the
     l_safe epsilon), so those shapes stay on the blockwise path, which
-    zeroes masked rows explicitly."""
+    zeroes masked rows explicitly. A ``window`` without causal, or of no
+    key, is no call at all: it raises (:func:`_band`) on every route."""
+    _band(window, causal, lq, lk)
     return _route.kernel_route("attention", (
         (on_tpu, _route.NO_KERNEL_BACKEND),
         (kb is not None, "the bias is neither absent nor a key-padding "
@@ -1071,7 +1135,8 @@ def _route_eligible(on_tpu, kb, lq, lk, d, causal, heads=1,
 
 
 def flash_attention_blhd(q, k, v, bias=None, causal=False, sm_scale=None,
-                         block_q=None, block_k=None, q_offset=None):
+                         block_q=None, block_k=None, q_offset=None,
+                         window=None):
     """q,k,v: (B, L, H, D) -> (B, L, H, D) — the layout a fused QKV
     projection's reshape produces. Transposes to (B, H, L, D), runs
     :func:`flash_attention`, transposes back. On the blockwise route the
@@ -1084,15 +1149,18 @@ def flash_attention_blhd(q, k, v, bias=None, causal=False, sm_scale=None,
     return tr(flash_attention(tr(q), tr(k), tr(v), bias=bias,
                               causal=causal, sm_scale=sm_scale,
                               block_q=block_q, block_k=block_k,
-                              q_offset=q_offset))
+                              q_offset=q_offset, window=window))
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
-                    block_q=None, block_k=None, q_offset=None):
+                    block_q=None, block_k=None, q_offset=None, window=None):
     """q: (B, H, L, D); k: (B, Hkv, L, D); v: (B, Hkv, L, Dv) with H a
     whole multiple of Hkv (consecutive query heads share a key/value head)
     -> (B, H, L, Dv). Dv is D unless the caller's values are narrower or
-    wider than its keys (latent attention: 192 and 128).
+    wider than its keys (latent attention: 192 and 128). ``window``
+    (causal only): a row sees its ``window`` latest keys, its own
+    included (a sliding-window layer); the kernels then walk only the
+    band's blocks and take names of their own (``zoo_flash_window_*``).
 
     Sequences of L >= KERNEL_MIN_SEQ route to the Pallas kernels on TPU
     (or interpreter mode when ``ZOO_TPU_PALLAS_INTERPRET=1`` on CPU)
@@ -1111,6 +1179,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     if h % hkv:
         raise ValueError(f"{h} query heads over {hkv} key/value heads")
     group = h // hkv
+    window = _band(window, causal, lq, lk)
     kb = _as_key_bias(bias, b, lk) if on_tpu else None
     # a non-default q_offset is the chunked-prefill rectangle; the Pallas
     # wrappers hardcode the bottom-right alignment, so those shapes take
@@ -1118,7 +1187,7 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
     default_off = q_offset is None or int(q_offset) == lk - lq
     dv = v.shape[-1]
     use_kernel = default_off and _route_eligible(on_tpu, kb, lq, lk, d,
-                                                 causal, h, hkv, dv)
+                                                 causal, h, hkv, dv, window)
     if not use_kernel:
         if group > 1:
             # the blockwise route knows one key/value head a query head
@@ -1128,11 +1197,12 @@ def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,
         # score tile defeats the O(L) contract) — attention_blockwise
         # picks strictly-smaller blocks itself
         return attention_blockwise(q, k, v, bias=bias, causal=causal,
-                                   sm_scale=sm_scale, q_offset=q_offset)
+                                   sm_scale=sm_scale, q_offset=q_offset,
+                                   window=window)
     block_q, block_k = _resolve_blocks(lq, lk, block_q, block_k, d)
     qf = q.reshape(b * h, lq, d)
     kf = k.reshape(b * hkv, lk, d)
     vf = v.reshape(b * hkv, lk, dv)
     o = _flash_attention_bhld(qf, kf, vf, kb, h, causal, sm_scale,
-                              block_q, block_k, group)
+                              block_q, block_k, group, window)
     return o.reshape(b, h, lq, dv)

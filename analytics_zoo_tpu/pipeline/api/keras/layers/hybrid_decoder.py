@@ -1,8 +1,10 @@
 """Decoder blocks of the kind today's open hybrid models use: a token
 mixer chosen per block from a layer pattern (Gated DeltaNet or Kimi Delta
-Attention linear attention; gated softmax attention with grouped query
-heads and partial rotary positions, or latent attention with or without
-positions and a query rank), zero-centred RMSNorm, and a dropless expert
+Attention linear attention; softmax attention with grouped query heads,
+partial rotary positions (default or YaRN), with or without an output gate
+and a query/key norm, over the whole causal context or a sliding window;
+latent attention with or without positions and a query rank),
+zero-centred RMSNorm, and a dropless expert
 layer that is told which experts it holds, how its router scores and
 whether it balances its selection bias, or a dense gated MLP in the
 leading blocks; optionally a multi-token-prediction module behind the
@@ -25,7 +27,8 @@ the absent chips.
 HLO scopes (docs/observability.md#names): ``zoo_gdn_conv``,
 ``zoo_gdn_scan``, ``zoo_kda_conv``, ``zoo_kda_scan``, ``zoo_mixer_proj``
 (a linear or gated attention mixer's work outside its core op and
-convolution), ``zoo_attn_core`` (gated attention's flash call),
+convolution), ``zoo_attn_core`` (softmax attention's flash call; a
+sliding window's within it under ``zoo_attn_window``),
 ``zoo_mla_proj``, ``zoo_mla_attn``, ``zoo_dense_mlp``, ``zoo_moe_route``,
 ``zoo_moe_experts``, ``zoo_moe_shared``, ``zoo_moe_bias``, ``zoo_embed``,
 ``zoo_norm`` (the residual stream: block norms, residual adds, final
@@ -34,6 +37,7 @@ norms), ``zoo_mtp``, ``zoo_lm_loss``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence
 
@@ -48,6 +52,7 @@ from .....ops.grouped_experts import (expected_tile, grouped_experts,
 from ..engine.base import KerasLayer
 
 LINEAR, FULL = "linear_attention", "full_attention"
+SLIDING = "sliding_attention"
 KDA, LATENT = "kimi_delta_attention", "latent_attention"
 # what a layer with routing reports each step; the trainer sums the
 # ``_total`` names over a dispatch's steps and publishes them as counters
@@ -72,14 +77,53 @@ def rms_norm(x, w, eps):
     return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
 
 
-def partial_rotary(x, rot: int, theta: float, interleave: bool = False):
+def rope_frequencies(rot: int, rope):
+    """(inv, scale): what position t turns pair i of ``rot`` dimensions by
+    (``t * inv[i]``) and the factor on cos and sin, from a layer type's
+    ``rope_parameters`` section (a number: default positions at that
+    theta). ``default``: ``inv_i = theta ** (-2i / rot)``, no factor.
+    ``yarn`` (arXiv:2309.00071, in the form of Hugging Face's
+    ``_compute_yarn_parameters``): with ``corr(r) = rot *
+    ln(original_max_position_embeddings / (2 pi r)) / (2 ln theta)``, ``low
+    = floor(corr(beta_fast))`` and ``high = ceil(corr(beta_slow))`` within
+    [0, rot - 1] and ``ramp_i = clip((i - low) / (high - low), 0, 1)``, the
+    pairs below ``low`` keep the default turn, those from ``high`` on turn
+    ``factor`` times slower, and cos and sin carry ``attention_factor``
+    (left out: ``0.1 ln(factor) + 1``)."""
+    if not isinstance(rope, dict):
+        rope = {"rope_type": "default", "rope_theta": rope}
+    theta = rope["rope_theta"]
+    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return inv, 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}")
+    factor = float(rope["factor"])
+    orig = rope["original_max_position_embeddings"]
+
+    def corr(turns):
+        return rot * math.log(orig / (turns * 2 * math.pi)) / \
+            (2 * math.log(theta))
+
+    low = max(math.floor(corr(rope.get("beta_fast", 32))), 0)
+    high = min(math.ceil(corr(rope.get("beta_slow", 1))), rot - 1)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low) /
+                    (high - low if high > low else 1e-3), 0.0, 1.0)
+    scale = rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+    return inv / factor * ramp + inv * (1.0 - ramp), float(scale)
+
+
+def partial_rotary(x, rot: int, rope, interleave: bool = False):
     """Rotary positions on the first ``rot`` of each head's dimensions of
     (B, L, heads, d); position t is row t. Column i of the first half is
     paired with column i of the second (rotate-half), or with
     ``interleave`` columns 2i and 2i+1 are a pair; pair i turns by ``t *
-    theta ** (-2i / rot)``, in float32."""
+    inv[i]`` and cos and sin carry a factor, both from ``rope``, the
+    layer's ``rope_parameters`` section or a theta
+    (:func:`rope_frequencies`), in float32."""
     length = x.shape[1]
-    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    inv, scale = rope_frequencies(rot, rope)
     ang = jnp.arange(length, dtype=jnp.float32)[:, None] * inv[None, :]
     xr = x[..., :rot].astype(jnp.float32)
     if interleave:
@@ -92,59 +136,87 @@ def partial_rotary(x, rot: int, theta: float, interleave: bool = False):
                                 -1)
     cos = twice(jnp.cos(ang))[None, :, None, :]
     sin = twice(jnp.sin(ang))[None, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     return jnp.concatenate([(xr * cos + other * sin).astype(x.dtype),
                             x[..., rot:]], -1)
 
 
 class GatedAttention(KerasLayer):
     """Causal softmax attention with ``n_head`` query heads over
-    ``n_kv_head`` key/value heads, a zero-centred RMSNorm on each query and
-    key head, rotary positions on ``rotary_dim`` of each head, and a
-    sigmoid gate on the output taken from the query projection.
-    (B, L, H) -> (B, L, H); no bias anywhere."""
+    ``n_kv_head`` key/value heads and rotary positions on ``rotary_dim`` of
+    each head. ``gated`` (the default, Qwen3-Next's gated attention): a
+    zero-centred RMSNorm on each query and key head and a sigmoid gate on
+    the output taken from the query projection; without it, plain
+    grouped-query attention. ``window``: each row sees its
+    ``window`` latest keys, its own included (a sliding-window layer), and
+    the flash call runs under ``zoo_attn_window``. ``rope_parameters``: the
+    layer type's section (:func:`rope_frequencies`); left out, default
+    rotary positions at ``rope_theta``. (B, L, H) -> (B, L, H); no bias
+    anywhere."""
 
     def __init__(self, n_head: int, n_kv_head: int, head_dim: int,
                  rotary_dim: int, rope_theta: float = 1e7, eps: float = 1e-6,
+                 window: Optional[int] = None, gated: bool = True,
+                 rope_parameters: Optional[dict] = None,
                  input_shape=None, name: Optional[str] = None, **kwargs):
         super().__init__(input_shape=input_shape, name=name)
         if n_head % n_kv_head:
             raise ValueError(f"{n_head} query heads over {n_kv_head}")
         self.n_head, self.n_kv_head, self.head_dim = n_head, n_kv_head, \
             head_dim
-        self.rotary_dim, self.rope_theta, self.eps = rotary_dim, rope_theta, \
-            eps
+        self.rotary_dim, self.eps = rotary_dim, eps
+        self.rope = rope_parameters or rope_theta
+        self.window, self.gated = window, gated
 
     def build(self, rng, input_shape):
         h = int(input_shape[-1])
         qd, kvd = self.n_head * self.head_dim, self.n_kv_head * self.head_dim
         r = jax.random.split(rng, 4)
-        return {"w_q": _normal(r[0], (h, 2 * qd)),
-                "w_k": _normal(r[1], (h, kvd)),
-                "w_v": _normal(r[2], (h, kvd)), "w_o": _normal(r[3], (qd, h)),
-                "q_norm": jnp.zeros((self.head_dim,)),
-                "k_norm": jnp.zeros((self.head_dim,))}
+        params = {"w_q": _normal(r[0], (h, (2 if self.gated else 1) *
+                                        qd)),
+                  "w_k": _normal(r[1], (h, kvd)),
+                  "w_v": _normal(r[2], (h, kvd)),
+                  "w_o": _normal(r[3], (qd, h))}
+        if self.gated:
+            params.update(q_norm=jnp.zeros((self.head_dim,)),
+                          k_norm=jnp.zeros((self.head_dim,)))
+        return params
 
     def call(self, params, inputs, training: bool = False, **kwargs):
         x = inputs[0] if isinstance(inputs, (list, tuple)) else inputs
         b, l, _ = x.shape
         n, nkv, d = self.n_head, self.n_kv_head, self.head_dim
         tr = lambda t: t.transpose(0, 2, 1, 3)
+
+        def turn(t, norm):
+            if self.gated:
+                t = rms_norm(t, params[norm], self.eps)
+            return partial_rotary(t, self.rotary_dim, self.rope)
+
         with jax.named_scope("zoo_mixer_proj"):
-            qg = (x @ params["w_q"]).reshape(b, l, n, 2 * d)
-            q, gate = qg[..., :d], qg[..., d:].reshape(b, l, n * d)
+            if self.gated:
+                qg = (x @ params["w_q"]).reshape(b, l, n, 2 * d)
+                q, gate = qg[..., :d], qg[..., d:].reshape(b, l, n * d)
+            else:
+                q = (x @ params["w_q"]).reshape(b, l, n, d)
             k = (x @ params["w_k"]).reshape(b, l, nkv, d)
             v = (x @ params["w_v"]).reshape(b, l, nkv, d)
-            q = partial_rotary(rms_norm(q, params["q_norm"], self.eps),
-                               self.rotary_dim, self.rope_theta)
-            k = partial_rotary(rms_norm(k, params["k_norm"], self.eps),
-                               self.rotary_dim, self.rope_theta)
+            q, k = turn(q, "q_norm"), turn(k, "k_norm")
             q, k, v = tr(q), tr(k), tr(v)
+        attend = functools.partial(flash_attention, causal=True,
+                                   sm_scale=1.0 / math.sqrt(d))
         with jax.named_scope("zoo_attn_core"):
-            o = flash_attention(q, k, v, causal=True,
-                                sm_scale=1.0 / math.sqrt(d))
+            if self.window is None:
+                o = attend(q, k, v)
+            else:
+                with jax.named_scope("zoo_attn_window"):
+                    o = attend(q, k, v, window=self.window)
         with jax.named_scope("zoo_mixer_proj"):
-            o = tr(o).reshape(b, l, n * d) * jax.nn.sigmoid(
-                gate.astype(jnp.float32)).astype(x.dtype)
+            o = tr(o).reshape(b, l, n * d)
+            if self.gated:
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    x.dtype)
             return o @ params["w_o"]
 
 
@@ -558,7 +630,8 @@ class HeldExpertsMoE(KerasLayer):
 
 
 MIXERS = {LINEAR: GatedDeltaNet, FULL: GatedAttention,
-          KDA: KimiDeltaAttention, LATENT: LatentAttention}
+          SLIDING: GatedAttention, KDA: KimiDeltaAttention,
+          LATENT: LatentAttention}
 
 
 def _ff_key(ff) -> str:

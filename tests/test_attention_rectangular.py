@@ -119,7 +119,8 @@ def test_flash_blhd_entry_rectangular_causal(lq, lk):
 # Pallas kernels in interpret mode: bottom-right-aligned causal mask
 # ---------------------------------------------------------------------------
 
-def _rectangle(lq, lk, block_q, block_k, group=1, key_outer=False):
+def _rectangle(lq, lk, block_q, block_k, group=1, key_outer=False,
+               window=None):
     """Every (query block, key block) pair in the rectangle's order, as the
     tables of :func:`_causal_walk`: the grid before causal calls walked
     only the live pairs."""
@@ -602,3 +603,275 @@ def test_route_eligible_rectangular_causal(monkeypatch):
     assert not _route_eligible(True, kb, 512, 128, 64, True)
     # non-causal rectangular was always eligible either way
     assert _route_eligible(True, kb, 512, 128, 64, False)
+
+
+# ---------------------------------------------------------------------------
+# a sliding window: each row sees its ``window`` latest keys, itself
+# included, through every route
+# ---------------------------------------------------------------------------
+
+def _band_mask(lq, lk, window):
+    """The window's mask written out: row i (at position lk - lq + i)
+    sees key j when 0 <= position - j < window."""
+    pos = lk - lq + np.arange(lq)[:, None]
+    gap = pos - np.arange(lk)[None, :]
+    return jnp.asarray((gap >= 0) & (gap < window))
+
+
+def _masked_reference(q, k, v, kb, mask, sm, group):
+    """Softmax attention with an explicit (Lq, Lk) mask and a key bias,
+    ``group`` query heads a key/value head."""
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * sm + kb[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+@pytest.mark.parametrize("h,hkv,lq,lk,d,dv,window,blocks", [
+    # grouped heads, a window that is no multiple of the blocks
+    pytest.param(4, 2, 384, 384, 64, 64, 200, (128, 128), id="gqa-200"),
+    pytest.param(2, 2, 384, 384, 64, 64, 128, (128, 128), id="window-128"),
+    # the forward's two bodies: tiles straddle both edges of the band
+    pytest.param(2, 1, 512, 512, 64, 64, 300, (128, 256), id="128x256-300"),
+    pytest.param(2, 2, 512, 512, 192, 128, 250, (128, 128),
+                 id="keys192-values128"),
+    # keys before the first row's band: key blocks no row sees
+    pytest.param(2, 1, 256, 768, 64, 64, 100, (128, 128), id="rectangular"),
+])
+def test_a_window_through_the_kernels_matches_a_masked_reference(
+        monkeypatch, h, hkv, lq, lk, d, dv, window, blocks):
+    """The forward and both backward forms (one fused kernel, and dq
+    beside dk, dv) with a window and a key bias, against softmax attention
+    under the band's mask written out; ``attention_reference`` and the
+    blockwise route agree with it too."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    from analytics_zoo_tpu.ops import attention as A
+
+    b, group = 1, h // hkv
+    bq, bk = blocks
+    q = _rand(0, (b, h, lq, d))
+    k = _rand(1, (b, hkv, lk, d))
+    v = _rand(2, (b, hkv, lk, dv))
+    kb = _rand(3, (b, lk))
+    do = _rand(4, (b, h, lq, dv))
+    sm = 1.0 / np.sqrt(d)
+    mask = _band_mask(lq, lk, window)
+
+    def ref_loss(q, k, v, kb):
+        return (_masked_reference(q, k, v, kb, mask, sm, group) * do).sum()
+
+    ref = jax.jit(lambda *a: _masked_reference(*a, mask, sm, group))(
+        q, k, v, kb)
+    want = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2, 3)))(q, k, v, kb)
+
+    rep = lambda t: jnp.repeat(t, group, axis=1)
+    for route in (A.attention_reference, A.attention_blockwise):
+        got = jax.jit(lambda q, k, v, kb: route(
+            q, rep(k), rep(v), bias=kb[:, None, None, :], causal=True,
+            sm_scale=sm, window=window))(q, k, v, kb)
+        assert float(jnp.abs(got - ref).max()) < 1e-5, route.__name__
+
+    flat = lambda t: t.reshape((-1,) + t.shape[2:])
+    qf, kf, vf, dof = flat(q), flat(k), flat(v), flat(do)
+    o, lse = jax.jit(lambda q, k, v, kb: A._flash_forward(
+        q, k, v, kb, h, True, sm, bq, bk, group, window))(qf, kf, vf, kb)
+    assert float(jnp.abs(o.reshape(ref.shape) - ref).max()) < 1e-5
+    for limit in (A.FUSED_BWD_DQ_BYTES, 0):
+        monkeypatch.setattr(A, "FUSED_BWD_DQ_BYTES", limit)
+        grads = jax.jit(lambda *a: A._flash_backward(
+            *a, h, True, sm, bq, bk, group, window))(qf, kf, vf, kb, o, lse,
+                                                     dof)
+        for got, exp in zip(grads, want):
+            assert float(jnp.abs(got.reshape(exp.shape) - exp).max()) < 2e-4
+    # the window changes the answer: not a causal call under another name
+    causal = jax.jit(lambda *a: _masked_reference(
+        *a, _band_mask(lq, lk, lk), sm, group))(q, k, v, kb)
+    assert float(jnp.abs(causal - ref).max()) > 1e-2
+
+
+@pytest.mark.parametrize("window", [256, 4096])
+def test_a_window_of_every_key_is_the_causal_call_to_the_bit(monkeypatch,
+                                                             window):
+    """A window of ``lk`` keys or more hides nothing: the kernels' and the
+    blockwise route's outputs and gradients are the causal call's bits."""
+    monkeypatch.setenv("ZOO_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
+    from analytics_zoo_tpu.ops import attention as A
+
+    q = _rand(0, (1, 4, 256, 64))
+    k = _rand(1, (1, 2, 256, 64))
+    v = _rand(2, (1, 2, 256, 64))
+
+    def both(route, **kw):
+        loss = lambda q, k, v: (route(q, k, v, causal=True, block_q=128,
+                                      block_k=128, **kw) ** 2).sum()
+        return (jax.jit(lambda q, k, v: route(
+            q, k, v, causal=True, block_q=128, block_k=128, **kw))(q, k, v),
+        ) + jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    rep = lambda t: jnp.repeat(t, 2, axis=1)
+    blockwise = lambda q, k, v, **kw: A.attention_blockwise(
+        q, rep(k), rep(v), **kw)
+    for route in (A.flash_attention, blockwise):
+        for got, want in zip(both(route, window=window), both(route)):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _band_walk(lq, lk, block_q, block_k, window, group=1, key_outer=False):
+    """The walk read off the band's mask: the pairs whose tile holds a
+    score the band keeps, and for a key block no row sees one step with
+    the last query block."""
+    num_q, num_k = lq // block_q, lk // block_k
+    tiles = np.asarray(_band_mask(lq, lk, window)).reshape(
+        num_q, block_q, num_k, block_k).any(axis=(1, 3))
+    if key_outer:
+        tiles[-1] |= ~tiles.any(axis=0)
+        pairs = [(ki, g * num_q + qi) for ki in range(num_k)
+                 for g in range(group) for qi in range(num_q)
+                 if tiles[qi, ki]]
+    else:
+        pairs = [(qi, ki) for qi in range(num_q) for ki in range(num_k)
+                 if tiles[qi, ki]]
+    outer = [p[0] for p in pairs]
+    n = len(pairs)
+    first = [t == 0 or outer[t - 1] != outer[t] for t in range(n)]
+    last = [t == n - 1 or outer[t + 1] != outer[t] for t in range(n)]
+    return [np.asarray(c, np.int32) for c in
+            (outer, [p[1] for p in pairs], first, last)]
+
+
+@pytest.mark.parametrize("b,h,hkv,lq,lk,d,window,steps", [
+    # the sliding layers of a Mellum2 share: 8,192 positions in blocks of
+    # 512 queries by 1,024 keys, a window of 1,024: 30 of a head's 128
+    # steps (72 causal); dk, dv walk 8 query heads' 30
+    pytest.param(2, 32, 4, 8192, 8192, 128, 1024,
+                 {"zoo_flash_window_fwd": (64, 30),
+                  "zoo_flash_window_bwd_dq": (64, 30),
+                  "zoo_flash_window_bwd_dkv": (8, 240)}, id="sliding-layer"),
+    pytest.param(1, 2, 1, 512, 1536, 64, 300,
+                 {"zoo_flash_window_fwd": (2, 2),
+                  "zoo_flash_window_bwd_dq_dkv": (1, 6)}, id="rectangular"),
+])
+def test_a_window_walks_only_the_bands_blocks(b, h, hkv, lq, lk, d, window,
+                                              steps):
+    """Traced, not run: each kernel's walk against the band read off its
+    mask, under the window's kernel names, and the walk's counters: the
+    steps walked, those left out of the rectangle, and the masked ones
+    (at 1,024-key blocks the forward masks every tile in one body)."""
+    from analytics_zoo_tpu.ops import attention as A
+    from analytics_zoo_tpu.utils import telemetry
+
+    group = h // hkv
+    S = jax.ShapeDtypeStruct
+    args = (S((b * h, lq, d), jnp.bfloat16), S((b * hkv, lk, d), jnp.bfloat16),
+            S((b * hkv, lk, d), jnp.bfloat16), S((b, lk), jnp.float32),
+            S((b * h, lq, d), jnp.bfloat16), S((b * h, lq, 1), jnp.float32),
+            S((b * h, lq, d), jnp.bfloat16))
+
+    def both(q, k, v, kb, o, lse, do):
+        return (A._flash_forward(q, k, v, kb, h, True, 0.1, None, None,
+                                 group, window),
+                A._flash_backward(q, k, v, kb, o, lse, do, h, True, 0.1,
+                                  None, None, group, window))
+
+    def counts():
+        return {(kind, name): telemetry.counter(
+            f"zoo_flash_grid_steps{kind}_total", kernel=name).value
+            for kind in ("", "_skipped", "_masked") for name in steps}
+
+    before = counts()
+    closed = jax.make_jaxpr(both)(*args)
+    after = counts()
+    consts = dict(zip(closed.jaxpr.constvars, closed.consts))
+    calls = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert sorted(e.params["name"] for e in calls) == sorted(steps)
+    bq, bk = A._resolve_blocks(lq, lk, None, None, d)
+    num_q, num_k = lq // bq, lk // bk
+    for e in calls:
+        name = e.params["name"]
+        rows, per_row = steps[name]
+        key_outer = not name.endswith(("_fwd", "_bwd_dq"))
+        assert e.params["grid_mapping"].grid == (rows, per_row)
+        tables = [np.asarray(consts[x]) for x in e.invars[:4]]
+        for got, want in zip(tables, _band_walk(lq, lk, bq, bk, window,
+                                                group, key_outer)):
+            np.testing.assert_array_equal(got, want)
+        rectangle = num_q * num_k * (group if key_outer else 1)
+        delta = {kind: after[(kind, name)] - before[(kind, name)]
+                 for kind in ("", "_skipped", "_masked")}
+        assert delta[""] == rows * per_row
+        assert delta["_skipped"] == rows * (rectangle - per_row)
+        two = name.endswith("_fwd") and \
+            A._masks_only_straddling_tiles(bk, d)
+        if not two:
+            assert delta["_masked"] == rows * per_row
+
+
+@pytest.mark.parametrize("lq,lk,block_q,block_k,window,group", [
+    # a key block that ends one key before the first row's band
+    pytest.param(1024, 1024, 128, 128, 129, 1, id="one-key-short"),
+    pytest.param(1024, 1024, 128, 256, 300, 2, id="128x256-groups-2"),
+    pytest.param(2048, 2048, 512, 1024, 1024, 8, id="sliding-layer"),
+    pytest.param(256, 1024, 128, 128, 200, 1, id="unseen-key-blocks"),
+    pytest.param(512, 512, 128, 128, 1, 1, id="window-1"),
+])
+def test_the_band_walk_is_read_off_the_mask(lq, lk, block_q, block_k, window,
+                                            group):
+    """:func:`_causal_walk` with a window, both orders, against the walk
+    read off the band's mask, key blocks no row sees included."""
+    from analytics_zoo_tpu.ops import attention as A
+
+    for key_outer in (False, True):
+        got = A._causal_walk(lq, lk, block_q, block_k, group, key_outer,
+                             window)
+        want = _band_walk(lq, lk, block_q, block_k, window, group, key_outer)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("lq,lk,block_q,block_k,window", [
+    pytest.param(1024, 1024, 128, 128, 200, id="square-200"),
+    pytest.param(1024, 1024, 128, 256, 128, id="128x256-128"),
+    pytest.param(8192, 8192, 512, 1024, 1024, id="sliding-layer"),
+    pytest.param(256, 1024, 128, 128, 300, id="offset-300"),
+    pytest.param(6, 9, 2, 3, 4, id="unaligned"),
+])
+def test_a_tile_straddles_where_an_edge_of_the_band_changes_a_score(
+        lq, lk, block_q, block_k, window):
+    """:func:`_tile_straddles` with a window against the band's mask over
+    every tile of the walk: true exactly where some score is masked, the
+    lower edge's tiles among them."""
+    from analytics_zoo_tpu.ops import attention as A
+
+    off = lk - lq
+    qi, ki, _, _ = A._causal_walk(lq, lk, block_q, block_k, window=window)
+    rows = off + np.arange(block_q)[:, None]
+    cols = np.arange(block_k)[None, :]
+    masked, lower = [], []
+    for i, j in zip(qi, ki):
+        gap = rows + i * block_q - (j * block_k + cols)
+        masked.append(not np.all((gap >= 0) & (gap < window)))
+        lower.append(bool(np.any(gap >= window)))
+    got = A._tile_straddles(qi, ki, block_q, block_k, off, window)
+    np.testing.assert_array_equal(got, masked)
+    assert any(lower)
+    if lq == lk:
+        assert not A._tile_straddles(qi, ki, block_q, block_k, off).all() \
+            or all(masked)
+
+
+@pytest.mark.parametrize("causal,window,lq,lk", [
+    (False, 64, 512, 512), (True, 0, 512, 512), (True, -3, 256, 1024)])
+def test_route_eligible_refuses_a_window_that_is_no_band(monkeypatch, causal,
+                                                         window, lq, lk):
+    """A window needs causal and at least one key: the route says so
+    loudly, naming the call's shape, and so does every entry."""
+    monkeypatch.setenv("ZOO_TPU_FORCE_PALLAS", "1")
+    with pytest.raises(ValueError, match=f"window {window} at lengths "
+                                         f"{lq} x {lk}"):
+        _route_eligible(True, object(), lq, lk, 64, causal, window=window)
+    q = jnp.zeros((1, 1, lq, 64))
+    k = jnp.zeros((1, 1, lk, 64))
+    for route in (flash_attention, attention_blockwise, attention_reference):
+        with pytest.raises(ValueError, match="a window is the band"):
+            route(q, k, k, causal=causal, window=window)

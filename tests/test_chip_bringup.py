@@ -263,20 +263,40 @@ def test_every_flash_kernels_name_is_found_by_the_rooflines_pattern():
     under a name the pattern misses would drop out of the roofline's
     seconds and raise the share. Every ``name=`` and every scope the
     module can emit is found, and each call sits in the scope of its
-    name."""
+    name. A call with a sliding window names its kernels apart
+    (``_kernel_name``): the window's roofline finds those and the full
+    calls' patterns do not."""
     import json
+
+    from analytics_zoo_tpu.ops.attention import _kernel_name
 
     with open(os.path.join(REPO, "analytics_zoo_tpu/ops/attention.py")) as f:
         source = f.read()
-    with open(os.path.join(
-            REPO, "benchmark/metrics/flash_attn_roofline.json")) as f:
-        pattern = json.load(f)["args"]["pattern"]
-    names = re.findall(r'name="(zoo_[a-z_]+)"', source)
-    scopes = re.findall(r'named_scope\("(zoo_[a-z_]+)"\)', source)
-    assert sorted(names) == sorted(scopes) == [
+
+    def pattern(metric):
+        with open(os.path.join(REPO, "benchmark/metrics",
+                               metric + ".json")) as f:
+            return json.load(f)["args"]["pattern"]
+
+    kinds = re.findall(r'name=_kernel_name\("([a-z_]+)", window\)', source)
+    scopes = re.findall(
+        r'named_scope\(_kernel_name\("([a-z_]+)", window\)\)', source)
+    assert sorted(kinds) == sorted(scopes) == [
+        "bwd_dkv", "bwd_dq", "bwd_dq_dkv", "fwd"]
+    names = [_kernel_name(kind, None) for kind in kinds]
+    assert sorted(names) == [
         "zoo_flash_bwd_dkv", "zoo_flash_bwd_dq", "zoo_flash_bwd_dq_dkv",
         "zoo_flash_fwd"]
-    assert all(re.search(pattern, name) for name in names)
+    assert all(re.search(pattern("flash_attn_roofline"), name)
+               for name in names)
+    for kind, name in zip(kinds, names):
+        band = _kernel_name(kind, 1024)
+        assert band == f"zoo_flash_window_{kind}"
+        assert re.fullmatch(pattern("window_flash_roofline.mellum"), band)
+        for full in ("full_flash_roofline.mellum",
+                     "mla_flash_roofline.joyai"):
+            assert re.fullmatch(pattern(full), name)
+            assert not re.fullmatch(pattern(full), band)
 
 
 @pytest.mark.parametrize("n,d,dtype", [

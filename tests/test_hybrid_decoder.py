@@ -388,7 +388,7 @@ def test_flash_kernels_take_grouped_heads(monkeypatch, heads, kv_heads, l):
     calls = []
     real = A._flash_attention_bhld
     monkeypatch.setattr(A, "_flash_attention_bhld",
-                        lambda *a: calls.append(a[-1]) or real(*a))
+                        lambda *a: calls.append(a[9]) or real(*a))
 
     def plain(q, k, v):
         rep = lambda t: jnp.repeat(t, heads // kv_heads, axis=1)

@@ -1,7 +1,7 @@
 """Every op of the trainer's fused step lies under a ``zoo_*`` role scope
 (docs/observability.md#names), so that the benchmark's device trace splits
-a step by layer: toy-size BERT and three hybrid decoders like the
-benchmark's cells (Qwen3-Next, Kimi Linear, JoyAI-LLM-Flash), traced
+a step by layer: toy-size BERT and four hybrid decoders like the
+benchmark's cells (Qwen3-Next, Kimi Linear, JoyAI-LLM-Flash, Mellum2), traced
 through ``build_multi_step`` on the kernels' route as the chip takes it.
 
 The walk goes into every nested jaxpr (scan, checkpoint, custom rules,
@@ -167,6 +167,22 @@ def joyai_flash():
         dense_size=256, remat_rows=1, name="decoder"), mtp=True)
 
 
+def mellum2():
+    """Three sliding-window layers and a full one of plain grouped-query
+    attention, YaRN on the full one, an expert layer with no shared
+    expert in every block."""
+    attention = dict(n_head=2, n_kv_head=1, head_dim=64, rotary_dim=64,
+                     gated=False)
+    yarn = {"rope_type": "yarn", "rope_theta": 5e5, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782}
+    return _lm(hd.HybridDecoder(
+        vocab=256, hidden_size=128, layer_types=[hd.SLIDING] * 3 + [hd.FULL],
+        mixers={hd.SLIDING: dict(attention, window=64, rope_theta=5e5),
+                hd.FULL: dict(attention, rope_parameters=yarn)},
+        moe=dict(MOE, shared_size=0), remat_rows=1, name="decoder"))
+
+
 def bert():
     """The encoder as the benchmark's classifier holds it, its pooled
     output straight into the loss: the classifier's own ``Dense`` is a
@@ -191,7 +207,7 @@ def bert():
 
 
 MODELS = {"bert": bert, "qwen3_next": qwen3_next, "kimi_linear": kimi_linear,
-          "joyai_flash": joyai_flash}
+          "joyai_flash": joyai_flash, "mellum2": mellum2}
 ENGINE = {"zoo_optimizer", "zoo_loss", "zoo_embed", "zoo_norm"}
 HYBRID = ENGINE | {"zoo_lm_loss"}
 ROLES = {
@@ -208,6 +224,10 @@ ROLES = {
     "joyai_flash": HYBRID | {"zoo_mla_proj", "zoo_mla_attn", "zoo_dense_mlp",
                              "zoo_moe_route", "zoo_moe_experts",
                              "zoo_moe_bias", "zoo_mtp"},
+    "mellum2": HYBRID | {"zoo_mixer_proj", "zoo_attn_core", "zoo_attn_window",
+                         "zoo_flash_window_fwd", "zoo_flash_window_bwd_dq_dkv",
+                         "zoo_flash_fwd", "zoo_flash_bwd_dq_dkv",
+                         "zoo_moe_route", "zoo_moe_experts"},
 }
 
 
@@ -259,7 +279,7 @@ def test_each_role_of_the_model_appears(jaxprs, name):
 
 
 @pytest.mark.parametrize("name", ["qwen3_next", "kimi_linear",
-                                  "joyai_flash"])
+                                  "joyai_flash", "mellum2"])
 def test_what_the_row_loop_sums_is_parameter_gradients(jaxprs, name):
     """The one piece left out of the check is what it says: each sum is
     of a parameter's shape; the decoder's carry no tag (the head's loop
