@@ -59,7 +59,7 @@ KDA, LATENT = "kimi_delta_attention", "latent_attention"
 # (a gauge keeps its last value): pipeline/engine.py ``_collect_step_stats``
 MOE_STATS = ("zoo_moe_assignments_total", "zoo_moe_assignments_held_total",
              "zoo_moe_dropped_total", "zoo_moe_held_load_max_over_mean",
-             "zoo_moe_tiles_total")
+             "zoo_moe_tiles_total", "zoo_moe_padded_rows_total")
 # what a router that balances its bias adds: the largest count of its
 # experts over their mean, which is what the rule acts on
 ROUTER_LOAD = "zoo_moe_router_load_max_over_mean"
@@ -482,8 +482,8 @@ class HeldExpertsMoE(KerasLayer):
     for every assignment that lands on them: there is no capacity and
     nothing is dropped (``ops/grouped_experts.py``).
     With ``n_held == n_routed`` it is the whole layer. ``tile``: the rows
-    of a tile of the expert loop; left out, what the call's tokens make
-    of it (``expected_tile``). Its state: what it reports of its routing
+    of an expert tile; left out, what the call's tokens make of it
+    (``expected_tile``). Its state: what it reports of its routing
     each step (``MOE_STATS``; a balanced router ``ROUTER_LOAD`` too) and
     the balanced bias. (..., H) -> (..., H)."""
 
@@ -606,7 +606,8 @@ class HeldExpertsMoE(KerasLayer):
                 jnp.asarray(float(top_i.size), f32), held,
                 held - jnp.sum(tables.tile_rows).astype(f32),
                 jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
-                tables.n_tiles.astype(f32))))
+                tables.n_tiles.astype(f32),
+                tables.n_tiles.astype(f32) * tile - held)))
         router_counts = None
         if self.balanced:
             with jax.named_scope("zoo_moe_bias"):

@@ -245,6 +245,50 @@ def test_kimi_linear_step_holds_the_kernels_the_layout_predicts(on_tpu):
         "zoo_flash_fwd": 1, "zoo_flash_bwd_dq_dkv": 1}
 
 
+@pytest.mark.parametrize("h,f,held,k,routed", [
+    (2304, 896, 16, 8, 64),      # `mellum2_pretrain_l8192`: 16 of 64
+    (2048, 768, 16, 8, 256),     # `joyaiflash_pretrain_l8192`: 16 of 256
+])
+def test_expert_kernels_cross_lower_under_the_experts_scope(on_tpu, h, f,
+                                                            held, k, routed):
+    """The expert layer of a hybrid cell, one sequence of 8,192 a call:
+    its forward and backward lowered for the TPU hold the three grouped
+    kernels once each and the combining kernel twice (the output, the
+    tokens' cotangent), and every call's ``op_name`` holds
+    ``zoo_moe_experts``, which the expert rooflines and device shares
+    (pattern unchanged) read."""
+    import json
+
+    from analytics_zoo_tpu.ops import grouped_experts as GE
+    from analytics_zoo_tpu.utils.profiling import mosaic_kernel_counts
+
+    n, bf = 8192, jnp.bfloat16
+    tile = GE.expected_tile(n, k, routed)
+    s = jax.ShapeDtypeStruct
+    args = (s((n, h), bf), s((held, h, f), bf), s((held, h, f), bf),
+            s((held, f, h), bf), s((n, k), jnp.float32))
+    ids = s((n, k), jnp.int32)
+
+    def loss(x, w_gate, w_up, w_down, top_w, top_i):
+        tables = GE.route_tables(top_i, 0, held, tile)
+        return GE.grouped_experts(x, w_gate, w_up, w_down, top_w, tables,
+                                  tile).astype(jnp.float32).sum()
+
+    sites = _call_sites(_tpu_lowered(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), *args, ids))
+    assert mosaic_kernel_counts("\n".join(sites)) == {
+        "zoo_moe_gmm_fwd": 1, "zoo_moe_gmm_bwd": 1, "zoo_moe_gmm_dw": 1,
+        "zoo_moe_combine": 2}
+    tags = [re.findall(r"zoo_[a-z0-9_]+", site) for site in sites]
+    for metric in ("moe_experts_roofline", "moe_device_share_pct"):
+        for suffix in ("", ".kimi", ".joyai", ".mellum"):
+            with open(os.path.join(REPO, "benchmark/metrics",
+                                   metric + suffix + ".json")) as fh:
+                pattern = json.load(fh)["args"]["pattern"]
+            assert all(any(re.fullmatch(pattern, t) for t in site)
+                       for site in tags), (metric + suffix, tags)
+
+
 def test_blhd_entry_cross_lowers_through_the_bhld_kernel(on_tpu):
     """The layer's default entry: (B, L, H, d) in, the bhld kernels
     underneath."""
